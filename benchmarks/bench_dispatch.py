@@ -38,7 +38,10 @@ def bwd_rate(x, y, n=100):
 
 def main():
     import paddle_tpu as paddle
+    from paddle_tpu.framework.device import enable_compile_cache
     from paddle_tpu.ops import enable_dispatch_cache
+
+    enable_compile_cache()
 
     x = paddle.to_tensor(np.random.rand(16).astype(np.float32),
                          stop_gradient=False)

@@ -23,6 +23,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 def main():
     from paddle_tpu.distributed.ps import SparseTable
     from paddle_tpu.distributed.ps.heter import HotRowCache
+    from paddle_tpu.framework.device import enable_compile_cache
+
+    enable_compile_cache()
 
     for n_keys in (1_000, 10_000, 100_000):
         dim = 16

@@ -49,7 +49,10 @@ def main():
 
     from paddle_tpu.distributed.fleet.meta_parallel.sequence_parallel \
         import context_parallel_attention
+    from paddle_tpu.framework.device import enable_compile_cache
     from paddle_tpu.ops import pallas
+
+    enable_compile_cache()
 
     n_dev = args.devices or len(jax.devices())
     if n_dev > len(jax.devices()):

@@ -98,20 +98,13 @@ import numpy as np
 def _force_device_count(n):
     """Make >= n devices visible BEFORE the jax backend initializes.
 
-    Newer jax exposes a config knob; older ones only honor the XLA
-    flag, which must be in the environment before first device use
-    (importing jax is fine, touching jax.devices() is not).  Only
+    Importing jax is fine, touching jax.devices() is not.  Only
     meaningful on CPU-only hosts — on a real multichip platform the
-    host-platform flag changes nothing.
+    CPU device count changes nothing.
     """
     import jax
 
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            f"{flags} --xla_force_host_platform_device_count={int(n)}")
+    jax.config.update("jax_num_cpu_devices", int(n))
 
 
 def _build_engine(max_batch, seed=0, max_model_len=64,
@@ -544,6 +537,10 @@ def main():
         _force_device_count(args.tp)
 
     import jax
+
+    from paddle_tpu.framework.device import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.sim:
         return _main_sim(args, jax)

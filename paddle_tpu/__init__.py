@@ -9,8 +9,6 @@ as subpackages.  The compute path is jax; the eager frontend records a tape
 
 __version__ = "0.1.0"
 
-from .framework import jax_compat as _jax_compat  # noqa: F401  (shims first)
-
 from .core.tensor import Tensor, to_tensor  # noqa: F401
 
 from .framework import (  # noqa: F401

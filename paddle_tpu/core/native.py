@@ -4,33 +4,60 @@ The reference keeps its host-side runtime (TCPStore rendezvous, flag
 registry, memory stats — SURVEY §2.2/§2.6) in C++; so do we.  The library is
 built on demand with g++ (toolchain is guaranteed in the image) and cached
 next to the sources; if compilation is impossible the Python fallbacks in
-``distributed.store`` keep everything working.
+``distributed.store`` keep everything working — with a warning, and
+:func:`status` says which of the two a process ended up with.
+
+The ``.so`` is not tracked by git, so a fresh checkout builds it; whether
+a cached one is current is decided by the CONTENT of the sources (a hash
+stamped beside the library), not by mtimes, which any copy reorders.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libpaddle_native.so")
+_STAMP_PATH = _LIB_PATH + ".srchash"
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_error = None
+_built_here = False
+
+
+def _source_hash():
+    h = hashlib.sha256()
+    for f in sorted(os.listdir(_NATIVE_DIR)):
+        if f.endswith((".cc", ".h")) or f == "Makefile":
+            h.update(f.encode())
+            with open(os.path.join(_NATIVE_DIR, f), "rb") as fh:
+                h.update(fh.read())
+    return h.hexdigest()
 
 
 def _stale():
     if not os.path.exists(_LIB_PATH):
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    for f in os.listdir(_NATIVE_DIR):
-        if f.endswith((".cc", ".h")):
-            if os.path.getmtime(os.path.join(_NATIVE_DIR, f)) > lib_mtime:
-                return True
-    return False
+    try:
+        with open(_STAMP_PATH) as fh:
+            return fh.read().strip() != _source_hash()
+    except OSError:
+        return True
+
+
+def _build():
+    # -B: make compares mtimes too, and would keep a stale library
+    # whose copy happens to look newer than its sources
+    subprocess.run(["make", "-s", "-B"], cwd=_NATIVE_DIR, check=True,
+                   capture_output=True, timeout=300)
+    with open(_STAMP_PATH, "w") as fh:
+        fh.write(_source_hash())
 
 
 def _bind(lib):
@@ -87,9 +114,9 @@ def load():
     Search order: (1) the wheel-installed copy inside the package
     (``paddle_tpu/native/`` — placed there by setup.py's build_py hook),
     (2) the source checkout's ``native/`` directory, rebuilding on demand
-    when sources are newer than the .so.
+    when the sources no longer hash to the stamp beside the .so.
     """
-    global _lib, _build_error
+    global _lib, _build_error, _built_here
     with _lib_lock:
         if _lib is not None or _build_error is not None:
             return _lib
@@ -99,12 +126,16 @@ def load():
                 _lib = _bind(ctypes.CDLL(_PKG_LIB_PATH))
             else:
                 if _stale():
-                    subprocess.run(["make", "-s"], cwd=_NATIVE_DIR,
-                                   check=True, capture_output=True,
-                                   timeout=120)
+                    _build()
+                    _built_here = True
                 _lib = _bind(ctypes.CDLL(_LIB_PATH))
         except Exception as e:  # missing toolchain / RO filesystem
             _build_error = e
+            detail = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                f"native runtime unavailable, Python fallbacks in use: "
+                f"{e!r} {detail.decode(errors='replace')[-400:]}",
+                RuntimeWarning, stacklevel=2)
             return None
     # replay Python-side flags set before the library existed
     try:
@@ -118,6 +149,15 @@ def load():
 
 def available():
     return load() is not None
+
+
+def status():
+    """One line for a run's log: the library was built by this process,
+    found up to date, or could not be had (the Python fallbacks run)."""
+    if load() is None:
+        return f"python fallback ({_build_error!r})"
+    return ("built and loaded" if _built_here
+            else "loaded (cached build matches the sources)")
 
 
 def loaded():

@@ -13,8 +13,9 @@ FLOPs / chip + collective bytes over ICI), with a measured refinement
 (profile-based tuner parity) that jit-compiles the best K candidates on
 the live (or virtual) mesh and times one step.  Chip capabilities come
 from the attached device kind (``ClusterSpec.from_devices``) instead of
-the reference's hand-written cluster json, with a measured-calibration
-fallback for unknown parts.
+the reference's hand-written cluster json.  Off the TPU (CPU meshes) a
+measured matmul calibrates the FLOP rate; an unknown TPU kind is an
+error, never a nominal constant.
 """
 
 import math
@@ -35,15 +36,16 @@ __all__ = ["ClusterSpec", "CostEstimator", "ParallelTuner", "Mapper"]
 #   -> ~9.8e10 usable per direction; v6e: ~9.0e10).  These are ANALYTIC
 #   RANKING constants, not promises: refine() re-times the top-K
 #   candidates with real compiled steps, so a constant being 2x off can
-#   reorder the shortlist but not the final choice; unknown kinds
-#   calibrate flops by a measured matmul instead of trusting a table.
+#   reorder the shortlist but not the final choice.
+# Keys are ``device_kind.lower()`` as libtpu 0.0.34 reports it: v5e is
+# "TPU v5 lite", v5p is "TPU v5", v6e is "TPU v6 lite".
 _DEVICE_KINDS = {
-    "tpu v4":  dict(flops_bf16=275e12, hbm_bytes=32e9, ici_bandwidth=1.2e11),
-    "tpu v5e": dict(flops_bf16=197e12, hbm_bytes=16e9, ici_bandwidth=4.5e10),
-    "tpu v5p": dict(flops_bf16=459e12, hbm_bytes=95e9, ici_bandwidth=9.8e10),
-    "tpu v5":  dict(flops_bf16=459e12, hbm_bytes=95e9, ici_bandwidth=9.8e10),
-    "tpu v6e": dict(flops_bf16=918e12, hbm_bytes=32e9, ici_bandwidth=9.0e10),
-    "tpu v6":  dict(flops_bf16=918e12, hbm_bytes=32e9, ici_bandwidth=9.0e10),
+    "tpu v4": dict(flops_bf16=275e12, hbm_bytes=32e9, ici_bandwidth=1.2e11),
+    "tpu v5 lite": dict(flops_bf16=197e12, hbm_bytes=16e9,
+                        ici_bandwidth=4.5e10),
+    "tpu v5": dict(flops_bf16=459e12, hbm_bytes=95e9, ici_bandwidth=9.8e10),
+    "tpu v6 lite": dict(flops_bf16=918e12, hbm_bytes=32e9,
+                        ici_bandwidth=9.0e10),
 }
 
 
@@ -52,9 +54,9 @@ class ClusterSpec:
 
     ``ClusterSpec()`` auto-detects from ``jax.devices()[0].device_kind``
     (+ ``memory_stats()`` for the real HBM budget when the runtime exposes
-    it); unknown kinds (CPU hosts, future parts) fall back to a measured
-    matmul calibration so the tuner never ranks with fictional constants.
-    Explicit keyword overrides always win.
+    it).  A CPU host calibrates by a measured matmul so the tuner never
+    ranks with fictional constants; a TPU kind missing from the table
+    raises.  Explicit keyword overrides always win.
     """
 
     def __init__(self, num_devices=None, hbm_bytes=None, flops_bf16=None,
@@ -66,6 +68,11 @@ class ClusterSpec:
         self.device_kind = getattr(devices[0], "device_kind", "cpu")
         base = _DEVICE_KINDS.get(self.device_kind.lower())
         if base is None:
+            if devices[0].platform == "tpu":
+                raise ValueError(
+                    f"no peaks known for TPU device_kind "
+                    f"{self.device_kind!r} (known: "
+                    f"{sorted(_DEVICE_KINDS)}); add it with its source")
             base = dict(flops_bf16=None, hbm_bytes=None, ici_bandwidth=2e10)
         self.flops_bf16 = flops_bf16 or base["flops_bf16"]
         self.hbm_bytes = hbm_bytes or base["hbm_bytes"]
@@ -169,7 +176,7 @@ class ClusterSpec:
 class CostEstimator:
     """Analytic memory + step-time estimate for one parallel config.
 
-    Model taxonomy follows the reference comp/comm CostEstimator
+    Model classification follows the reference comp/comm CostEstimator
     (static/cost/estimate_cost.py): per-op compute from FLOPs, comm from
     collective bytes x bandwidth, memory from param/grad/optimizer-state
     + activation partitioning.  Extends the reference's (dp, mp, pp)

@@ -5,8 +5,11 @@ per-rank env, master KV rendezvous, log dirs per rank).  TPU redesign: on a
 TPU pod each *host* runs ONE process (single-controller per host, jax
 multi-host runtime); the launcher's job is rank env + rendezvous via the
 native TCPStore (rank 0 hosts) + log aggregation.  ``--nproc_per_node`` > 1
-is supported for CPU testing (the reference's multi-process-per-box test
-pattern, SURVEY §4.2).
+is for CPU runs (``JAX_PLATFORMS=cpu`` — the reference's
+multi-process-per-box test pattern, SURVEY §4.2) and is refused
+otherwise: every local rank would get the same chips, and a chip
+belongs to one process.  The launcher itself never initializes a JAX
+backend, so its one rank per host finds the chips free.
 """
 
 import argparse
@@ -70,6 +73,11 @@ def _rank_env(args, local_rank, world_size, master):
 def launch(argv=None):
     args = _parse_args(argv)
     world_size = args.nnodes * args.nproc_per_node
+    from ...framework.device import refuse_chip_sharing
+    try:
+        refuse_chip_sharing(args.nproc_per_node, "--nproc_per_node")
+    except RuntimeError as e:
+        raise SystemExit(str(e)) from None
 
     store = None
     if args.master is None:

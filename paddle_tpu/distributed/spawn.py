@@ -1,6 +1,8 @@
 """paddle.distributed.spawn parity (reference
 python/paddle/distributed/spawn.py): run ``func`` in nprocs subprocesses
-with per-rank env, joined at the end."""
+with per-rank env, joined at the end.  Refused where the workers would
+share a TPU chip with each other or with this process
+(``framework.device.refuse_chip_sharing``)."""
 
 import multiprocessing as mp
 import os
@@ -14,8 +16,11 @@ def _worker(func, rank, nprocs, master_port, args):
 
 
 def spawn(func, args=(), nprocs=1, join=True, daemon=False, **options):
-    ctx = mp.get_context("spawn")
+    from ..framework.device import refuse_chip_sharing
     from .store import TCPStore
+
+    refuse_chip_sharing(nprocs, "distributed.spawn")
+    ctx = mp.get_context("spawn")
 
     store = TCPStore("127.0.0.1", 0, is_master=True, world_size=nprocs)
     procs = []

@@ -725,7 +725,7 @@ class _CompileKeyLog(logging.Handler):
 
     jax has no public API for enumerating a pjit cache's keys, but the
     lowering path logs ``Compiling <fn> with global shapes and types
-    [ShapedArray(...)]`` for each new executable — at DEBUG even when
+    (ShapedArray(...),). Argument mapping: ...`` for each new executable — at DEBUG even when
     ``jax_log_compiles`` is off, and including ``weak_type=True``
     (exactly the bit the classic python-scalar bucket leak flips).
     This handler parses those lines so :class:`RecompileError` can name
@@ -739,8 +739,8 @@ class _CompileKeyLog(logging.Handler):
     """
 
     _RE = re.compile(
-        r"Compiling ([^\s]+) with global shapes and types (\[.*?\])"
-        r"(?:\.|$)")
+        r"Compiling (\S+) with global shapes and types (\(.*?\))"
+        r"\. Argument mapping")
     _LOGGER = "jax._src.interpreters.pxla"
 
     def __init__(self):

@@ -107,6 +107,26 @@ DEVICE_PROFILES = {
             "ici_bytes_per_s": 2.0e10, "vmem_bytes": 16 * 1024 * 1024},
 }
 
+# ``device_kind`` as JAX reports it -> the profile that prices it
+_PROFILE_BY_DEVICE_KIND = {"TPU v4": "tpu-v4", "TPU v5 lite": "tpu-v5e"}
+
+
+def attached_profile():
+    """DEVICE_PROFILES key of the device this process runs on: "cpu"
+    off the TPU; on a TPU the profile of its ``device_kind``, and a
+    kind with no profile raises — pricing one machine with another's
+    rates is worse than refusing."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return "cpu"
+    if dev.device_kind not in _PROFILE_BY_DEVICE_KIND:
+        raise ValueError(
+            f"no device profile for TPU device_kind {dev.device_kind!r} "
+            f"(known: {sorted(_PROFILE_BY_DEVICE_KIND)}); add one to "
+            f"DEVICE_PROFILES with its source")
+    return _PROFILE_BY_DEVICE_KIND[dev.device_kind]
+
+
 _BYTE_UNITS = {"b": 1, "kb": 1000, "mb": 1000**2, "gb": 1000**3,
                "tb": 1000**4, "kib": 1024, "mib": 1024**2,
                "gib": 1024**3, "tib": 1024**4}
@@ -187,7 +207,7 @@ _CUMULATIVE = {"cumsum", "cummax", "cummin", "cumprod", "cumlogsumexp"}
 
 # call-like primitives whose cost is their sub-jaxpr's cost
 _CALL_PRIMS = {
-    "pjit", "closed_call", "core_call", "custom_jvp_call",
+    "jit", "closed_call", "core_call", "custom_jvp_call",
     "custom_vjp_call", "custom_vjp_call_jaxpr", "remat", "remat2",
     "checkpoint", "custom_lin", "shard_map", "named_call",
 }
@@ -491,10 +511,7 @@ def xla_cost_analysis(fn, *args):
     Returns at least {"flops", "bytes accessed", "transcendentals"}."""
     if not hasattr(fn, "trace"):
         fn = jax.jit(fn)
-    analysis = fn.trace(*args).lower().compile().cost_analysis()
-    if isinstance(analysis, list):  # older jax: one dict per device
-        analysis = analysis[0]
-    return dict(analysis)
+    return dict(fn.trace(*args).lower().compile().cost_analysis())
 
 
 # --------------------------------------------------------------------------

@@ -630,6 +630,7 @@ def decode_attention(jaxpr, consts=()):
                   _d=head_dim, _q=q_var, _k=k_var, _v=v_real,
                   _pred=pred_var, _vfirst=v_first, _tr=tr is not None,
                   _dt=out_dtype):
+            from ..ops.pallas import _use_pallas
             from ..ops.pallas import decode_attention_kernel as dk
 
             q = read(_q)            # [B, 1, N, D]
@@ -650,11 +651,12 @@ def decode_attention(jaxpr, consts=()):
                 lengths = lsum.reshape(b)              # per-batch mask
             else:
                 lengths = jnp.broadcast_to(lsum.reshape(-1)[0], (b,))
-            if dk.supports(s_max, _d, q.shape[2], k.shape[2]) and \
-                    jax.default_backend() == "tpu":
-                out = dk.decode_attention_pallas(q[:, 0], k, v, lengths)
-            else:
-                out = dk.decode_attention_xla(q[:, 0], k, v, lengths)
+            if _use_pallas() and \
+                    dk.supports(s_max, _d, q.shape[2], k.shape[2]):
+                # the XLA composition is a decision (the flag), never
+                # a silent substitute for a kernel that cannot compile
+                raise NotImplementedError(dk.TPU_REFUSAL)
+            out = dk.decode_attention_xla(q[:, 0], k, v, lengths)
             out = out.astype(_dt)           # [B, N, D]
             if _tr:
                 return out[:, None]         # [B, 1, N, D]

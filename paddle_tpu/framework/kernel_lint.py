@@ -151,27 +151,25 @@ def _kernel_info(eqn):
         grid = tuple(int(g) for g in gm.grid)
     except (TypeError, ValueError):
         return None                         # dynamic grid: out of scope
-    num_in = int(getattr(gm, "num_inputs", 0))
+    num_in = gm.num_inputs
+    num_prefetch = gm.num_index_operands
+    body = _raw(eqn.params["jaxpr"])
+    # the kernel's own parameter names (``x_ref``) are the origins the
+    # findings quote; BlockMapping.origin is only positional (args[0])
+    names = body.debug_info.arg_names
     blocks = []
     for idx, bm in enumerate(gm.block_mappings):
-        sds = bm.array_shape_dtype
-        bs = []
-        for x in bm.block_shape:
-            try:
-                bs.append(int(x))
-            except (TypeError, ValueError):
-                bs.append(1)                # squeezed/mapped dim
+        sds = bm.array_aval
+        # Blocked/Element dims carry block_size; a Squeezed dim is 1 wide
+        bs = tuple(int(getattr(x, "block_size", 1))
+                   for x in bm.block_shape)
         blocks.append(BlockInfo(
-            str(getattr(bm, "origin", f"operand {idx}")), tuple(bs),
-            tuple(sds.shape), sds.dtype,
-            getattr(bm, "index_map_jaxpr", None), idx >= num_in))
-    num_prefetch = int(getattr(gm, "num_index_operands", 0))
-    body = _raw(eqn.params["jaxpr"])
+            str(names[num_prefetch + idx]), bs, tuple(sds.shape),
+            sds.dtype, bm.index_map_jaxpr, idx >= num_in))
     scratch = []
     for v in body.invars[num_prefetch + len(blocks):]:
         scratch.append(_ref_shape_dtype(v.aval))
-    nsi = eqn.params.get("name_and_src_info")
-    name = getattr(nsi, "name", None) or str(nsi or "pallas_call")
+    name = eqn.params.get("name") or "pallas_call"
     return KernelInfo(name, grid, blocks, scratch, num_prefetch, body)
 
 

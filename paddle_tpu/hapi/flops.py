@@ -39,10 +39,7 @@ def flops(net, input_size, dtypes=None, print_detail=False):
                          for o in outs)
 
         compiled = jax.jit(fn).lower(state, *examples).compile()
-        analysis = compiled.cost_analysis()
-        if isinstance(analysis, list):  # older jax: one dict per device
-            analysis = analysis[0]
-        total = int(analysis.get("flops", 0))
+        total = int(compiled.cost_analysis().get("flops", 0))
     finally:
         if was_training:
             net.train()

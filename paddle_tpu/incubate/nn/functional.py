@@ -208,8 +208,10 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths,
     q [B, Nq, D]; k_cache/v_cache [B, S_max, Nkv, D] with Nq % Nkv == 0
     (query heads grouped contiguously per KV head); lengths [B] = valid
     prefix.  Uses the Pallas kernel
-    (ops/pallas/decode_attention_kernel.py) when the shapes qualify,
-    else the dense masked XLA fallback — identical semantics.
+    (ops/pallas/decode_attention_kernel.py) in interpret mode when the
+    shapes qualify, else the dense masked XLA fallback — identical
+    semantics.  ``use_pallas=True`` on a TPU raises the kernel's
+    ``TPU_REFUSAL``: it does not compile there yet.
     """
     from ...ops.pallas import decode_attention_kernel as dk
 
@@ -220,15 +222,14 @@ def ragged_decode_attention(q, k_cache, v_cache, lengths,
         s_max, nkv = kk.shape[1], kk.shape[2]
         ok = dk.supports(s_max, d, nq, nkv) and (
             interpret or _jax.default_backend() == "tpu")
-        # on hardware the kernel is opt-in (use_pallas=True) until its
-        # scalar-lengths layout is validated on a real chip; interpret
-        # mode (numerics-verified) auto-selects it
-        default_on = interpret
-        use = (default_on and ok) if use_pallas is None \
+        # interpret mode (numerics-verified) auto-selects the kernel
+        use = (interpret and ok) if use_pallas is None \
             else (use_pallas and ok)
+        if use and not interpret:
+            raise NotImplementedError(dk.TPU_REFUSAL)
         if use:
             return dk.decode_attention_pallas(qq, kk, vv, ll,
-                                              interpret=interpret)
+                                              interpret=True)
         return dk.decode_attention_xla(qq, kk, vv, ll)
 
     return apply_op("ragged_decode_attention", pure,

@@ -2,7 +2,8 @@
 
 The serving counterpart of incubate.nn.FusedMultiTransformer: the same
 stacked-params lax.scan decoder, but the KV cache is one paged pool
-([L, num_blocks, block_size, Nkv, D] per K and V) shared by every
+([L, num_blocks, Nkv, block_size, D] per K and V — head-major, the
+layout the ragged Pallas kernel's page block needs) shared by every
 in-flight request, so the engine runs MANY requests of ragged lengths
 through exactly ONE family of jitted executables:
 
@@ -41,7 +42,7 @@ Tensor parallelism (``mesh=`` / ``tensor_parallel=``): the same
 executable spans a device mesh with an ``'mp'`` axis.  Params shard
 Megatron-style — qkv/fc_in column-parallel, proj/fc_out row-parallel
 with an explicit psum — and the paged K/V pools shard along the HEAD
-axis ([L, NB, bs, Nkv/mp, D] per device), so each device runs its head
+axis ([L, NB, Nkv/mp, bs, D] per device), so each device runs its head
 slice of paged_ragged_attention against its LOCAL pool shard.
 The whole step body runs under ``jax.shard_map`` (the paged Pallas
 kernels index the pool through scalar-prefetched block tables, which
@@ -67,7 +68,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ... import profiler
-from ...framework import jax_compat  # noqa: F401  (aliases jax.shard_map)
 from ...incubate.nn import _layernorm
 from .block_manager import BlockManager, NoFreeBlocksError
 from .faults import (
@@ -505,8 +505,8 @@ class LLMEngine:
             if self.prefix_store is not None:
                 self.scheduler.prefix_fetch_hook = self._tier_prefix_fetch
                 self.block_manager.evict_hook = self._promote_evicted
-        cache_shape = (self.num_layers, self.num_blocks, self.block_size,
-                       self.num_heads, self.head_dim)
+        cache_shape = (self.num_layers, self.num_blocks, self.num_heads,
+                       self.block_size, self.head_dim)
         self._kv_dtype = jnp.int8 if self._kv_quant else self.dtype
         # per-(layer, page, head, slot) dequant scales for the int8
         # pool; head axis shards with the pool under TP
@@ -572,7 +572,7 @@ class LLMEngine:
                        for k in params["blocks"]},
             "head": {k: P() for k in params["head"]},
         }
-        self._cache_spec = P(None, None, None, "mp", None)
+        self._cache_spec = P(None, None, "mp", None, None)
         self._scale_spec = P(None, None, "mp", None)
         self._ks = self._vs = None
         if tp > 1:
@@ -688,29 +688,23 @@ class LLMEngine:
             rows carry an out-of-range slot and are dropped, not
             written.  Under TP ``cache`` is the LOCAL pool shard and
             ``values`` this shard's heads — slots are replicated, so
-            every shard writes the same pages of its own head slice."""
-            flat = cache.reshape(nb * bs, nh_l, hd)
-            flat = flat.at[slots].set(values.astype(cache.dtype),
-                                      mode="drop")
-            return flat.reshape(nb, bs, nh_l, hd)
+            every shard writes the same pages of its own head slice.
+            ``cache`` is head-major [nb, nh_l, bs, hd]: token slot s
+            is row ``s % bs`` of every head of page ``s // bs`` (the
+            padding slot nb * bs lands on page nb, out of range)."""
+            return cache.at[slots // bs, :, slots % bs].set(
+                values.astype(cache.dtype), mode="drop")
 
         def scatter_pages_quant(cache, scales, slots, values):
             """Quantize-at-append: each written [nh_l, hd] token row
             quantizes per head (absmax / 127) and lands as int8 values
             plus one f32 scale per (slot, head).  Padding slots carry
-            the out-of-range id ``nb * bs`` — both scatters drop them
-            (the scale index lands past the flat scale pool exactly
-            when the slot lands past the flat cache)."""
+            the out-of-range id ``nb * bs`` — page ``nb`` — and both
+            scatters drop them."""
             q, s = quantize_kv_rows(values)      # int8 [N,nh_l,hd], [N,nh_l]
-            flat = cache.reshape(nb * bs, nh_l, hd)
-            flat = flat.at[slots].set(q, mode="drop")
             page, off = slots // bs, slots % bs
-            sidx = (page[:, None] * (nh_l * bs)
-                    + jnp.arange(nh_l)[None, :] * bs + off[:, None])
-            sflat = scales.reshape(nb * nh_l * bs)
-            sflat = sflat.at[sidx].set(s, mode="drop")
-            return (flat.reshape(nb, bs, nh_l, hd),
-                    sflat.reshape(nb, nh_l, bs))
+            return (cache.at[page, :, off].set(q, mode="drop"),
+                    scales.at[page, :, off].set(s, mode="drop"))
 
         def head_logits(params, x):
             x = _layernorm(x, params["head"]["weight"],
@@ -884,7 +878,7 @@ class LLMEngine:
                     in_specs=(self._param_specs, rep) + pool_specs
                     + extra,
                     out_specs=(rep, rep) + pool_specs,
-                    check_rep=False)
+                    check_vma=False)
                 rsh = self._rep
                 return jax.jit(
                     sm,
@@ -1670,7 +1664,7 @@ class LLMEngine:
     def _gather_pages(self, block_ids):
         """Host-staged page gather: device-side row select of the
         pools, then a transfer of JUST those rows.  Returns (k_pages,
-        v_pages) as [L, n, bs, Nkv, D] numpy arrays in ``block_ids``
+        v_pages) as [L, n, Nkv, bs, D] numpy arrays in ``block_ids``
         order — the GLOBAL view even when the pools are head-sharded
         (jax assembles addressable shards)."""
         idx = np.asarray(block_ids, np.int64)  # noqa: H001 (host block-id list, not a tensor)
@@ -1803,7 +1797,7 @@ class LLMEngine:
                 f"{'no lora= configured' if self.lora is None else 'adapter not registered'}",
                 reason="adapter")
         expect = (self.num_layers, len(seq["block_ids"]),
-                  self.block_size, self.num_heads, self.head_dim)
+                  self.num_heads, self.block_size, self.head_dim)
         if tuple(k_pages.shape) != expect or \
                 tuple(v_pages.shape) != expect:
             raise ValueError(

@@ -166,7 +166,9 @@ class MigrationPolicy:
         cheaper side; "always" / "never" force the choice.
     ``profile``
         DEVICE_PROFILES key converting byte/FLOP counts to seconds
-        (default "cpu" — what the serving stack runs on today).
+        (default: the attached device's own — "cpu" off the TPU, the
+        ``device_kind``'s profile on one, an error for an unknown
+        TPU; see ``framework.cost.attached_profile``).
     ``link_gbps``
         Replica-to-replica bandwidth in GB/s for the transfer term;
         None uses the profile's ICI rate.
@@ -178,7 +180,7 @@ class MigrationPolicy:
     """
 
     mode: str = "auto"
-    profile: str = "cpu"
+    profile: str = None
     link_gbps: float = None
 
     def __post_init__(self):
@@ -186,7 +188,9 @@ class MigrationPolicy:
             raise ValueError(
                 f"mode must be 'auto'|'always'|'never', got "
                 f"{self.mode!r}")
-        from ...framework.cost import DEVICE_PROFILES
+        from ...framework.cost import DEVICE_PROFILES, attached_profile
+        if self.profile is None:
+            self.profile = attached_profile()
         if self.profile not in DEVICE_PROFILES:
             raise ValueError(
                 f"unknown device profile {self.profile!r} "

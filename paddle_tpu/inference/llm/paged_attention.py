@@ -21,6 +21,16 @@ Two implementations with identical semantics:
   which is what keeps the engine-vs-dense token-exactness tests
   meaningful across the refactor.
 
+On a TPU the masked-XLA path is never taken silently: it runs only
+when ``FLAGS_use_pallas_kernels`` is off or when the kernel's
+``supports()`` refuses the shape, and the refusal warns once with the
+shape and the reason (``ops.pallas.KernelFallbackWarning``).
+
+The pool is HEAD-MAJOR, ``[NB, Nkv, bs, D]`` — the layout the kernel's
+page block needs (see the kernel module); the fallbacks gather pages
+and swap the head and slot axes back into the dense ``[S, Nkv, D]``
+order their einsums always used.
+
 Like the kernel, the 1/sqrt(D) scale is applied inside.  Note the
 engine no longer pre-scales query heads before calling in — the old
 decode/verify paths multiplied by ``scale * sqrt(head_dim)`` (exactly
@@ -37,7 +47,7 @@ non-TPU path and keeps its regression test.
 Tensor parallelism: the ragged entry point is head-count generic and
 attention never mixes heads — the TP engine calls it UNCHANGED from
 inside ``jax.shard_map`` with per-shard shapes (q [T, Nq/mp, D], pool
-[NB, bs, Nkv/mp, D], block tables and row descriptors replicated).
+[NB, Nkv/mp, bs, D], block tables and row descriptors replicated).
 Each shard runs its head slice against its LOCAL pool shard; no
 collective is needed until the row-parallel output projection.  This
 is also why the Pallas path survives the mesh: scalar-prefetched
@@ -48,15 +58,40 @@ it only ever sees fully local operands.
 import jax
 import jax.numpy as jnp
 
-from ...framework.flags import get_flags
+from ...ops.pallas import _use_pallas, warn_fallback
 from ...ops.pallas import ragged_attention_kernel as _kernel
 from ...ops.pallas.decode_attention_kernel import decode_attention_xla
 
 
-def _use_pallas():
-    return (jax.default_backend() == "tpu"
-            and get_flags("FLAGS_use_pallas_kernels")
-            ["FLAGS_use_pallas_kernels"])
+def _take_kernel(q_shape, k_pages, interpret):
+    """True when the ragged Pallas kernel runs these packed query
+    tokens ``q_shape`` [T, Nq, D] against ``k_pages``; a TPU shape the
+    kernel refuses warns once (module docstring) and takes XLA."""
+    if not (_use_pallas() or interpret):
+        return False
+    t, nq, d = q_shape
+    _, nkv, bs, _ = k_pages.shape
+    if _kernel.supports(bs, d, nq, nkv, t):
+        return True
+    warn_fallback(
+        "paged_ragged_attention",
+        f"q{tuple(q_shape)} pool{tuple(k_pages.shape)}",
+        "the kernel needs head_dim <= 128, block_size % 8 == 0, "
+        "Nq % Nkv == 0 and tokens % 8 == 0")
+    return False
+
+
+def _gather_dense(pages, block_tables, scales=None):
+    """[NB, Nkv, bs, D] pool x [R, P] tables -> dense [R, P*bs, Nkv, D]
+    (each row's pages in table order, slot-major within a page).  An
+    int8 pool passes its [NB, Nkv, bs] ``scales``: the GATHERED pages
+    dequantize in f32 (``int8 * scale``), never the whole pool."""
+    r, num_pages = block_tables.shape
+    _, nkv, bs, d = pages.shape
+    pg = pages[block_tables]                        # [R, P, Nkv, bs, D]
+    if scales is not None:
+        pg = pg.astype(jnp.float32) * scales[block_tables][..., None]
+    return pg.transpose(0, 1, 3, 2, 4).reshape(r, num_pages * bs, nkv, d)
 
 
 def paged_ragged_attention_xla(q, k_pages, v_pages, block_tables, ctx,
@@ -71,12 +106,8 @@ def paged_ragged_attention_xla(q, k_pages, v_pages, block_tables, ctx,
     each output token is bitwise the single-token decode the engine
     would have run at that position.
     """
-    t, nq, d = q.shape
-    r, num_pages = block_tables.shape
-    _, bs, nkv, _ = k_pages.shape
-    s_max = num_pages * bs
-    k = k_pages[block_tables].reshape(r, s_max, nkv, d)[rows]
-    v = v_pages[block_tables].reshape(r, s_max, nkv, d)[rows]
+    k = _gather_dense(k_pages, block_tables)[rows]
+    v = _gather_dense(v_pages, block_tables)[rows]
     return _ragged_masked_chain(q, k, v, ctx)
 
 
@@ -106,26 +137,16 @@ def paged_ragged_attention_quant_xla(q, k_pages, v_pages, k_scales,
                                      v_scales, block_tables, ctx, rows):
     """Masked-XLA fallback for the INT8 ragged batch.
 
-    ``k_pages``/``v_pages`` [NB, bs, Nkv, D] int8 and
+    ``k_pages``/``v_pages`` [NB, Nkv, bs, D] int8 and
     ``k_scales``/``v_scales`` [NB, Nkv, bs] float32 — one symmetric
     dequant scale per (page, kv head, slot), as written by the
     engine's quantized append.  Gathers each token's pages AND scale
     rows, dequantizes in f32 (the same ``int8 * scale`` product the
-    Pallas kernel computes per loaded slot), then runs the identical
+    Pallas kernel applies per loaded slot), then runs the identical
     masked chain as :func:`paged_ragged_attention_xla`."""
-    t, nq, d = q.shape
-    r, num_pages = block_tables.shape
-    _, bs, nkv, _ = k_pages.shape
-    s_max = num_pages * bs
-
-    def deq(pages, scales):
-        pg = pages[block_tables].astype(jnp.float32)   # [R,P,bs,Nkv,D]
-        sc = scales[block_tables].astype(jnp.float32)  # [R,P,Nkv,bs]
-        pg = pg * sc.transpose(0, 1, 3, 2)[..., None]
-        return pg.reshape(r, s_max, nkv, d)[rows]
-
-    return _ragged_masked_chain(q, deq(k_pages, k_scales),
-                                deq(v_pages, v_scales), ctx)
+    return _ragged_masked_chain(
+        q, _gather_dense(k_pages, block_tables, k_scales)[rows],
+        _gather_dense(v_pages, block_tables, v_scales)[rows], ctx)
 
 
 def paged_ragged_attention_quant(q, k_pages, v_pages, k_scales,
@@ -137,10 +158,7 @@ def paged_ragged_attention_quant(q, k_pages, v_pages, k_scales,
     forms plus the two page-scale pools.  TPU (or ``interpret=True``)
     runs the in-kernel-dequant Pallas kernel; everywhere else the
     dequant-gather masked-XLA fallback."""
-    t, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
-    if ((_use_pallas() or interpret)
-            and _kernel.supports(bs, d, nq, nkv, t)):
+    if _take_kernel(q.shape, k_pages, interpret):
         return _kernel.paged_ragged_attention_quant_pallas(
             q, k_pages, v_pages, k_scales, v_scales, block_tables,
             row_start, row_qlen, row_pos0, interpret=interpret)
@@ -164,10 +182,7 @@ def paged_ragged_attention(q, k_pages, v_pages, block_tables, ctx, rows,
     every row.  Tokens outside every row come back as exact zeros on
     both paths.
     """
-    t, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
-    if ((_use_pallas() or interpret)
-            and _kernel.supports(bs, d, nq, nkv, t)):
+    if _take_kernel(q.shape, k_pages, interpret):
         return _kernel.paged_ragged_attention_pallas(
             q, k_pages, v_pages, block_tables, row_start, row_qlen,
             row_pos0, interpret=interpret)
@@ -177,11 +192,9 @@ def paged_ragged_attention(q, k_pages, v_pages, block_tables, ctx, rows,
 
 def paged_decode_attention_xla(q, k_pages, v_pages, block_tables, lengths):
     """Masked-XLA fallback: gather pages -> dense ragged decode."""
-    b, num_pages = block_tables.shape
-    _, bs, nkv, d = k_pages.shape
-    k = k_pages[block_tables].reshape(b, num_pages * bs, nkv, d)
-    v = v_pages[block_tables].reshape(b, num_pages * bs, nkv, d)
-    return decode_attention_xla(q, k, v, lengths)
+    return decode_attention_xla(q, _gather_dense(k_pages, block_tables),
+                                _gather_dense(v_pages, block_tables),
+                                lengths)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
@@ -193,10 +206,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
     than the ragged chunk width (B % 8 != 0) take the XLA fallback —
     the engine never does, its token buckets floor at 8.
     """
-    b, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
-    if ((_use_pallas() or interpret)
-            and _kernel.supports(bs, d, nq, nkv, b)):
+    b = q.shape[0]
+    if _take_kernel(q.shape, k_pages, interpret):
         return _kernel.paged_ragged_attention_pallas(
             q, k_pages, v_pages, block_tables,
             jnp.arange(b, dtype=jnp.int32),
@@ -221,11 +232,9 @@ def paged_verify_attention_xla(q, k_pages, v_pages, block_tables, ctx):
     have run — at 1/T of the flattened form's gather traffic.
     """
     b, t, nq, d = q.shape
-    num_pages = block_tables.shape[1]
-    _, bs, nkv, _ = k_pages.shape
-    s_max = num_pages * bs
-    k = k_pages[block_tables].reshape(b, s_max, nkv, d)
-    v = v_pages[block_tables].reshape(b, s_max, nkv, d)
+    k = _gather_dense(k_pages, block_tables)
+    v = _gather_dense(v_pages, block_tables)
+    s_max, nkv = k.shape[1], k.shape[2]
     g = nq // nkv
     qg = (q.reshape(b, t, nkv, g, d).transpose(0, 2, 1, 3, 4)
           .reshape(b, nkv, t * g, d))
@@ -252,9 +261,7 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, ctx,
     no per-token table replication is materialized.  XLA path gathers
     once per sequence via paged_verify_attention_xla."""
     b, t, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
-    if ((_use_pallas() or interpret)
-            and _kernel.supports(bs, d, nq, nkv, b * t)):
+    if _take_kernel((b * t, nq, d), k_pages, interpret):
         flat = _kernel.paged_ragged_attention_pallas(
             q.reshape(b * t, nq, d), k_pages, v_pages, block_tables,
             jnp.arange(b, dtype=jnp.int32) * t,
@@ -278,17 +285,16 @@ def paged_prefill_attention_xla(q, k_pages, v_pages, block_table, start):
     nothing.
     """
     _, c, n, d = q.shape
-    num_pages = block_table.shape[0]
-    _, bs, nkv, _ = k_pages.shape
-    kk = k_pages[block_table].reshape(1, num_pages * bs, nkv, d)
-    vv = v_pages[block_table].reshape(1, num_pages * bs, nkv, d)
+    kk = _gather_dense(k_pages, block_table[None])
+    vv = _gather_dense(v_pages, block_table[None])
+    s_max, nkv = kk.shape[1], kk.shape[2]
     if nkv != n:                                 # GQA: expand KV heads
         kk = jnp.repeat(kk, n // nkv, axis=2)
         vv = jnp.repeat(vv, n // nkv, axis=2)
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, q.dtype))
     logits = jnp.einsum("bqnd,bknd->bnqk", q, kk.astype(q.dtype)) * scale
     q_pos = start + jnp.arange(c)[:, None]
-    k_pos = jnp.arange(num_pages * bs)[None, :]
+    k_pos = jnp.arange(s_max)[None, :]
     mask = (k_pos <= q_pos)[None, None]
     logits = jnp.where(mask, logits, jnp.asarray(-1e30, q.dtype))
     att = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
@@ -301,10 +307,8 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, start,
     attention over positions 0..start+C-1 through the block table.
     Pallas path: the chunk is the single ragged row (start=0, qlen=C,
     pos0=start); ``start`` may be traced."""
-    _, c, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
-    if ((_use_pallas() or interpret)
-            and _kernel.supports(bs, d, nq, nkv, c)):
+    c = q.shape[1]
+    if _take_kernel(q.shape[1:], k_pages, interpret):
         out = _kernel.paged_ragged_attention_pallas(
             q[0], k_pages, v_pages, block_table[None],
             jnp.zeros((1,), jnp.int32),

@@ -369,32 +369,44 @@ class TrainStep:
             step_fn_scaled if scaler is not None else step_fn,
             donate_argnums=donate)
 
-    def __call__(self, inputs, labels=()):
-        """inputs: Tensor or tuple for the model; labels: Tensor or tuple for
-        loss_fn(output, *labels)."""
+    def _operands(self, step, key, inputs, labels):
+        """The compiled step's argument tuple for one call."""
         if self._compiled is None:
             self._build()
-        self._step += 1
-        lr = jnp.float32(self.optimizer.get_lr())
-        key = get_rng_key()
         if isinstance(inputs, Tensor):
             inputs = (inputs,)
         if isinstance(labels, Tensor):
             labels = (labels,)
         in_data = tuple(t._data if isinstance(t, Tensor) else t for t in inputs)
         lb_data = tuple(t._data if isinstance(t, Tensor) else t for t in labels)
+        args = (self._params, self._frozen, self._opt_state,
+                jnp.int32(step), jnp.float32(self.optimizer.get_lr()), key,
+                in_data, lb_data)
         if self.scaler is not None:
             # the scaler object owns the live state (set_state_dict can
             # replace it between steps)
-            loss, self._params, self._opt_state, new_sstate = \
-                self._compiled(self._params, self._frozen, self._opt_state,
-                               jnp.int32(self._step), lr, key, in_data,
-                               lb_data, self.scaler._compiled_state)
+            args += (self.scaler._compiled_state,)
+        return args
+
+    def lower(self, inputs, labels=()):
+        """jax's ``Lowered`` form of the step for these operands — what
+        the compiler is handed (``.as_text()``, ``.compile()``).  Runs
+        nothing: no step is counted and no RNG key is drawn."""
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        args = self._operands(self._step + 1, key, inputs, labels)
+        return self._compiled.lower(*args)
+
+    def __call__(self, inputs, labels=()):
+        """inputs: Tensor or tuple for the model; labels: Tensor or tuple for
+        loss_fn(output, *labels)."""
+        self._step += 1
+        args = self._operands(self._step, get_rng_key(), inputs, labels)
+        out = self._compiled(*args)
+        if self.scaler is not None:
+            loss, self._params, self._opt_state, new_sstate = out
             self.scaler._compiled_state = new_sstate
         else:
-            loss, self._params, self._opt_state = self._compiled(
-                self._params, self._frozen, self._opt_state,
-                jnp.int32(self._step), lr, key, in_data, lb_data)
+            loss, self._params, self._opt_state = out
         self.sync_to_model()
         return Tensor(loss)
 

@@ -474,7 +474,10 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-05):
         for d in x.shape[:-1]:
             rows *= d
         if supports(rows, x.shape[-1]):
-            return layernorm_pallas(x, weight, bias, eps=epsilon)
+            if not _pallas._partitioned_by_gspmd():
+                return layernorm_pallas(x, weight, bias, eps=epsilon)
+            _pallas.warn_fallback("layernorm", f"x{tuple(x.shape)}",
+                                  _pallas.GSPMD_REASON)
     axes = tuple(range(x.ndim - len(normalized_shape), x.ndim))
     mean = jnp.mean(x, axis=axes, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), axis=axes, keepdims=True)

@@ -5,7 +5,15 @@ Analog of the reference's hand-fused CUDA kernels
 paddle/phi/kernels/gpu/flash_attn_kernel.cu).  Selection order:
 Pallas kernel (TPU, flag-gated) → XLA composition fallback (works everywhere,
 still fuses well).  ``FLAGS_use_pallas_kernels`` toggles.
+
+On a TPU the XLA composition is never taken silently in place of a
+kernel: a dispatcher that gives up a kernel for a shape or argument it
+cannot serve calls :func:`warn_fallback`, which warns once per distinct
+(kernel, shape, reason) under :class:`KernelFallbackWarning`, so a run
+can turn it into an error (``chip_smoke.py`` does).
 """
+
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -13,9 +21,42 @@ import jax.numpy as jnp
 from ...framework.flags import get_flags
 
 
+class KernelFallbackWarning(RuntimeWarning):
+    """On a TPU, an XLA composition ran where a Pallas kernel exists."""
+
+
 def _use_pallas():
     return (jax.default_backend() == "tpu"
             and get_flags("FLAGS_use_pallas_kernels")["FLAGS_use_pallas_kernels"])
+
+
+GSPMD_REASON = ("GSPMD cannot partition a Mosaic kernel; it needs a "
+                "shard_map over every mesh axis")
+
+
+def _partitioned_by_gspmd():
+    """True while tracing a computation that GSPMD will partition over
+    several devices.  JAX refuses a Mosaic kernel there ("Mosaic kernels
+    cannot be automatically partitioned"): one lowers only for a single
+    device, or inside a ``shard_map`` that makes EVERY mesh axis manual
+    (the serving engine's tensor-parallel step).  What tracing can see:
+    the shard_map's abstract mesh, else the mesh the SPMD entry points
+    enter (``fleet.spmd.use_mesh``)."""
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
+        return set(am.manual_axes) != set(am.axis_names)
+    from ...distributed.fleet.spmd import current_mesh
+    mesh = current_mesh()
+    return mesh is not None and mesh.size > 1
+
+
+def warn_fallback(kernel, shape, reason):
+    """Record that ``kernel`` gave way to its XLA composition.  Silent
+    off the TPU, where the composition is the normal path."""
+    if jax.default_backend() == "tpu":
+        warnings.warn(
+            f"{kernel}: XLA composition taken on TPU for {shape}: {reason}",
+            KernelFallbackWarning, stacklevel=3)
 
 
 def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
@@ -61,21 +102,33 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     if dropout_p > 0.0 and dropout_key is None:
         from ...framework.random import get_rng_key
         dropout_key = get_rng_key()
-    if (_use_pallas() and attn_mask is None and dropout_p == 0.0
-            and scale is None):
+    if _use_pallas():
         from .attention_kernel import flash_attention_pallas, supports
-        # causal masking in the kernel is top-left aligned; for seq_q !=
-        # seq_k the paddle/XLA semantics are bottom-right aligned, so only
-        # self-attention-shaped causal inputs take the kernel path
-        causal_ok = (not is_causal) or q.shape[1] == k.shape[1]
         # Below this sequence length the fused XLA attention is faster on
         # TPU (profiled on v5e: the kernel's small per-program blocks and
         # lane-padded head_dim lose to the MXU-saturating einsum); flash
-        # pays off once the [T, S] score matrix dominates HBM.
+        # pays off once the [T, S] score matrix dominates HBM.  That is a
+        # choice, not a fallback: short sequences take XLA without a word.
         min_seq = get_flags("FLAGS_flash_min_seqlen")["FLAGS_flash_min_seqlen"]
-        if (causal_ok and q.shape[1] >= int(min_seq)
-                and supports(q.shape[1], k.shape[1], q.shape[3])):
-            return flash_attention_pallas(q, k, v, is_causal)
+        if q.shape[1] >= int(min_seq):
+            reason = None
+            if _partitioned_by_gspmd():
+                reason = GSPMD_REASON
+            elif attn_mask is not None or dropout_p > 0.0 \
+                    or scale is not None:
+                reason = "the kernel takes no attn_mask, dropout or scale"
+            elif is_causal and q.shape[1] != k.shape[1]:
+                # causal masking in the kernel is top-left aligned; for
+                # seq_q != seq_k the paddle/XLA semantics are bottom-right
+                # aligned, so only self-attention-shaped causal inputs
+                # take the kernel path
+                reason = "causal with seq_q != seq_k"
+            elif not supports(q.shape[1], k.shape[1], q.shape[3]):
+                reason = "attention_kernel.supports() refuses the shape"
+            if reason is None:
+                return flash_attention_pallas(q, k, v, is_causal)
+            warn_fallback("flash_attention",
+                          f"q{tuple(q.shape)} k{tuple(k.shape)}", reason)
     return _xla_attention(q, k, v, attn_mask=attn_mask, is_causal=is_causal,
                           dropout_p=dropout_p, dropout_key=dropout_key,
                           scale=scale)

@@ -99,6 +99,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
                           scale=scale),
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
@@ -215,6 +216,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, causal=causal,
                           scale=scale),
+        name="flash_attention_bwd_dq",
         grid=(bn, seq_q // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
@@ -232,6 +234,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, causal=causal,
                           scale=scale),
+        name="flash_attention_bwd_dkv",
         grid=(bn, seq_k // block_k),
         in_specs=[
             pl.BlockSpec((1, seq_q, head), lambda b, j: (b, 0, 0)),

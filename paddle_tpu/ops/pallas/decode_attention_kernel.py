@@ -15,9 +15,14 @@ blocks and per-position masking by ``lengths`` — ragged sequences cost
 only their occupied blocks' bandwidth, never S_max compute on the VPU
 path.
 
-TPU-shape constraints: D <= 128, S_max % block_s == 0.  ``supports``
-gates callers; the XLA fallback (used by FusedMultiTransformer by
-default) computes the same masked attention densely.
+Shape constraints: D <= 128, S_max % block_s == 0.  ``supports``
+gates callers; the XLA fallback computes the same masked attention
+densely.
+
+INTERPRET MODE ONLY today — see ``TPU_REFUSAL``.  The TPU gates
+(framework/ir.py, incubate.nn.functional.ragged_decode_attention) raise
+it where they would select this kernel, so a TPU caller learns why
+instead of silently getting the XLA composition.
 """
 
 import functools
@@ -30,6 +35,19 @@ from . import registry
 
 DEFAULT_BLOCK_S = 128
 _NEG_INF = -1e30
+
+# What the Pallas TPU lowering says to this kernel (jax 0.9.0, checked
+# by tests/test_tpu_lowering.py).  The ragged paged kernel had the same
+# fault and was repaired by making its pool head-major; here the cache
+# layout belongs to the callers (FusedMultiTransformer, llama decode),
+# so the repair is theirs to make first.
+TPU_REFUSAL = (
+    "decode_attention_pallas does not compile for TPU: its "
+    "(1, s_max, 1, d) cache block and (1, g, 1, d) q/out blocks squeeze "
+    "the second-minor (head) axis of [B, S_max, Nkv, D], and its (1,) "
+    "lengths block is not a VMEM tile — Mosaic needs the last two block "
+    "dims tile-aligned or whole.  It needs a head-major dense cache.  "
+    "Set FLAGS_use_pallas_kernels=0 to run decode_attention_xla.")
 
 
 def _pick_block(s_max, preferred=DEFAULT_BLOCK_S):
@@ -111,7 +129,8 @@ def _engine_cases(engine):
     parity="tests/test_pallas_kernels.py::TestDecodeAttention::"
            "test_matches_xla_reference_ragged_gqa",
     engine_shapes=_engine_cases,
-    supports=supports)
+    supports=supports,
+    tpu_refusal=TPU_REFUSAL)
 def decode_attention_pallas(q, k_cache, v_cache, lengths, block_s=None,
                             interpret=False):
     """Returns [B, Nq, D] attention outputs for one decode step."""
@@ -126,6 +145,7 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, block_s=None,
     grid = (b, nkv)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_s=block_s),
+        name="decode_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, g, 1, d), lambda i, j: (i, 0, j, 0)),

@@ -20,17 +20,23 @@ from jax.experimental import pallas as pl
 from . import registry
 
 _ROW_BLOCK = 256
+# The backward keeps the x, dy and dx row blocks resident at once, each
+# double-buffered: a [256, 4096] f32 block makes that 16.04 MiB and
+# Mosaic refuses it (16 MiB scoped VMEM, v5e).  One block of at most
+# 1 MiB of f32 keeps every width inside; up to C = 1024 that is still
+# the full 256 rows.
+_MAX_BLOCK_ELEMS = 256 * 1024
 
 
-def _pick_rows(rows):
+def _pick_rows(rows, channels):
     for b in (_ROW_BLOCK, 128, 64, 32, 16, 8):
-        if rows % b == 0:
+        if rows % b == 0 and b * channels <= _MAX_BLOCK_ELEMS:
             return b
     return None
 
 
 def supports(rows, channels):
-    return channels % 128 == 0 and _pick_rows(rows) is not None
+    return channels % 128 == 0 and _pick_rows(rows, channels) is not None
 
 
 def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, mu_ref, rstd_ref, *, eps):
@@ -75,6 +81,7 @@ def _ln_fwd(x2d, g, b, eps, block_rows, interpret):
     grid = (rows // block_rows,)
     y, mu, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="layernorm_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
@@ -101,6 +108,7 @@ def _ln_bwd(x2d, g, mu, rstd, dy, block_rows, interpret):
     nb = rows // block_rows
     dx, dgp, dbp = pl.pallas_call(
         _bwd_kernel,
+        name="layernorm_bwd",
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
@@ -126,18 +134,18 @@ def _ln_bwd(x2d, g, mu, rstd, dy, block_rows, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _layernorm2d(x2d, g, b, eps, interpret):
-    y, _, _ = _ln_fwd(x2d, g, b, eps, _pick_rows(x2d.shape[0]), interpret)
+    y, _, _ = _ln_fwd(x2d, g, b, eps, _pick_rows(*x2d.shape), interpret)
     return y
 
 
 def _layernorm2d_fwd(x2d, g, b, eps, interpret):
-    y, mu, rstd = _ln_fwd(x2d, g, b, eps, _pick_rows(x2d.shape[0]), interpret)
+    y, mu, rstd = _ln_fwd(x2d, g, b, eps, _pick_rows(*x2d.shape), interpret)
     return y, (x2d, g, mu, rstd)
 
 
 def _layernorm2d_bwd(eps, interpret, res, dy):
     x2d, g, mu, rstd = res
-    dx, dg, db = _ln_bwd(x2d, g, mu, rstd, dy, _pick_rows(x2d.shape[0]),
+    dx, dg, db = _ln_bwd(x2d, g, mu, rstd, dy, _pick_rows(*x2d.shape),
                          interpret)
     return dx, dg.astype(g.dtype), db.astype(g.dtype)
 

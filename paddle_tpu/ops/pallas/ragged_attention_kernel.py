@@ -12,8 +12,8 @@ of them, because the token at absolute position ``p`` sees exactly the
 
     q             [T, Nq, D]      T packed query tokens (GQA: G =
                                   Nq//Nkv query heads per KV head)
-    k_pages       [NB, bs, Nkv, D] the whole paged pool, NB pages of
-    v_pages       [NB, bs, Nkv, D] bs tokens each
+    k_pages       [NB, Nkv, bs, D] the whole paged pool, HEAD-MAJOR:
+    v_pages       [NB, Nkv, bs, D] NB pages of bs tokens per kv head
     block_tables  [R, P] int32    page id of row r's p-th page
     row_start     [R]    int32    first flat token of row r
     row_qlen      [R]    int32    query tokens of row r (0: dead row)
@@ -42,6 +42,22 @@ host: speculative verify used to materialize
 ``jnp.repeat(block_tables, K+1, axis=0)`` — here every row's K+1
 tokens share one descriptor and one block-table row.
 
+What Mosaic accepts (checked by tests/test_tpu_lowering.py, and by an
+ahead-of-time v5e compile in its slow tier) shapes two choices here:
+
+- the pool is head-major so one (page, kv head) block is a contiguous
+  ``[bs, D]`` tile whose last two dims ARE the array's last two dims.
+  A token-major ``[NB, bs, Nkv, D]`` pool needs a ``(1, bs, 1, D)``
+  block, which squeezes the second-minor axis — refused at lowering —
+  and walking heads inside a whole-page block needs a dynamic index
+  on a packed (bf16/int8) sublane axis, which Mosaic refuses too;
+- q and the output cross the kernel boundary in float32.  A row
+  starts at ANY flat token, so its ``pl.ds(off, tqg)`` slices are
+  dynamic and unaligned; Mosaic proves alignment only for 32-bit
+  rows.  The kernel accumulated in f32 already, so the staging casts
+  (bf16 -> f32 in, f32 -> bf16 out) are the same two roundings the
+  in-kernel casts performed — results are unchanged.
+
 Like the other kernels, the 1/sqrt(D) scale is applied INSIDE; the
 masked-XLA fallback (inference/llm/paged_attention.py) computes
 bitwise-defined identical semantics everywhere the kernel is gated
@@ -63,6 +79,9 @@ from jax.experimental.pallas import tpu as pltpu
 from . import registry
 
 _NEG_INF = -1e30
+# the Mosaic custom call's kernel_name in a lowered step, and the name
+# a device trace shows
+KERNEL_NAME = "ragged_paged_attention"
 # query tokens processed per inner chunk: one f32 sublane tile when
 # G == 1, a multiple of it otherwise — the flat axis is padded by one
 # chunk so a row's tail chunk can spill without leaving the block
@@ -93,15 +112,18 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref, pos0_ref,
     exactly zero.
 
     ``quant=True`` (static) adds two page-scale operands after the K/V
-    blocks — int8 pages dequantize AT THE OPERAND LOAD into the same
-    f32 accumulation the full-precision path runs, one multiply per
-    loaded slot row; no dequantized copy of the pool ever exists.
+    blocks, each the page's whole [Nkv, bs] scale tile; this head's
+    row is a [1, bs] LANE vector, so the dequant multiply lands where
+    slots are lanes — on the scores (``(q . k8) * ks``) and on the
+    probabilities (``(p * vs) @ v8``) — which equals dequantizing the
+    page rows first; no dequantized copy of the pool ever exists.
     """
     if quant:
         (q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
          o_scr, m_scr, l_scr) = refs
     else:
         q_ref, k_ref, v_ref, o_ref, o_scr, m_scr, l_scr = refs
+    j = pl.program_id(0)
     r = pl.program_id(1)
     p = pl.program_id(2)
     num_pages = pl.num_programs(2)
@@ -145,17 +167,19 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref, pos0_ref,
     # real state before any fully-masked page can touch them
     @pl.when(base < pos0 + qlen)
     def _accumulate():
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # [bs, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # [bs, D]
+        k = k_ref[0, 0].astype(jnp.float32)                 # [bs, D]
+        v = v_ref[0, 0].astype(jnp.float32)                 # [bs, D]
         if quant:
-            # per-(slot, head) dequant scales of this page/head block
-            k = k * ks_ref[0, 0, :][:, None]
-            v = v * vs_ref[0, 0, :][:, None]
+            # per-slot dequant scales of this head: [1, bs] lane rows
+            ks = ks_ref[0, pl.ds(j, 1), :]
+            vs = vs_ref[0, pl.ds(j, 1), :]
 
         def acc_chunk(c):
             off = (start + c * _TQ) * group
-            q = q_ref[0, pl.ds(off, tqg), :].astype(jnp.float32)
+            q = q_ref[0, pl.ds(off, tqg), :]
             s = q @ k.T / jnp.sqrt(jnp.asarray(d, jnp.float32))
+            if quant:
+                s = s * ks
             # flat row i of the chunk is query token c*_TQ + i//G of
             # this batch row, at absolute position pos0 + that index
             ti = c * _TQ + jax.lax.broadcasted_iota(
@@ -170,7 +194,8 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref, pos0_ref,
             m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
             pe = jnp.exp(s - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            o_scr[pl.ds(off, tqg), :] = o_prev * alpha + pe @ v
+            pv = (pe * vs if quant else pe) @ v
+            o_scr[pl.ds(off, tqg), :] = o_prev * alpha + pv
             m_scr[pl.ds(off, tqg), :] = m_new
             l_scr[pl.ds(off, tqg), :] = \
                 l_prev * alpha + pe.sum(axis=1, keepdims=True)
@@ -185,23 +210,28 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref, pos0_ref,
             o = o_scr[pl.ds(off, tqg), :] \
                 / jnp.maximum(l_scr[pl.ds(off, tqg), :], 1e-30)
             cur = o_ref[0, pl.ds(off, tqg), :]
-            o_ref[0, pl.ds(off, tqg), :] = \
-                jnp.where(ti < qlen, o.astype(o_ref.dtype), cur)
+            o_ref[0, pl.ds(off, tqg), :] = jnp.where(ti < qlen, o, cur)
         each_chunk(fin_chunk)
 
 
-def _engine_cases(engine):
+def _cases(engine, quant):
     """Every launch the serving engine makes IS this kernel now: one
     case per token bucket of the collapsed ``_bucket_grid()`` family,
     with the fixed [max_batch, max_pages] descriptor rails.  The
     scalar_bounds let K003 prove the block-table prefetch indirection
     in-bounds (page ids in [0, num_blocks - 1]) and bound the row
-    descriptors by the token bucket / model horizon."""
+    descriptors by the token bucket / model horizon.  ``quant`` yields
+    the int8-KV family instead: int8 pools, each with its
+    [NB, Nkv, bs] f32 page-scale operand."""
     nkv = max(engine.num_heads // engine.tp, 1)
     d = engine.head_dim
     sds = jax.ShapeDtypeStruct
-    kp = sds((engine.num_blocks, engine.block_size, nkv, d),
-             engine.dtype)
+    kp = sds((engine.num_blocks, nkv, engine.block_size, d),
+             jnp.int8 if quant else engine.dtype)
+    sp = sds((engine.num_blocks, nkv, engine.block_size), jnp.float32)
+    pools = (kp, kp, sp, sp) if quant else (kp, kp)
+    fn = paged_ragged_attention_quant_pallas if quant \
+        else paged_ragged_attention_pallas
     rmax = engine.max_batch
     for kind, tb in engine._bucket_grid():
         if kind != "ragged":
@@ -211,11 +241,15 @@ def _engine_cases(engine):
         bounds = {0: (0, engine.num_blocks - 1), 1: (0, tb),
                   2: (0, tb), 3: (0, engine.max_model_len - 1)}
         yield registry.KernelCase(
-            f"ragged[{tb}]", paged_ragged_attention_pallas,
-            (sds((tb, nkv, d), engine.dtype), kp, kp,
-             sds((rmax, engine.max_pages), jnp.int32),
-             sds((rmax,), jnp.int32), sds((rmax,), jnp.int32),
-             sds((rmax,), jnp.int32)), bounds)
+            f"ragged{'_quant' if quant else ''}[{tb}]", fn,
+            (sds((tb, nkv, d), engine.dtype),) + pools
+            + (sds((rmax, engine.max_pages), jnp.int32),
+               sds((rmax,), jnp.int32), sds((rmax,), jnp.int32),
+               sds((rmax,), jnp.int32)), bounds)
+
+
+def _engine_cases(engine):
+    return _cases(engine, quant=False)
 
 
 @registry.register_kernel(
@@ -235,34 +269,43 @@ def paged_ragged_attention_pallas(q, k_pages, v_pages, block_tables,
     the module docstring for the row-descriptor layout and the host
     packing contract.
     """
+    return _launch(q, k_pages, v_pages, (), block_tables, row_start,
+                   row_qlen, row_pos0, interpret)
+
+
+def _launch(q, k_pages, v_pages, scales, block_tables, row_start,
+            row_qlen, row_pos0, interpret):
+    """The one pallas_call behind both entry points; ``scales`` is ()
+    or the int8 pool's (k_scales, v_scales)."""
     t, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
+    _, nkv, bs, _ = k_pages.shape
     r, num_pages = block_tables.shape
     g = nq // nkv
     nc = t // _TQ
     tg = (t + _TQ) * g          # one chunk of spill slack
-    # [T, Nkv, G, D] -> [Nkv, T*G, D]: flat row i of head j is query
-    # token i // G, padded so a tail chunk never leaves the block
-    qg = q.reshape(t, nkv, g, d).transpose(1, 0, 2, 3)
+    # [T, Nkv, G, D] -> [Nkv, T*G, D] in f32 (see module docstring):
+    # flat row i of head j is query token i // G, padded so a tail
+    # chunk never leaves the block
+    qg = q.astype(jnp.float32).reshape(t, nkv, g, d).transpose(1, 0, 2, 3)
     qg = jnp.pad(qg.reshape(nkv, t * g, d), ((0, 0), (0, _TQ * g),
                                              (0, 0)))
 
+    q_spec = pl.BlockSpec((1, tg, d),
+                          lambda j, rr, p, bt, st, ql, p0: (j, 0, 0))
+    page_spec = pl.BlockSpec((1, 1, bs, d),
+                             lambda j, rr, p, bt, st, ql, p0:
+                             (bt[rr, p], j, 0, 0))
+    # a (1, 1, bs) block of the [NB, Nkv, bs] scales would squeeze the
+    # second-minor axis; the whole-page tile is Nkv*bs floats
+    scale_spec = pl.BlockSpec((1, nkv, bs),
+                              lambda j, rr, p, bt, st, ql, p0:
+                              (bt[rr, p], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(nkv, r, num_pages),
-        in_specs=[
-            pl.BlockSpec((1, tg, d),
-                         lambda j, rr, p, bt, st, ql, p0: (j, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda j, rr, p, bt, st, ql, p0:
-                         (bt[rr, p], 0, j, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda j, rr, p, bt, st, ql, p0:
-                         (bt[rr, p], 0, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tg, d),
-                               lambda j, rr, p, bt, st, ql, p0:
-                               (j, 0, 0)),
+        in_specs=[q_spec, page_spec, page_spec]
+        + [scale_spec] * len(scales),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((tg, d), jnp.float32),
             pltpu.VMEM((tg, 1), jnp.float32),
@@ -271,44 +314,23 @@ def paged_ragged_attention_pallas(q, k_pages, v_pages, block_tables,
     )
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, block_size=bs, group=g,
-                          nc=nc),
+                          nc=nc, quant=len(scales) > 0),
+        name=KERNEL_NAME + ("_int8" if scales else ""),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nkv, tg, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((nkv, tg, d), jnp.float32),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), row_start.astype(jnp.int32),
       row_qlen.astype(jnp.int32), row_pos0.astype(jnp.int32),
-      qg, k_pages, v_pages)
+      qg, k_pages, v_pages, *scales)
     return out[:, :t * g].reshape(nkv, t, g, d).transpose(
-        1, 0, 2, 3).reshape(t, nq, d)
+        1, 0, 2, 3).reshape(t, nq, d).astype(q.dtype)
 
 
 def _quant_engine_cases(engine):
-    """Launch shapes of the int8-KV ragged family — yielded only for a
-    KV-quantized engine (a full-precision engine never launches this
-    kernel, so its sweep stays the bf16 entry's).  Same descriptor
-    rails and scalar bounds as ``_engine_cases``; the pools are int8
-    and each carries its [NB, Nkv, bs] f32 page-scale operand."""
-    if not getattr(engine, "_kv_quant", False):
-        return
-    nkv = max(engine.num_heads // engine.tp, 1)
-    d = engine.head_dim
-    sds = jax.ShapeDtypeStruct
-    kp = sds((engine.num_blocks, engine.block_size, nkv, d), jnp.int8)
-    sp = sds((engine.num_blocks, nkv, engine.block_size), jnp.float32)
-    rmax = engine.max_batch
-    for kind, tb in engine._bucket_grid():
-        if kind != "ragged":
-            continue
-        if not supports(engine.block_size, d, nkv, nkv, tb):
-            continue
-        bounds = {0: (0, engine.num_blocks - 1), 1: (0, tb),
-                  2: (0, tb), 3: (0, engine.max_model_len - 1)}
-        yield registry.KernelCase(
-            f"ragged_quant[{tb}]", paged_ragged_attention_quant_pallas,
-            (sds((tb, nkv, d), engine.dtype), kp, kp, sp, sp,
-             sds((rmax, engine.max_pages), jnp.int32),
-             sds((rmax,), jnp.int32), sds((rmax,), jnp.int32),
-             sds((rmax,), jnp.int32)), bounds)
+    """Yielded only for a KV-quantized engine (a full-precision engine
+    never launches this kernel, so its sweep stays the bf16 entry's)."""
+    if getattr(engine, "_kv_quant", False):
+        yield from _cases(engine, quant=True)
 
 
 @registry.register_kernel(
@@ -329,53 +351,8 @@ def paged_ragged_attention_quant_pallas(q, k_pages, v_pages, k_scales,
     ``k_scales``/``v_scales`` [NB, Nkv, bs] float32 — one symmetric
     dequant scale per (page, kv head, slot), written by the engine's
     quantized append (inference/llm/quant.py).  Each (kv head, row,
-    page) program loads its int8 [bs, D] page block and its [bs] scale
-    row, dequantizes in f32 registers, and runs the identical
+    page) program loads its int8 [bs, D] page block and the page's
+    scale tile, dequantizes in f32 registers, and runs the identical
     online-softmax walk — HBM reads stay 1 byte per pool element."""
-    t, nq, d = q.shape
-    _, bs, nkv, _ = k_pages.shape
-    r, num_pages = block_tables.shape
-    g = nq // nkv
-    nc = t // _TQ
-    tg = (t + _TQ) * g          # one chunk of spill slack
-    qg = q.reshape(t, nkv, g, d).transpose(1, 0, 2, 3)
-    qg = jnp.pad(qg.reshape(nkv, t * g, d), ((0, 0), (0, _TQ * g),
-                                             (0, 0)))
-
-    page_spec = pl.BlockSpec((1, bs, 1, d),
-                             lambda j, rr, p, bt, st, ql, p0:
-                             (bt[rr, p], 0, j, 0))
-    scale_spec = pl.BlockSpec((1, 1, bs),
-                              lambda j, rr, p, bt, st, ql, p0:
-                              (bt[rr, p], j, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(nkv, r, num_pages),
-        in_specs=[
-            pl.BlockSpec((1, tg, d),
-                         lambda j, rr, p, bt, st, ql, p0: (j, 0, 0)),
-            page_spec,
-            page_spec,
-            scale_spec,
-            scale_spec,
-        ],
-        out_specs=pl.BlockSpec((1, tg, d),
-                               lambda j, rr, p, bt, st, ql, p0:
-                               (j, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tg, d), jnp.float32),
-            pltpu.VMEM((tg, 1), jnp.float32),
-            pltpu.VMEM((tg, 1), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_ragged_kernel, block_size=bs, group=g,
-                          nc=nc, quant=True),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nkv, tg, d), q.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), row_start.astype(jnp.int32),
-      row_qlen.astype(jnp.int32), row_pos0.astype(jnp.int32),
-      qg, k_pages, v_pages, k_scales, v_scales)
-    return out[:, :t * g].reshape(nkv, t, g, d).transpose(
-        1, 0, 2, 3).reshape(t, nq, d)
+    return _launch(q, k_pages, v_pages, (k_scales, v_scales),
+                   block_tables, row_start, row_qlen, row_pos0, interpret)

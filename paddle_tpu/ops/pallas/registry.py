@@ -20,6 +20,11 @@ ship without:
   ``[0, num_blocks - 1]``), which is what lets K003 prove index maps
   in-bounds through the prefetch indirection.
 
+A kernel the TPU compiler does not accept yet also declares
+**tpu_refusal** — the reason, which its TPU gates raise; every
+other entry must lower for TPU wherever its ``supports()`` says yes
+(tests/test_tpu_lowering.py holds both to it).
+
 The decorator is a zero-overhead passthrough: it records the entry and
 returns the function unchanged, so registration costs nothing on the
 serving hot path.  :mod:`paddle_tpu.framework.kernel_lint` consumes the
@@ -57,10 +62,10 @@ class KernelEntry:
     """One registered kernel entry point (see module docstring)."""
 
     __slots__ = ("name", "fn", "fallback", "parity", "engine_shapes",
-                 "supports", "grad")
+                 "supports", "grad", "tpu_refusal")
 
     def __init__(self, name, fn, fallback, parity, engine_shapes,
-                 supports, grad):
+                 supports, grad, tpu_refusal):
         self.name = name
         self.fn = fn
         self.fallback = fallback
@@ -68,24 +73,27 @@ class KernelEntry:
         self.engine_shapes = engine_shapes
         self.supports = supports
         self.grad = grad
+        self.tpu_refusal = tpu_refusal
 
     def __repr__(self):
         return f"KernelEntry({self.name!r} -> {self.fallback!r})"
 
 
 def register_kernel(name, *, fallback, parity, engine_shapes,
-                    supports=None, grad=False):
+                    supports=None, grad=False, tpu_refusal=None):
     """Decorator registering a kernel entry point under ``name``.
 
     ``supports`` is the module's hand-written shape gate (consulted by
     the supports-vs-lint consistency tests); ``grad=True`` declares that
     the entry differentiates through a custom_vjp and its
     ``engine_shapes`` cases include a grad-traced case covering the
-    backward kernels.
+    backward kernels.  ``tpu_refusal`` names why the kernel does not
+    compile for TPU yet (interpret-mode only); None for all others.
     """
     def deco(fn):
         _REGISTRY[name] = KernelEntry(name, fn, fallback, parity,
-                                      engine_shapes, supports, grad)
+                                      engine_shapes, supports, grad,
+                                      tpu_refusal)
         return fn
     return deco
 

@@ -116,7 +116,12 @@ class Optimizer:
             if self._weight_decay and not self._decay_applied_in_rule():
                 g = g + float(self._weight_decay) * p
             np_, ns = self._update(p, g, s, lr, ctx)
-            new_p.append(np_)
+            # ``lr`` arrives as a float32 ARRAY here, so a rule that
+            # multiplies it into a bf16 parameter (AdamW's decay, SGD)
+            # promotes the result: without this cast amp-O2 params turn
+            # float32 after the first step, which retraces the step and
+            # trains the rest of the run in float32
+            new_p.append(np_.astype(p.dtype))
             new_s.append(ns)
         return (jax.tree_util.tree_unflatten(treedef, new_p),
                 jax.tree_util.tree_unflatten(treedef, new_s))

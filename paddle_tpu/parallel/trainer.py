@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
+from ..distributed.fleet.spmd import use_mesh
 from ..framework.random import get_rng_key, key_stream
 from .pipeline import spmd_pipeline
 
@@ -229,7 +230,9 @@ class SpmdTrainStep:
         lbl = jax.device_put(lbl, self.batch_sharding)
         lr = jnp.float32(self.optimizer.get_lr())
         key = get_rng_key()
-        with self.mesh:
+        # use_mesh, not a bare ``with mesh``: the kernel dispatchers read
+        # fleet.spmd.current_mesh() to know GSPMD partitions this step
+        with use_mesh(self.mesh):
             if self.scaler is not None:
                 loss, self.params, self.opt_state, new_sstate = \
                     self._compiled(self.params, self.opt_state,

@@ -77,8 +77,6 @@ class TestSeededBugs:
     def test_s001_shard_map_axis_not_on_declared_mesh(self):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from paddle_tpu.framework import jax_compat
-        jax_compat.ensure_compat()
         devs = jax.devices()
         assert len(devs) >= 2               # conftest forces 8 virtual
         mesh_dp = Mesh(np.array(devs[:2]), ("dp",))
@@ -106,9 +104,7 @@ class TestSeededBugs:
         assert fs and fs[0].rule == "S001"
 
     def test_t001_f64_leak(self):
-        from jax.experimental import enable_x64
-
-        with enable_x64():
+        with jax.enable_x64(True):
             f = jax.jit(lambda x: x * np.float64(0.5))
             fs = A.analyze_jitted(f, SDS((4,), jnp.float64))
         hits = [x for x in fs if x.rule == "T001" and

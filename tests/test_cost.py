@@ -169,7 +169,6 @@ class TestC001Seeded:
     and stays silent on the legitimate per-iteration pattern."""
 
     def test_loop_invariant_psum_in_scan(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def bad(xs, w):
@@ -179,22 +178,21 @@ class TestC001Seeded:
             c, _ = jax.lax.scan(body, jnp.zeros(xs.shape[1:]), xs)
             return c
 
-        f = shard_map(bad, mesh=_mesh2(), in_specs=(P(), P()),
-                      out_specs=P(), check_rep=False)
+        f = jax.shard_map(bad, mesh=_mesh2(), in_specs=(P(), P()),
+                          out_specs=P(), check_vma=False)
         closed = jax.jit(f).trace(jnp.ones((4, 2)), jnp.ones((2,))).jaxpr
         fs = C.check_collectives(closed, label="seeded")
         assert [f.rule for f in fs] == ["C001"]
         assert "loop-invariant" in fs[0].message
 
     def test_redundant_psum_of_psum(self):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def bad(x):
             return jax.lax.psum(jax.lax.psum(x, "mp"), "mp")
 
-        f = shard_map(bad, mesh=_mesh2(), in_specs=P(), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(bad, mesh=_mesh2(), in_specs=P(),
+                          out_specs=P(), check_vma=False)
         closed = jax.jit(f).trace(jnp.ones((2,))).jaxpr
         fs = C.check_collectives(closed)
         assert [f.rule for f in fs] == ["C001"]
@@ -203,7 +201,6 @@ class TestC001Seeded:
     def test_carry_dependent_psum_is_clean(self):
         """The shipped per-layer pattern: the reduced value depends on
         the loop carry, so it is NOT hoistable and must not fire."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         def good(xs):
@@ -213,8 +210,8 @@ class TestC001Seeded:
             c, _ = jax.lax.scan(body, jnp.zeros(xs.shape[1:]), xs)
             return c
 
-        f = shard_map(good, mesh=_mesh2(), in_specs=P(), out_specs=P(),
-                      check_rep=False)
+        f = jax.shard_map(good, mesh=_mesh2(), in_specs=P(),
+                          out_specs=P(), check_vma=False)
         closed = jax.jit(f).trace(jnp.ones((4, 2))).jaxpr
         assert C.check_collectives(closed) == []
 

@@ -448,8 +448,8 @@ class TestSupportsConsistency:
                 fs = KL.analyze_kernel(
                     paged_ragged_attention_pallas,
                     SDS((t, 4, d), jnp.float32),
-                    SDS((nb, bs, 2, d), jnp.float32),
-                    SDS((nb, bs, 2, d), jnp.float32),
+                    SDS((nb, 2, bs, d), jnp.float32),
+                    SDS((nb, 2, bs, d), jnp.float32),
                     SDS((4, pages), jnp.int32),
                     SDS((4,), jnp.int32),
                     SDS((4,), jnp.int32),

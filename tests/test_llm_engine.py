@@ -277,8 +277,11 @@ class TestPagedAttention:
         rng = np.random.RandomState(seed)
         nb = b * pages
         q = rng.randn(b, nq, d).astype(np.float32)
-        kp = rng.randn(nb, bs, nkv, d).astype(np.float32)
-        vp = rng.randn(nb, bs, nkv, d).astype(np.float32)
+        # drawn token-major, stored head-major [NB, Nkv, bs, D]
+        kp = rng.randn(nb, bs, nkv, d).astype(np.float32) \
+            .transpose(0, 2, 1, 3)
+        vp = rng.randn(nb, bs, nkv, d).astype(np.float32) \
+            .transpose(0, 2, 1, 3)
         bt = rng.permutation(nb).reshape(b, pages).astype(np.int32)
         lens = np.array([5, 0, 30], np.int32)[:b]
         return q, kp, vp, bt, lens
@@ -295,9 +298,9 @@ class TestPagedAttention:
         out = paged_decode_attention_xla(*map(jnp.asarray,
                                               (q, kp, vp, bt, lens)))
         b, pages = bt.shape
-        bs = kp.shape[1]
-        k = kp[bt].reshape(b, pages * bs, *kp.shape[2:])
-        v = vp[bt].reshape(b, pages * bs, *vp.shape[2:])
+        nkv, bs, d = kp.shape[1:]
+        k = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, pages * bs, nkv, d)
+        v = vp[bt].transpose(0, 1, 3, 2, 4).reshape(b, pages * bs, nkv, d)
         ref = decode_attention_xla(jnp.asarray(q), jnp.asarray(k),
                                    jnp.asarray(v), jnp.asarray(lens))
         np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
@@ -316,8 +319,10 @@ class TestPagedAttention:
         rng = np.random.RandomState(7)
         nb = b * pages
         q = rng.randn(b, nq, d).astype(np.float32)
-        kp = rng.randn(nb, bs, nkv, d).astype(np.float32)
-        vp = rng.randn(nb, bs, nkv, d).astype(np.float32)
+        kp = rng.randn(nb, bs, nkv, d).astype(np.float32) \
+            .transpose(0, 2, 1, 3)
+        vp = rng.randn(nb, bs, nkv, d).astype(np.float32) \
+            .transpose(0, 2, 1, 3)
         bt = rng.permutation(nb).reshape(b, pages).astype(np.int32)
         lens = np.array([5, 0, 30, 1, 2, 8, 32, 17], np.int32)
         # decode rows as ragged descriptors: one query token per live
@@ -748,8 +753,8 @@ class TestTensorParallel:
         m = _make_model()
         tp = LLMEngine(m, block_size=8, max_batch=2, max_model_len=64,
                        tensor_parallel=4)
-        # pool: [L, NB, bs, Nkv/mp, D] per shard — axis 3 carries 'mp'
-        assert tp._kc.sharding.spec == P(None, None, None, "mp", None)
+        # pool: [L, NB, Nkv/mp, bs, D] per shard — axis 2 carries 'mp'
+        assert tp._kc.sharding.spec == P(None, None, "mp", None, None)
         qkv = tp.params["blocks"]["attn.qkv.weight"]
         assert qkv.sharding.spec == P(None, None, "mp")
         proj = tp.params["blocks"]["attn.proj.weight"]
@@ -995,8 +1000,10 @@ class TestSpeculative:
         rng = np.random.RandomState(3)
         b, t, nq, nkv, d, bs, pages = 2, 3, 4, 2, 16, 8, 4
         q = jnp.asarray(rng.randn(b, t, nq, d), jnp.float32)
-        kp = jnp.asarray(rng.randn(b * pages, bs, nkv, d), jnp.float32)
-        vp = jnp.asarray(rng.randn(b * pages, bs, nkv, d), jnp.float32)
+        kp = jnp.asarray(rng.randn(b * pages, bs, nkv, d), jnp.float32) \
+            .transpose(0, 2, 1, 3)
+        vp = jnp.asarray(rng.randn(b * pages, bs, nkv, d), jnp.float32) \
+            .transpose(0, 2, 1, 3)
         tables = jnp.asarray(
             rng.permutation(b * pages)[:b * pages]
             .reshape(b, pages), jnp.int32)

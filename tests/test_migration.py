@@ -258,8 +258,8 @@ class TestEngineMigration:
         before = e1.block_manager.num_free_blocks
         with pytest.raises(ValueError, match="payload"):
             e1.import_request(state["request"], state["seq"],
-                              state["k_pages"][:, :, :4],
-                              state["v_pages"][:, :, :4])
+                              state["k_pages"][:, :, :, :4],
+                              state["v_pages"][:, :, :, :4])
         assert e1.block_manager.num_free_blocks == before
 
     def test_import_fault_reclaims_exactly(self):

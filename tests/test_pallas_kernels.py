@@ -218,9 +218,10 @@ class TestRaggedAttention:
 
     def _pool(self, NB=6, BS=8, NKV=2, D=16, seed=0):
         rng = np.random.RandomState(seed)
+        # drawn token-major, stored head-major [NB, NKV, BS, D]
         k = jnp.asarray(rng.rand(NB, BS, NKV, D).astype(np.float32))
         v = jnp.asarray(rng.rand(NB, BS, NKV, D).astype(np.float32))
-        return k, v
+        return k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
 
     def _token_descriptors(self, T, row_start, row_qlen, row_pos0):
         """The per-token (ctx, rows) form of the per-row descriptors —
@@ -392,7 +393,8 @@ class TestRaggedAttentionQuant:
         for _ in range(2):
             f = jnp.asarray(rng.randn(NB, BS, NKV, D).astype(np.float32))
             q, s = quantize_kv_rows(f)           # s: [NB, BS, NKV]
-            out += [q, jnp.transpose(s, (0, 2, 1))]   # pool layout
+            out += [jnp.transpose(q, (0, 2, 1, 3)),   # pool layouts:
+                    jnp.transpose(s, (0, 2, 1))]      # head-major
         kq, ks, vq, vs = out
         return kq, vq, ks, vs
 
@@ -513,10 +515,8 @@ class TestRaggedAttentionQuant:
         got = paged_ragged_attention_quant_xla(q, kq, vq, ks, vs, bt,
                                                ctx, rows)
         # dequantize the pools on the host and run the f32 reference
-        kf = dequantize_kv_rows(jnp.transpose(kq, (0, 2, 1, 3)),
-                                ks).transpose(0, 2, 1, 3)
-        vf = dequantize_kv_rows(jnp.transpose(vq, (0, 2, 1, 3)),
-                                vs).transpose(0, 2, 1, 3)
+        kf = dequantize_kv_rows(kq, ks)     # pools are head-major
+        vf = dequantize_kv_rows(vq, vs)
         ref = paged_ragged_attention_xla(q, kf.astype(jnp.float32),
                                          vf.astype(jnp.float32), bt,
                                          ctx, rows)

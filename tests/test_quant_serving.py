@@ -364,7 +364,7 @@ class TestQuantDtypeLint:
         def bad_dequant(q, s64):
             return q.astype(jnp.float64) * s64
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             closed = jax.make_jaxpr(bad_dequant)(
                 jax.ShapeDtypeStruct((8, 16), jnp.int8),
                 jax.ShapeDtypeStruct((1, 16), jnp.float64))

@@ -1,0 +1,158 @@
+"""Every registered Pallas kernel against the TPU compiler, from the CPU.
+
+The kernels run here only in interpret mode, which accepts block shapes
+the TPU lowering refuses.  Two screens keep "passes in interpret mode"
+from meaning "has never met Mosaic":
+
+- tier-1: cross-lower each registry entry for platform ``tpu`` on this
+  host (the Pallas->Mosaic block-shape rules run; no TPU needed) at the
+  shapes a GPT-124M-width and a head_dim-128 engine launch, plus the
+  training shapes.  An entry lowers wherever its ``supports()`` let the
+  case through, unless it declares a ``tpu_refusal`` — and then it must
+  really fail, so the refusal is deleted with the fault;
+- slow: the full Mosaic compile of the same cases for a compile-only
+  v5e topology, which also enforces the alignment proofs lowering
+  cannot see (dynamic sublane offsets on packed dtypes).
+"""
+
+import functools
+import types
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.inference.llm import LLMEngine
+from paddle_tpu.ops.pallas import registry
+from paddle_tpu.ops.pallas.layernorm_kernel import layernorm_pallas
+
+
+def _engine_like(num_heads, head_dim, dtype):
+    """What the ``engine_shapes`` builders read off an engine, at the
+    sizes ``chip_smoke.py`` serves with, without allocating weights or
+    pools.  ``_kv_quant`` is on so the int8 entry yields its cases too
+    (the full-precision entries ignore it)."""
+    e = types.SimpleNamespace(
+        num_heads=num_heads, head_dim=head_dim,
+        hidden=num_heads * head_dim, tp=1, dtype=jnp.dtype(dtype),
+        block_size=16, max_batch=8, max_model_len=1024, max_pages=64,
+        num_blocks=8 * 64, token_budget=256, _kv_quant=True)
+    e._bucket_grid = functools.partial(LLMEngine._bucket_grid, e)
+    return e
+
+
+def _train_layernorm_cases():
+    """Fused layernorm at the GPT-124M training activations (the
+    registry's own layernorm cases are float32 at serving rows)."""
+    def vjp(x, g, b):
+        def loss(*a):
+            return jnp.sum(layernorm_pallas(*a).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(x, g, b)
+
+    sds = jax.ShapeDtypeStruct
+    w = sds((768,), jnp.bfloat16)
+    for seq in (1024, 512):
+        x = sds((8, seq, 768), jnp.bfloat16)
+        yield registry.KernelCase(f"train_vjp[8x{seq}x768]", vjp,
+                                  (x, w, w), None)
+
+
+def _cases():
+    """(id, entry, case) for every registry entry at every engine."""
+    engines = {"gpt124m-bf16": _engine_like(12, 64, jnp.bfloat16),
+               "gpt124m-f32": _engine_like(12, 64, jnp.float32),
+               "hd128-bf16": _engine_like(32, 128, jnp.bfloat16)}
+    for name, entry in sorted(registry.load_all().items()):
+        for ename, eng in engines.items():
+            for case in entry.engine_shapes(eng):
+                yield f"{name}/{ename}/{case.label}", entry, case
+        if name == "layernorm":
+            for case in _train_layernorm_cases():
+                yield f"{name}/{case.label}", entry, case
+
+
+def test_registry_lowers_for_tpu_where_supported():
+    lowered = 0
+    for cid, entry, case in _cases():
+        traced = jax.jit(case.fn).trace(*case.args)
+        if entry.tpu_refusal is not None:
+            with pytest.raises(Exception, match="last two dimensions"):
+                traced.lower(lowering_platforms=("tpu",))
+            continue
+        try:
+            text = traced.lower(lowering_platforms=("tpu",)).as_text()
+        except Exception as e:      # noqa: BLE001 — name the case
+            pytest.fail(f"{cid}: supports() says yes, the TPU lowering "
+                        f"says no: {str(e).splitlines()[0]}")
+        assert "tpu_custom_call" in text, cid
+        lowered += 1
+    # ragged + ragged_quant over 6 buckets x 3 engines, flash and
+    # layernorm fwd+vjp x 3, the two training layernorm shapes
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 * 3 + 2
+
+
+def test_refusals_are_declared_only_where_needed():
+    refused = {n for n, e in registry.load_all().items()
+               if e.tpu_refusal is not None}
+    assert refused == {"decode_attention"}
+
+
+def test_gspmd_partitioning_is_seen_at_trace_time():
+    """JAX refuses a Mosaic kernel in a computation GSPMD partitions
+    ("cannot be automatically partitioned"); the dispatchers must know
+    before they pick one: off under a multi-device mesh, on again
+    inside a shard_map that makes every axis manual."""
+    import numpy as np
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+    from paddle_tpu.ops.pallas import _partitioned_by_gspmd
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+    seen = {}
+
+    def probe(tag):
+        def f(x):
+            seen[tag] = _partitioned_by_gspmd()
+            return x
+        return f
+
+    x = jnp.ones((4,))
+    jax.jit(probe("plain jit"))(x)
+    with use_mesh(mesh):
+        jax.jit(probe("under the mesh"))(x)
+    jax.jit(jax.shard_map(probe("all axes manual"), mesh=mesh,
+                          in_specs=P("dp"), out_specs=P("dp"),
+                          check_vma=False))(x)
+    jax.jit(jax.shard_map(probe("one axis manual"), mesh=mesh,
+                          in_specs=P("dp"), out_specs=P("dp"),
+                          axis_names={"dp"}, check_vma=False))(x)
+    assert seen == {"plain jit": False, "under the mesh": True,
+                    "all axes manual": False, "one axis manual": True}
+
+
+@pytest.mark.slow
+def test_registry_compiles_for_v5e():
+    """The real Mosaic compile, ahead of time, for a device this host
+    does not have.  libtpu prints a TPU_ACCELERATOR_TYPE warning and
+    carries on; where it cannot describe the topology, skip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # noqa: BLE001 — no usable libtpu
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    dev = SingleDeviceSharding(topo.devices[0])
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    for cid, entry, case in _cases():
+        if entry.tpu_refusal is not None:
+            continue
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=dev)
+                for a in case.args]
+        try:
+            jax.jit(case.fn).lower(*args).compile()
+        except Exception as e:      # noqa: BLE001 — name the case
+            pytest.fail(f"{cid}: Mosaic refuses: "
+                        f"{str(e).splitlines()[0]}")
