@@ -252,7 +252,11 @@ def scaler_guarded_update(scaler, scaler_state, grads, grad_clip, optimizer,
                           params, opt_state, step, lr):
     """Shared compiled-step epilogue: unscale, clip, update, and keep the
     old params/opt-state when non-finite gradients were found."""
-    grads, found_inf, new_sstate = scaler_apply(scaler, scaler_state, grads)
+    # unscaling and the guarded merge are the update's own work: the same
+    # trace scope as ``apply_gradients_pytree`` opens for itself
+    with jax.named_scope("optimizer"):
+        grads, found_inf, new_sstate = scaler_apply(scaler, scaler_state,
+                                                    grads)
     if grad_clip is not None:
         grads = grad_clip.clip_pytree(grads)
     cand_params, cand_opt = optimizer.apply_gradients_pytree(
@@ -262,7 +266,9 @@ def scaler_guarded_update(scaler, scaler_state, grads, grad_clip, optimizer,
         return jax.tree_util.tree_map(
             lambda o, n: jnp.where(found_inf, o, n), old, new)
 
-    return merge(params, cand_params), merge(opt_state, cand_opt), new_sstate
+    with jax.named_scope("optimizer"):
+        return (merge(params, cand_params), merge(opt_state, cand_opt),
+                new_sstate)
 
 
 from . import debugging  # noqa: E402,F401

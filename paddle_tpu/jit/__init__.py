@@ -28,6 +28,7 @@ from ..core.tensor import Tensor
 from ..framework import mode
 from ..framework.random import get_rng_key, key_stream
 from ..nn.layer_base import Layer
+from ..profiler import RecordEvent, StepTrace
 
 _is_tensor = lambda x: isinstance(x, Tensor)
 
@@ -301,6 +302,7 @@ class TrainStep:
         self._opt_state = optimizer.init_state_pytree(self._params)
         self._step = 0
         self._compiled = None
+        self._trace = StepTrace()
         self._donate = donate
         # loss scaling composed INTO the compiled step (reference
         # fleet/scaler.py distributed_scaler + update_loss_scaling_ kernel)
@@ -327,7 +329,7 @@ class TrainStep:
                     lambda d: Tensor(d) if isinstance(d, jax.Array) else d, out)
                 label_t = tuple(Tensor(l) if isinstance(l, jax.Array) else l
                                 for l in labels)
-                with mode.grad_enabled(False):
+                with mode.grad_enabled(False), jax.named_scope("loss"):
                     loss = loss_fn(out_t, *label_t)
                 return loss._data if isinstance(loss, Tensor) else loss
 
@@ -337,7 +339,8 @@ class TrainStep:
                 loss_f = jax.checkpoint(loss_f)
             return loss_f
 
-        def step_fn(params, frozen, opt_state, step, lr, key, inputs, labels):
+        def train_step(params, frozen, opt_state, step, lr, key, inputs,
+                       labels):
             loss_f = make_loss_f(frozen, key, inputs, labels)
             loss, grads = jax.value_and_grad(loss_f)(params)
             if grad_clip is not None:
@@ -348,8 +351,8 @@ class TrainStep:
 
         scaler = self.scaler
 
-        def step_fn_scaled(params, frozen, opt_state, step, lr, key, inputs,
-                           labels, scaler_state):
+        def train_step_scaled(params, frozen, opt_state, step, lr, key,
+                              inputs, labels, scaler_state):
             from ..amp import scaler_guarded_update
             loss_f = make_loss_f(frozen, key, inputs, labels)
 
@@ -366,7 +369,7 @@ class TrainStep:
 
         donate = (0, 2) if self._donate else ()
         self._compiled = jax.jit(
-            step_fn_scaled if scaler is not None else step_fn,
+            train_step_scaled if scaler is not None else train_step,
             donate_argnums=donate)
 
     def _operands(self, step, key, inputs, labels):
@@ -400,15 +403,25 @@ class TrainStep:
         """inputs: Tensor or tuple for the model; labels: Tensor or tuple for
         loss_fn(output, *labels)."""
         self._step += 1
-        args = self._operands(self._step, get_rng_key(), inputs, labels)
-        out = self._compiled(*args)
-        if self.scaler is not None:
-            loss, self._params, self._opt_state, new_sstate = out
-            self.scaler._compiled_state = new_sstate
-        else:
-            loss, self._params, self._opt_state = out
-        self.sync_to_model()
+        step, trace = self._step, self._trace
+        with RecordEvent(trace.STEP, step=step):
+            with RecordEvent(trace.OPERANDS, step=step):
+                args = self._operands(step, get_rng_key(), inputs, labels)
+            out = trace.dispatch(self._compiled, args, step)
+            if self.scaler is not None:
+                loss, self._params, self._opt_state, new_sstate = out
+                self.scaler._compiled_state = new_sstate
+            else:
+                loss, self._params, self._opt_state = out
+            with RecordEvent(trace.SYNC, step=step):
+                self.sync_to_model()
         return Tensor(loss)
+
+    def stats(self):
+        """``{"steps", "compiles"}``: calls so far, and how many of them
+        compiled (1 after the first; more means a shape or a dtype changed
+        under way -- the trace marks which step, ``train_step::compiled``)."""
+        return {"steps": self._step, "compiles": self._trace.compiles}
 
     def sync_to_model(self):
         """Rebind updated device arrays into the model's Parameters."""

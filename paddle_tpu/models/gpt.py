@@ -198,7 +198,8 @@ class GPTForCausalLM(nn.Layer):
         hidden = self.gpt(input_ids, position_ids)
         # tied head: logits = h @ wte^T (sharded over mp vocab dim via GSPMD)
         w = self.gpt.embeddings.word_embeddings.weight
-        return F.linear(hidden, w.T)
+        with jax.named_scope("lm_head"):    # no Layer of its own to name it
+            return F.linear(hidden, w.T)
 
     def loss(self, logits, labels):
         """Causal LM loss: logits[:, :-1] vs labels[:, 1:]."""
@@ -264,7 +265,8 @@ class GPTForCausalLM(nn.Layer):
         def head_fn(p, hidden, embed_p):
             h = functional_call(ln_f, p, Tensor(hidden))
             w = embed_p["word_embeddings.weight"]
-            return jnp.matmul(h, w.T)
+            with jax.named_scope("lm_head"):
+                return jnp.matmul(h, w.T)
 
         def loss_fn(logits, labels):
             shift_logits = logits[:, :-1, :].reshape((-1, logits.shape[-1]))

@@ -40,11 +40,15 @@ class LayerList(Layer):
 
     def __getitem__(self, idx):
         if isinstance(idx, slice):
-            return LayerList(list(self._sub_layers.values())[idx])
+            # a view: the layers keep the scope their owner gave them
+            view = LayerList()
+            for i, layer in enumerate(list(self._sub_layers.values())[idx]):
+                view._sub_layers[str(i)] = layer
+            return view
         return list(self._sub_layers.values())[idx]
 
     def __setitem__(self, idx, layer):
-        self._sub_layers[str(idx)] = layer
+        self.add_sublayer(str(idx), layer)
 
     def __len__(self):
         return len(self._sub_layers)
@@ -61,7 +65,7 @@ class LayerList(Layer):
         layers.insert(index, layer)
         self._sub_layers.clear()
         for i, l in enumerate(layers):
-            self._sub_layers[str(i)] = l
+            self.add_sublayer(str(i), l)
 
     def extend(self, layers):
         for layer in layers:

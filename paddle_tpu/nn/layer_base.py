@@ -67,6 +67,9 @@ class Layer:
         self._forward_pre_hooks = collections.OrderedDict()
         self._forward_post_hooks = collections.OrderedDict()
         self._hook_id = 0
+        # the name ``__call__`` scopes its device ops under: the class's
+        # until a parent registers this layer under the name it holds it by
+        self._scope = type(self).__name__
         self.training = True
 
     # ---- attribute routing ----
@@ -83,7 +86,7 @@ class Layer:
         elif isinstance(value, Layer):
             if layers is None:
                 raise RuntimeError("call Layer.__init__ before assigning sublayers")
-            layers[name] = value
+            self.add_sublayer(name, value)
             params.pop(name, None) if params else None
             self.__dict__.pop(name, None)
         else:
@@ -150,7 +153,37 @@ class Layer:
         if not isinstance(sublayer, Layer):
             raise TypeError("add_sublayer expects a Layer")
         self._sub_layers[str(name)] = sublayer
+        sublayer._set_scope(self._child_scope(str(name)))
         return sublayer
+
+    # ---- trace scopes ----
+    def _is_container(self):
+        """No ``forward`` of its own (LayerList, LayerDict): iterated by its
+        parent and never called, so it opens no scope itself."""
+        return type(self).forward is Layer.forward
+
+    def _child_scope(self, name):
+        return f"{self._scope}.{name}" if self._is_container() else name
+
+    def _set_scope(self, scope):
+        self._scope = scope
+        if self._is_container():
+            for name, sub in self._sub_layers.items():
+                sub._set_scope(f"{scope}.{name}")
+
+    # the scope is derived from the tree: a pickled Layer (``jit.save``)
+    # is written without it and names itself again when read
+    def __getstate__(self):
+        state = self.__dict__.copy()
+        state.pop("_scope", None)
+        return state
+
+    def __setstate__(self, state):
+        self.__dict__.update(state)
+        self._set_scope(type(self).__name__)
+        for name, sub in self._sub_layers.items():
+            if sub is not None:
+                sub._set_scope(self._child_scope(name))
 
     def register_buffer(self, name, tensor, persistable=True):
         if tensor is not None and not isinstance(tensor, Tensor):
@@ -321,7 +354,11 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        outputs = self.forward(*inputs, **kwargs)
+        # metadata only: names every device op traced below by the model
+        # part it belongs to (``gpt/h.3/attn/qkv``), at no cost in the
+        # compiled program
+        with jax.named_scope(self._scope):
+            outputs = self.forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, outputs)
             if result is not None:

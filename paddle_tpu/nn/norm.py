@@ -127,7 +127,7 @@ class SyncBatchNorm(_BatchNormBase):
     def convert_sync_batchnorm(cls, layer):
         # structural conversion kept for API parity
         for name, sub in list(layer._sub_layers.items()):
-            layer._sub_layers[name] = cls.convert_sync_batchnorm(sub)
+            layer.add_sublayer(name, cls.convert_sync_batchnorm(sub))
         if isinstance(layer, _BatchNormBase) and not isinstance(layer, cls):
             new = cls(layer.num_features, layer.momentum, layer.epsilon,
                       data_format=layer.data_format)

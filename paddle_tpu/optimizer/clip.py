@@ -15,7 +15,8 @@ class ClipGradBase:
 
     def clip_pytree(self, grads):
         flat, treedef = jax.tree_util.tree_flatten(grads)
-        clipped = self._clip_jax([None] * len(flat), flat)
+        with jax.named_scope("grad_clip"):
+            clipped = self._clip_jax([None] * len(flat), flat)
         return jax.tree_util.tree_unflatten(treedef, clipped)
 
 

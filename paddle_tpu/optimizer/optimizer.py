@@ -104,6 +104,7 @@ class Optimizer:
         """params: pytree of jax arrays -> pytree-of-state (same structure)."""
         return jax.tree_util.tree_map(self._init_state, params)
 
+    @jax.named_scope("optimizer")
     def apply_gradients_pytree(self, params, grads, states, step, lr=None):
         """Pure whole-tree update for use inside jit. Returns (params, states)."""
         lr = self.get_lr() if lr is None else lr

@@ -26,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.tensor import Tensor
 from ..distributed.fleet.spmd import use_mesh
 from ..framework.random import get_rng_key, key_stream
+from ..profiler import RecordEvent, StepTrace
 from .pipeline import spmd_pipeline
 
 
@@ -159,6 +160,7 @@ class SpmdTrainStep:
         self.batch_sharding = NamedSharding(mesh, P(self._batch_axes))
         self._step_count = 0
         self._compiled = None
+        self._trace = StepTrace()
 
     # ---- the step program ----
     def _build(self):
@@ -188,9 +190,10 @@ class SpmdTrainStep:
                 h = jax.lax.with_sharding_constraint(
                     h, NamedSharding(mesh, seq_spec))
                 logits = head_fn(params["head"], h, params["embed"])
-                return loss_fn(logits, labels)
+                with jax.named_scope("loss"):
+                    return loss_fn(logits, labels)
 
-        def step_fn(params, opt_state, step, lr, key, input_ids, labels):
+        def train_step(params, opt_state, step, lr, key, input_ids, labels):
             loss, grads = jax.value_and_grad(forward)(params, input_ids,
                                                       labels, key)
             if grad_clip is not None:
@@ -201,8 +204,8 @@ class SpmdTrainStep:
 
         scaler = self.scaler
 
-        def step_fn_scaled(params, opt_state, step, lr, key, input_ids,
-                           labels, scaler_state):
+        def train_step_scaled(params, opt_state, step, lr, key, input_ids,
+                              labels, scaler_state):
             from ..amp import scaler_guarded_update
 
             def scaled(params, input_ids, labels, key):
@@ -217,35 +220,41 @@ class SpmdTrainStep:
             return loss, new_params, new_opt, new_sstate
 
         self._compiled = jax.jit(
-            step_fn_scaled if scaler is not None else step_fn,
+            train_step_scaled if scaler is not None else train_step,
             donate_argnums=(0, 1))
 
     def step(self, input_ids, labels):
         if self._compiled is None:
             self._build()
         self._step_count += 1
-        ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
-        lbl = labels._data if isinstance(labels, Tensor) else labels
-        ids = jax.device_put(ids, self.batch_sharding)
-        lbl = jax.device_put(lbl, self.batch_sharding)
-        lr = jnp.float32(self.optimizer.get_lr())
-        key = get_rng_key()
-        # use_mesh, not a bare ``with mesh``: the kernel dispatchers read
-        # fleet.spmd.current_mesh() to know GSPMD partitions this step
-        with use_mesh(self.mesh):
+        step, trace = self._step_count, self._trace
+        with RecordEvent(trace.STEP, step=step):
+            with RecordEvent(trace.OPERANDS, step=step):
+                ids = input_ids._data if isinstance(input_ids, Tensor) \
+                    else input_ids
+                lbl = labels._data if isinstance(labels, Tensor) else labels
+                args = (self.params, self.opt_state, jnp.int32(step),
+                        jnp.float32(self.optimizer.get_lr()), get_rng_key(),
+                        jax.device_put(ids, self.batch_sharding),
+                        jax.device_put(lbl, self.batch_sharding))
+                if self.scaler is not None:
+                    args += (self.scaler._compiled_state,)
+            # use_mesh, not a bare ``with mesh``: the kernel dispatchers read
+            # fleet.spmd.current_mesh() to know GSPMD partitions this step
+            with use_mesh(self.mesh):
+                out = trace.dispatch(self._compiled, args, step)
             if self.scaler is not None:
-                loss, self.params, self.opt_state, new_sstate = \
-                    self._compiled(self.params, self.opt_state,
-                                   jnp.int32(self._step_count), lr, key,
-                                   ids, lbl, self.scaler._compiled_state)
+                loss, self.params, self.opt_state, new_sstate = out
                 self.scaler._compiled_state = new_sstate
             else:
-                loss, self.params, self.opt_state = self._compiled(
-                    self.params, self.opt_state, jnp.int32(self._step_count),
-                    lr, key, ids, lbl)
+                loss, self.params, self.opt_state = out
         return Tensor(loss)
 
     __call__ = step
+
+    def stats(self):
+        """``{"steps", "compiles"}``, as ``jit.TrainStep.stats``."""
+        return {"steps": self._step_count, "compiles": self._trace.compiles}
 
     def _canonical_params(self):
         """Params with the stacked-layer dim in model order (the interleave

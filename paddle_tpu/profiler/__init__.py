@@ -70,29 +70,37 @@ _events = _HostEvents()
 
 class RecordEvent:
     """Host event span (reference platform/profiler RecordEvent); also
-    emits a jax TraceAnnotation so spans appear in the XLA timeline."""
+    emits a jax TraceAnnotation so spans appear in the XLA timeline, on
+    the device trace's clock.  ``attrs`` (``step=7``) become the
+    annotation's keywords: the trace shows them on the span.
 
-    def __init__(self, name, event_type=None):
+    While nothing records, a span costs the one TraceAnnotation (it asks
+    the profiler whether a session is on and formats nothing) and this
+    wrapper's few attribute reads; what the annotation raises is raised, a
+    span never silently vanishes."""
+
+    def __init__(self, name, event_type=None, **attrs):
         self.name = name
+        self._attrs = attrs
         self._ann = None
         self._t0 = None
 
     def begin(self):
         if _events.active:
             self._t0 = time.perf_counter()
-        try:
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
-        except Exception:
-            self._ann = None
+        self._ann = ann = jax.profiler.TraceAnnotation(self.name,
+                                                       **self._attrs)
+        ann.__enter__()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
+        ann = self._ann
+        if ann is not None:
             self._ann = None
-        if self._t0 is not None and _events.active:
-            _events.records.append(
-                (self.name, self._t0, time.perf_counter() - self._t0))
+            ann.__exit__(None, None, None)
+        if self._t0 is not None:
+            if _events.active:
+                _events.records.append(
+                    (self.name, self._t0, time.perf_counter() - self._t0))
             self._t0 = None
 
     def __enter__(self):
@@ -101,6 +109,68 @@ class RecordEvent:
 
     def __exit__(self, *exc):
         self.end()
+
+
+class _ExecutablesBuilt:
+    """How many executables this process has compiled or loaded from the
+    persistent cache so far: jax reports each to its monitoring
+    listeners.  One listener for the process, registered by the first
+    ``StepTrace`` (jax offers no public way to take one away again)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+    count = 0
+    _listening = False
+
+    @classmethod
+    def listen(cls):
+        if not cls._listening:
+            cls._listening = True
+            jax.monitoring.register_event_duration_secs_listener(cls._on)
+
+    @classmethod
+    def _on(cls, event, _seconds, **_kw):
+        if event == cls.EVENT:
+            cls.count += 1
+
+
+class StepTrace:
+    """The host spans and the compile count of a compiled train step:
+    ``jit.TrainStep`` and ``parallel.SpmdTrainStep`` mark every call the
+    same way, so one reader of the trace serves both (docs/PROFILER.md).
+
+    ``train_step`` covers the whole call; inside it ``::operands`` (RNG
+    key, step and learning-rate scalars, the batch's placement),
+    ``::dispatch`` (the call of the compiled step and nothing else) and,
+    where the trainer rebinds its model, ``::sync_to_model``.  A dispatch
+    that added an executable to the step's cache is followed by the
+    zero-length marker ``::compiled``.  Every span carries ``step``."""
+
+    STEP = "train_step"
+    OPERANDS = "train_step::operands"
+    DISPATCH = "train_step::dispatch"
+    SYNC = "train_step::sync_to_model"
+    COMPILED = "train_step::compiled"
+
+    def __init__(self):
+        self.compiles = 0
+        _ExecutablesBuilt.listen()
+
+    def dispatch(self, compiled, args, step):
+        """``compiled(*args)`` under its span; counts and marks the call
+        if it compiled: the step's cache gained an entry
+        (``_cache_size``, as ``CompileWatcher`` counts) AND an executable
+        was built meanwhile.  The cache alone also grows when operands
+        merely come back described differently (``SpmdTrainStep``'s second
+        call: its outputs drop the mesh axes of size 1 from their specs),
+        which builds nothing."""
+        known, built = compiled._cache_size(), _ExecutablesBuilt.count
+        with RecordEvent(self.DISPATCH, step=step):
+            out = compiled(*args)
+        if compiled._cache_size() > known and _ExecutablesBuilt.count > built:
+            self.compiles += 1
+            with RecordEvent(self.COMPILED, step=step):
+                pass
+        return out
 
 
 def record_host_event(name, start, dur):

@@ -1,0 +1,218 @@
+"""The train step names itself: model-part scopes on the device ops of
+``jit.TrainStep`` / ``parallel.SpmdTrainStep``, host spans and a compile
+mark round every call, and ``stats()`` (docs/PROFILER.md)."""
+
+import contextlib
+import copy
+import pickle
+import re
+
+import numpy as np
+import pytest
+
+import jax
+
+import paddle_tpu as paddle
+from paddle_tpu import nn, optimizer
+from paddle_tpu.distributed.fleet.topology import build_mesh
+from paddle_tpu.jit import TrainStep
+from paddle_tpu.models.gpt import gpt_tiny
+from paddle_tpu.parallel import SpmdTrainStep
+from paddle_tpu.profiler import Profiler, RecordEvent, StepTrace
+
+PARTS = ["attn", "mlp", "ln_1", "ln_2", "ln_f", "embeddings", "lm_head",
+         "loss", "optimizer", "grad_clip"]
+
+
+def _batch(rows=4, length=16, seed=0):
+    rng = np.random.default_rng(seed)
+    return rng.integers(0, 128, (rows, length)).astype("int32")
+
+
+def _model_and_opt():
+    paddle.seed(0)
+    model = gpt_tiny(num_layers=2)
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters(),
+                          grad_clip=optimizer.ClipGradByGlobalNorm(1.0))
+    return model, opt
+
+
+def _train_step(**kw):
+    model, opt = _model_and_opt()
+    return TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt, **kw)
+
+
+def _spmd_step(**kw):
+    model, opt = _model_and_opt()
+    mesh = build_mesh(devices=jax.devices()[:4], dp=2, mp=2)
+    return SpmdTrainStep(model, opt, mesh, **kw)
+
+
+def _op_names(hlo_text):
+    """The ``op_name`` of every instruction, each as its path segments with
+    the transformations' wrappers taken off: ``transpose(jvp(loss))`` is
+    the segment ``loss`` of a backward op."""
+    out = []
+    for name in re.findall(r'op_name="([^"]*)"', hlo_text):
+        segs = [re.sub(r"^(?:[\w-]+\()+|\)+$", "", s)
+                for s in name.split("/")]
+        out.append((name, segs))
+    return out
+
+
+def _spmd_hlo(trainer, ids):
+    trainer._build()
+    shapes = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        (trainer.params, trainer.opt_state))
+    batch = jax.ShapeDtypeStruct(ids.shape, ids.dtype,
+                                 sharding=trainer.batch_sharding)
+    return trainer._compiled.lower(
+        *shapes, jax.ShapeDtypeStruct((), np.int32),
+        jax.ShapeDtypeStruct((), np.float32),
+        jax.ShapeDtypeStruct((2,), np.uint32), batch, batch
+    ).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def compiled_names():
+    """{"train": ..., "spmd": ...}: op names of the optimized HLO of both
+    steps, blocks rematerialised."""
+    ids = _batch()
+    step = _train_step(remat=True)
+    t = paddle.to_tensor(ids)
+    return {"train": _op_names(step.lower(t, t).compile().as_text()),
+            "spmd": _op_names(_spmd_hlo(_spmd_step(remat=True), ids))}
+
+
+# ---- scopes from the Layer tree ------------------------------------------
+def test_a_layer_is_scoped_by_the_name_its_parent_holds_it_by():
+    model = gpt_tiny(num_layers=2)
+    scopes = {n: l._scope for n, l in model.named_sublayers(include_self=True)}
+    assert scopes[""] == "GPTForCausalLM"        # a root: its class
+    assert scopes["gpt"] == "gpt"
+    assert scopes["gpt.h.1"] == "h.1"            # a LayerList is never called
+    assert scopes["gpt.h.1.attn.qkv"] == "qkv"
+    assert scopes["gpt.embeddings"] == "embeddings"
+
+
+def test_containers_name_their_children_and_slices_leave_them_alone():
+    blocks = nn.LayerList([nn.Linear(2, 2) for _ in range(3)])
+    assert [b._scope for b in blocks] == ["LayerList.0", "LayerList.1",
+                                          "LayerList.2"]
+
+    class Net(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.stack = blocks
+            self.seq = nn.Sequential(nn.Linear(2, 2), nn.ReLU())
+
+        def forward(self, x):
+            return self.seq(x)
+
+    net = Net()
+    assert [b._scope for b in blocks] == ["stack.0", "stack.1", "stack.2"]
+    assert net.seq._scope == "seq" and net.seq[0]._scope == "0"
+    blocks[:2]                                   # a view renames nothing
+    assert blocks[0]._scope == "stack.0"
+    blocks[1] = nn.Linear(2, 2)
+    blocks.append(nn.Linear(2, 2))
+    assert blocks[1]._scope == "stack.1" and blocks[3]._scope == "stack.3"
+
+
+def test_a_pickled_layer_is_written_without_its_scope_and_regains_it():
+    model = gpt_tiny(num_layers=2)
+    want = [(n, l._scope) for n, l in model.named_sublayers(include_self=True)]
+    assert "_scope" not in model.__getstate__()
+    for twin in (pickle.loads(pickle.dumps(model)), copy.deepcopy(model)):
+        assert [(n, l._scope) for n, l in
+                twin.named_sublayers(include_self=True)] == want
+
+
+# ---- scopes in the compiled steps ----------------------------------------
+@pytest.mark.parametrize("part", PARTS)
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_compiled_step_names_its_parts(compiled_names, which, part):
+    hits = [n for n, segs in compiled_names[which] if part in segs]
+    assert hits, f"no op of the {which} step is scoped {part!r}"
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_backward_and_recomputed_ops_are_marked(compiled_names, which):
+    mlp = [n for n, segs in compiled_names[which] if "mlp" in segs]
+    assert any("transpose(" in n for n in mlp)
+    assert any("rematted_computation" in n for n in mlp)
+    assert any("transpose(" not in n for n in mlp)       # and a forward one
+
+
+def test_scopes_change_nothing_but_metadata(monkeypatch):
+    """The optimized HLO with the scopes every ``with jax.named_scope``
+    opens (the Layer tree's, ``lm_head``, ``loss``, ``grad_clip``) is the
+    HLO without them: the same instructions under the same names, in the
+    same order."""
+    def instructions():
+        t = paddle.to_tensor(_batch())
+        hlo = _train_step().lower(t, t).compile().as_text()
+        return [re.sub(r", metadata=\{[^}]*\}", "", line)
+                for line in hlo.splitlines() if " = " in line]
+
+    scoped = instructions()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = instructions()
+    assert len(scoped) > 100 and scoped == bare
+
+
+# ---- host spans, the compile mark, stats() --------------------------------
+def _spans_of(profiler, fn):
+    before = profiler.aggregated_events()
+    fn()
+    after = profiler.aggregated_events()
+    return {k: after[k][1] - before.get(k, (0, 0, 0))[1] for k in after
+            if k.startswith("train_step")
+            and after[k][1] != before.get(k, (0, 0, 0))[1]}
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_a_step_records_its_spans_and_marks_the_one_that_compiled(which):
+    step = _train_step() if which == "train" else _spmd_step()
+    put = paddle.to_tensor if which == "train" else (lambda a: a)
+    ids, short = put(_batch()), put(_batch(length=8))
+    own = {StepTrace.STEP: 1, StepTrace.OPERANDS: 1, StepTrace.DISPATCH: 1}
+    if which == "train":
+        own[StepTrace.SYNC] = 1
+    with Profiler(timer_only=True) as p:
+        assert _spans_of(p, lambda: step(ids, ids)) == {
+            **own, StepTrace.COMPILED: 1}
+        assert step.stats() == {"steps": 1, "compiles": 1}
+        assert _spans_of(p, lambda: step(ids, ids)) == own
+        assert _spans_of(p, lambda: step(ids, ids)) == own
+        assert step.stats() == {"steps": 3, "compiles": 1}
+        assert _spans_of(p, lambda: step(short, short)) == {
+            **own, StepTrace.COMPILED: 1}
+    assert step.stats() == {"steps": 4, "compiles": 2}
+
+
+def test_record_event_raises_what_the_annotation_raises(monkeypatch):
+    class Broken:
+        def __init__(self, name, **kw):
+            raise ValueError(f"bad span {name}")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Broken)
+    with pytest.raises(ValueError, match="bad span x"):
+        with RecordEvent("x"):
+            pass
+
+
+def test_record_event_hands_its_attributes_to_the_annotation(monkeypatch):
+    seen = []
+
+    class Spy(contextlib.nullcontext):
+        def __init__(self, name, **kw):
+            super().__init__()
+            seen.append((name, kw))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    with RecordEvent("train_step", step=7):
+        pass
+    assert seen == [("train_step", {"step": 7})]
