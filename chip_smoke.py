@@ -45,10 +45,14 @@ import numpy as np
 
 # Stated tolerances.  bf16 keeps 8 significant bits: neighbouring values
 # are 2**-8 to 2**-7 (0.4-0.8%) apart, and the compared paths round at
-# different points (the kernels accumulate in f32, XLA's default TPU
-# matmul feeds the MXU bf16 operands).  Attention outputs are compared
+# different points.  Both feed the MXU bf16 operands and accumulate in
+# f32; the flash kernel rounds a block's probabilities before the row's
+# sum is known and divides at the end, XLA rounds the normalised row,
+# and the ragged kernel takes q in f32.  Attention outputs are compared
 # as |kernel - XLA| / max(1, |XLA|): 2e-2 is two to five bf16 steps.
-# Measured on the v5e (PR 21): flash 0.8e-2, ragged 0.8e-2 — one step.
+# Measured on the v5e: flash 0.8e-2 (PR 21, and 7.81e-3 again in PR 25
+# with 512 x 512 blocks and explicit bf16 operands), ragged 0.8e-2 — one
+# step.
 ATTN_TOL_BF16 = 2e-2
 # engine log-probability vs an exact-as-possible f32 dense forward: the
 # engine's [T, V] logits are bf16 (spacing 2**-6 = 0.016 at the chosen

@@ -104,11 +104,13 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
         dropout_key = get_rng_key()
     if _use_pallas():
         from .attention_kernel import flash_attention_pallas, supports
-        # Below this sequence length the fused XLA attention is faster on
-        # TPU (profiled on v5e: the kernel's small per-program blocks and
-        # lane-padded head_dim lose to the MXU-saturating einsum); flash
-        # pays off once the [T, S] score matrix dominates HBM.  That is a
-        # choice, not a fallback: short sequences take XLA without a word.
+        # Below this sequence length the fused XLA attention is taken on
+        # TPU; flash pays off once the [T, S] score matrix dominates HBM.
+        # The crossover was profiled on the v5e when the kernel ran 128 x
+        # 128 blocks; since PR 25 it runs blocks up to 512 x 512 (2.3x
+        # faster at seq 1024, 3.2x at 2048), so the crossover may lie
+        # lower now: not measured (PERF.md section 7).  That is a choice,
+        # not a fallback: short sequences take XLA without a word.
         min_seq = get_flags("FLAGS_flash_min_seqlen")["FLAGS_flash_min_seqlen"]
         if q.shape[1] >= int(min_seq):
             reason = None

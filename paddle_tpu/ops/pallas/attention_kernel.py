@@ -4,7 +4,8 @@ The reference ships FlashAttention as a dyn-loaded CUDA library
 (paddle/phi/kernels/gpu/flash_attn_kernel.cu, loader
 paddle/phi/backends/dynload/flashattn.h).  Here the kernel is written
 TPU-native in Pallas: online-softmax over key blocks (never materializes the
-[T, T] score matrix), fp32 accumulation feeding the MXU, and a
+[T, T] score matrix), MXU operands in the dtype q, k, v are stored in with
+float32 accumulation and a float32 softmax between the dots, and a
 recompute-based backward (dq and dk/dv as separate kernels), wired up as a
 jax.custom_vjp.
 
@@ -43,48 +44,85 @@ def supports(seq_q, seq_k, head_dim):
             and _pick_block(seq_k, DEFAULT_BLOCK_K) is not None)
 
 
+# ----------------------------------------------------------- inner loops --
+#
+# What the MXU is handed: q, k, v and dO go into every dot in the dtype they
+# are stored in, and the dot accumulates in float32
+# (``preferred_element_type``).  bf16 x bf16 products are exact in a float32
+# accumulator, so the dots whose operands both come from HBM lose nothing.
+# The probabilities and dS are cast to the input dtype only where they
+# become an operand of the second dot; the scores, the mask, exp, m, l, lse,
+# delta and the accumulators stay float32, and the softmax scale is applied
+# to the float32 scores (never to a bf16 q).  float32 inputs keep float32
+# operands.
+#
+# This states the rounding; it does not buy time.  At Mosaic's default
+# precision the v5e's MXU takes a float32 dot in ONE bf16 pass as well
+# (measured, PR 25: the same kernels with every operand upcast run within
+# 1%, at 90 TFLOP/s, above what three passes could reach), so an upcast
+# costs converts and hides from interpret mode the rounding the chip
+# applies anyway.  What bounds these kernels is the block shape
+# (``_block_candidates``).
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _rows(ref, i, block):
+    """Rows [i*block, (i+1)*block) of a [1, seq, head] ref.  Mosaic has to
+    prove the alignment of a dynamic row slice of a packed dtype."""
+    return ref[0, pl.ds(pl.multiple_of(i * block, block), block), :]
+
+
+def _mask_below_diagonal(s, row0, col0, row_axis):
+    """Keep s where (row0 + row) >= (col0 + col); ``row_axis`` is the axis
+    of ``s`` that runs over queries."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, row_axis)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - row_axis)
+    return jnp.where(rows >= cols, s, _NEG_INF)
+
+
+def _num_key_blocks(qi, block_q, block_k, seq_k, causal):
+    """Key blocks one q block attends to: causal, those past its last row
+    are fully masked and skipped."""
+    if causal:
+        return ((qi + 1) * block_q + block_k - 1) // block_k
+    return seq_k // block_k
+
+
 # ---------------------------------------------------------------- forward --
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
                 scale):
     """One (batch*head, q-block) program: online softmax over key blocks."""
-    q = q_ref[0].astype(jnp.float32) * scale          # [Bq, H]
+    q = q_ref[0]                                       # [Bq, H]
     block_q = q.shape[0]
-    seq_k = k_ref.shape[1]
-    num_kb = seq_k // block_k
     qi = pl.program_id(1)
 
     def body(j, carry):
         o_acc, m, l = carry
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [Bq,Bk]
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _dot(q, k, _NT) * scale                    # [Bq, Bk]
         if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        o_new = o_acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        o_new = o_acc * alpha + _dot(p.astype(v.dtype), v, _NN)
         return o_new, m_new, l_new
 
-    o0 = jnp.zeros((block_q, q.shape[1]), jnp.float32)
+    o0 = jnp.zeros(q.shape, jnp.float32)
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    if causal:
-        # key blocks beyond this q block's diagonal are fully masked
-        upper = (qi + 1) * block_q
-        num_active = (upper + block_k - 1) // block_k
-        o_acc, m, l = jax.lax.fori_loop(0, num_active, body, (o0, m0, l0))
-    else:
-        o_acc, m, l = jax.lax.fori_loop(0, num_kb, body, (o0, m0, l0))
+    num_kb = _num_key_blocks(qi, block_q, block_k, k_ref.shape[1], causal)
+    o_acc, m, l = jax.lax.fori_loop(0, num_kb, body, (o0, m0, l0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (o_acc / l).astype(o_ref.dtype)
     # lse is [bn, seq, 1]: a (1, block_q, 1) block per program satisfies the
@@ -123,85 +161,64 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    *, block_k, causal, scale):
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    do = do_ref[0]
     block_q = q.shape[0]
-    seq_k = k_ref.shape[1]
     qi = pl.program_id(1)
     lse = lse_ref[0]                                           # [Bq, 1]
     delta = delta_ref[0]
 
     def body(j, dq_acc):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        k = _rows(k_ref, j, block_k)
+        v = _rows(v_ref, j, block_k)
+        s = _dot(q, k, _NT) * scale
         if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
+            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0)
         p = jnp.exp(s - lse)                                   # [Bq, Bk]
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        return dq_acc + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        ds = p * (_dot(do, v, _NT) - delta)
+        return dq_acc + _dot(ds.astype(k.dtype), k, _NN)
 
-    if causal:
-        num_active = ((qi + 1) * block_q + block_k - 1) // block_k
-    else:
-        num_active = seq_k // block_k
-    dq = jax.lax.fori_loop(0, num_active, body,
-                           jnp.zeros_like(q, dtype=jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    num_kb = _num_key_blocks(qi, block_q, block_k, k_ref.shape[1], causal)
+    dq = jax.lax.fori_loop(0, num_kb, body, jnp.zeros(q.shape, jnp.float32))
+    # dS = P * (dP - delta) * scale: the scale goes on once, at the end
+    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, block_q, causal, scale):
-    k = k_ref[0].astype(jnp.float32)                           # [Bk, H]
-    v = v_ref[0].astype(jnp.float32)
+    """One (batch*head, k-block) program over the q blocks that see it.
+
+    The scores are formed transposed, [Bk, Bq] = K Q^T, so that P^T dO and
+    dS^T Q are plain matmuls: a dot that contracts dimension 0 of both
+    operands would have Mosaic transpose a [Bq, Bk] tile for each.  lse and
+    delta then come as rows, one [1, Bq] row of a [num_qb, Bq] block per q
+    block (a [seq_q, 1] block would be lane-padded 128x in VMEM)."""
+    k = k_ref[0]                                               # [Bk, H]
+    v = v_ref[0]
     block_k = k.shape[0]
-    seq_q = q_ref.shape[1]
+    num_qb = q_ref.shape[1] // block_q
     ki = pl.program_id(1)
-    num_qb = seq_q // block_q
 
     def body(i, carry):
         dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        q = _rows(q_ref, i, block_q)
+        do = _rows(do_ref, i, block_q)
+        lse = lse_ref[0, pl.ds(i, 1), :]                       # [1, Bq]
+        delta = delta_ref[0, pl.ds(i, 1), :]
+        st = _dot(k, q, _NT) * scale                           # [Bk, Bq]
         if causal:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dv_new = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dk_new = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            st = _mask_below_diagonal(st, i * block_q, ki * block_k, 1)
+        pt = jnp.exp(st - lse)
+        dv_new = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - delta)
+        dk_new = dk_acc + _dot(dst.astype(q.dtype), q, _NN)
         return dk_new, dv_new
 
-    zeros = jnp.zeros_like(k, dtype=jnp.float32)
-    if causal:
-        # q blocks before this k block's diagonal contribute nothing
-        start = (ki * block_k) // block_q
-        dk, dv = jax.lax.fori_loop(start, num_qb, body, (zeros, zeros))
-    else:
-        dk, dv = jax.lax.fori_loop(0, num_qb, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    zeros = jnp.zeros(k.shape, jnp.float32)
+    # q blocks before this k block's diagonal contribute nothing
+    first = (ki * block_k) // block_q if causal else 0
+    dk, dv = jax.lax.fori_loop(first, num_qb, body, (zeros, zeros))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -209,6 +226,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                interpret):
     bn, seq_q, head = q.shape
     seq_k = k.shape[1]
+    num_qb = seq_q // block_q
     # delta = rowsum(dO * O) — cheap elementwise, leave to XLA fusion
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -217,7 +235,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         functools.partial(_bwd_dq_kernel, block_k=block_k, causal=causal,
                           scale=scale),
         name="flash_attention_bwd_dq",
-        grid=(bn, seq_q // block_q),
+        grid=(bn, num_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
@@ -231,6 +249,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
+    rows = (bn, num_qb, block_q)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, causal=causal,
                           scale=scale),
@@ -241,8 +260,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
             pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, seq_q, head), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, seq_q, 1), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, seq_q, 1), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, num_qb, block_q), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, num_qb, block_q), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
@@ -253,7 +272,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
 
@@ -266,9 +285,23 @@ def _flash_attention_bnsh(q, k, v, causal, scale, interpret):
 
 
 def _block_candidates(seq_q, seq_k):
-    """(block_q, block_k) candidates, author heuristic first."""
-    qs = [b for b in (128, 256, 512, 64) if seq_q % b == 0]
-    ks = [b for b in (128, 256, 512, 64) if seq_k % b == 0]
+    """(block_q, block_k) candidates, author heuristic first: the largest of
+    512, 256, 128, 64 that divides the sequence, on both axes.
+
+    Measured on the v5e (PR 25, bf16 causal, fwd + dq + dkv a call): a block
+    step is bound by the latency of its dot -> softmax -> dot chain, not by
+    the MXU or the vector unit, and a larger block gives the scheduler more
+    independent rows to overlap: [128, 2048, 128] takes 19.8 ms at 128 x 128,
+    9.9 at 256 x 256, 6.9 at 512 x 512; 1024 on either axis loses again (8.0
+    to 8.2: the diagonal blocks compute more masked scores than the longer
+    block saves), at seq 4096 too (11.1 against 10.9).  head_dim 64 at seq
+    1024 orders the shapes the same way (4.8, 2.8, 2.3 ms), so the head
+    size does not enter the choice.  Mosaic takes all three kernels at 512 x
+    512 for head_dim 64 and 128 as far as it took them at 128 x 128: seq
+    8192 in bf16, 4096 in float32 (AOT for the v5e; beyond, K and V no
+    longer fit VMEM whole)."""
+    qs = [b for b in (512, 256, 128, 64) if seq_q % b == 0]
+    ks = [b for b in (512, 256, 128, 64) if seq_k % b == 0]
     if not qs:
         qs = [_pick_block(seq_q, DEFAULT_BLOCK_Q)]
     if not ks:
@@ -296,7 +329,7 @@ def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4"):
               ((1, bq, 1), f32), ((1, bq, 1), f32), ((1, bq, head), dtype)]
         dkv = [((1, seq_q, head), dtype), ((1, bk, head), dtype),
                ((1, bk, head), dtype), ((1, seq_q, head), dtype),
-               ((1, seq_q, 1), f32), ((1, seq_q, 1), f32),
+               ((1, seq_q // bq, bq), f32), ((1, seq_q // bq, bq), f32),
                ((1, bk, head), dtype), ((1, bk, head), dtype)]
         return all(vmem_fits(blocks, profile=profile)
                    for blocks in (fwd, dq, dkv))

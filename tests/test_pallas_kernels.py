@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.ops.pallas import _xla_attention
+from paddle_tpu.ops.pallas import attention_kernel
 from paddle_tpu.ops.pallas.attention_kernel import (
     flash_attention_pallas,
     supports,
@@ -117,6 +118,126 @@ def test_flash_attention_bf16():
     np.testing.assert_allclose(
         np.asarray(got, dtype=np.float32), np.asarray(want, np.float32),
         rtol=2e-2, atol=2e-2)
+
+
+# bf16 keeps 8 significant bits: neighbouring values are 2**-8 (0.4%) of
+# their magnitude apart.  Against a float32 reference of the same inputs the
+# kernel rounds at three points: the probabilities and dS where they become
+# an MXU operand (relative 2**-9 each, averaged over a row), and the result
+# itself (2**-9).  Each compared tensor therefore has to stay within TWO bf16
+# steps of its largest value; a wrong mask, block bound or scale shows as
+# tens of steps.
+BF16_STEP = 2.0 ** -8
+
+
+def _dense_attention_f32(q, k, v, causal):
+    """[bn, seq, head] attention in float32, nothing fused, nothing rounded."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bqh,bkh->bqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        t = s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    return jnp.einsum("bqk,bkh->bqh", jax.nn.softmax(s, axis=-1), v)
+
+
+def _flash_fwd_bwd(q, k, v, do, causal, block_q, block_k):
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    out, lse = attention_kernel._flash_fwd(q, k, v, causal, scale, block_q,
+                                           block_k, True)
+    grads = attention_kernel._flash_bwd(q, k, v, out, lse, do, causal, scale,
+                                        block_q, block_k, True)
+    return (out,) + tuple(grads)
+
+
+# seq 512 at 128 x 64 and 64 x 128: per q block some key blocks lie wholly
+# below the diagonal, one or two cross it and the rest are skipped, with
+# block_q != block_k both ways round; seq 192 is the uneven case (no 128
+# divides it, the heuristic picks 64 x 64).
+@pytest.mark.parametrize("seq,blocks", [(512, (128, 64)), (512, (64, 128)),
+                                        (192, None)])
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_attention_bf16_forward_and_grads(causal, head_dim, seq, blocks):
+    shape = (2, seq, head_dim)
+    q, k, v, do = (_rand(shape, s, jnp.bfloat16) for s in (30, 31, 32, 33))
+    if blocks is None:
+        blocks = attention_kernel._block_candidates(seq, seq)[0]
+        assert blocks == (64, 64)
+    got = _flash_fwd_bwd(q, k, v, do, causal, *blocks)
+    want_out, vjp = jax.vjp(
+        lambda q, k, v: _dense_attention_f32(q, k, v, causal), q, k, v)
+    want = (want_out,) + vjp(do.astype(jnp.float32))
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.dtype == jnp.bfloat16, name
+        w = np.asarray(w, np.float32)
+        err = np.abs(np.asarray(g, np.float32) - w).max()
+        assert err <= 2 * BF16_STEP * np.abs(w).max(), (
+            f"{name}: {err / (BF16_STEP * np.abs(w).max()):.2f} bf16 steps "
+            f"off the float32 reference")
+
+
+def _eqns(jaxpr, primitive):
+    """The equations of one primitive in a jaxpr and all its sub-jaxprs."""
+    from paddle_tpu.framework.analysis import walk_jaxprs
+
+    return [eqn for _, sub in walk_jaxprs(jaxpr) for eqn in sub.eqns
+            if eqn.primitive.name == primitive]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_dots_take_the_input_dtype(dtype):
+    """The MXU gets its operands in the dtype q, k, v are stored in and
+    accumulates in float32.  An ``.astype(float32)`` put back before a dot
+    fails here: the chip would round that operand to bf16 in the MXU's feed
+    all the same, and interpret mode would no longer show it."""
+    x = jax.ShapeDtypeStruct((2, 256, 64), dtype)
+
+    def fwd_bwd(q, k, v, do):
+        return _flash_fwd_bwd(q, k, v, do, True, 128, 64)
+
+    dots = {
+        call.params["name"]: [
+            (tuple(v.aval.dtype for v in dot.invars),
+             dot.params["preferred_element_type"])
+            for dot in _eqns(call.params["jaxpr"], "dot_general")]
+        for call in _eqns(jax.make_jaxpr(fwd_bwd)(x, x, x, x), "pallas_call")}
+    # fwd QK^T, PV; dq QK^T, dO V^T, dS K; dkv KQ^T, P^T dO, V dO^T, dS^T Q
+    assert {name: len(d) for name, d in dots.items()} == {
+        "flash_attention_fwd": 2, "flash_attention_bwd_dq": 3,
+        "flash_attention_bwd_dkv": 4}
+    want = ((jnp.dtype(dtype),) * 2, jnp.dtype(jnp.float32))
+    for name, found in dots.items():
+        for operands, acc in found:
+            assert (operands, jnp.dtype(acc)) == want, (name, operands, acc)
+
+
+def test_flash_attention_bf16_key_bias_gradient_stays_noise():
+    """A constant added to every key moves no softmax, so the gradient of a
+    key bias, sum_t dk[t], is zero; what a bf16 run leaves there is rounding
+    noise (the benchmark's ``zero_grad_leaf_norm`` holds a training step to
+    it).  The kernel rounds dS to bf16 where it becomes an MXU operand; that
+    must not lift the noise, taken in units of dk's own largest value, above
+    THREE times what ``_xla_attention`` leaves on the same inputs.  Here on the
+    CPU XLA keeps dS float32 and leaves only the rounding of dk itself;
+    measured over 9 draws: this kernel 1.9-2.1x, the kernel with a float32
+    dS 1.4-1.5x (delta is formed from the ROUNDED output, so a row of dS
+    does not sum to zero exactly).  A dS scaled or rounded twice in bf16
+    goes past three."""
+    shape = (2, 512, 4, 64)
+    q, k, v, w = (_rand(shape, s, jnp.bfloat16) for s in (40, 41, 42, 43))
+
+    def key_bias_grad(attention):
+        def loss(k):
+            out = attention(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
+        dk = np.asarray(jax.grad(loss)(k), np.float32)
+        return np.linalg.norm(dk.sum(axis=1)) / np.abs(dk).max()
+
+    flash = key_bias_grad(lambda q, k, v: flash_attention_pallas(
+        q, k, v, is_causal=True, interpret=True))
+    xla = key_bias_grad(lambda q, k, v: _xla_attention(
+        q, k, v, is_causal=True))
+    assert 0 < xla and flash <= 3 * xla, (flash, xla)
 
 
 class TestDecodeAttention:
