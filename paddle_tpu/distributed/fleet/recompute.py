@@ -11,7 +11,10 @@ hand-rolled RecomputeFunction/PyLayer machinery.
 Policy knobs map to ``jax.checkpoint_policies``: ``checkpoint="full"``
 saves nothing (default), ``"dots"`` saves matmul results
 (dots_saveable), ``"nothing_saveable"``/``"everything_saveable"`` pass
-through to jax.
+through to jax; a list or tuple of names keeps only the values tagged with
+them by ``jax.ad_checkpoint.checkpoint_name`` (``save_only_these_names``:
+e.g. ``ops.pallas.attention_kernel.SAVED_BY_NAME``, the flash forward's
+output and row statistics).
 """
 
 import functools
@@ -33,6 +36,8 @@ _POLICIES = {
 
 
 def _resolve_policy(name):
+    if isinstance(name, (list, tuple)):
+        return jax.checkpoint_policies.save_only_these_names(*name)
     key = _POLICIES.get(name, name)
     if key is None:
         return None

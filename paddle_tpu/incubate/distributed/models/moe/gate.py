@@ -69,7 +69,25 @@ def topk_gating(logits, top_k, capacity_factor, jitter_key=None,
             "probs": probs}
 
 
+def topk_routing(logits, top_k, jitter_key=None, jitter_eps=0.0):
+    """The same choice with NO capacity, for a dropless dispatch: the
+    ``top_k`` largest softmax probabilities of each token, weights normed
+    over them (what ``topk_gating``'s combine tensor holds when nothing is
+    dropped).  Returns (idx [S, k], weights [S, k], aux_loss)."""
+    e = logits.shape[1]
+    if jitter_eps and jitter_key is not None:
+        logits = logits + jitter_eps * jax.random.uniform(
+            jitter_key, logits.shape, minval=-1.0, maxval=1.0)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, top_k)
+    w = w / jnp.maximum(jnp.sum(w, axis=1, keepdims=True), 1e-9)
+    fracs = jnp.mean(_one_hot(idx, e), axis=(0, 1))
+    return idx, w, e * jnp.sum(jnp.mean(probs, axis=0) * fracs)
+
+
 class BaseGate:
+    jitter_eps = 0.0
+
     def __init__(self, d_model, num_experts, top_k, capacity_factor):
         self.d_model = d_model
         self.num_experts = num_experts
@@ -78,6 +96,11 @@ class BaseGate:
 
     def __call__(self, logits, jitter_key=None):
         raise NotImplementedError
+
+    def route(self, logits, jitter_key=None):
+        """This gate's choice without a capacity (``topk_routing``): what
+        ``MoELayer(dropless=True)`` dispatches by."""
+        return topk_routing(logits, self.top_k, jitter_key, self.jitter_eps)
 
 
 class NaiveGate(BaseGate):

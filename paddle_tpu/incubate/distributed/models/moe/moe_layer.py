@@ -11,6 +11,17 @@ dispatch einsum into exactly the all-to-all that global_scatter performs —
 no index plumbing, and the expert FFN runs as one batched matmul on the MXU.
 ``global_scatter``/``global_gather`` are also provided directly (shard_map
 all-to-all) for API parity.
+
+Two dispatches, and why (ROADMAP D9).  The dense einsum above stays for an
+``ep`` mesh axis under GSPMD: the einsum IS the exchange there, at the
+price of a capacity and of dropped tokens.  The dropless dispatch
+(``dropless.py``: sort by expert, a grouped matmul over ragged groups, a
+weighted gather back) drops nothing and is told which experts it holds;
+it has no exchange yet, so it serves one chip's share of an
+expert-parallel layer.  ``MoELayer(dropless=True)`` takes it with the
+gate's own choice (``gate.route``: its top-k and jitter, no capacity, so a
+``capacity_factor`` is refused there); :class:`DroplessMoELayer` is the
+routed-and-shared experts layer of the DeepSeek-V3 family built on it.
 """
 
 import jax
@@ -24,6 +35,7 @@ from .....nn import functional as F
 from .....nn.initializer import Normal, Constant
 from .....nn.layer_base import Layer
 from .....ops.registry import op
+from . import dropless as _dl
 from .gate import GShardGate, NaiveGate, SwitchGate
 
 _GATES = {"gshard": GShardGate, "switch": SwitchGate, "naive": NaiveGate}
@@ -51,6 +63,26 @@ def _moe_forward(x2d, wg, w1, b1, w2, b2, *, gate, jitter_key=None,
     return out, g["aux_loss"]
 
 
+@op("moe_forward_dropless")
+def _moe_forward_dropless(x2d, wg, w1, b1, w2, b2, *, gate, jitter_key=None,
+                          activation="gelu"):
+    """The same experts behind the dropless dispatch, routed by the
+    gate's own choice without its capacity (``gate.route``).  Returns
+    (out, aux)."""
+    e = wg.shape[1]
+    logits = x2d.astype(jnp.float32) @ wg.astype(jnp.float32)
+    idx, w, aux = gate.route(logits, jitter_key=jitter_key)
+    order, inverse, counts = _dl.sort_by_expert(idx, 0, e)
+    expert_of_row = jnp.repeat(jnp.arange(e), counts,
+                               total_repeat_length=order.shape[0])
+    xs = _dl.dispatch(x2d, order, inverse, counts)
+    act = {"gelu": jax.nn.gelu, "relu": jax.nn.relu,
+           "silu": jax.nn.silu}[activation]
+    h = act(_dl.grouped_matmul(xs, w1, counts) + b1[expert_of_row])
+    eo = _dl.grouped_matmul(h, w2, counts) + b2[expert_of_row]
+    return _dl.combine(eo, w, order, inverse, counts), aux
+
+
 class MoELayer(Layer):
     """Expert-parallel FFN block.
 
@@ -61,8 +93,16 @@ class MoELayer(Layer):
 
     def __init__(self, d_model, d_hidden, num_experts, gate="gshard",
                  top_k=None, capacity_factor=None, activation="gelu",
-                 group=None, recompute_interval=0, name=None):
+                 group=None, recompute_interval=0, name=None,
+                 dropless=False):
         super().__init__()
+        # dropless: no capacity, no dropped token (one chip; the dense
+        # path is the one GSPMD turns into the ``ep`` exchange)
+        self.dropless = dropless
+        if dropless and capacity_factor is not None:
+            raise ValueError(
+                "MoELayer(dropless=True) has no capacity and drops no "
+                f"token: capacity_factor={capacity_factor} would be ignored")
         self.d_model = d_model
         self.d_hidden = d_hidden
         self.num_experts = num_experts
@@ -77,6 +117,10 @@ class MoELayer(Layer):
             self.gate = cls(d_model, num_experts, **kw)
         else:
             self.gate = gate
+        if dropless and not hasattr(self.gate, "route"):
+            raise ValueError(
+                "MoELayer(dropless=True) routes by gate.route(logits), "
+                f"which {type(self.gate).__name__} does not define")
         init = Normal(0.0, 0.02)
         self.gate_weight = self.create_parameter(
             (d_model, num_experts), default_initializer=init)
@@ -101,11 +145,164 @@ class MoELayer(Layer):
         if self.training and getattr(self.gate, "jitter_eps", 0.0):
             from .....framework.random import get_rng_key
             jitter_key = get_rng_key()
-        out, aux = _moe_forward(
+        forward = _moe_forward_dropless if self.dropless else _moe_forward
+        out, aux = forward(
             x2d, self.gate_weight, self.w1, self.b1, self.w2, self.b2,
             gate=self.gate, jitter_key=jitter_key,
             activation=self.activation)
         self.l_aux = aux
+        return out.reshape(shape)
+
+
+# ------------------------------------- routed and shared experts, dropless --
+
+class SigmoidTopKRouter(Layer):
+    """The ``noaux_tc`` router of the DeepSeek-V3 family: float32 sigmoid
+    scores over ALL ``num_experts`` (the published width, whatever part of
+    them this chip holds), the ``top_k`` largest of score + bias, weights
+    normed over the chosen and scaled.  ``e_score_correction_bias`` is a
+    buffer (the published training moves it outside the gradient)."""
+
+    def __init__(self, d_model, num_experts, top_k, scale=1.0,
+                 norm_topk_prob=True, init_std=0.02):
+        super().__init__()
+        self.top_k, self.scale = top_k, scale
+        self.norm_topk_prob = norm_topk_prob
+        self.weight = self.create_parameter(
+            (d_model, num_experts), default_initializer=Normal(0.0, init_std))
+        self.register_buffer("e_score_correction_bias",
+                             Tensor(jnp.zeros((num_experts,), jnp.float32)))
+
+    def forward(self, x2d):
+        return _route(x2d, self.weight, self.e_score_correction_bias,
+                      top_k=self.top_k, scale=self.scale,
+                      norm_topk=self.norm_topk_prob)
+
+
+@op("moe_route_sigmoid_topk")
+def _route(x2d, wg, bias, *, top_k, scale, norm_topk):
+    logits = jnp.matmul(x2d.astype(jnp.float32), wg.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    return _dl.route_sigmoid_topk(logits, bias, top_k, scale, norm_topk)
+
+
+@op("moe_dispatch_dropless")
+def _dispatch(x2d, idx, *, expert_offset, num_local):
+    with jax.named_scope("dispatch"):
+        order, inverse, counts = _dl.sort_by_expert(idx, expert_offset,
+                                                    num_local)
+        return (_dl.dispatch(x2d, order, inverse, counts), order, inverse,
+                counts)
+
+
+@op("moe_combine_dropless")
+def _combine(ys, weights, order, inverse, counts):
+    with jax.named_scope("combine"):
+        return _dl.combine(ys, weights, order, inverse, counts)
+
+
+@op("moe_swiglu_experts")
+def _swiglu_experts(xs, gate_up, down, counts):
+    return _dl.swiglu_experts(xs, gate_up, down, counts)
+
+
+class GroupedSwiGLUExperts(Layer):
+    """The experts held here, stacked: ``gate_up [G, H, 2I]`` (gate | up),
+    ``down [G, I, H]``; forward takes rows sorted by expert and the rows
+    each expert has."""
+
+    def __init__(self, num_local, d_model, d_expert, init_std=0.02,
+                 down_std=None):
+        super().__init__()
+        self.gate_up = self.create_parameter(
+            (num_local, d_model, 2 * d_expert),
+            default_initializer=Normal(0.0, init_std))
+        self.down = self.create_parameter(
+            (num_local, d_expert, d_model),
+            default_initializer=Normal(0.0, down_std or init_std))
+        for p_ in (self.gate_up, self.down):
+            p_.mesh_axes = ("ep", None, None)
+            p_.expert = True
+
+    def forward(self, xs, counts):
+        return _swiglu_experts(xs, self.gate_up, self.down, counts)
+
+
+class SwiGLUMLP(Layer):
+    """``down(silu(gate(x)) * up(x))``, gate | up packed in one matmul, no
+    bias: the dense layers' MLP and the shared expert."""
+
+    def __init__(self, d_model, d_hidden, init_std=0.02, down_std=None):
+        super().__init__()
+        from .....nn.common import Linear
+        from .....nn.layer_base import ParamAttr
+
+        self.gate_up = Linear(d_model, 2 * d_hidden, bias_attr=False,
+                              weight_attr=ParamAttr(
+                                  initializer=Normal(0.0, init_std)))
+        self.down = Linear(d_hidden, d_model, bias_attr=False,
+                           weight_attr=ParamAttr(initializer=Normal(
+                               0.0, down_std or init_std)))
+        self._hidden = d_hidden
+
+    def forward(self, x):
+        from .....incubate.nn.functional import swiglu
+
+        gu = self.gate_up(x)
+        return self.down(swiglu(gu[..., :self._hidden],
+                                gu[..., self._hidden:]))
+
+
+class DroplessMoELayer(Layer):
+    """Routed and shared experts: ``sum_i w_i E_i(x) + S(x)``.
+
+    ``num_experts`` is the router's width; ``num_local_experts`` of them,
+    from ``expert_offset`` on, live here (all of them by default).  The
+    layer routes over all, norms over the chosen ``top_k`` and computes
+    the part its own experts give: what the absent experts would add is
+    left out, and a token none of whose experts is local gets the shared
+    expert only.  No token is dropped.  After a forward,
+    ``tokens_per_expert`` holds the tokens each local expert received
+    (int32 ``[num_local_experts]``).
+
+    Scopes: ``router``, ``dispatch``, ``experts``, ``combine``,
+    ``shared_experts`` (``docs/PROFILER.md``)."""
+
+    def __init__(self, d_model, d_expert, num_experts, top_k,
+                 num_shared_experts=0, routed_scaling_factor=1.0,
+                 norm_topk_prob=True, num_local_experts=None,
+                 expert_offset=0, init_std=0.02, down_std=None):
+        super().__init__()
+        num_local = num_experts if num_local_experts is None \
+            else num_local_experts
+        if not 0 <= expert_offset <= num_experts - num_local:
+            raise ValueError(
+                f"experts [{expert_offset}, {expert_offset + num_local}) "
+                f"are not among the router's {num_experts}")
+        self.d_model = d_model
+        self.num_local_experts, self.expert_offset = num_local, expert_offset
+        self.router = SigmoidTopKRouter(d_model, num_experts, top_k,
+                                        routed_scaling_factor,
+                                        norm_topk_prob, init_std)
+        self.experts = GroupedSwiGLUExperts(num_local, d_model, d_expert,
+                                            init_std, down_std)
+        self.shared_experts = SwiGLUMLP(
+            d_model, num_shared_experts * d_expert, init_std, down_std) \
+            if num_shared_experts else None
+        self.tokens_per_expert = None
+
+    def forward(self, x):
+        shape = x.shape
+        x2d = x.reshape([-1, self.d_model])
+        idx, weights = self.router(x2d)
+        xs, order, inverse, counts = _dispatch(
+            x2d, idx, expert_offset=self.expert_offset,
+            num_local=self.num_local_experts)
+        out = _combine(self.experts(xs, counts), weights, order, inverse,
+                       counts)
+        if self.shared_experts is not None:
+            out = out + self.shared_experts(x2d)
+        self.tokens_per_expert = counts
         return out.reshape(shape)
 
 

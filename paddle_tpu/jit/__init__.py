@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor
 from ..framework import mode
 from ..framework.random import get_rng_key, key_stream
-from ..nn.layer_base import Layer
+from ..nn.layer_base import Layer, block_remat
 from ..profiler import RecordEvent, StepTrace
 
 _is_tensor = lambda x: isinstance(x, Tensor)
@@ -290,6 +290,22 @@ class TrainStep:
     Usage::
         step = TrainStep(model, loss_fn, opt)
         loss = step(batch_x, batch_y)      # Tensors in, loss Tensor out
+
+    ``remat``: activation rematerialisation BY BLOCK.  Every layer that a
+    ``LayerList`` of the model holds (its repeated blocks) runs under
+    ``fleet.recompute`` inside the step: its forward is run again in the
+    backward pass, so one block's activations are live at a time.  ``True``
+    saves nothing of a block; a policy name of ``fleet.recompute`` or a
+    list of ``checkpoint_name`` tags (``["flash_attention_out",
+    "flash_attention_lse"]``: the flash kernel is not run twice) keeps what
+    it names.  A block must hand on what it makes through its outputs, not
+    through attributes.
+
+    Counters: a model that defines ``step_counters()`` (a dict of small
+    arrays its last forward made, e.g. the tokens each expert received)
+    has them returned by the compiled step beside the loss; after a call
+    they are ``step.counters``, device arrays nobody has waited for.  Keep
+    them and read them when the timing is over (``docs/PROFILER.md``).
     """
 
     def __init__(self, model, loss_fn, optimizer, donate=True, remat=False,
@@ -304,6 +320,7 @@ class TrainStep:
         self._compiled = None
         self._trace = StepTrace()
         self._donate = donate
+        self.counters = {}      # the last step's, see the class docstring
         # loss scaling composed INTO the compiled step (reference
         # fleet/scaler.py distributed_scaler + update_loss_scaling_ kernel)
         self.scaler = scaler if (scaler is not None and scaler.is_enable()) \
@@ -320,34 +337,40 @@ class TrainStep:
         loss_fn = self.loss_fn
         optimizer = self.optimizer
         grad_clip = optimizer._grad_clip
+        counters_of = getattr(model, "step_counters", None)
+        # ``True`` saves nothing of a block; a policy name or a list of
+        # ``checkpoint_name`` tags is handed on (``fleet.recompute``)
+        remat = "full" if self.remat is True else (self.remat or None)
 
         def make_loss_f(frozen, key, inputs, labels):
+            """``loss_f(params) -> (loss, counters)``."""
             def loss_f(p):
-                with key_stream(key):
+                with key_stream(key), block_remat(remat):
                     out = functional_call(model, {**p, **frozen}, *inputs)
+                counters = counters_of() if counters_of is not None else {}
                 out_t = jax.tree_util.tree_map(
                     lambda d: Tensor(d) if isinstance(d, jax.Array) else d, out)
                 label_t = tuple(Tensor(l) if isinstance(l, jax.Array) else l
                                 for l in labels)
                 with mode.grad_enabled(False), jax.named_scope("loss"):
                     loss = loss_fn(out_t, *label_t)
-                return loss._data if isinstance(loss, Tensor) else loss
+                loss = loss._data if isinstance(loss, Tensor) else loss
+                return loss, jax.tree_util.tree_map(
+                    lambda c: c._data if isinstance(c, Tensor) else c,
+                    counters, is_leaf=_is_tensor)
 
-            if self.remat:
-                # activation rematerialization: recompute the forward during
-                # the backward pass instead of saving activations
-                loss_f = jax.checkpoint(loss_f)
             return loss_f
 
         def train_step(params, frozen, opt_state, step, lr, key, inputs,
                        labels):
             loss_f = make_loss_f(frozen, key, inputs, labels)
-            loss, grads = jax.value_and_grad(loss_f)(params)
+            (loss, counters), grads = jax.value_and_grad(
+                loss_f, has_aux=True)(params)
             if grad_clip is not None:
                 grads = grad_clip.clip_pytree(grads)
             new_params, new_opt = optimizer.apply_gradients_pytree(
                 params, grads, opt_state, step, lr=lr)
-            return loss, new_params, new_opt
+            return loss, new_params, new_opt, counters
 
         scaler = self.scaler
 
@@ -357,15 +380,16 @@ class TrainStep:
             loss_f = make_loss_f(frozen, key, inputs, labels)
 
             def scaled_f(p):
-                l = loss_f(p)
-                return l * scaler_state["scale"].astype(l.dtype), l
+                l, counters = loss_f(p)
+                return l * scaler_state["scale"].astype(l.dtype), \
+                    (l, counters)
 
-            (_, loss), grads = jax.value_and_grad(
+            (_, (loss, counters)), grads = jax.value_and_grad(
                 scaled_f, has_aux=True)(params)
             new_params, new_opt, new_sstate = scaler_guarded_update(
                 scaler, scaler_state, grads, grad_clip, optimizer,
                 params, opt_state, step, lr)
-            return loss, new_params, new_opt, new_sstate
+            return loss, new_params, new_opt, counters, new_sstate
 
         donate = (0, 2) if self._donate else ()
         self._compiled = jax.jit(
@@ -408,11 +432,9 @@ class TrainStep:
             with RecordEvent(trace.OPERANDS, step=step):
                 args = self._operands(step, get_rng_key(), inputs, labels)
             out = trace.dispatch(self._compiled, args, step)
+            loss, self._params, self._opt_state, self.counters = out[:4]
             if self.scaler is not None:
-                loss, self._params, self._opt_state, new_sstate = out
-                self.scaler._compiled_state = new_sstate
-            else:
-                loss, self._params, self._opt_state = out
+                self.scaler._compiled_state = out[4]
             with RecordEvent(trace.SYNC, step=step):
                 self.sync_to_model()
         return Tensor(loss)

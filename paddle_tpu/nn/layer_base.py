@@ -8,6 +8,7 @@ jax.jit/pjit — the Layer is the ergonomic front, not the execution unit.
 """
 
 import collections
+import contextlib
 
 import numpy as np
 
@@ -53,8 +54,31 @@ class HookRemoveHelper:
 
 _layer_counter = collections.defaultdict(int)
 
+# the rematerialisation policy of the step being traced, or None: set by
+# ``jit.TrainStep(remat=...)`` round the model's forward (`block_remat`)
+_block_remat = None
+
+
+@contextlib.contextmanager
+def block_remat(policy):
+    """While this is open, every layer that a container holds (a block of
+    a ``LayerList``) runs under ``fleet.recompute(policy=policy)`` when it
+    is called: its forward is run again in the backward pass and only
+    what ``policy`` keeps is saved.  ``None`` switches it off (it is off
+    inside a block, so blocks do not nest)."""
+    global _block_remat
+    before, _block_remat = _block_remat, policy
+    try:
+        yield
+    finally:
+        _block_remat = before
+
 
 class Layer:
+    # held by a container (``add_sublayer`` of a LayerList / LayerDict
+    # says so): one of a model's repeated blocks
+    _block = False
+
     def __init__(self, name_scope=None, dtype=None):
         cls = type(self).__name__.lower()
         _layer_counter[cls] += 1
@@ -154,6 +178,7 @@ class Layer:
             raise TypeError("add_sublayer expects a Layer")
         self._sub_layers[str(name)] = sublayer
         sublayer._set_scope(self._child_scope(str(name)))
+        sublayer._block = self._is_container()
         return sublayer
 
     # ---- trace scopes ----
@@ -349,16 +374,26 @@ class Layer:
     def forward(self, *inputs, **kwargs):
         raise NotImplementedError
 
+    def _scoped_forward(self, *inputs, **kwargs):
+        # metadata only: names every device op traced below by the model
+        # part it belongs to (``gpt/h.3/attn/qkv``), at no cost in the
+        # compiled program
+        with jax.named_scope(self._scope):
+            return self.forward(*inputs, **kwargs)
+
     def __call__(self, *inputs, **kwargs):
         for hook in self._forward_pre_hooks.values():
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        # metadata only: names every device op traced below by the model
-        # part it belongs to (``gpt/h.3/attn/qkv``), at no cost in the
-        # compiled program
-        with jax.named_scope(self._scope):
-            outputs = self.forward(*inputs, **kwargs)
+        if self._block and _block_remat is not None:
+            from ..distributed.fleet.recompute import recompute
+            policy = _block_remat
+            with block_remat(None):
+                outputs = recompute(self._scoped_forward, *inputs,
+                                    policy=policy, **kwargs)
+        else:
+            outputs = self._scoped_forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
             result = hook(self, inputs, outputs)
             if result is not None:

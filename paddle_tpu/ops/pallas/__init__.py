@@ -125,7 +125,8 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
                 # aligned, so only self-attention-shaped causal inputs
                 # take the kernel path
                 reason = "causal with seq_q != seq_k"
-            elif not supports(q.shape[1], k.shape[1], q.shape[3]):
+            elif not supports(q.shape[1], k.shape[1], q.shape[3],
+                              v.shape[3]):
                 reason = "attention_kernel.supports() refuses the shape"
             if reason is None:
                 return flash_attention_pallas(q, k, v, is_causal)
@@ -134,6 +135,38 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     return _xla_attention(q, k, v, attn_mask=attn_mask, is_causal=is_causal,
                           dropout_p=dropout_p, dropout_key=dropout_key,
                           scale=scale)
+
+
+def grouped_matmul(xs, w, group_sizes):
+    """``xs [M, K]`` @ ``w [G, K, N]`` by consecutive groups of rows:
+    rows ``sum(group_sizes[:g])`` to ``sum(group_sizes[:g + 1])`` meet
+    ``w[g]``; rows behind the last group meet nothing and are not read
+    back by any caller (``incubate/distributed/models/moe/dropless.py``
+    masks them).  The work follows the rows that are there, not ``M``.
+
+    On the TPU: JAX's own grouped-matmul Pallas kernel (``megablox``
+    ``gmm``, with ``tgmm`` for the weights' gradient), whose tiles follow
+    ``group_sizes``.  ``jax.lax.ragged_dot`` runs as fast there (the
+    experts' two matmuls over 12,288 real rows of a 98,304-row buffer,
+    forward and backward: 9.18 ms against 9.45, PR 26) but the compiler
+    names its kernels ``ragged-dot-none`` and drops the ``jax.named_scope``
+    path, so a trace could not say whose time they are; the Pallas calls
+    keep both (``gmm``, ``tgmm`` under ``.../experts/...``).  Elsewhere,
+    and under GSPMD: ``jax.lax.ragged_dot``."""
+    if _use_pallas():
+        tm = next((t for t in (512, 256, 128) if xs.shape[0] % t == 0), None)
+        if _partitioned_by_gspmd():
+            warn_fallback("grouped_matmul", tuple(xs.shape), GSPMD_REASON)
+        elif tm is None:
+            warn_fallback("grouped_matmul", tuple(xs.shape),
+                          "rows not a multiple of 128")
+        else:
+            from jax.experimental.pallas.ops.tpu.megablox import ops
+            return ops.gmm(xs, w, group_sizes.astype(jnp.int32),
+                           preferred_element_type=xs.dtype,
+                           tiling=(tm, 512, 512))
+    return jax.lax.ragged_dot(xs, w, group_sizes,
+                              preferred_element_type=xs.dtype)
 
 
 def pick_block(size, preferred, candidates=(512, 256, 128, 64, 32, 16, 8)):

@@ -13,15 +13,26 @@ Layouts: paddle's flash-attn API is [batch, seq, num_heads, head_dim]
 (python/paddle/nn/functional/flash_attention.py:125); kernels run on
 [batch*heads, seq, head_dim].
 
+Head widths: q and k share one width, v and the output another.  Served
+are one width <= 128 for all three (GPT, Llama), and latent attention's
+expanded training form (MLA): q and k 192 wide (128 + a 64-wide rotary
+part), v 128 wide.  The kernels see only the shapes; the second form is the
+same three kernels with a wider score dot and a larger VMEM allowance.  The
+192-wide score is ONE dot: split into a 128-wide and a 64-wide dot it ran
+0.4% (forward) and 0.7% (backward) slower on the v5e ([64, 8192, 192 | 128]
+bf16 causal, PR 26: 15.03 / 43.56 ms against 15.10 / 43.86; the MXU takes
+two passes over the contraction either way).
+
 Constraints (else the caller falls back to the XLA composition): seq divisible
-by the block size, head_dim <= 128.  Attention dropout and additive masks use
-the fallback path.
+by the block size, head widths as :func:`supports` lists them.  Attention
+dropout and additive masks use the fallback path.
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,10 +49,33 @@ def _pick_block(seq, preferred):
     return pick_block(seq, preferred)
 
 
-def supports(seq_q, seq_k, head_dim):
-    return (head_dim <= 128
+# q/k and v head widths that differ: the pairs Mosaic was shown to take
+# (AOT for the v5e and on the chip, PR 26) -- MLA's 128 + 64 rotary over 128
+_SPLIT_HEADS = {(192, 128)}
+
+
+def supports(seq_q, seq_k, head_dim, v_head_dim=None):
+    """``head_dim`` is q's and k's width, ``v_head_dim`` v's (the same
+    where it is not given)."""
+    if v_head_dim is None or v_head_dim == head_dim:
+        heads_ok = head_dim <= 128
+    else:
+        heads_ok = (head_dim, v_head_dim) in _SPLIT_HEADS
+    return (heads_ok
             and _pick_block(seq_q, DEFAULT_BLOCK_Q) is not None
             and _pick_block(seq_k, DEFAULT_BLOCK_K) is not None)
+
+
+def _compiler_params(head_qk, head_v):
+    """Nothing for one head width (the program Mosaic built before PR 26
+    is built again, to the byte).  K, V, Q and dO are held whole in VMEM,
+    double-buffered: at seq 8192 a 192-wide bf16 operand is lane-padded to
+    256 and takes 4 MB a buffer, which passes the 16 MB Mosaic allows a
+    kernel by default on a chip that has 128."""
+    if head_qk == head_v:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=64 * 1024 * 1024)}
 
 
 # ----------------------------------------------------------- inner loops --
@@ -118,7 +152,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         o_new = o_acc * alpha + _dot(p.astype(v.dtype), v, _NN)
         return o_new, m_new, l_new
 
-    o0 = jnp.zeros(q.shape, jnp.float32)
+    o0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     num_kb = _num_key_blocks(qi, block_q, block_k, k_ref.shape[1], causal)
@@ -132,7 +166,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     bn, seq_q, head = q.shape
-    seq_k = k.shape[1]
+    seq_k, head_v = k.shape[1], v.shape[2]
     grid = (bn, seq_q // block_q)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
@@ -142,17 +176,18 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, seq_k, head_v), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q, head_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, seq_q, head), q.dtype),
+            jax.ShapeDtypeStruct((bn, seq_q, head_v), q.dtype),
             jax.ShapeDtypeStruct((bn, seq_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        **_compiler_params(head, head_v),
     )(q, k, v)
     return out, lse
 
@@ -214,10 +249,11 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_new = dk_acc + _dot(dst.astype(q.dtype), q, _NN)
         return dk_new, dv_new
 
-    zeros = jnp.zeros(k.shape, jnp.float32)
+    dk0 = jnp.zeros(k.shape, jnp.float32)
+    dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
     # q blocks before this k block's diagonal contribute nothing
     first = (ki * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(first, num_qb, body, (zeros, zeros))
+    dk, dv = jax.lax.fori_loop(first, num_qb, body, (dk0, dv0))
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -225,8 +261,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                interpret):
     bn, seq_q, head = q.shape
-    seq_k = k.shape[1]
+    seq_k, head_v = k.shape[1], v.shape[2]
     num_qb = seq_q // block_q
+    params = _compiler_params(head, head_v)
     # delta = rowsum(dO * O) — cheap elementwise, leave to XLA fusion
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
@@ -239,14 +276,15 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, seq_k, head_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, block_q, head_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        **params,
     )(q, k, v, do, lse, delta)
 
     rows = (bn, num_qb, block_q)
@@ -258,20 +296,21 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
         in_specs=[
             pl.BlockSpec((1, seq_q, head), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, seq_q, head), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, block_k, head_v), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, seq_q, head_v), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, num_qb, block_q), lambda b, j: (b, 0, 0)),
             pl.BlockSpec((1, num_qb, block_q), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, head_v), lambda b, j: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        **params,
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
@@ -311,39 +350,42 @@ def _block_candidates(seq_q, seq_k):
     return head + rest
 
 
-def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4"):
+def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None):
     """Candidate screen for autotune.pick: reject (block_q, block_k) whose
     per-grid-step residency (kernel_lint's K002 model — double-buffered
     blocks) cannot fit VMEM for the forward, dq, or dkv kernel."""
     from ...framework.kernel_lint import vmem_fits
 
     f32 = jnp.float32
+    hv = head if head_v is None else head_v
 
     def validate(cand):
         bq, bk = cand
         fwd = [((1, bq, head), dtype), ((1, seq_k, head), dtype),
-               ((1, seq_k, head), dtype), ((1, bq, head), dtype),
+               ((1, seq_k, hv), dtype), ((1, bq, hv), dtype),
                ((1, bq, 1), f32)]
         dq = [((1, bq, head), dtype), ((1, seq_k, head), dtype),
-              ((1, seq_k, head), dtype), ((1, bq, head), dtype),
+              ((1, seq_k, hv), dtype), ((1, bq, hv), dtype),
               ((1, bq, 1), f32), ((1, bq, 1), f32), ((1, bq, head), dtype)]
         dkv = [((1, seq_q, head), dtype), ((1, bk, head), dtype),
-               ((1, bk, head), dtype), ((1, seq_q, head), dtype),
+               ((1, bk, hv), dtype), ((1, seq_q, hv), dtype),
                ((1, seq_q // bq, bq), f32), ((1, seq_q // bq, bq), f32),
-               ((1, bk, head), dtype), ((1, bk, head), dtype)]
+               ((1, bk, head), dtype), ((1, bk, hv), dtype)]
         return all(vmem_fits(blocks, profile=profile)
                    for blocks in (fwd, dq, dkv))
 
     return validate
 
 
-def _tuned_blocks(q, k, causal, scale, interpret):
+def _tuned_blocks(q, k, v, causal, scale, interpret):
     """Autotuned (block_q, block_k) for this shape (FLAGS_use_autotune);
     the heuristic (128-preferred divisor) wins with the flag off."""
     from . import autotune
 
     bn, seq_q, head = q.shape
-    seq_k = k.shape[1]
+    seq_k, head_v = k.shape[1], v.shape[2]
+    # one head width keeps the key it had; a second width joins it
+    heads = head if head_v == head else (head, head_v)
     cands = _block_candidates(seq_q, seq_k)
 
     def measure(cand):
@@ -355,7 +397,7 @@ def _tuned_blocks(q, k, causal, scale, interpret):
         shape_k = (min(bn, 8), seq_k, head)
         qq = jnp.asarray(rng.rand(*shape_q), q.dtype)
         kk = jnp.asarray(rng.rand(*shape_k), q.dtype)
-        vv = jnp.asarray(rng.rand(*shape_k), q.dtype)
+        vv = jnp.asarray(rng.rand(*shape_k[:2], head_v), q.dtype)
         out, lse = _flash_fwd(qq, kk, vv, causal, scale, bq, bk, interpret)
         # measure (and VMEM-validate) the backward too: a candidate that
         # fits the fwd can overflow the bwd's working set, and training
@@ -366,20 +408,29 @@ def _tuned_blocks(q, k, causal, scale, interpret):
 
     return autotune.pick(
         "flash_attention",
-        (seq_q, seq_k, head, str(q.dtype), causal),
+        (seq_q, seq_k, heads, str(q.dtype), causal),
         cands, measure=measure,
-        validate=_vmem_validate(seq_q, seq_k, head, q.dtype))
+        validate=_vmem_validate(seq_q, seq_k, head, q.dtype, head_v=head_v))
+
+
+# the forward's own results among the residuals, by the names a
+# rematerialisation policy can keep (``jax.checkpoint_policies
+# .save_only_these_names``): with both saved, the recomputed forward of a
+# layer forms q, k and v again and never runs this kernel a second time
+SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
 def _fwd_rule(q, k, v, causal, scale, interpret):
-    block_q, block_k = _tuned_blocks(q, k, causal, scale, interpret)
+    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret)
     out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+    out = checkpoint_name(out, SAVED_BY_NAME[0])
+    lse = checkpoint_name(lse, SAVED_BY_NAME[1])
     return out, (q, k, v, out, lse)
 
 
 def _bwd_rule(causal, scale, interpret, res, do):
     q, k, v, out, lse = res
-    block_q, block_k = _tuned_blocks(q, k, causal, scale, interpret)
+    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret)
     return _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                       interpret)
 
@@ -409,6 +460,15 @@ def _engine_cases(engine):
 
     yield registry.KernelCase(f"fwd[s{seq}]", fwd, (x, x, x), None)
     yield registry.KernelCase(f"vjp[s{seq}]", vjp, (x, x, x), None)
+    # latent attention's expanded form: q and k carry a rotary part half
+    # as wide again as the head (128 + 64 over 128)
+    wide = h + h // 2
+    if supports(seq, seq, wide, h):
+        xw = sds((engine.max_batch, seq, n, wide), engine.dtype)
+        yield registry.KernelCase(f"fwd_mla[s{seq},{wide}|{h}]", fwd,
+                                  (xw, xw, x), None)
+        yield registry.KernelCase(f"vjp_mla[s{seq},{wide}|{h}]", vjp,
+                                  (xw, xw, x), None)
 
 
 @registry.register_kernel(
@@ -420,17 +480,19 @@ def _engine_cases(engine):
     grad=True)
 def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
                            interpret=False):
-    """q, k, v: [batch, seq, num_heads, head_dim] (paddle flash-attn layout).
+    """q, k: [batch, seq, num_heads, head_dim]; v: [batch, seq, num_heads,
+    v_head_dim] (paddle flash-attn layout; the two widths as
+    :func:`supports` lists them).
 
-    Returns [batch, seq, num_heads, head_dim]; differentiable.
+    Returns [batch, seq, num_heads, v_head_dim]; differentiable.
     """
     b, sq, n, h = q.shape
-    sk = k.shape[1]
+    sk, hv = k.shape[1], v.shape[3]
     if scale is None:
         scale = 1.0 / (h ** 0.5)
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, h)
     kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, h)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, h)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, hv)
     out = _flash_attention_bnsh(qt, kt, vt, bool(is_causal), float(scale),
                                 interpret)
-    return out.reshape(b, n, sq, h).transpose(0, 2, 1, 3)
+    return out.reshape(b, n, sq, hv).transpose(0, 2, 1, 3)

@@ -1,0 +1,506 @@
+"""Latent attention with routed and shared experts (``models/mla_moe.py``,
+``incubate/distributed/models/moe``), at a size the CPU runs, on seeded
+weights:
+
+- the model against the plain reference ``chipbench/reference/mla_moe.py``:
+  logits, loss, every leaf's gradient, the counters;
+- the dropless dispatch against a loop over experts under a routing that
+  sends most tokens to one expert: nothing dropped;
+- the SHARE test: the routed parts the eight shares of a layer give, plus
+  the shared expert counted once, are what the uncut layer gives;
+- ``jit.TrainStep`` hands the model's counters back beside the loss;
+- the attention dispatch at q/k 192 over v 128.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import optimizer
+from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.incubate.distributed.models.moe import (DroplessMoELayer,
+                                                        MoELayer, dropless)
+from paddle_tpu.jit import TrainStep, functional_call
+from paddle_tpu.models.mla_moe import (MlaMoeConfig, MlaMoeForCausalLM,
+                                       apply_rope, mla_moe_tiny, rope_tables)
+
+from chipbench.reference import mla_moe as ref
+from chipbench.runners import mla_moe_train as runner
+
+# 16 router outputs, three a token; one dense layer, two expert layers
+BASE = dict(hidden_size=64, num_attention_heads=4, num_hidden_layers=3,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            kv_lora_rank=32, q_lora_rank=None, intermediate_size=128,
+            moe_intermediate_size=32, n_shared_experts=2,
+            num_experts_per_tok=3, first_k_dense_replace=1,
+            routed_scaling_factor=2.448, norm_topk_prob=True,
+            rms_norm_eps=1e-6, rope_theta=1e6, rope_interleave=True,
+            vocab_size=96, max_position_embeddings=64)
+SHARES = {"uncut": dict(n_routed_experts=16, deployment={}),
+          "share-4-of-16-from-4": dict(
+              n_routed_experts=4,
+              deployment={"router_experts": 16, "expert_offset": 4})}
+
+
+def _seeded(share, seed=7):
+    """(model group, program model holding the reference's seeded float32
+    weights, the reference's tree)."""
+    m = runner.model_group({**BASE, **SHARES[share]})
+    paddle.seed(0)
+    model = MlaMoeForCausalLM(runner.model_config(m))
+    tree = ref.init_params(seed, m, jnp.float32)
+    runner.load_seeded(model, tree, m["first_k_dense_replace"])
+    return m, model, tree
+
+
+def _ids(seed=0, rows=2, seq=32):
+    return np.random.RandomState(seed).randint(
+        0, BASE["vocab_size"], (rows, seq)).astype("int32")
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+def test_logits_and_counters_match_the_reference(share):
+    m, model, tree = _seeded(share)
+    ids = _ids()
+    got = model(paddle.to_tensor(ids))._data
+    with jax.default_matmul_precision("highest"):
+        rows = [ref.forward_row(tree, jnp.asarray(r), m) for r in ids]
+    want = jnp.stack([r[0] for r in rows])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=2e-6)
+    counts = np.asarray(model.step_counters()["moe_tokens_per_expert"])
+    np.testing.assert_array_equal(counts, sum(np.asarray(r[1])
+                                              for r in rows))
+    assert counts.shape == (2, m["n_routed_experts"])
+
+
+@pytest.mark.parametrize("share", list(SHARES))
+def test_loss_and_every_leafs_gradient_match_the_reference(share):
+    m, model, tree = _seeded(share)
+    ids = _ids(1)
+    state = {n: t._data for n, t in model.state_dict().items()}
+    params = {n: a for n, a in state.items() if not n.endswith("_bias")}
+    frozen = {n: a for n, a in state.items() if n.endswith("_bias")}
+
+    def loss_f(p):
+        out = functional_call(model, {**p, **frozen}, jnp.asarray(ids))
+        return model.loss(Tensor(out), Tensor(jnp.asarray(ids)))._data
+
+    def ref_loss(p):
+        total = sum(ref._row_loss_sum(p, jnp.asarray(r), jnp.asarray(r), m,
+                                      "float32")[0] for r in ids)
+        return total / (ids.shape[0] * (ids.shape[1] - 1))
+
+    loss, grads = jax.value_and_grad(loss_f)(params)
+    with jax.default_matmul_precision("highest"):
+        want_loss, want = jax.value_and_grad(ref_loss)(tree)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    first_k = m["first_k_dense_replace"]
+    assert len(grads) == len(params) > 30
+    for name, g in grads.items():
+        group, leaf, layer = runner.program_key(name, first_k)
+        w = want[group][leaf]
+        if layer is not None:
+            w = w[layer if group == "dense" else layer - first_k]
+        gap = float(jnp.linalg.norm(g - w) / jnp.linalg.norm(w))
+        assert gap < 1e-4, (name, gap)
+
+
+def test_remat_by_block_changes_no_value_and_recomputes_each_layer():
+    ids = paddle.to_tensor(_ids(2))
+    losses, recomputed = [], []
+    for remat in (False, True, ["flash_attention_out"]):
+        _, model, _ = _seeded("share-4-of-16-from-4")
+        opt = optimizer.AdamW(learning_rate=1e-3,
+                              parameters=model.parameters())
+        step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                         remat=remat)
+        recomputed.append(step.lower(ids, ids).compile().as_text().count(
+            "rematted_computation/layers."))
+        losses.append([float(step(ids, ids)._data) for _ in range(3)])
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+    np.testing.assert_allclose(losses[0], losses[2], rtol=1e-5)
+    assert losses[0][2] < losses[0][0]
+    assert recomputed[0] == 0 and recomputed[1] > 0 and recomputed[2] > 0
+
+
+def test_train_step_hands_back_the_counters_beside_the_loss():
+    _, model, _ = _seeded("share-4-of-16-from-4")
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                     remat=True)
+    assert step.counters == {}
+    ids = _ids(3)
+    step(paddle.to_tensor(ids), paddle.to_tensor(ids))
+    counts = step.counters["moe_tokens_per_expert"]
+    assert isinstance(counts, jax.Array) and counts.dtype == jnp.int32
+    assert counts.shape == (2, 4)
+    # a share serves part of the 3 assignments a token makes, never more
+    served = np.asarray(counts).sum(axis=1)
+    assert ((served > 0) & (served <= ids.size * 3)).all()
+    assert step.stats() == {"steps": 1, "compiles": 1}
+
+
+def test_a_model_without_counters_returns_none():
+    from paddle_tpu.models.gpt import gpt_tiny
+
+    model = gpt_tiny(num_layers=1)
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt)
+    ids = paddle.to_tensor(_ids(4, seq=16))
+    loss = step(ids, ids)
+    assert step.counters == {} and np.isfinite(float(loss._data))
+
+
+def test_eager_backward_reaches_every_parameter():
+    model = mla_moe_tiny(num_local_experts=4, expert_offset=2)
+    ids = paddle.to_tensor(_ids(5, seq=16) % 64)
+    model.loss(model(ids), ids).backward()
+    assert all(p.grad is not None for p in model.parameters())
+
+
+# ------------------------------------------------------------ dispatch ----
+def _loop_over_experts(x, idx, w, gate_up, down, offset):
+    """Each local expert on the tokens that chose it, one at a time."""
+    out = np.zeros(x.shape, np.float64)
+    inter = down.shape[1]
+    for e in range(gate_up.shape[0]):
+        for t, k in zip(*np.nonzero(np.asarray(idx) == e + offset)):
+            gu = np.asarray(x[t], np.float64) @ np.asarray(gate_up[e],
+                                                           np.float64)
+            g, u = gu[:inter], gu[inter:]
+            h = g / (1.0 + np.exp(-g)) * u
+            out[t] += float(w[t, k]) * (h @ np.asarray(down[e], np.float64))
+    return out
+
+
+def _skewed_routing(s, e, k, hot, seed):
+    """Most tokens choose expert ``hot``; the rest of their k distinct."""
+    rng = np.random.RandomState(seed)
+    idx = np.stack([rng.permutation(e)[:k] for _ in range(s)])
+    for t in range(s):
+        if rng.rand() < 0.9 and hot not in idx[t]:
+            idx[t, 0] = hot
+    return jnp.asarray(idx, jnp.int32), \
+        jnp.asarray(rng.rand(s, k) + 0.1, jnp.float32)
+
+
+@pytest.mark.parametrize("offset,held", [(0, 8), (2, 4), (6, 2)])
+def test_dropless_dispatch_against_a_loop_over_experts_skewed(offset, held):
+    s, h, inter, e, k = 96, 32, 16, 8, 3
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(s, h), jnp.float32)
+    gate_up = jnp.asarray(rng.randn(held, h, 2 * inter) * 0.2, jnp.float32)
+    down = jnp.asarray(rng.randn(held, inter, h) * 0.2, jnp.float32)
+    idx, w = _skewed_routing(s, e, k, hot=offset, seed=1)
+
+    def routed(x, gate_up, down, w):
+        order, inverse, counts = dropless.sort_by_expert(idx, offset, held)
+        xs = dropless.dispatch(x, order, inverse, counts)
+        ys = dropless.swiglu_experts(xs, gate_up, down, counts)
+        return dropless.combine(ys, w, order, inverse, counts), counts
+
+    got, counts = routed(x, gate_up, down, w)
+    want = _loop_over_experts(x, idx, w, gate_up, down, offset)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+    # no token is dropped whatever the imbalance: every assignment to an
+    # expert held here is counted and served, the hot expert's too
+    local = (np.asarray(idx) >= offset) & (np.asarray(idx) < offset + held)
+    assert int(counts.sum()) == int(local.sum())
+    assert int(counts[0]) == int((np.asarray(idx) == offset).sum()) > 0.8 * s
+    # gradients through the permutation gathers (no scatter): against the
+    # plain formulation under jax.grad
+    def plain(x, gate_up, down, w):
+        out = 0.0
+        for j in range(held):
+            hit = idx == j + offset
+            w_e = jnp.sum(jnp.where(hit, w, 0.0), axis=1)
+            gu = x @ gate_up[j]
+            out = out + w_e[:, None] * (
+                (jax.nn.silu(gu[:, :inter]) * gu[:, inter:]) @ down[j])
+        return out
+
+    probe = jnp.asarray(rng.randn(s, h), jnp.float32)
+    g1 = jax.grad(lambda *a: jnp.sum(routed(*a)[0] * probe),
+                  argnums=(0, 1, 2, 3))(x, gate_up, down, w)
+    g2 = jax.grad(lambda *a: jnp.sum(plain(*a) * probe),
+                  argnums=(0, 1, 2, 3))(x, gate_up, down, w)
+    for a, b, name in zip(g1, g2, ("x", "gate_up", "down", "weights")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
+                                   atol=2e-4, err_msg=name)
+
+
+def test_router_norms_over_the_chosen_and_the_bias_only_steers():
+    logits = jnp.asarray(np.random.RandomState(0).randn(32, 16), jnp.float32)
+    zero = jnp.zeros((16,))
+    idx, w = dropless.route_sigmoid_topk(logits, zero, 6, 2.448)
+    s = np.asarray(jax.nn.sigmoid(logits))
+    np.testing.assert_array_equal(np.sort(np.asarray(idx), 1),
+                                  np.sort(np.argsort(-s, 1)[:, :6], 1))
+    np.testing.assert_allclose(np.asarray(w).sum(1), 2.448, rtol=1e-5)
+    chosen = np.take_along_axis(s, np.asarray(idx), 1)
+    np.testing.assert_allclose(np.asarray(w),
+                               chosen / chosen.sum(1, keepdims=True) * 2.448,
+                               rtol=1e-5)
+    # a large bias on expert 3 puts it among every token's six; its weight
+    # is still its own score's share
+    bias = zero.at[3].set(10.0)
+    idx_b, w_b = dropless.route_sigmoid_topk(logits, bias, 6, 1.0)
+    assert (np.asarray(idx_b) == 3).any(axis=1).all()
+    chosen = np.take_along_axis(s, np.asarray(idx_b), 1)
+    np.testing.assert_allclose(np.asarray(w_b),
+                               chosen / chosen.sum(1, keepdims=True),
+                               rtol=1e-5)
+
+
+# --------------------------------------------------------------- share ----
+def _layer(num_local, offset, full=None):
+    paddle.seed(0)
+    layer = DroplessMoELayer(32, 16, num_experts=16, top_k=6,
+                             num_shared_experts=2,
+                             routed_scaling_factor=2.448,
+                             num_local_experts=num_local,
+                             expert_offset=offset)
+    if full is not None:        # hold a slice of the uncut layer's experts
+        sd = {n: t for n, t in full.state_dict().items()
+              if "experts.gate_up" not in n and "experts.down" not in n
+              or "shared" in n}
+        sl = slice(offset, offset + num_local)
+        sd["experts.gate_up"] = Tensor(full.experts.gate_up._data[sl])
+        sd["experts.down"] = Tensor(full.experts.down._data[sl])
+        layer.set_state_dict(sd)
+    return layer
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer():
+    """Routed parts of the eight shares + the shared expert ONCE = the
+    uncut layer; the shares' counts are the uncut layer's, split."""
+    full = _layer(16, 0)
+    x = paddle.to_tensor(np.random.RandomState(1).randn(2, 24, 32)
+                         .astype("float32"))
+    want = np.asarray(full(x)._data)
+    want_counts = np.asarray(full.tokens_per_expert._data)
+    shared = np.asarray(full.shared_experts(x.reshape([-1, 32]))._data
+                        ).reshape(want.shape)
+    routed, counts = np.zeros_like(want), []
+    for chip in range(8):
+        share = _layer(2, 2 * chip, full)
+        routed += np.asarray(share(x)._data) - shared
+        counts.append(np.asarray(share.tokens_per_expert._data))
+    np.testing.assert_allclose(routed + shared, want, rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.concatenate(counts), want_counts)
+    assert want_counts.sum() == 2 * 24 * 6          # every assignment served
+    assert np.abs(routed).max() > 10 * np.abs(routed + shared - want).max()
+
+
+def test_a_share_outside_the_router_is_refused():
+    with pytest.raises(ValueError, match="not among the router's"):
+        DroplessMoELayer(32, 16, num_experts=16, top_k=6,
+                         num_local_experts=4, expert_offset=14)
+
+
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_moe_layer_dropless_equals_dense_when_nothing_is_dropped(top_k):
+    paddle.seed(0)
+    dense = MoELayer(32, 64, 8, gate="naive", top_k=top_k,
+                     capacity_factor=8.0)           # ample: nothing dropped
+    paddle.seed(0)
+    free = MoELayer(32, 64, 8, gate="naive", top_k=top_k, dropless=True)
+    free.set_state_dict(dense.state_dict())
+    x = paddle.to_tensor(np.random.RandomState(2).randn(4, 16, 32)
+                         .astype("float32"))
+    np.testing.assert_allclose(np.asarray(free(x)._data),
+                               np.asarray(dense(x)._data), rtol=2e-4,
+                               atol=2e-5)
+    assert float(free.l_aux._data) == pytest.approx(
+        float(dense.l_aux._data), rel=1e-5)
+
+
+def test_moe_layer_dropless_drops_nothing_where_dense_does():
+    """All tokens to one expert: the dense path keeps ``capacity`` of them,
+    the dropless one all."""
+    paddle.seed(0)
+    dense = MoELayer(16, 32, 4, gate="naive", top_k=1, capacity_factor=1.0)
+    free = MoELayer(16, 32, 4, gate="naive", top_k=1, dropless=True)
+    free.set_state_dict(dense.state_dict())
+    wg = np.zeros((16, 4), "float32")
+    wg[:, 2] = 1.0                                  # expert 2 for everybody
+    for layer in (dense, free):
+        layer.gate_weight._data = jnp.asarray(wg)
+    x = paddle.to_tensor(np.abs(np.random.RandomState(3).randn(1, 32, 16))
+                         .astype("float32"))
+    kept_dense = (np.abs(np.asarray(dense(x)._data)).sum(-1) > 0).sum()
+    kept_free = (np.abs(np.asarray(free(x)._data)).sum(-1) > 0).sum()
+    assert kept_dense == 8 and kept_free == 32
+
+
+def test_moe_layer_dropless_routes_through_its_gate():
+    """The gate's own jitter reaches the dropless dispatch, a capacity is
+    refused, and so is a gate that cannot route without one."""
+    with pytest.raises(ValueError, match="capacity_factor"):
+        MoELayer(16, 32, 4, gate="naive", capacity_factor=2.0, dropless=True)
+    with pytest.raises(ValueError, match="gate.route"):
+        MoELayer(16, 32, 4, gate=lambda logits, jitter_key=None: None,
+                 dropless=True)
+    paddle.seed(0)
+    free = MoELayer(16, 32, 4, gate="switch", dropless=True)
+    x = paddle.to_tensor(np.random.RandomState(4).randn(2, 64, 16)
+                         .astype("float32"))
+    free.gate.jitter_eps = 5.0          # large: the choice visibly moves
+    paddle.seed(1)
+    a = np.asarray(free(x)._data)
+    paddle.seed(2)
+    b = np.asarray(free(x)._data)
+    free.eval()
+    c, d = np.asarray(free(x)._data), np.asarray(free(x)._data)
+    assert np.abs(a - b).max() > 1e-6 and np.array_equal(c, d)
+
+
+# ----------------------------------------------------------- attention ----
+def test_rope_interleaved_layout_gives_the_pairwise_rotation_scores():
+    """De-interleave then rotate-half (the published code) and the
+    pairwise rotation in place give the same q . k."""
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.randn(1, 12, 2, 8), jnp.float32)
+    k = jnp.asarray(rng.randn(1, 12, 2, 8), jnp.float32)
+    cos, sin = (jnp.asarray(a) for a in rope_tables(8, 12, 1e6))
+    got = jnp.einsum("btnd,bsnd->bnts", apply_rope(q, cos, sin, True),
+                     apply_rope(k, cos, sin, True))
+    want = jnp.einsum("tnd,snd->nts", ref._rope(q[0], 1e6),
+                      ref._rope(k[0], 1e6))[None]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+
+
+def test_sdpa_takes_keys_wider_than_values():
+    from paddle_tpu.nn import functional as F
+
+    rng = np.random.RandomState(0)
+    q, k = (paddle.to_tensor(rng.randn(1, 16, 2, 192).astype("float32"))
+            for _ in range(2))
+    v = paddle.to_tensor(rng.randn(1, 16, 2, 128).astype("float32"))
+    out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
+    assert out.shape == [1, 16, 2, 128]
+    s = np.einsum("tnd,snd->nts", np.asarray(q._data[0]),
+                  np.asarray(k._data[0])) / np.sqrt(192.0)
+    s = np.where(np.tril(np.ones((16, 16), bool)), s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("nts,snd->tnd", p, np.asarray(v._data[0]))
+    np.testing.assert_allclose(np.asarray(out._data[0]), want, rtol=1e-4,
+                               atol=1e-5)
+
+
+@pytest.mark.parametrize("qk,v,ok", [(128, None, True), (128, 128, True),
+                                     (192, 128, True), (192, None, False),
+                                     (192, 192, False), (256, 128, False),
+                                     (96, 64, False)])
+def test_flash_supports_says_what_is_true(qk, v, ok):
+    from paddle_tpu.ops.pallas.attention_kernel import supports
+
+    assert supports(8192, 8192, qk, v) is ok
+
+
+def test_flash_dispatch_takes_the_kernel_at_192_over_128(monkeypatch):
+    """On the TPU path the dispatcher hands q/k 192, v 128 to the kernel
+    (no fallback warning); a width the kernel does not serve falls back
+    aloud."""
+    import warnings
+
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas import attention_kernel as ak
+
+    seen = []
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        ak, "flash_attention_pallas",
+        lambda q, k, v, causal: seen.append((q.shape, v.shape)) or v)
+    x = lambda d: jnp.zeros((1, 1024, 2, d), jnp.bfloat16)  # noqa: E731
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", pk.KernelFallbackWarning)
+        pk.flash_attention(x(192), x(192), x(128), is_causal=True)
+    assert seen == [((1, 1024, 2, 192), (1, 1024, 2, 128))]
+    with pytest.warns(pk.KernelFallbackWarning, match="supports"):
+        pk.flash_attention(x(192), x(192), x(192), is_causal=True)
+
+
+def test_preset_counts_the_published_parameters():
+    """kanana-2-30b-a3b's layer sizes from its config: attention 26.35M,
+    an expert 4.72M, the shared expert 9.44M, the dense MLP 37.75M."""
+    cfg = MlaMoeConfig(
+        vocab_size=128256, hidden_size=2048, num_hidden_layers=48,
+        num_attention_heads=32, qk_nope_head_dim=128, qk_rope_head_dim=64,
+        v_head_dim=128, kv_lora_rank=512, intermediate_size=6144,
+        moe_intermediate_size=768, n_routed_experts=128, n_shared_experts=2,
+        num_experts_per_tok=6)
+    assert cfg.qk_head_dim == 192 and cfg.num_local_experts == 128
+    from chipbench.readers.mfu_active import active_params
+
+    m = dict(hidden_size=2048, num_attention_heads=32, qk_nope_head_dim=128,
+             qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+             intermediate_size=6144, moe_intermediate_size=768,
+             router_experts=128, n_shared_experts=2, num_hidden_layers=1,
+             first_k_dense_replace=1, vocab_size=0)
+    dense_layer = active_params(m, 0)
+    assert dense_layer == pytest.approx(26.35e6 + 37.75e6, rel=1e-3)
+    m["first_k_dense_replace"] = 0
+    assert active_params(m, 6) - active_params(m, 0) == 6 * 3 * 2048 * 768
+    assert active_params(m, 0) == pytest.approx(26.35e6 + 0.26e6 + 9.44e6,
+                                                rel=1e-3)
+    with pytest.raises(NotImplementedError, match="q_lora_rank"):
+        MlaMoeConfig(q_lora_rank=1536)
+
+
+# ------------------------------------------------------ grouped matmul ----
+def test_grouped_matmul_is_ragged_dot_off_the_tpu():
+    from paddle_tpu.ops import pallas as pk
+
+    rng = np.random.RandomState(0)
+    xs = jnp.asarray(rng.randn(24, 8), jnp.float32)
+    w = jnp.asarray(rng.randn(3, 8, 5), jnp.float32)
+    sizes = jnp.asarray([10, 0, 9], jnp.int32)      # 5 rows belong to nobody
+    got = np.asarray(pk.grouped_matmul(xs, w, sizes))
+    np.testing.assert_allclose(got[:10], np.asarray(xs[:10] @ w[0]),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[10:19], np.asarray(xs[10:19] @ w[2]),
+                               rtol=1e-5, atol=1e-5)
+    assert not got[19:].any()
+
+
+def test_grouped_matmul_lowers_to_the_pallas_kernels_for_the_tpu(monkeypatch):
+    """At the cell's widths (the worst case's 98,304 rows, 16 experts of
+    2048 x 1536 and 768 x 2048), forward and backward: the Mosaic calls are
+    there and ``ragged_dot`` is not."""
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    sds = jax.ShapeDtypeStruct
+
+    def loss(xs, gate_up, down, sizes):
+        ys = dropless.swiglu_experts(xs, gate_up, down, sizes)
+        return jnp.sum(ys.astype(jnp.float32))
+
+    args = (sds((98304, 2048), jnp.bfloat16),
+            sds((16, 2048, 1536), jnp.bfloat16),
+            sds((16, 768, 2048), jnp.bfloat16), sds((16,), jnp.int32))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    # the first forward, and a gmm and a tgmm for each matmul's backward
+    # (a sum needs no second forward)
+    assert text.count("tpu_custom_call") >= 5
+    assert "ragged_dot" not in text
+
+
+def test_grouped_matmul_gives_way_aloud_where_the_rows_do_not_tile(
+        monkeypatch):
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    xs, w = jnp.ones((100, 8)), jnp.ones((2, 8, 4))
+    with pytest.warns(pk.KernelFallbackWarning, match="multiple of 128"):
+        out = pk.grouped_matmul(xs, w, jnp.asarray([60, 40], jnp.int32))
+    assert out.shape == (100, 4)

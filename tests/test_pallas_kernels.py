@@ -24,10 +24,12 @@ def _rand(shape, seed, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("shape", [(2, 128, 2, 64), (1, 256, 4, 32)])
+@pytest.mark.parametrize("shape", [(2, 128, 2, 64), (1, 256, 4, 32),
+                                   (1, 256, 2, 192, 128)])
 def test_flash_attention_forward(shape, causal):
-    b, t, n, h = shape
-    q, k, v = (_rand(shape, s) for s in (0, 1, 2))
+    """A fifth entry is v's width where it differs from q's and k's."""
+    q, k = (_rand(shape[:4], s) for s in (0, 1))
+    v = _rand(shape[:3] + shape[-1:], 2)
     got = flash_attention_pallas(q, k, v, is_causal=causal, interpret=True)
     want = _xla_attention(q, k, v, is_causal=causal)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -35,9 +37,15 @@ def test_flash_attention_forward(shape, causal):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_attention_grads(causal):
-    shape = (1, 128, 2, 32)
-    q, k, v = (_rand(shape, s) for s in (3, 4, 5))
+@pytest.mark.parametrize("heads", [(32, 32), (192, 128)],
+                         ids=["32", "mla192|128"])
+def test_flash_attention_grads(causal, heads):
+    """``heads``: q/k width and v width; 192 | 128 is latent attention's
+    expanded form (scores over 128 + 64 rotary, values 128 wide)."""
+    qk, hv = heads
+    q, k = (_rand((1, 128, 2, qk), s) for s in (3, 4))
+    v = _rand((1, 128, 2, hv), 5)
+    assert supports(128, 128, qk, hv)
 
     def loss_pallas(q, k, v):
         out = flash_attention_pallas(q, k, v, is_causal=causal,

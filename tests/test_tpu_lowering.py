@@ -87,8 +87,10 @@ def test_registry_lowers_for_tpu_where_supported():
         assert "tpu_custom_call" in text, cid
         lowered += 1
     # ragged + ragged_quant over 6 buckets x 3 engines, flash and
-    # layernorm fwd+vjp x 3, the two training layernorm shapes
-    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 * 3 + 2
+    # layernorm fwd+vjp x 3, flash at 192 | 128 (latent attention's
+    # expanded form) fwd+vjp on the head_dim-128 engine, the two
+    # training layernorm shapes
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 2 * 3 + 2
 
 
 def test_refusals_are_declared_only_where_needed():

@@ -145,6 +145,22 @@ def test_backward_and_recomputed_ops_are_marked(compiled_names, which):
     assert any("transpose(" not in n for n in mlp)       # and a forward one
 
 
+def test_train_step_remat_is_by_block_and_changes_no_value(compiled_names):
+    """``TrainStep(remat=True)`` rematerialises each block its LayerList
+    holds (one block's activations live at a time), not the whole loss:
+    every block is recomputed under its own name, the head and the loss
+    are not, and the losses are those of the plain step."""
+    recomputed = {seg for n, segs in compiled_names["train"]
+                  if "rematted_computation" in n for seg in segs}
+    assert {"h.0", "h.1"} <= recomputed
+    assert not {"lm_head", "loss", "embeddings"} & recomputed
+    t = paddle.to_tensor(_batch())
+    plain, remat = _train_step(), _train_step(remat=True)
+    for _ in range(3):
+        assert float(remat(t, t)._data) == pytest.approx(
+            float(plain(t, t)._data), rel=1e-6)
+
+
 def test_scopes_change_nothing_but_metadata(monkeypatch):
     """The optimized HLO with the scopes every ``with jax.named_scope``
     opens (the Layer tree's, ``lm_head``, ``loss``, ``grad_clip``) is the
