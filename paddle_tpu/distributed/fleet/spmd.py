@@ -72,6 +72,13 @@ def shard_parameters(layer, mesh, placement=True):
     return layer
 
 
+def data_axes(mesh):
+    """The mesh axes a training batch is split over: ``dp`` and a
+    dedicated ``sharding`` axis (the sharding group is extra data
+    parallelism, reference group_sharded), those larger than 1."""
+    return tuple(a for a in ("dp", "sharding") if mesh.shape.get(a, 1) > 1)
+
+
 def batch_spec(mesh, extra_batch_axes=("dp",)):
     axes = tuple(a for a in extra_batch_axes if a in mesh.axis_names)
     if not axes:

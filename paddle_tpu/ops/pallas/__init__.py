@@ -13,6 +13,7 @@ cannot serve calls :func:`warn_fallback`, which warns once per distinct
 can turn it into an error (``chip_smoke.py`` does).
 """
 
+import math
 import warnings
 
 import jax
@@ -90,6 +91,60 @@ def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     return out.astype(q.dtype)
 
 
+def _mesh_split(mesh):
+    """How attention divides over ``mesh``, by the repo's own convention:
+    the batch over the axes ``SpmdTrainStep`` shards its batch by, the
+    heads over ``mp`` (the column-parallel q|k|v projection leaves them
+    there).  ``(batch_axes, head_axis)``, of the axes larger than 1."""
+    from ...distributed.fleet.spmd import data_axes
+
+    head_axis = "mp" if mesh.shape.get("mp", 1) > 1 else None
+    return data_axes(mesh), head_axis
+
+
+def _sharded_refusal(q, mesh):
+    """Why :func:`flash_attention_sharded` cannot serve ``q [batch, seq,
+    heads, head_dim]`` where GSPMD partitions the computation, or None."""
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
+        # spmd_pipeline at pp > 1: a second shard_map over the axes left
+        # to GSPMD would have to be nested; no cell runs it
+        return (f"{GSPMD_REASON}; already inside a shard_map that is "
+                f"manual over {tuple(am.manual_axes)} only")
+    if mesh.shape.get("sep", 1) > 1:
+        return (f"the mesh has a sep axis of {mesh.shape['sep']}: context "
+                f"parallelism has its own path")
+    batch_axes, head_axis = _mesh_split(mesh)
+    ways = math.prod(mesh.shape[a] for a in batch_axes)
+    if q.shape[0] % ways:
+        return (f"batch {q.shape[0]} does not divide over mesh axes "
+                f"{batch_axes} of {ways}")
+    if head_axis and q.shape[2] % mesh.shape[head_axis]:
+        return (f"{q.shape[2]} heads do not divide over {head_axis} of "
+                f"{mesh.shape[head_axis]}")
+    return None
+
+
+def flash_attention_sharded(q, k, v, is_causal, mesh, interpret=False):
+    """The flash kernels where GSPMD partitions the step: a ``shard_map``
+    that makes EVERY axis of ``mesh`` manual (what a Mosaic kernel needs),
+    each shard running the kernels on its rows and heads.  Attention mixes
+    neither batch rows nor heads, so no collective is inside; the backward
+    is the kernels' ``custom_vjp``, transposed per shard.  The caller has
+    asked :func:`_sharded_refusal`."""
+    from jax.sharding import PartitionSpec as P
+
+    from .attention_kernel import flash_attention_pallas
+
+    batch_axes, head_axis = _mesh_split(mesh)
+    spec = P(batch_axes or None, None, head_axis, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention_pallas(q, k, v, is_causal,
+                                               interpret=interpret),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
+
+
 def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
                     dropout_key=None, scale=None):
     """Flash attention on [batch, seq, num_heads, head_dim].
@@ -98,7 +153,10 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     the global RNG (paddle.seed-controlled) — attention dropout must not be
     silently dropped.  Attention dropout forces the XLA path (the Pallas
     kernel is dropout-free, like most production flash kernels at
-    inference/bf16 pretrain settings)."""
+    inference/bf16 pretrain settings).
+
+    Under a mesh that GSPMD partitions (``SpmdTrainStep``, ``use_mesh``)
+    the kernels run inside :func:`flash_attention_sharded`."""
     if dropout_p > 0.0 and dropout_key is None:
         from ...framework.random import get_rng_key
         dropout_key = get_rng_key()
@@ -113,10 +171,8 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
         # not a fallback: short sequences take XLA without a word.
         min_seq = get_flags("FLAGS_flash_min_seqlen")["FLAGS_flash_min_seqlen"]
         if q.shape[1] >= int(min_seq):
-            reason = None
-            if _partitioned_by_gspmd():
-                reason = GSPMD_REASON
-            elif attn_mask is not None or dropout_p > 0.0 \
+            reason = mesh = None
+            if attn_mask is not None or dropout_p > 0.0 \
                     or scale is not None:
                 reason = "the kernel takes no attn_mask, dropout or scale"
             elif is_causal and q.shape[1] != k.shape[1]:
@@ -127,8 +183,16 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
                 reason = "causal with seq_q != seq_k"
             elif not supports(q.shape[1], k.shape[1], q.shape[3],
                               v.shape[3]):
+                # sequence and head widths are whole in every shard, so
+                # this answers for the sharded launch too
                 reason = "attention_kernel.supports() refuses the shape"
+            elif _partitioned_by_gspmd():
+                from ...distributed.fleet.spmd import current_mesh
+                mesh = current_mesh()
+                reason = _sharded_refusal(q, mesh)
             if reason is None:
+                if mesh is not None:
+                    return flash_attention_sharded(q, k, v, is_causal, mesh)
                 return flash_attention_pallas(q, k, v, is_causal)
             warn_fallback("flash_attention",
                           f"q{tuple(q.shape)} k{tuple(k.shape)}", reason)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
-from ..distributed.fleet.spmd import use_mesh
+from ..distributed.fleet.spmd import data_axes, use_mesh
 from ..framework.random import get_rng_key, key_stream
 from ..profiler import RecordEvent, StepTrace
 from .pipeline import spmd_pipeline
@@ -152,9 +152,7 @@ class SpmdTrainStep:
 
         # batch parallelism rides dp AND a dedicated sharding axis — the
         # sharding group is extra data parallelism (reference group_sharded)
-        self._batch_axes = tuple(
-            a for a in ("dp", "sharding")
-            if mesh.shape.get(a, 1) > 1) or None
+        self._batch_axes = data_axes(mesh) or None
         if self._batch_axes is not None and len(self._batch_axes) == 1:
             self._batch_axes = self._batch_axes[0]
         self.batch_sharding = NamedSharding(mesh, P(self._batch_axes))

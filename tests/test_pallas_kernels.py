@@ -248,6 +248,144 @@ def test_flash_attention_bf16_key_bias_gradient_stays_noise():
     assert 0 < xla and flash <= 3 * xla, (flash, xla)
 
 
+# ------------------------------------------ flash under a dp x mp mesh ----
+
+def _dp_mp_mesh(**degrees):
+    from paddle_tpu.distributed.fleet.topology import build_mesh
+
+    n = int(np.prod(list(degrees.values())))
+    return build_mesh(devices=jax.devices()[:n], **degrees)
+
+
+@pytest.mark.parametrize("form", ["plain", "checkpoint_in_scan"])
+def test_flash_attention_sharded_matches_xla(form):
+    """The kernels inside the dispatcher's shard_map on the four-axis mesh
+    ``build_mesh(dp=2, mp=2)`` gives (2 x 1 x 1 x 2: two rows, four heads)
+    against the XLA composition, in value and in the gradients the
+    custom_vjp gives per shard; ``checkpoint_in_scan`` is the form
+    ``SpmdTrainStep(remat=True)`` puts it in (a rematerialised block
+    inside the scan over layers), where the forward kernel runs twice."""
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+    from paddle_tpu.ops.pallas import flash_attention_sharded
+
+    mesh = _dp_mp_mesh(dp=2, mp=2)
+    assert tuple(mesh.shape.items()) == (
+        ("dp", 2), ("pp", 1), ("sharding", 1), ("mp", 2))
+    q, k, v, w = (_rand((2, 128, 4, 32), s) for s in (40, 41, 42, 43))
+
+    def loss(attn):
+        def layer(h, _):
+            return h + attn(q + h, k, v), None
+
+        def f(q, k, v):
+            if form == "plain":
+                return jnp.sum(attn(q, k, v) * w)
+            h, _ = jax.lax.scan(jax.checkpoint(layer), jnp.zeros_like(q),
+                                None, length=2)
+            return jnp.sum(h * w)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))
+
+    with use_mesh(mesh):
+        got, grads = jax.jit(loss(lambda q, k, v: flash_attention_sharded(
+            q, k, v, True, mesh, interpret=True)))(q, k, v)
+    want, ref = loss(lambda q, k, v: _xla_attention(
+        q, k, v, is_causal=True))(q, k, v)
+    np.testing.assert_allclose(got, want, rtol=2e-4)
+    for a, b, name in zip(grads, ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-4, atol=5e-4,
+                                   err_msg=f"d{name} mismatch")
+    if form == "plain":     # dq leaves the shard_map as q went in
+        assert grads[0].sharding.spec == jax.sharding.PartitionSpec(
+            "dp", None, "mp")
+
+
+@pytest.fixture
+def tpu_dispatch(monkeypatch):
+    """The flash dispatcher as it decides on a TPU, with each of its three
+    ways out replaced by a recorder."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas import attention_kernel as ak
+
+    took = []
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(ak, "flash_attention_pallas",
+                        lambda q, k, v, causal: took.append("kernel") or v)
+    monkeypatch.setattr(
+        pk, "flash_attention_sharded",
+        lambda q, k, v, causal, mesh: took.append(("sharded", mesh)) or v)
+    monkeypatch.setattr(pk, "_xla_attention",
+                        lambda q, k, v, **kw: took.append("xla") or v)
+    return pk, took
+
+
+def test_flash_dispatch_under_a_mesh_takes_the_sharded_launch(tpu_dispatch):
+    """Where GSPMD partitions the step the dispatcher no longer gives way:
+    at the four-chip cell's per-layer shape it hands the mesh it finds to
+    ``flash_attention_sharded`` and announces nothing."""
+    import warnings
+
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+
+    pk, took = tpu_dispatch
+    mesh = _dp_mp_mesh(dp=2, mp=2)
+    x = jax.ShapeDtypeStruct((4, 2048, 32, 128), jnp.bfloat16)
+    attn = lambda q: pk.flash_attention(q, q, q, is_causal=True)  # noqa: E731
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", pk.KernelFallbackWarning)
+        jax.eval_shape(attn, x)
+        with use_mesh(mesh):
+            jax.eval_shape(attn, x)
+    assert took == ["kernel", ("sharded", mesh)]
+
+
+@pytest.mark.parametrize("case,reason", [
+    ("heads_not_divisible", r"3 heads do not divide over mp of 2"),
+    ("batch_not_divisible", r"batch 3 does not divide over mesh axes "
+                            r"\('dp',\) of 2"),
+    ("sep_axis", r"sep axis of 2: context parallelism has its own path"),
+    ("attn_mask", r"takes no attn_mask, dropout or scale"),
+    # spmd_pipeline at pp > 1 is manual over 'pp' alone: the announced
+    # fallback was kept, no second shard_map is nested
+    ("inside_partial_shard_map", r"GSPMD cannot partition a Mosaic kernel.*"
+                                 r"manual over \('pp',\) only"),
+])
+def test_flash_dispatch_under_a_mesh_refuses_aloud(tpu_dispatch, case,
+                                                   reason):
+    """What the sharded launch cannot serve takes ``_xla_attention`` as
+    before, with a warning that says which condition failed."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+
+    pk, took = tpu_dispatch
+    degrees, shape, mask = dict(dp=2, mp=2), (2, 1024, 4, 32), None
+    if case == "heads_not_divisible":
+        shape = (2, 1024, 3, 32)
+    elif case == "batch_not_divisible":
+        shape = (3, 1024, 4, 32)
+    elif case == "sep_axis":
+        degrees = dict(dp=2, sep=2)
+    elif case == "attn_mask":
+        mask = jnp.ones((1024, 1024), bool)
+    elif case == "inside_partial_shard_map":
+        degrees = dict(dp=2, pp=2)
+    mesh = _dp_mp_mesh(**degrees)
+    x = jnp.zeros(shape, jnp.bfloat16)
+
+    def attn(q):
+        return pk.flash_attention(q, q, q, attn_mask=mask, is_causal=True)
+
+    if case == "inside_partial_shard_map":
+        attn = jax.shard_map(attn, mesh=mesh, in_specs=P(), out_specs=P(),
+                             axis_names={"pp"}, check_vma=False)
+    with use_mesh(mesh), pytest.warns(pk.KernelFallbackWarning,
+                                      match=reason):
+        jax.eval_shape(attn, x)
+    assert took == ["xla"]
+
+
 class TestDecodeAttention:
     def _mk(self, B=3, NQ=4, NKV=2, D=16, S=64, seed=0):
         import jax.numpy as jnp

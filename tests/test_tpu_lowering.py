@@ -133,6 +133,46 @@ def test_gspmd_partitioning_is_seen_at_trace_time():
                     "all axes manual": False, "one axis manual": True}
 
 
+def test_flash_under_the_dp_mp_mesh_lowers_for_tpu(monkeypatch):
+    """The four-chip cell's attention, forward and backward, under the
+    mesh ``SpmdTrainStep`` enters: the dispatcher, on its kernel path,
+    must hand the TPU lowering something it takes.  "Mosaic kernels cannot
+    be automatically partitioned" is raised while lowering, so a CPU can
+    hold this guard: the kernel called bare under the same mesh is the
+    control that it still would be."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+    from paddle_tpu.distributed.fleet.topology import build_mesh
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas.attention_kernel import flash_attention_pallas
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    mesh = build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+    assert tuple(mesh.shape.values()) == (2, 1, 1, 2)
+    x = jax.ShapeDtypeStruct(
+        (4, 2048, 32, 128), jnp.bfloat16,
+        sharding=NamedSharding(mesh, P("dp", None, "mp", None)))
+
+    def grads(attn):
+        def loss(q, k, v):
+            return jnp.sum(attn(q, k, v).astype(jnp.float32))
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(x, x, x)
+
+    with use_mesh(mesh):
+        text = grads(lambda q, k, v: pk.flash_attention(
+            q, k, v, is_causal=True)).lower(
+                lowering_platforms=("tpu",)).as_text()
+        with pytest.raises(NotImplementedError,
+                           match="cannot be automatically partitioned"):
+            grads(lambda q, k, v: flash_attention_pallas(
+                q, k, v, True)).lower(lowering_platforms=("tpu",))
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert f'kernel_name = "{kernel}"' in text, kernel
+    assert text.count("tpu_custom_call") == 3
+
+
 @pytest.mark.slow
 def test_registry_compiles_for_v5e():
     """The real Mosaic compile, ahead of time, for a device this host
