@@ -366,7 +366,7 @@ def serve_phase(report, build_model, *, dtype, max_model_len, token_budget,
                      "custom call (the XLA fallback is a failure)",
                      not missing, f"buckets without it: {missing}")
     if tensor_parallel:
-        devs = eng._kc.sharding.device_set
+        devs = eng.kv_cache["k"].sharding.device_set
         report.check(phase, f"KV pool spans {tensor_parallel} devices",
                      len(devs) == tensor_parallel, f"{len(devs)} devices")
         _every_device_holds_bytes(report, phase, tensor_parallel)
@@ -517,7 +517,7 @@ def fleet_placement(report, build_model):
     model.eval()
     fleet = Fleet(model, replicas=4, dtype="bfloat16", max_model_len=256,
                   block_size=16, max_batch=2, token_budget=16)
-    where = [sorted(d.id for d in r.engine._kc.devices())
+    where = [sorted(d.id for d in r.engine.kv_cache["k"].devices())
              for r in fleet.replicas]
     print(f"  fleet: four replicas' KV pools on device ids {where}",
           flush=True)

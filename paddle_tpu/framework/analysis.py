@@ -481,9 +481,8 @@ def analyze_engine(engine, rules=None):
     if engine.mesh is not None and _want(rules, "S001"):
         findings += check_placements(engine.params, engine.mesh,
                                      label="params")
-        findings += check_placements(
-            {"kc": engine._kc, "vc": engine._vc}, engine.mesh,
-            label="kv_pool")
+        findings += check_placements(engine.kv_cache, engine.mesh,
+                                     label="kv_pool")
     return findings
 
 

@@ -13,8 +13,17 @@ The serving-shaped subsystem over the round-4 ragged decode kernel:
                   point (paged_ragged_attention) covers decode, verify,
                   and prefill-chunk rows via per-row descriptors
                   (Pallas ragged kernel on TPU, masked-XLA gather
-                  fallback everywhere); the per-phase entry points
-                  remain as thin wrappers over it
+                  fallback everywhere), against one layer's view of
+                  the cache
+- kv_cache:       the paged KV cache as ONE pytree ({k, v} and, under
+                  int8 KV, {k_scale, v_scale}): allocation (device,
+                  sharded, the simulator's numpy), token writes,
+                  copy-on-write, page gather/scatter, 'mp' specs
+- gpt2_block:     the ONE serving model — GPT-2's embed/block/head
+                  over the cache, its parameter names, the table of
+                  its four GEMMs (column-/row-parallel) every int8,
+                  LoRA and tensor-parallel spec derives from, the
+                  cache's shape, the draft model's construction
 - spec:           model-free speculative decoding — prompt-lookup
                   n-gram drafter (NgramDrafter / SpeculativeConfig);
                   the engine scores K drafts + 1 bonus position per
@@ -133,14 +142,8 @@ from .faults import (  # noqa: F401
     StepWatchdog,
 )
 from .paged_attention import (  # noqa: F401
-    paged_decode_attention,
-    paged_decode_attention_xla,
-    paged_prefill_attention,
-    paged_prefill_attention_xla,
     paged_ragged_attention,
     paged_ragged_attention_xla,
-    paged_verify_attention,
-    paged_verify_attention_xla,
 )
 from .scheduler import (  # noqa: F401
     PrefillChunk,
@@ -174,7 +177,4 @@ __all__ = ["BlockManager", "NoFreeBlocksError", "hash_block_tokens",
            "MigrationError", "PoolLostError", "RetryPolicy", "StepWatchdog",
            "EVENT_FIELDS", "SCHEMA_VERSION", "assert_wall_clock_free",
            "to_records",
-           "paged_decode_attention", "paged_decode_attention_xla",
-           "paged_prefill_attention", "paged_prefill_attention_xla",
-           "paged_ragged_attention", "paged_ragged_attention_xla",
-           "paged_verify_attention", "paged_verify_attention_xla"]
+           "paged_ragged_attention", "paged_ragged_attention_xla"]

@@ -1,11 +1,13 @@
 """LLMEngine — continuous-batching generation over a paged KV cache.
 
-The serving counterpart of incubate.nn.FusedMultiTransformer: the same
-stacked-params lax.scan decoder, but the KV cache is one paged pool
-([L, num_blocks, Nkv, block_size, D] per K and V — head-major, the
-layout the ragged Pallas kernel's page block needs) shared by every
-in-flight request, so the engine runs MANY requests of ragged lengths
-through exactly ONE family of jitted executables:
+The engine schedules, packs, owns the pools and launches; WHAT it
+launches — the served model's math, parameter names and tensor-parallel
+layout — is gpt2_block.py's, and the KV cache is kv_cache.py's one
+pytree ([L, num_blocks, Nkv, block_size, D] per K and V — head-major,
+the layout the ragged Pallas kernel's page block needs — plus scale
+pools under int8 KV) shared by every in-flight request, so the engine
+runs MANY requests of ragged lengths through exactly ONE family of
+jitted executables:
 
 - ragged: the step's query tokens — prefill chunks, plain decodes, and
   speculative-verify rows alike — packed back-to-back into one flat
@@ -68,7 +70,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ... import profiler
-from ...incubate.nn import _layernorm
 from .block_manager import BlockManager, NoFreeBlocksError
 from .faults import (
     FinishReason,
@@ -79,18 +80,11 @@ from .faults import (
     StepWatchdog,
 )
 from .interleave import interleave_point, interleave_wait, masked
+from .gpt2_block import GPT2ServingModel
+from .kv_cache import PAYLOAD_KEYS, copy_pages, gather_pages, scatter_pages
 from .kv_tier import KVTierConfig
 from .lora import AdapterManager, LoRAConfig, init_adapter_pools, lora_key
-from .paged_attention import (
-    paged_ragged_attention,
-    paged_ragged_attention_quant,
-)
-from .quant import (
-    ServingQuantConfig,
-    quantize_block_weights,
-    quantize_kv_rows,
-    scale_key,
-)
+from .quant import ServingQuantConfig, quantize_block_weights
 from .sampling import (
     StopStringWatcher,
     apply_logits_pipeline,
@@ -115,67 +109,6 @@ from .spec import (
     SpeculativeConfig,
     rollback_draft_reservation,
 )
-
-# Megatron-style sharding of the stacked block params over the 'mp' axis
-# (leading dim is the layer stack): qkv/fc_in split their OUTPUT columns,
-# proj/fc_out split their INPUT rows (the psum pair per layer); every
-# other leaf (layernorms, biases of row-parallel matmuls) is replicated.
-# Weight-only int8 scale leaves follow their weight's OUTPUT axis: the
-# column-parallel weights' per-column scales shard with the columns,
-# the row-parallel weights' scales stay replicated (their output axis
-# is unsharded), so shard-then-dequant equals dequant-then-shard.
-_TP_BLOCK_SPECS = {
-    "attn.qkv.weight": P(None, None, "mp"),
-    "attn.qkv.bias": P(None, "mp"),
-    "attn.proj.weight": P(None, "mp", None),
-    "mlp.fc_in.weight": P(None, None, "mp"),
-    "mlp.fc_in.bias": P(None, "mp"),
-    "mlp.fc_out.weight": P(None, "mp", None),
-    "attn.qkv.weight_scale": P(None, None, "mp"),
-    "mlp.fc_in.weight_scale": P(None, None, "mp"),
-    # multi-LoRA adapter pools ([L, A, in, r] / [L, A, r, out]) shard
-    # with their base GEMM's Megatron layout: a column-parallel
-    # target's B pool splits its output columns (A replicated), a
-    # row-parallel target's A pool splits its input rows (B
-    # replicated) — the per-device partial deltas ride the layer's
-    # existing psum, so tp>1 stays bit-identical to tp=1
-    "lora.attn.qkv.weight.A": P(),
-    "lora.attn.qkv.weight.B": P(None, None, None, "mp"),
-    "lora.attn.proj.weight.A": P(None, None, "mp", None),
-    "lora.attn.proj.weight.B": P(),
-    "lora.mlp.fc_in.weight.A": P(),
-    "lora.mlp.fc_in.weight.B": P(None, None, None, "mp"),
-    "lora.mlp.fc_out.weight.A": P(None, None, "mp", None),
-    "lora.mlp.fc_out.weight.B": P(),
-}
-
-
-def _params_bytes_per_chip(params, tp):
-    """Per-chip weight bytes under the Megatron layout: block leaves
-    whose _TP_BLOCK_SPECS entry names 'mp' hold 1/tp of the global
-    tensor; everything else (embed/head/layernorms) is replicated."""
-    total = 0
-    for group, sub in params.items():
-        for key, w in sub.items():
-            nbytes = int(np.prod(w.shape)) * jnp.dtype(w.dtype).itemsize
-            spec = _TP_BLOCK_SPECS.get(key, P()) if group == "blocks" \
-                else P()
-            sharded = any(
-                "mp" in (part if isinstance(part, tuple) else (part,))
-                for part in tuple(spec))
-            total += nbytes // tp if sharded else nbytes
-    return total
-
-
-def _qkv_head_permutation(num_heads, head_dim, tp):
-    """Column permutation taking the fused qkv layout (3, NH, D) to
-    (tp, 3, NH/tp, D): a contiguous 1/tp column slice then holds the
-    q, k AND v projections of one head GROUP, so the plain 'mp' shard
-    of the last weight dim is exactly one device's heads."""
-    nhl = num_heads // tp
-    return np.arange(3 * num_heads * head_dim).reshape(
-        3, tp, nhl, head_dim).transpose(1, 0, 2, 3).reshape(-1)
-
 
 class RequestOutput:
     """One finished request: ids are plain python/numpy on the host.
@@ -313,14 +246,17 @@ class LLMEngine:
         self.record_step_gauges = bool(record_step_gauges)
         self.step_gauges = []
 
-        d = model.functional_decompose()
-        cfg = model.config
-        self.num_layers = d["num_layers"]
-        self.num_heads = cfg.num_attention_heads
-        self.head_dim = cfg.head_dim
-        self.hidden = cfg.hidden_size
-        self.eps = cfg.layer_norm_epsilon
-        self.vocab_size = int(cfg.vocab_size)  # noqa: H001 (config attr, not a tensor)
+        self.dtype = jnp.dtype(dtype) if dtype else jnp.float32
+        # what is served: the model's math, parameter names and 'mp'
+        # layout are the serving model's (gpt2_block.py); the engine
+        # keeps the sizes its scheduling and memory math read
+        sm = self.serving_model = GPT2ServingModel(model, self.dtype)
+        self.num_layers = sm.num_layers
+        self.num_heads = sm.num_heads
+        self.head_dim = sm.head_dim
+        self.hidden = sm.hidden
+        self.eps = sm.eps
+        self.vocab_size = sm.vocab_size
         # ids -> text, for stop-string matching (sampling.py); requests
         # carrying stop= are rejected up front when no detokenizer is
         # configured, so the failure is a loud add_request ValueError
@@ -331,11 +267,9 @@ class LLMEngine:
         self.detokenizer = detokenizer
         self.block_size = int(block_size)
         self.max_batch = int(max_batch)
-        self.max_model_len = int(min(max_model_len or  # noqa: H001 (static config int)
-                                     cfg.max_position_embeddings,
-                                     cfg.max_position_embeddings))
+        self.max_model_len = int(min(max_model_len or sm.max_positions,  # noqa: H001 (static config int)
+                                     sm.max_positions))
         self.max_pages = -(-self.max_model_len // self.block_size)
-        self.dtype = jnp.dtype(dtype) if dtype else jnp.float32
         # int8 serving (None | "int8" | dict | ServingQuantConfig |
         # QuantConfig): weight-only int8 GEMM and/or the int8 KV pool
         self.quant = ServingQuantConfig.resolve(quantize)
@@ -391,24 +325,19 @@ class LLMEngine:
                 f"tensor_parallel={tensor_parallel} disagrees with the "
                 f"mesh 'mp' extent {self.tp}")
         self.mesh = mesh if self.tp > 1 else None
-        if self.num_heads % self.tp:
-            raise ValueError(
-                f"num_attention_heads {self.num_heads} not divisible by "
-                f"tensor_parallel {self.tp} (head-axis sharding)")
 
         cast = (lambda x: jnp.asarray(x, self.dtype)
                 if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
                 else jnp.asarray(x))
-        params = jax.tree_util.tree_map(cast, d["params"])
+        params = jax.tree_util.tree_map(cast, sm.take_params())
         if self._w_quant:
             # int8 weight storage BEFORE the budget math below, so the
             # admissible-batch derivation prices 1 byte/param (+ the
-            # f32 per-output-channel scale leaves) for the four GEMMs
+            # f32 per-output-channel scale leaves) for the block GEMMs
             params = dict(params)
             params["blocks"] = quantize_block_weights(
-                dict(params["blocks"]))
+                params["blocks"], sm.GEMM_LEAVES)
         self._lora_mgr = None
-        self._qkv_perm = None
         if self.lora is not None:
             # adapter pools join the BLOCK leaves before the budget
             # math below, so adapter residency is priced into the
@@ -424,6 +353,12 @@ class LLMEngine:
                 params["blocks"], self.lora, self.dtype))
             self._lora_mgr = AdapterManager(self.lora,
                                             self._lora_shapes)
+        # the 'mp' layout (adapter pools start zero: nothing of theirs
+        # to lay out yet) and the cache the block computes on
+        params = sm.shard_params(params, self.tp)
+        self._param_specs = sm.param_specs(params)
+        self.kv_spec = sm.cache_spec(self.block_size, self._kv_quant,
+                                     self.mesh)
 
         # ---------------------------------------------- HBM budget --------
         # pages + weights bound max_batch (ROADMAP item 3): under a
@@ -432,17 +367,10 @@ class LLMEngine:
         # for THAT batch so the pool itself cannot overrun the budget.
         from ...framework.cost import derive_max_batch, parse_bytes
         self.memory_budget = parse_bytes(memory_budget)
-        weights_per_chip = _params_bytes_per_chip(params, self.tp)
-        # an int8 slot costs head_dim bytes of values plus one f32
-        # scale per (slot, head); full precision costs head_dim *
-        # itemsize.  Same count for K and V.
-        slot_bytes = (self.head_dim + 4 if self._kv_quant
-                      else self.head_dim * jnp.dtype(self.dtype).itemsize)
-        page_bytes = (2 * self.num_layers * self.block_size
-                      * (self.num_heads // self.tp) * slot_bytes)
+        weights_per_chip = sm.params_bytes_per_chip(params)
         # per-chip K+V bytes of one page — the migration cost model's
         # bytes-moved unit (global payload = page_bytes * tp)
-        self.page_bytes = int(page_bytes)
+        page_bytes = self.page_bytes = self.kv_spec.page_bytes(self.tp)
         if self.memory_budget is not None:
             seq_bytes = self.max_pages * page_bytes
             admissible = derive_max_batch(self.memory_budget,
@@ -505,14 +433,6 @@ class LLMEngine:
             if self.prefix_store is not None:
                 self.scheduler.prefix_fetch_hook = self._tier_prefix_fetch
                 self.block_manager.evict_hook = self._promote_evicted
-        cache_shape = (self.num_layers, self.num_blocks, self.num_heads,
-                       self.block_size, self.head_dim)
-        self._kv_dtype = jnp.int8 if self._kv_quant else self.dtype
-        # per-(layer, page, head, slot) dequant scales for the int8
-        # pool; head axis shards with the pool under TP
-        scale_shape = (self.num_layers, self.num_blocks,
-                       self.num_heads, self.block_size)
-
         self._requests = {}
         self._next_id = 0
         self.seed = 0 if seed is None else int(seed)
@@ -534,220 +454,69 @@ class LLMEngine:
                       "aborted": 0, "deadline_missed": 0, "shed": 0,
                       "retries": 0, "quarantined": 0, "step_faults": 0}
 
-        tp = self.tp
-        nh, hd, eps = self.num_heads, self.head_dim, self.eps
-        nb, bs = self.num_blocks, self.block_size
-        nh_l = nh // tp          # heads per shard (== nh when tp == 1)
-
-        if tp > 1:
-            inter = params["blocks"]["mlp.fc_in.weight"].shape[-1]
-            if inter % tp:
-                raise ValueError(
-                    f"intermediate_size {inter} not divisible by "
-                    f"tensor_parallel {tp}")
-            # regroup fused-qkv columns head-major so the contiguous 'mp'
-            # shard of the last dim is one device's (q, k, v) head group.
-            # Kept on self: adapter loads apply the SAME permutation to
-            # a qkv-target LoRA B half (its output columns are base qkv
-            # columns; the pools start zero, so nothing to permute now)
-            perm = _qkv_head_permutation(nh, hd, tp)
-            self._qkv_perm = perm
-            params = dict(params)
-            params["blocks"] = dict(params["blocks"])
-            params["blocks"]["attn.qkv.weight"] = \
-                params["blocks"]["attn.qkv.weight"][:, :, perm]
-            params["blocks"]["attn.qkv.bias"] = \
-                params["blocks"]["attn.qkv.bias"][:, perm]
-            if self._w_quant:
-                # per-output-channel scales ride their columns through
-                # the same head-major regrouping
-                qs = scale_key("attn.qkv.weight")
-                params["blocks"][qs] = params["blocks"][qs][:, :, perm]
-
-        # param/cache sharding layout (replicated pseudo-specs at tp == 1
-        # are never built — the single-device path skips device_put)
-        self._param_specs = {
-            "embed": {k: P() for k in params["embed"]},
-            "blocks": {k: _TP_BLOCK_SPECS.get(k, P())
-                       for k in params["blocks"]},
-            "head": {k: P() for k in params["head"]},
-        }
-        self._cache_spec = P(None, None, "mp", None, None)
-        self._scale_spec = P(None, None, "mp", None)
-        self._ks = self._vs = None
-        if tp > 1:
-            named = lambda spec: NamedSharding(self.mesh, spec)  # noqa: E731
+        # params and cache go to the device (sharded over the mesh
+        # under TP; the single-device path skips device_put)
+        self._param_shardings = None
+        if self.tp > 1:
             self._param_shardings = jax.tree_util.tree_map(
-                named, self._param_specs,
-                is_leaf=lambda x: isinstance(x, P))
-            self._cache_sharding = named(self._cache_spec)
-            self._rep = named(P())
-            self.params = jax.tree_util.tree_map(
+                lambda spec: NamedSharding(self.mesh, spec),
+                self._param_specs, is_leaf=lambda x: isinstance(x, P))
+            params = jax.tree_util.tree_map(
                 jax.device_put, params, self._param_shardings)
-            # build the pool SHARDED (never materialized on one device —
-            # the point of TP serving is a pool larger than one chip)
-            zeros = jax.jit(lambda: jnp.zeros(cache_shape,
-                                              self._kv_dtype),
-                            out_shardings=self._cache_sharding)
-            self._kc = zeros()
-            self._vc = zeros()
-            if self._kv_quant:
-                self._scale_sharding = named(self._scale_spec)
-                szeros = jax.jit(
-                    lambda: jnp.zeros(scale_shape, jnp.float32),
-                    out_shardings=self._scale_sharding)
-                self._ks = szeros()
-                self._vs = szeros()
-        else:
-            self.params = params
-            self._alloc_pools(cache_shape, scale_shape)
+        self.params = params
+        self.kv_cache = self._alloc_cache()
+        self._ragged = self._build_step()
 
-        def psum_mp(y):
-            """Row-parallel reduction; identity on the single-device path
-            (keeps the tp=1 graph bitwise identical to the pre-TP one)."""
-            return jax.lax.psum(y, "mp") if tp > 1 else y
+        # model-based drafting: draft params (leading target layers +
+        # identity blocks, see GPT2ServingModel.draft_params) and a
+        # second cache of the same spec ride the SAME executable family
+        # — zero extra compiles.  The draft gets its own BlockManager
+        # (prefix caching off — draft state is disposable) sized like
+        # the target's.
+        self._draft_params = None
+        self._draft_bm = None
+        if self.spec is not None and self.spec.uses_draft_model:
+            dl = min(int(self.spec.draft_layers), self.num_layers)
+            self._draft_params = sm.draft_params(
+                self.params, dl, self._param_shardings)
+            self._draft_cache = self._alloc_cache()
+            self._draft_bm = BlockManager(self.num_blocks, self.block_size,
+                                          enable_prefix_caching=False)
+            self.events.append((self._step_index, "draft_model_load", dl,
+                                self.num_blocks))
 
-        if self._w_quant:
-            act_dtype = self.dtype
+    def _alloc_cache(self):
+        """Allocate one KV cache of this engine's spec — the target's
+        pools and the draft model's alike.  The seam the discrete-event
+        simulator overrides (numpy pools)."""
+        return self.kv_spec.zeros(self.num_blocks)
 
-            def wmat(p_l, key):
-                # dequant fused into the GEMM operand load: XLA folds
-                # the convert+multiply into the weight stream, so the
-                # matmul runs in the activation dtype while HBM pays
-                # 1 byte/param (+ the per-column f32 scale row)
-                return (p_l[key].astype(act_dtype)
-                        * p_l[scale_key(key)].astype(act_dtype))
-        else:
-            def wmat(p_l, key):
-                return p_l[key]
+    def _build_step(self):
+        """Jit THE executable: one ragged token batch covers every
+        serving phase.  Traced once per token bucket; shared by the
+        target and the draft model (params are an operand)."""
+        serving = self.serving_model
 
-        lora_targets = self.lora.targets if self.lora is not None \
-            else ()
-
-        def lora_delta(p_l, key, x_t, slots_t):
-            """Batched per-token adapter delta for one target GEMM:
-            gather each token's [in, r] / [r, out] halves by its row's
-            adapter slot, then two rank-r einsums — ``(x @ A_g) @ B_g``
-            with the alpha/rank scale pre-folded into the stored B.
-            Slot 0 is all-zero, so base rows (and dead warmup rows)
-            contribute exact float zeros.  Under TP the halves carry
-            their base GEMM's sharding (_TP_BLOCK_SPECS): column
-            targets produce the local output shard directly, row
-            targets produce a partial summed by the caller's psum."""
-            a = p_l[lora_key(key, "A")][slots_t]      # [Tb, in, r]
-            b_ = p_l[lora_key(key, "B")][slots_t]     # [Tb, r, out]
-            h = jnp.einsum("ti,tir->tr", x_t, a)
-            return jnp.einsum("tr,tro->to", h, b_)
-
-        def attn_proj(p_l, x, slots_t=None):
-            """LN -> fused QKV, the FusedMultiTransformer block head.
-            Under TP the local qkv columns are this shard's head group
-            (see _qkv_head_permutation), so nh_l heads come out."""
-            hh = _layernorm(x, p_l["ln_1.weight"], p_l["ln_1.bias"], eps)
-            qkv = hh @ wmat(p_l, "attn.qkv.weight") \
-                + p_l["attn.qkv.bias"]
-            if slots_t is not None and "attn.qkv.weight" in lora_targets:
-                # column-parallel target: the (permuted) B columns
-                # shard like the base qkv columns, so the delta IS the
-                # local shard — added before the head reshape
-                qkv = qkv + lora_delta(p_l, "attn.qkv.weight",
-                                       hh[0], slots_t)[None]
-            b, t = x.shape[0], x.shape[1]
-            qkv = qkv.reshape(b, t, 3, nh_l, hd)
-            return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-
-        def mlp_residual(p_l, x, att_out, slots_t=None):
-            # row-parallel proj/fc_out: partial matmul + psum, bias added
-            # once AFTER the reduction (replicated).  A row-parallel
-            # LoRA delta is a PARTIAL too (A shards the input rows), so
-            # it joins the base partial INSIDE the psum — linearity
-            # keeps tp>1 bit-identical to tp=1
-            part = att_out @ wmat(p_l, "attn.proj.weight")
-            if slots_t is not None and \
-                    "attn.proj.weight" in lora_targets:
-                part = part + lora_delta(p_l, "attn.proj.weight",
-                                         att_out[0], slots_t)[None]
-            x = x + psum_mp(part) + p_l["attn.proj.bias"]
-            h2 = _layernorm(x, p_l["ln_2.weight"], p_l["ln_2.bias"], eps)
-            pre = h2 @ wmat(p_l, "mlp.fc_in.weight") \
-                + p_l["mlp.fc_in.bias"]
-            if slots_t is not None and \
-                    "mlp.fc_in.weight" in lora_targets:
-                pre = pre + lora_delta(p_l, "mlp.fc_in.weight",
-                                       h2[0], slots_t)[None]
-            ff = jax.nn.gelu(pre, approximate=True)
-            part = ff @ wmat(p_l, "mlp.fc_out.weight")
-            if slots_t is not None and \
-                    "mlp.fc_out.weight" in lora_targets:
-                part = part + lora_delta(p_l, "mlp.fc_out.weight",
-                                         ff[0], slots_t)[None]
-            return x + psum_mp(part) + p_l["mlp.fc_out.bias"]
-
-        def scatter_pages(cache, slots, values):
-            """Write [N, nh_l, hd] rows at absolute token slots; padded
-            rows carry an out-of-range slot and are dropped, not
-            written.  Under TP ``cache`` is the LOCAL pool shard and
-            ``values`` this shard's heads — slots are replicated, so
-            every shard writes the same pages of its own head slice.
-            ``cache`` is head-major [nb, nh_l, bs, hd]: token slot s
-            is row ``s % bs`` of every head of page ``s // bs`` (the
-            padding slot nb * bs lands on page nb, out of range)."""
-            return cache.at[slots // bs, :, slots % bs].set(
-                values.astype(cache.dtype), mode="drop")
-
-        def scatter_pages_quant(cache, scales, slots, values):
-            """Quantize-at-append: each written [nh_l, hd] token row
-            quantizes per head (absmax / 127) and lands as int8 values
-            plus one f32 scale per (slot, head).  Padding slots carry
-            the out-of-range id ``nb * bs`` — page ``nb`` — and both
-            scatters drop them."""
-            q, s = quantize_kv_rows(values)      # int8 [N,nh_l,hd], [N,nh_l]
-            page, off = slots // bs, slots % bs
-            return (cache.at[page, :, off].set(q, mode="drop"),
-                    scales.at[page, :, off].set(s, mode="drop"))
-
-        def head_logits(params, x):
-            x = _layernorm(x, params["head"]["weight"],
-                           params["head"]["bias"], eps)
-            w = params["embed"]["word_embeddings.weight"]
-            return x @ w.T.astype(self.dtype)
-
-        def copy_cow_pages(pool, cow_src, cow_dst):
-            """Copy-on-write page payloads for fork siblings diverging
-            off a shared partial tail: dst pages get src contents
-            BEFORE this step's token writes land.  Padding entries
-            carry dst == num_blocks (out of range) and drop.  Under TP
-            each shard copies its own head slice — indices ride
-            replicated, pools are local."""
-            return pool.at[:, cow_dst].set(pool[:, cow_src],
-                                           mode="drop")
-
-        def ragged_fn(params, ids, kc, vc, block_tables, positions,
-                      rows, row_start, row_qlen, row_pos0, cow_src,
-                      cow_dst, top_k, top_p, min_p, rep_pen, pres_pen,
-                      freq_pen, bias, counts, *lora_args):
-            """THE executable: one ragged token batch covers every
-            serving phase.  ids [Tb] — the step's query tokens packed
-            back-to-back and padded to the token bucket; positions [Tb]
-            is each token's absolute position (-1 for padding: page
-            writes drop, outputs are never read); rows [Tb] maps each
-            token to its block-table row.  row_start/row_qlen/row_pos0
+        def ragged_fn(params, ids, cache, block_tables, positions, rows,
+                      row_start, row_qlen, row_pos0, cow_src, cow_dst,
+                      top_k, top_p, min_p, rep_pen, pres_pen, freq_pen,
+                      bias, counts, *lora_args):
+            """ids [Tb] — the step's query tokens packed back-to-back
+            and padded to the token bucket; positions [Tb] is each
+            token's absolute position (-1 for padding: page writes
+            drop, outputs are never read); rows [Tb] maps each token
+            to its block-table row.  row_start/row_qlen/row_pos0
             [R = max_batch] are the per-row ragged descriptors the
             Pallas kernel consumes (see paged_attention.py for the
             dual-descriptor contract; R is FIXED, so only the token
-            axis buckets).
+            axis buckets).  ``cache`` is the KV cache pytree
+            (kv_cache.py), donated: the pools update in place.
 
             A decode row is one query token, a speculative-verify row
             is 1 + K draft tokens, a prefill chunk is a C-token slice —
-            identical causal semantics: after the per-layer scatter
-            (every query's K/V lands before attention reads), the token
-            at position p attends over pool positions 0..p through its
-            row's table.  Every per-element reduction (projections,
-            attention scores, softmax, layernorm, head) matches the
-            retired per-phase graphs', so outputs are bitwise the
-            chunk/decode/verify steps the old engine ran — the retired
-            decode/verify bodies' pre-scale dance (q times
-            ``scale * sqrt(hd)``, exactly 1.0) is dropped outright.
+            identical causal semantics: the token at position p
+            attends over pool positions 0..p through its row's table
+            (the serving model's ``forward``).
 
             The request-surface operands (sampling.py): ``cow_src`` /
             ``cow_dst`` [R] are fork COW page copies applied up front;
@@ -759,222 +528,45 @@ class LLMEngine:
             Neutral operand values are bitwise identities.
 
             A LoRA engine appends ONE operand: ``adapter_rows`` [R],
-            each row's resident adapter slot, gathered to per-token
-            slots through the same token→row map — the multi-tenant
-            batch costs one int32 vector, not an executable.
-            Returns (argmax [Tb], logits [Tb, V], kc, vc)."""
-            kc = copy_cow_pages(kc, cow_src, cow_dst)
-            vc = copy_cow_pages(vc, cow_src, cow_dst)
-            emb = params["embed"]
-            tb = ids.shape[0]
-            p_safe = jnp.maximum(positions, 0)
-            x = (emb["word_embeddings.weight"][ids]
-                 + emb["position_embeddings.weight"][p_safe])
-            x = x.astype(self.dtype)[None]           # [1, Tb, hidden]
-            slot = (block_tables[rows, p_safe // bs] * bs + p_safe % bs)
-            slots = jnp.where(positions >= 0, slot, nb * bs)
-            ctx = p_safe + jnp.where(positions >= 0, 1, 0)
-            slots_t = lora_args[0][rows] if lora_args else None
-
-            def layer(carry, xs):
-                x = carry
-                p_l, kc_l, vc_l = xs
-                q, k, v = attn_proj(p_l, x, slots_t)  # [1, Tb, nh_l, hd]
-                kc_l = scatter_pages(kc_l, slots, k[0])
-                vc_l = scatter_pages(vc_l, slots, v[0])
-                out = paged_ragged_attention(q[0], kc_l, vc_l,
-                                             block_tables, ctx, rows,
-                                             row_start, row_qlen,
-                                             row_pos0)
-                out = out.astype(x.dtype).reshape(1, tb, nh_l * hd)
-                return mlp_residual(p_l, x, out, slots_t), (kc_l, vc_l)
-
-            x, (kc, vc) = jax.lax.scan(layer, x,
-                                       (params["blocks"], kc, vc))
-            logits = head_logits(params, x[0])       # [Tb, V]
+            each row's resident adapter slot — the multi-tenant batch
+            costs one int32 vector, not an executable.
+            Returns (argmax [Tb], logits [Tb, V], cache)."""
+            cache = copy_pages(cache, cow_src, cow_dst)
+            logits, cache = serving.forward(
+                params, ids, positions, cache, block_tables, rows,
+                row_start, row_qlen, row_pos0, *lora_args)
             logits = apply_logits_pipeline(
                 logits, rows, top_k, top_p, min_p, rep_pen, pres_pen,
                 freq_pen, bias, counts)
-            return jnp.argmax(logits, -1), logits, kc, vc
+            return jnp.argmax(logits, -1), logits, cache
 
-        def ragged_fn_quant(params, ids, kc, vc, ks, vs, block_tables,
-                            positions, rows, row_start, row_qlen,
-                            row_pos0, cow_src, cow_dst, top_k, top_p,
-                            min_p, rep_pen, pres_pen, freq_pen, bias,
-                            counts, *lora_args):
-            """ragged_fn with the int8 KV pool: identical packing and
-            causal semantics, but the per-layer scatter quantizes each
-            written token row (int8 values + per-head f32 scale) and
-            attention dequantizes at read time INSIDE the kernel —
-            no bf16 copy of the pool is ever materialized.  COW copies
-            cover the scale pools too (int8 payload + scales move
-            together).  Returns
-            (argmax [Tb], logits [Tb, V], kc, vc, ks, vs)."""
-            kc = copy_cow_pages(kc, cow_src, cow_dst)
-            vc = copy_cow_pages(vc, cow_src, cow_dst)
-            ks = copy_cow_pages(ks, cow_src, cow_dst)
-            vs = copy_cow_pages(vs, cow_src, cow_dst)
-            emb = params["embed"]
-            tb = ids.shape[0]
-            p_safe = jnp.maximum(positions, 0)
-            x = (emb["word_embeddings.weight"][ids]
-                 + emb["position_embeddings.weight"][p_safe])
-            x = x.astype(self.dtype)[None]           # [1, Tb, hidden]
-            slot = (block_tables[rows, p_safe // bs] * bs + p_safe % bs)
-            slots = jnp.where(positions >= 0, slot, nb * bs)
-            ctx = p_safe + jnp.where(positions >= 0, 1, 0)
-            slots_t = lora_args[0][rows] if lora_args else None
-
-            def layer(carry, xs):
-                x = carry
-                p_l, kc_l, vc_l, ks_l, vs_l = xs
-                q, k, v = attn_proj(p_l, x, slots_t)  # [1, Tb, nh_l, hd]
-                kc_l, ks_l = scatter_pages_quant(kc_l, ks_l, slots,
-                                                 k[0])
-                vc_l, vs_l = scatter_pages_quant(vc_l, vs_l, slots,
-                                                 v[0])
-                out = paged_ragged_attention_quant(
-                    q[0], kc_l, vc_l, ks_l, vs_l, block_tables, ctx,
-                    rows, row_start, row_qlen, row_pos0)
-                out = out.astype(x.dtype).reshape(1, tb, nh_l * hd)
-                return mlp_residual(p_l, x, out, slots_t), (kc_l, vc_l,
-                                                            ks_l, vs_l)
-
-            x, (kc, vc, ks, vs) = jax.lax.scan(
-                layer, x, (params["blocks"], kc, vc, ks, vs))
-            logits = head_logits(params, x[0])       # [Tb, V]
-            logits = apply_logits_pipeline(
-                logits, rows, top_k, top_p, min_p, rep_pen, pres_pen,
-                freq_pen, bias, counts)
-            return jnp.argmax(logits, -1), logits, kc, vc, ks, vs
-
-        step_fn = ragged_fn_quant if self._kv_quant else ragged_fn
-        n_pools = 4 if self._kv_quant else 2
-
-        if tp > 1:
-            # shard_map: each device runs the SAME program on its local
-            # head slice — local qkv/fc columns, local pool shard, the
-            # two explicit psums per layer; block tables / ids /
-            # positions / activations ride replicated.  The jit wrapper
-            # pins NamedShardings so host operands are placed without
-            # resharding and the donated pool keeps its layout.
-            c_spec, rep = self._cache_spec, P()
-            if self._kv_quant:
-                pool_specs = (c_spec, c_spec,
-                              self._scale_spec, self._scale_spec)
-                pool_shards = (self._cache_sharding,
-                               self._cache_sharding,
-                               self._scale_sharding,
-                               self._scale_sharding)
-            else:
-                pool_specs = (c_spec, c_spec)
-                pool_shards = (self._cache_sharding,
-                               self._cache_sharding)
-
-            def tp_wrap(fn, n_extra):
-                extra = (rep,) * n_extra
-                sm = jax.shard_map(
-                    fn, mesh=self.mesh,
-                    in_specs=(self._param_specs, rep) + pool_specs
-                    + extra,
-                    out_specs=(rep, rep) + pool_specs,
-                    check_vma=False)
-                rsh = self._rep
-                return jax.jit(
-                    sm,
-                    in_shardings=(self._param_shardings, rsh)
-                    + pool_shards + (rsh,) * n_extra,
-                    out_shardings=(rsh, rsh) + pool_shards,
-                    donate_argnums=tuple(range(2, 2 + n_pools)))
-
-            # tables, positions, rows, row_start, row_qlen, row_pos0,
-            # cow_src, cow_dst, then the eight sampling operands (six
-            # per-row knob vectors + the two [Tb, V] channels) — all
-            # replicated, like every host-packed descriptor.  A LoRA
-            # engine appends one more replicated operand: the per-row
-            # adapter_rows slot vector.
-            self._ragged = tp_wrap(
-                step_fn, 17 if self.lora is not None else 16)
-        else:
-            self._ragged = jax.jit(
-                step_fn, donate_argnums=tuple(range(2, 2 + n_pools)))
-
-        # model-based drafting: draft params (leading target layers +
-        # zero-padded identity blocks) and a second set of paged pools
-        # that ride the SAME executable family — zero extra compiles
-        self._draft_params = None
-        self._draft_bm = None
-        if self.spec is not None and self.spec.uses_draft_model:
-            self._init_draft_model(cache_shape, scale_shape)
-
-    def _init_draft_model(self, cache_shape, scale_shape):
-        """Build the draft model's params and paged pools.
-
-        The draft model is the target's first ``draft_layers``
-        transformer blocks followed by ZERO blocks: with every leaf of
-        a padded layer zeroed (weights AND biases), qkv is zero, so
-        attention reads all-zero values, projection and MLP emit zero,
-        and the residual stream passes through bit-exactly — the
-        padded layers are exact identities.  Leaf shapes match the
-        target's, so the draft rides the already-jitted ragged
-        executable (params are its first operand) with ZERO new
-        compiles; embed/head dicts are shared by reference.  The draft
-        gets its own K/V pools and BlockManager (prefix caching off —
-        draft state is disposable) sized like the target's."""
-        dl = min(int(self.spec.draft_layers), self.num_layers)
-        blocks = {}
-        for k, w in self.params["blocks"].items():
-            if dl >= self.num_layers or k.startswith("lora."):
-                # full-depth draft degenerates to the target; LoRA
-                # pools are reused as-is — draft rows always pass
-                # slot 0, the all-zero base identity, so stale pool
-                # contents can never leak into a draft
-                blocks[k] = w
-                continue
-            pad = jnp.concatenate([w[:dl], jnp.zeros_like(w[dl:])],
-                                  axis=0)
-            if self.tp > 1:
-                pad = jax.device_put(
-                    pad, self._param_shardings["blocks"][k])
-            blocks[k] = pad
-        self._draft_params = {"embed": self.params["embed"],
-                              "blocks": blocks,
-                              "head": self.params["head"]}
-        if self.tp > 1:
-            zeros = jax.jit(lambda: jnp.zeros(cache_shape,
-                                              self._kv_dtype),
-                            out_shardings=self._cache_sharding)
-            self._draft_kc = zeros()
-            self._draft_vc = zeros()
-            if self._kv_quant:
-                szeros = jax.jit(
-                    lambda: jnp.zeros(scale_shape, jnp.float32),
-                    out_shardings=self._scale_sharding)
-                self._draft_ks = szeros()
-                self._draft_vs = szeros()
-        else:
-            self._draft_kc = jnp.zeros(cache_shape, self._kv_dtype)
-            self._draft_vc = jnp.zeros(cache_shape, self._kv_dtype)
-            if self._kv_quant:
-                self._draft_ks = jnp.zeros(scale_shape, jnp.float32)
-                self._draft_vs = jnp.zeros(scale_shape, jnp.float32)
-        self._draft_bm = BlockManager(self.num_blocks, self.block_size,
-                                      enable_prefix_caching=False)
-        self.events.append((self._step_index, "draft_model_load", dl,
-                            self.num_blocks))
-
-    def _draft_pools(self):
-        if self._kv_quant:
-            return (self._draft_kc, self._draft_vc,
-                    self._draft_ks, self._draft_vs)
-        return (self._draft_kc, self._draft_vc)
-
-    def _set_draft_pools(self, pools):
-        if self._kv_quant:
-            (self._draft_kc, self._draft_vc,
-             self._draft_ks, self._draft_vs) = pools
-        else:
-            self._draft_kc, self._draft_vc = pools
+        if self.tp == 1:
+            return jax.jit(ragged_fn, donate_argnums=(2,))
+        # shard_map: each device runs the SAME program on its local
+        # head slice — local qkv/fc columns, local pool shard, the
+        # two explicit psums per layer; block tables / ids /
+        # positions / activations ride replicated.  The jit wrapper
+        # pins NamedShardings so host operands are placed without
+        # resharding and the donated cache keeps its layout.
+        # Replicated operands after the cache: tables, positions, rows,
+        # row_start, row_qlen, row_pos0, cow_src, cow_dst, then the
+        # eight sampling operands (six per-row knob vectors + the two
+        # [Tb, V] channels), like every host-packed descriptor — and,
+        # on a LoRA engine, the per-row adapter_rows slot vector.
+        n_extra = 17 if self.lora is not None else 16
+        rep, rsh = P(), NamedSharding(self.mesh, P())
+        spec = self.kv_spec
+        mapped = jax.shard_map(
+            ragged_fn, mesh=self.mesh,
+            in_specs=(self._param_specs, rep, spec.specs)
+            + (rep,) * n_extra,
+            out_specs=(rep, rep, spec.specs), check_vma=False)
+        return jax.jit(
+            mapped,
+            in_shardings=(self._param_shardings, rsh, spec.shardings)
+            + (rsh,) * n_extra,
+            out_shardings=(rsh, rsh, spec.shardings),
+            donate_argnums=(2,))
 
     # ----------------------------------------------------------- requests --
     def add_request(self, prompt_ids, max_new_tokens=16, eos_token_id=None,
@@ -1245,11 +837,11 @@ class LLMEngine:
         pools — framework.analysis traces these without executing (or
         donating) anything, so a lint pass never touches cache state."""
         sds = jax.ShapeDtypeStruct
-        pools = tuple(sds(c.shape, c.dtype) for c in self._pools())
+        cache = self.kv_spec.abstract(self.num_blocks)
         i32, f32 = jnp.int32, jnp.float32
         rmax, v = self.max_batch, self.vocab_size
         for kind, tb in self._bucket_grid():
-            args = (self.params, sds((tb,), i32)) + pools + (
+            args = (self.params, sds((tb,), i32), cache,
                     sds((rmax, self.max_pages), i32), sds((tb,), i32),
                     sds((tb,), i32), sds((rmax,), i32),
                     sds((rmax,), i32), sds((rmax,), i32),
@@ -1265,30 +857,6 @@ class LLMEngine:
                 # the single extra LoRA operand: per-row adapter slots
                 args = args + (sds((rmax,), i32),)
             yield kind, tb, self._ragged, args
-
-    def _alloc_pools(self, cache_shape, scale_shape):
-        """Allocate the single-device K/V pools.  The seam the
-        discrete-event simulator overrides: SimEngine allocates numpy
-        pools instead, so 100+ virtual replicas cost host RAM (lazily,
-        pages untouched until written) and zero device memory."""
-        self._kc = jnp.zeros(cache_shape, self._kv_dtype)
-        self._vc = jnp.zeros(cache_shape, self._kv_dtype)
-        if self._kv_quant:
-            self._ks = jnp.zeros(scale_shape, jnp.float32)
-            self._vs = jnp.zeros(scale_shape, jnp.float32)
-
-    def _pools(self):
-        """The donated pool operands of one ragged launch, in call
-        order: (kc, vc) or, under int8 KV, (kc, vc, ks, vs)."""
-        if self._kv_quant:
-            return (self._kc, self._vc, self._ks, self._vs)
-        return (self._kc, self._vc)
-
-    def _set_pools(self, pools):
-        if self._kv_quant:
-            self._kc, self._vc, self._ks, self._vs = pools
-        else:
-            self._kc, self._vc = pools
 
     def memory_model(self, memory_budget=None):
         """Static per-chip HBM breakdown — weight bytes (sharding-
@@ -1343,12 +911,11 @@ class LLMEngine:
                 # slot 0 (the all-zero base identity) for every dead
                 # warmup row — the LoRA operand's bitwise-neutral value
                 lora_ops = (zr,) if self.lora is not None else ()
-                out = self._ragged(
-                    self.params, ids, *self._pools(), tables,
+                _, _, self.kv_cache = self._ragged(
+                    self.params, ids, self.kv_cache, tables,
                     positions, rows, zr, zr, zr, zr, cow_dst,
                     *knobs, chan, chan, *lora_ops)
-                self._set_pools(out[2:])
-                jax.block_until_ready(self._kc)  # noqa: H001 (warmup timing sync — never on the serving step path)
+                jax.block_until_ready(self.kv_cache)  # noqa: H001 (warmup timing sync — never on the serving step path)
                 timings[f"{kind}[{tb}]"] = \
                     (time.perf_counter() - t0) * 1e3
         from ...framework.analysis import CompileWatcher
@@ -1489,8 +1056,8 @@ class LLMEngine:
                                                 t0)
 
     def _pool_lost(self):
-        deleted = getattr(self._kc, "is_deleted", None)
-        return bool(deleted and self._kc.is_deleted())
+        deleted = getattr(self.kv_cache["k"], "is_deleted", None)
+        return bool(deleted and deleted())
 
     def _quarantine(self, kind, reqs, exc):
         """A launch failed after every retry: quarantine the
@@ -1590,9 +1157,8 @@ class LLMEngine:
         base qkv weight was loaded in."""
         interleave_point("adapter-load")
         blocks = dict(self.params["blocks"])
-        for key, (a_h, b_h) in weights.items():
-            if key == "attn.qkv.weight" and self._qkv_perm is not None:
-                b_h = b_h[:, :, self._qkv_perm]
+        for key, halves in weights.items():
+            a_h, b_h = self.serving_model.adapter_layout(key, *halves)
             for side, val in (("A", a_h), ("B", b_h)):
                 lk = lora_key(key, side)
                 host = np.array(jax.device_get(blocks[lk]))  # noqa: H001 (host-staged slot swap by design)
@@ -1605,139 +1171,22 @@ class LLMEngine:
         self.params = {**self.params, "blocks": blocks}
 
     # ------------------------------------------------------------ migration --
-    _scatter_jit = None
-    _gather_jit = None
+    def _gather_payload(self, block_ids):
+        """The pages ``block_ids`` of every cache leaf as host numpy
+        arrays, under the migration / host-tier payload's names
+        (``k_pages``, ``v_pages`` and, for an int8 pool, ``k_scales``,
+        ``v_scales`` — kv_cache.PAYLOAD_KEYS): the pytree is flattened
+        to that format here, at the edge that crosses replicas."""
+        pages = gather_pages(self.kv_cache, block_ids)
+        return {PAYLOAD_KEYS[n]: p for n, p in pages.items()}
 
-    @classmethod
-    def _pool_kernels(cls):
-        """Jitted page-row scatter/gather for the migration and KV-tier
-        paths (cached per input shape — the page-bucket padding below
-        bounds the shape count).  The scatter DONATES its pool
-        argument, so XLA aliases the output buffer onto the input: an
-        in-place row write instead of the eager functional whole-pool
-        copy, and one dispatch instead of the eager op machinery that
-        dominated tier traffic.  Callers immediately reassign the
-        returned array over the donated one, so nothing observes the
-        consumed buffer."""
-        if cls._scatter_jit is None:
-            cls._scatter_jit = jax.jit(
-                lambda pool, idx, vals: pool.at[:, idx].set(vals),
-                donate_argnums=(0,))
-            cls._gather_jit = jax.jit(
-                lambda pool, idx: jnp.take(pool, idx, axis=1))
-        return cls._scatter_jit, cls._gather_jit
-
-    @staticmethod
-    def _page_bucket(n):
-        """Power-of-two bucket for a page-index batch.  The eager
-        gather/scatter updates below compile one executable per input
-        SHAPE; the KV tier turns page movement into a hot path with a
-        different chain length every call, so unpadded indices would
-        recompile per length (a silent compile storm outside the
-        watched ragged family).  Padding to buckets bounds that at
-        log2(max_pages) executables per op."""
-        return 1 << max(0, int(n - 1).bit_length())  # noqa: H001 (host page count, not a tensor)
-
-    @staticmethod
-    def _gather_pool(pool, idx):
-        """Select page rows [:, idx] of one KV pool as a host numpy
-        array, slicing ON DEVICE first so the host transfer carries
-        only the selected pages — O(len(idx)) bytes, not the whole
-        pool.  Eager ``jnp.take`` compiles outside the ragged family
-        (nothing for an armed CompileWatcher to see) and leaves the
-        committed pool buffer untouched, so donation is unaffected.
-        The index is padded to a power-of-two bucket (repeating the
-        last page — sliced back off before returning) so repeated
-        tier traffic reuses a handful of executables.  Plain-numpy
-        pools (the simulator's) skip the device round trip."""
-        if isinstance(pool, np.ndarray):
-            return pool[:, idx]
-        n = len(idx)
-        b = LLMEngine._page_bucket(n)
-        if b > n:
-            idx = np.concatenate(
-                [idx, np.full(b - n, idx[-1], dtype=np.int64)])
-        _, gather = LLMEngine._pool_kernels()
-        sel = gather(pool, np.asarray(idx, np.int32))  # noqa: H001 (host block-id list, not a tensor)
-        return np.asarray(jax.device_get(sel))[:, :n]  # noqa: H001 (migration pulls only the selected pages by design)
-
-    def _gather_pages(self, block_ids):
-        """Host-staged page gather: device-side row select of the
-        pools, then a transfer of JUST those rows.  Returns (k_pages,
-        v_pages) as [L, n, Nkv, bs, D] numpy arrays in ``block_ids``
-        order — the GLOBAL view even when the pools are head-sharded
-        (jax assembles addressable shards)."""
-        idx = np.asarray(block_ids, np.int64)  # noqa: H001 (host block-id list, not a tensor)
-        return (self._gather_pool(self._kc, idx),
-                self._gather_pool(self._vc, idx))
-
-    def _gather_scale_pages(self, block_ids):
-        """Scale-pool counterpart of :meth:`_gather_pages` for the int8
-        KV pool: [L, n, Nkv, bs] numpy arrays in ``block_ids`` order."""
-        idx = np.asarray(block_ids, np.int64)  # noqa: H001 (host block-id list, not a tensor)
-        return (self._gather_pool(self._ks, idx),
-                self._gather_pool(self._vs, idx))
-
-    def _scatter_pages(self, block_ids, k_pages, v_pages):
-        """Host-staged page scatter: upload the migrated pages and
-        write them into their destination pool rows ON DEVICE
-        (``.at[idx].set`` — an eager functional update outside the
-        ragged family), re-sharded under TP.  Transfer cost is the
-        migrated pages, not the pool.  The rebuilt arrays are ordinary
-        committed buffers — the next step's jitted call donates them
-        exactly like the ones they replace, so migration composes with
-        donation and compiles nothing in the watched family.  Indices
-        and payload are padded to a power-of-two bucket by repeating
-        the LAST page — duplicate indices carrying identical values
-        make the extra writes idempotent — so tier traffic reuses a
-        handful of executables instead of recompiling per chain
-        length."""
-        idxa, k_pages, v_pages = self._pad_scatter(
-            block_ids, k_pages, v_pages)
-        idx = np.asarray(idxa, np.int32)  # noqa: H001 (host block-id list, not a tensor)
-        scatter, _ = self._pool_kernels()
-        kc = scatter(self._kc, idx,
-                     np.asarray(k_pages, self._kc.dtype))  # noqa: H001 (host page payload upload by design)
-        vc = scatter(self._vc, idx,
-                     np.asarray(v_pages, self._vc.dtype))  # noqa: H001 (host page payload upload by design)
-        if self.tp > 1:
-            kc = jax.device_put(kc, self._cache_sharding)
-            vc = jax.device_put(vc, self._cache_sharding)
-        self._kc, self._vc = kc, vc
-
-    @staticmethod
-    def _pad_scatter(block_ids, k_pages, v_pages):
-        """Pad a scatter's index list and page payloads to the
-        power-of-two bucket (see :meth:`_page_bucket`) by repeating
-        the last page."""
-        idx = np.asarray(block_ids, np.int64)  # noqa: H001 (host block-id list, not a tensor)
-        n = len(idx)
-        b = LLMEngine._page_bucket(n)
-        if b > n:
-            idx = np.concatenate([idx, np.full(b - n, idx[-1],
-                                               dtype=np.int64)])
-            k_pages = np.concatenate(
-                [k_pages, np.repeat(k_pages[:, -1:], b - n, axis=1)],
-                axis=1)
-            v_pages = np.concatenate(
-                [v_pages, np.repeat(v_pages[:, -1:], b - n, axis=1)],
-                axis=1)
-        return idx, k_pages, v_pages
-
-    def _scatter_scale_pages(self, block_ids, k_scales, v_scales):
-        """Scale-pool counterpart of :meth:`_scatter_pages`."""
-        idxa, k_scales, v_scales = self._pad_scatter(
-            block_ids, k_scales, v_scales)
-        idx = np.asarray(idxa, np.int32)  # noqa: H001 (host block-id list, not a tensor)
-        scatter, _ = self._pool_kernels()
-        ks = scatter(self._ks, idx,
-                     np.asarray(k_scales, self._ks.dtype))  # noqa: H001 (host page payload upload by design)
-        vs = scatter(self._vs, idx,
-                     np.asarray(v_scales, self._vs.dtype))  # noqa: H001 (host page payload upload by design)
-        if self.tp > 1:
-            ks = jax.device_put(ks, self._scale_sharding)
-            vs = jax.device_put(vs, self._scale_sharding)
-        self._ks, self._vs = ks, vs
+    def _scatter_payload(self, block_ids, payload, lo=0, hi=None):
+        """Write pages ``[lo:hi]`` of a payload (same names as
+        :meth:`_gather_payload`) into pool rows ``block_ids``."""
+        pages = {n: payload[PAYLOAD_KEYS[n]][:, lo:hi]
+                 for n in self.kv_cache}
+        self.kv_cache = scatter_pages(
+            self.kv_cache, block_ids, pages, self.kv_spec.shardings)
 
     def export_request(self, request_id):
         """Serialize one RUNNING request for migration to a peer
@@ -1755,15 +1204,10 @@ class LLMEngine:
                 f"sequences with resident pages export (waiting/"
                 f"preempted ones requeue from scratch instead)")
         seq = self.block_manager.export_seq(request_id)
-        k, v = self._gather_pages(seq["block_ids"])
+        payload = self._gather_payload(seq["block_ids"])
         self.events.append((self._step_index, "export", request_id,
                             len(seq["block_ids"])))
-        state = {"request": req, "seq": seq, "k_pages": k, "v_pages": v}
-        if self._kv_quant:
-            ks, vs = self._gather_scale_pages(seq["block_ids"])
-            state["k_scales"] = ks
-            state["v_scales"] = vs
-        return state
+        return {"request": req, "seq": seq, **payload}
 
     def import_request(self, req, seq, k_pages, v_pages,
                        fault_hook=None, k_scales=None, v_scales=None):
@@ -1796,38 +1240,31 @@ class LLMEngine:
                 f"destination cannot serve adapter {aid!r} — "
                 f"{'no lora= configured' if self.lora is None else 'adapter not registered'}",
                 reason="adapter")
-        expect = (self.num_layers, len(seq["block_ids"]),
-                  self.num_heads, self.block_size, self.head_dim)
-        if tuple(k_pages.shape) != expect or \
-                tuple(v_pages.shape) != expect:
-            raise ValueError(
-                f"page payload {k_pages.shape} does not fit this pool "
-                f"(expected {expect}) — migration requires identically "
-                f"configured engines")
+        payload = {"k_pages": k_pages, "v_pages": v_pages,
+                   "k_scales": k_scales, "v_scales": v_scales}
         if self._kv_quant:
             if k_scales is None or v_scales is None:
                 raise ValueError(
                     "this engine's KV pool is int8 — the migration "
                     "payload must carry k_scales/v_scales (export from "
                     "an identically quantized engine)")
-            sexpect = (self.num_layers, len(seq["block_ids"]),
-                       self.num_heads, self.block_size)
-            if tuple(k_scales.shape) != sexpect or \
-                    tuple(v_scales.shape) != sexpect:
-                raise ValueError(
-                    f"scale payload {k_scales.shape} does not fit this "
-                    f"pool (expected {sexpect})")
         elif k_scales is not None or v_scales is not None:
             raise ValueError(
                 "scale payload offered to a full-precision pool — "
                 "migration requires identically configured engines")
+        for name in self.kv_cache:
+            pages = payload[PAYLOAD_KEYS[name]]
+            expect = self.kv_spec.shape(name, len(seq["block_ids"]))
+            if tuple(pages.shape) != expect:
+                raise ValueError(
+                    f"{PAYLOAD_KEYS[name]} payload {pages.shape} does "
+                    f"not fit this pool (expected {expect}) — migration "
+                    f"requires identically configured engines")
         table = self.block_manager.import_seq(rid, seq)
         try:
             if fault_hook is not None:
                 fault_hook()
-            self._scatter_pages(table, k_pages, v_pages)
-            if self._kv_quant:
-                self._scatter_scale_pages(table, k_scales, v_scales)
+            self._scatter_payload(table, payload)
             self.block_manager.register_imported(rid, seq["hashes"])
         except BaseException:
             # exact reclamation: every page import_seq allocated goes
@@ -1882,14 +1319,11 @@ class LLMEngine:
         try:
             if self.faults is not None:
                 self.faults.tier_fault("demote")
-            k, v = self._gather_pages(seq["block_ids"])
+            payload = self._gather_payload(seq["block_ids"])
         except InjectedFault:
             return
-        entry = {"seq": seq, "k_pages": k, "v_pages": v,
-                 "k_scales": None, "v_scales": None}
-        if self._kv_quant:
-            ks, vs = self._gather_scale_pages(seq["block_ids"])
-            entry["k_scales"], entry["v_scales"] = ks, vs
+        entry = {"seq": seq, "k_scales": None, "v_scales": None,
+                 **payload}
         for old in pool.put(rid, entry):
             # chains LRU-evicted to make room lose their swap-in, but
             # their FULL pages still promote into the prefix store
@@ -1942,14 +1376,7 @@ class LLMEngine:
             if self.faults is not None:
                 self.faults.tier_fault("promote")
             if moved:
-                self._scatter_pages(table[k:npay],
-                                    entry["k_pages"][:, k:npay],
-                                    entry["v_pages"][:, k:npay])
-                if self._kv_quant:
-                    self._scatter_scale_pages(
-                        table[k:npay],
-                        entry["k_scales"][:, k:npay],
-                        entry["v_scales"][:, k:npay])
+                self._scatter_payload(table[k:npay], entry, k, npay)
             bm.register_imported(rid, seq["hashes"])
         except BaseException:
             # exact reclamation: every page allocated above goes back
@@ -1986,15 +1413,9 @@ class LLMEngine:
         try:
             if self.faults is not None:
                 self.faults.tier_fault("promote")
-            kp = np.concatenate([e["k_pages"] for e in entries], axis=1)
-            vp = np.concatenate([e["v_pages"] for e in entries], axis=1)
-            self._scatter_pages(table[k:k + run], kp, vp)
-            if self._kv_quant:
-                ks = np.concatenate([e["k_scales"] for e in entries],
-                                    axis=1)
-                vs = np.concatenate([e["v_scales"] for e in entries],
-                                    axis=1)
-                self._scatter_scale_pages(table[k:k + run], ks, vs)
+            self._scatter_payload(table[k:k + run], {
+                key: np.concatenate([e[key] for e in entries], axis=1)
+                for key in (PAYLOAD_KEYS[n] for n in self.kv_cache)})
             for i, h in enumerate(hashes[k:k + run]):
                 bm.register_full_block(rid, k + i, h)
         except BaseException:
@@ -2013,13 +1434,9 @@ class LLMEngine:
         store = self.prefix_store
         if block_hash in store or self._pool_lost():
             return
-        k, v = self._gather_pages([blk])
-        entry = {"seq": {"block_ids": [blk]}, "k_pages": k, "v_pages": v,
-                 "k_scales": None, "v_scales": None}
-        if self._kv_quant:
-            ks, vs = self._gather_scale_pages([blk])
-            entry["k_scales"], entry["v_scales"] = ks, vs
-        store.put(block_hash, entry)
+        store.put(block_hash, {"seq": {"block_ids": [blk]},
+                               "k_scales": None, "v_scales": None,
+                               **self._gather_payload([blk])})
         self.last_tier_bytes += self.page_bytes * self.tp
         self.events.append((self._step_index, "promote", 1))
 
@@ -2300,8 +1717,7 @@ class LLMEngine:
                         self.block_manager.has_seq(row.table_id):
                     self.block_manager.free(row.table_id)
             return
-        nxt, logits = out[0], out[1]
-        self._set_pools(out[2:])
+        nxt, logits, self.kv_cache = out
         # async lookahead: the launch above is dispatched but NOT yet
         # synced — np.asarray(nxt) below is the blocking pull.  Plan
         # and pack step N+1 here so that host work runs entirely under
@@ -2688,17 +2104,16 @@ class LLMEngine:
         self.last_launches.append(("ragged", tb))
         self._launch_count += 1
         with profiler.RecordEvent("llm_engine::draft"):
-            out = self._ragged(
+            nxt, logits, self._draft_cache = self._ragged(
                 self._draft_params, jnp.asarray(ids),
-                *self._draft_pools(), jnp.asarray(tables),
+                self._draft_cache, jnp.asarray(tables),
                 jnp.asarray(positions), jnp.asarray(tok_rows),
                 jnp.asarray(row_start), jnp.asarray(row_qlen),
                 jnp.asarray(row_pos0), jnp.asarray(zr),
                 jnp.asarray(cow_dst),
                 *(jnp.asarray(k) for k in knobs), chan, chan,
                 *lora_ops)
-        self._set_draft_pools(out[2:])
-        return np.asarray(out[0]), out[1], starts  # noqa: H001 (draft argmax pull, one per draft launch by design)
+        return np.asarray(nxt), logits, starts  # noqa: H001 (draft argmax pull, one per draft launch by design)
 
     def _ragged_launch(self, rows, ids, tables, positions, tok_rows,
                        row_start, row_qlen, row_pos0, cow_src, cow_dst,
@@ -2720,7 +2135,7 @@ class LLMEngine:
                     else (jnp.asarray(adapter_rows),))
         with profiler.RecordEvent("llm_engine::ragged"):
             return self._ragged(
-                self.params, jnp.asarray(ids), *self._pools(),
+                self.params, jnp.asarray(ids), self.kv_cache,
                 jnp.asarray(tables), jnp.asarray(positions),
                 jnp.asarray(tok_rows), jnp.asarray(row_start),
                 jnp.asarray(row_qlen), jnp.asarray(row_pos0),

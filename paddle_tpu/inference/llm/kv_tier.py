@@ -22,7 +22,7 @@ two memory tiers a production fleet actually has:
   reads global store content instead of per-replica accident.
 
 Both tiers hold host numpy payloads gathered through the engine's
-host-staged migration path (``_gather_pages`` / ``_scatter_pages`` —
+host-staged migration path (``kv_cache.gather_pages`` / ``scatter_pages`` —
 no jit anywhere, so an armed CompileWatcher sees tier traffic as zero
 compiles), both are LRU-bounded in BYTES, and both expose
 ``check_invariants()`` so the engine-level page conservation check

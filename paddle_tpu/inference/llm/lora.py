@@ -21,11 +21,10 @@ so requests with ``adapter_id=None`` — and the dead warmup rows — run
 bit-identical to a LoRA-free engine.
 
 **Sharding.**  Adapters shard with the Megatron 'mp' layout of their
-base GEMM: a column-parallel target (``attn.qkv.weight``,
-``mlp.fc_in.weight``) splits its B pool on the output axis like the
-base columns (A replicated), and a row-parallel target
-(``attn.proj.weight``, ``mlp.fc_out.weight``) splits its A pool on the
-input axis like the base rows (B replicated) — the partial per-device
+base GEMM (``gpt2_block.GEMMS`` says which is which): a
+column-parallel target splits its B pool on the output axis like the
+base columns (A replicated), and a row-parallel target splits its A
+pool on the input axis like the base rows (B replicated) — the partial per-device
 deltas are summed by the SAME psum as the base partial products
 (psum(base + delta) == psum(base) + psum(delta)), so tp=2 stays
 bit-identical to tp=1.
@@ -39,25 +38,36 @@ churn runs.
 """
 # noqa-module: H001 (the manager is host bookkeeping by design — slot
 # assignment, LRU ticks, and registration shapes are python state; the
-# device-side einsum lives in engine.py's jitted closures)
+# device-side einsum lives in gpt2_block.py)
 
 import numpy as np
-
-from .quant import QUANT_BLOCK_LEAVES
 
 __all__ = [
     "LORA_TARGET_LEAVES", "LoRAConfig", "AdapterManager", "lora_key",
     "init_adapter_pools",
 ]
 
-# the four block GEMMs are the targetable leaves — the same set the
-# int8 weight path quantizes, because they are the O(hidden^2) matmuls
-LORA_TARGET_LEAVES = QUANT_BLOCK_LEAVES
+LORA_PREFIX = "lora."
+
+
+def _target_leaves():
+    """The targetable leaves: the served block's GEMM table
+    (gpt2_block.GEMMS) — the same set the int8 weight path quantizes,
+    because they are the O(hidden^2) matmuls.  Read on use, not at
+    import: gpt2_block.py imports this module."""
+    from .gpt2_block import GEMM_LEAVES
+    return GEMM_LEAVES
+
+
+def __getattr__(name):
+    if name == "LORA_TARGET_LEAVES":
+        return _target_leaves()
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def lora_key(key, side):
     """Pool-leaf name for a target GEMM: ``lora.<key>.A`` / ``.B``."""
-    return f"lora.{key}.{side}"
+    return f"{LORA_PREFIX}{key}.{side}"
 
 
 class LoRAConfig:
@@ -75,8 +85,7 @@ class LoRAConfig:
     ``tenant_quota`` bounds live same-adapter requests at admission —
     the per-tenant fairness knob on top of bounded admission/shed."""
 
-    def __init__(self, rank=8, max_adapters=8,
-                 targets=LORA_TARGET_LEAVES, alpha=None,
+    def __init__(self, rank=8, max_adapters=8, targets=None, alpha=None,
                  tenant_quota=None):
         self.rank = int(rank)
         if self.rank < 1:
@@ -86,15 +95,16 @@ class LoRAConfig:
             raise ValueError(
                 f"lora max_adapters must be >= 2 (slot 0 is the "
                 f"reserved base-model identity), got {max_adapters!r}")
-        targets = tuple(targets)
-        bad = [t for t in targets if t not in LORA_TARGET_LEAVES]
+        leaves = _target_leaves()
+        # None: every GEMM of the served block
+        targets = leaves if targets is None else tuple(targets)
+        bad = [t for t in targets if t not in leaves]
         if bad or not targets:
             raise ValueError(
                 f"lora targets must be a non-empty subset of "
-                f"{LORA_TARGET_LEAVES}, got {targets!r}")
+                f"{leaves}, got {targets!r}")
         # canonical order (the base-leaf order), deduped
-        self.targets = tuple(t for t in LORA_TARGET_LEAVES
-                             if t in targets)
+        self.targets = tuple(t for t in leaves if t in targets)
         self.alpha = float(alpha) if alpha is not None \
             else float(self.rank)
         self.tenant_quota = None if tenant_quota is None \

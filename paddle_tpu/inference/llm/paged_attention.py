@@ -1,13 +1,12 @@
-"""Paged attention — backend dispatch, now ONE ragged entry point.
+"""Paged attention — backend dispatch behind ONE ragged entry point.
 
 Every serving phase is the same computation: a query token at absolute
 position ``p`` attends over pool positions ``0..p`` through its row's
 block table.  A decode row is a one-token chunk, a speculative-verify
 row is a K+1-token chunk, a prefill chunk is a C-token chunk — so the
 engine launches a single ragged kernel over the step's packed query
-tokens, and the three legacy per-phase entry points below are kept as
-thin re-expressions over it (they remain the public API for tests and
-benchmarks).
+tokens, against one layer's view of the KV cache (kv_cache.py), whose
+leaves say whether the pool is int8.
 
 Two implementations with identical semantics:
 
@@ -37,12 +36,9 @@ decode/verify paths multiplied by ``scale * sqrt(head_dim)`` (exactly
 1.0 for every power-of-two head_dim the models here use) and the
 ragged path drops that identity dance outright.
 
-Speculative verify no longer materializes ``jnp.repeat(block_tables,
-K+1, axis=0)`` (a [B*(K+1), max_pages] int32 copy every verify step):
-under the ragged kernel a sequence's K+1 verify tokens share one row
-descriptor and ONE block-table row.  ``paged_verify_attention_xla`` —
-the fold-T-into-the-GQA-axis gather-once fallback — stays as the
-non-TPU path and keeps its regression test.
+A sequence's K+1 speculative-verify tokens share one row descriptor
+and ONE block-table row: no per-token table replication is
+materialized.
 
 Tensor parallelism: the ragged entry point is head-count generic and
 attention never mixes heads — the TP engine calls it UNCHANGED from
@@ -60,7 +56,6 @@ import jax.numpy as jnp
 
 from ...ops.pallas import _use_pallas, warn_fallback
 from ...ops.pallas import ragged_attention_kernel as _kernel
-from ...ops.pallas.decode_attention_kernel import decode_attention_xla
 
 
 def _take_kernel(q_shape, k_pages, interpret):
@@ -149,28 +144,17 @@ def paged_ragged_attention_quant_xla(q, k_pages, v_pages, k_scales,
         _gather_dense(v_pages, block_tables, v_scales)[rows], ctx)
 
 
-def paged_ragged_attention_quant(q, k_pages, v_pages, k_scales,
-                                 v_scales, block_tables, ctx, rows,
-                                 row_start, row_qlen, row_pos0,
-                                 interpret=False):
-    """Backend dispatch for the int8-KV ragged batch — the quantized
-    twin of :func:`paged_ragged_attention`, carrying both descriptor
-    forms plus the two page-scale pools.  TPU (or ``interpret=True``)
-    runs the in-kernel-dequant Pallas kernel; everywhere else the
-    dequant-gather masked-XLA fallback."""
-    if _take_kernel(q.shape, k_pages, interpret):
-        return _kernel.paged_ragged_attention_quant_pallas(
-            q, k_pages, v_pages, k_scales, v_scales, block_tables,
-            row_start, row_qlen, row_pos0, interpret=interpret)
-    return paged_ragged_attention_quant_xla(
-        q, k_pages, v_pages, k_scales, v_scales, block_tables, ctx,
-        rows)
-
-
-def paged_ragged_attention(q, k_pages, v_pages, block_tables, ctx, rows,
+def paged_ragged_attention(q, cache_l, block_tables, ctx, rows,
                            row_start, row_qlen, row_pos0,
                            interpret=False):
     """Ragged paged attention over T packed query tokens -> [T, Nq, D].
+
+    ``cache_l`` is one layer's view of the KV cache (kv_cache.py):
+    ``{"k", "v"}`` pools [NB, Nkv, bs, D] and, for an int8 pool,
+    ``{"k_scale", "v_scale"}`` [NB, Nkv, bs] — the leaves the view has
+    decide whether the read dequantizes (in-kernel on the Pallas path,
+    on the gathered pages on the XLA path; no float copy of the pool is
+    ever materialized).
 
     Carries BOTH descriptor forms because the two backends want
     different shapes of the same fact: the XLA fallback is per-token
@@ -182,139 +166,14 @@ def paged_ragged_attention(q, k_pages, v_pages, block_tables, ctx, rows,
     every row.  Tokens outside every row come back as exact zeros on
     both paths.
     """
+    k_pages, v_pages = cache_l["k"], cache_l["v"]
+    scales = ((cache_l["k_scale"], cache_l["v_scale"])
+              if "k_scale" in cache_l else ())
     if _take_kernel(q.shape, k_pages, interpret):
-        return _kernel.paged_ragged_attention_pallas(
-            q, k_pages, v_pages, block_tables, row_start, row_qlen,
-            row_pos0, interpret=interpret)
-    return paged_ragged_attention_xla(q, k_pages, v_pages, block_tables,
-                                      ctx, rows)
-
-
-def paged_decode_attention_xla(q, k_pages, v_pages, block_tables, lengths):
-    """Masked-XLA fallback: gather pages -> dense ragged decode."""
-    return decode_attention_xla(q, _gather_dense(k_pages, block_tables),
-                                _gather_dense(v_pages, block_tables),
-                                lengths)
-
-
-def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths,
-                           interpret=False):
-    """q [B, Nq, D] x paged pool -> [B, Nq, D]; lengths masks per row.
-
-    Re-expressed over the ragged kernel: batch row b is the one-token
-    row (start=b, qlen=1 if live, pos0=lengths[b]-1).  Batches smaller
-    than the ragged chunk width (B % 8 != 0) take the XLA fallback —
-    the engine never does, its token buckets floor at 8.
-    """
-    b = q.shape[0]
-    if _take_kernel(q.shape, k_pages, interpret):
-        return _kernel.paged_ragged_attention_pallas(
-            q, k_pages, v_pages, block_tables,
-            jnp.arange(b, dtype=jnp.int32),
-            (lengths > 0).astype(jnp.int32),
-            jnp.maximum(lengths - 1, 0).astype(jnp.int32),
-            interpret=interpret)
-    return paged_decode_attention_xla(q, k_pages, v_pages, block_tables,
-                                      lengths)
-
-
-def paged_verify_attention_xla(q, k_pages, v_pages, block_tables, ctx):
-    """Speculative verify: q [B, T, Nq, D] — T single-token query rows
-    per sequence at consecutive positions; ctx [B, T] is each row's
-    visible context length (0 for dead rows -> exact-zero output).
-
-    Gathers each sequence's pages ONCE and folds the T rows into the
-    GQA group axis before running decode_attention_xla's exact masked
-    chain (same einsum strings, f32 softmax, -1e30 mask).  Every
-    (query, key) score and every softmax row reduces over the same
-    elements in the same order as a [B*T] flattened single-token decode
-    batch, so the outputs are bitwise the decode steps the engine would
-    have run — at 1/T of the flattened form's gather traffic.
-    """
-    b, t, nq, d = q.shape
-    k = _gather_dense(k_pages, block_tables)
-    v = _gather_dense(v_pages, block_tables)
-    s_max, nkv = k.shape[1], k.shape[2]
-    g = nq // nkv
-    qg = (q.reshape(b, t, nkv, g, d).transpose(0, 2, 1, 3, 4)
-          .reshape(b, nkv, t * g, d))
-    lens_tg = jnp.repeat(ctx, g, axis=1)            # [B, T*G], t-major
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
-    logits = jnp.einsum("bngd,bsnd->bngs", qg.astype(jnp.float32),
-                        k.astype(jnp.float32)) * scale
-    mask = jnp.arange(s_max)[None, None, None, :] < \
-        lens_tg[:, None, :, None]
-    logits = jnp.where(mask, logits, jnp.float32(-1e30))
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bngs,bsnd->bngd", p, v.astype(jnp.float32))
-    out = jnp.where(lens_tg[:, None, :, None] > 0, out, 0.0)
-    return (out.reshape(b, nkv, t, g, d).transpose(0, 2, 1, 3, 4)
-            .reshape(b, t, nq, d).astype(q.dtype))
-
-
-def paged_verify_attention(q, k_pages, v_pages, block_tables, ctx,
-                           interpret=False):
-    """q [B, T, Nq, D] verify rows x paged pool -> [B, T, Nq, D]; ctx
-    masks per row.  Pallas path: sequence b becomes ragged row
-    (start=b*T, qlen=#live slots, pos0=ctx[b,0]-1) — the live slots of
-    a verify row are always a prefix — sharing ONE block-table row, so
-    no per-token table replication is materialized.  XLA path gathers
-    once per sequence via paged_verify_attention_xla."""
-    b, t, nq, d = q.shape
-    if _take_kernel((b * t, nq, d), k_pages, interpret):
-        flat = _kernel.paged_ragged_attention_pallas(
-            q.reshape(b * t, nq, d), k_pages, v_pages, block_tables,
-            jnp.arange(b, dtype=jnp.int32) * t,
-            (ctx > 0).astype(jnp.int32).sum(axis=1),
-            jnp.maximum(ctx[:, 0] - 1, 0).astype(jnp.int32),
-            interpret=interpret)
-        return flat.reshape(b, t, nq, d)
-    return paged_verify_attention_xla(q, k_pages, v_pages, block_tables,
-                                      ctx)
-
-
-def paged_prefill_attention_xla(q, k_pages, v_pages, block_table, start):
-    """Masked-XLA fallback for one sequence's prefill chunk.
-
-    q [1, C, Nq, D] at absolute positions start..start+C-1; the chunk's
-    own K/V must already be scattered into the pool.  Gathers the
-    sequence's pages and runs FusedMultiTransformer's masked prefill
-    chain bitwise (same einsum strings, f32 softmax, -1e30 mask), so a
-    chunked prefill reproduces the dense one-shot prefill exactly: the
-    extra gathered positions are masked to exact zeros and contribute
-    nothing.
-    """
-    _, c, n, d = q.shape
-    kk = _gather_dense(k_pages, block_table[None])
-    vv = _gather_dense(v_pages, block_table[None])
-    s_max, nkv = kk.shape[1], kk.shape[2]
-    if nkv != n:                                 # GQA: expand KV heads
-        kk = jnp.repeat(kk, n // nkv, axis=2)
-        vv = jnp.repeat(vv, n // nkv, axis=2)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, q.dtype))
-    logits = jnp.einsum("bqnd,bknd->bnqk", q, kk.astype(q.dtype)) * scale
-    q_pos = start + jnp.arange(c)[:, None]
-    k_pos = jnp.arange(s_max)[None, :]
-    mask = (k_pos <= q_pos)[None, None]
-    logits = jnp.where(mask, logits, jnp.asarray(-1e30, q.dtype))
-    att = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bnqk,bknd->bqnd", att, vv.astype(q.dtype))
-
-
-def paged_prefill_attention(q, k_pages, v_pages, block_table, start,
-                            interpret=False):
-    """q [1, C, Nq, D] chunk x paged pool -> [1, C, Nq, D] causal
-    attention over positions 0..start+C-1 through the block table.
-    Pallas path: the chunk is the single ragged row (start=0, qlen=C,
-    pos0=start); ``start`` may be traced."""
-    c = q.shape[1]
-    if _take_kernel(q.shape[1:], k_pages, interpret):
-        out = _kernel.paged_ragged_attention_pallas(
-            q[0], k_pages, v_pages, block_table[None],
-            jnp.zeros((1,), jnp.int32),
-            jnp.full((1,), c, jnp.int32),
-            jnp.reshape(jnp.asarray(start, jnp.int32), (1,)),
-            interpret=interpret)
-        return out[None]
-    return paged_prefill_attention_xla(q, k_pages, v_pages, block_table,
-                                       start)
+        kernel = (_kernel.paged_ragged_attention_quant_pallas if scales
+                  else _kernel.paged_ragged_attention_pallas)
+        return kernel(q, k_pages, v_pages, *scales, block_tables,
+                      row_start, row_qlen, row_pos0, interpret=interpret)
+    fallback = (paged_ragged_attention_quant_xla if scales
+                else paged_ragged_attention_xla)
+    return fallback(q, k_pages, v_pages, *scales, block_tables, ctx, rows)

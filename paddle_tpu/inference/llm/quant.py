@@ -2,9 +2,9 @@
 
 Two independent halves behind one ``LLMEngine(quantize=)`` knob:
 
-- **Weight-only int8 GEMM**: the four block matmul leaves of the
-  stacked params (``attn.qkv.weight``, ``attn.proj.weight``,
-  ``mlp.fc_in.weight``, ``mlp.fc_out.weight``) are stored int8 with
+- **Weight-only int8 GEMM**: the block matmul leaves of the stacked
+  params (the served block's GEMM table, ``gpt2_block.GEMMS``) are
+  stored int8 with
   per-output-channel float32 scales as sibling leaves
   (``<key>_scale``, shape [L, 1, out]).  Dequant happens at the GEMM
   operand load in the activation dtype — XLA fuses the
@@ -36,15 +36,16 @@ QMAX = 127.0
 # (q = 0 / eps = 0) without ever dividing by zero
 _EPS = 1e-9
 
-# the stacked-block weight leaves that quantize (the four GEMMs);
-# embeddings (tied to the head gather), layernorms, and biases stay in
-# the activation dtype — they are O(hidden) not O(hidden^2)
-QUANT_BLOCK_LEAVES = (
-    "attn.qkv.weight",
-    "attn.proj.weight",
-    "mlp.fc_in.weight",
-    "mlp.fc_out.weight",
-)
+def __getattr__(name):
+    """``QUANT_BLOCK_LEAVES``: the stacked-block weight leaves that
+    quantize — the served block's GEMM table (gpt2_block.GEMMS), the
+    O(hidden^2) matmuls; embeddings (tied to the head gather),
+    layernorms and biases stay in the activation dtype.  Read on use,
+    not at import: gpt2_block.py imports this module."""
+    if name == "QUANT_BLOCK_LEAVES":
+        from .gpt2_block import GEMM_LEAVES
+        return GEMM_LEAVES
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def scale_key(key):
@@ -112,9 +113,9 @@ def quantize_weight(w):
     return q, s.astype(jnp.float32)
 
 
-def quantize_block_weights(blocks, keys=QUANT_BLOCK_LEAVES):
-    """Quantize the GEMM leaves of the stacked block params in place
-    (a copy), adding ``<key>_scale`` sibling leaves."""
+def quantize_block_weights(blocks, keys):
+    """Quantize the GEMM leaves ``keys`` of the stacked block params in
+    place (a copy), adding ``<key>_scale`` sibling leaves."""
     out = dict(blocks)
     for key in keys:
         q, s = quantize_weight(out[key])

@@ -44,6 +44,7 @@ from ..framework.cost import StepTimeModel
 from ..inference.llm.engine import LLMEngine
 from ..inference.llm.events import to_records
 from ..inference.llm.fleet import Fleet
+from ..inference.llm.kv_cache import copy_pages
 from .clock import VirtualClock
 
 __all__ = [
@@ -114,12 +115,12 @@ class ReplayOracle:
 class SimEngine(LLMEngine):
     """LLMEngine with the device replaced by a token oracle.
 
-    Exactly the two device seams are overridden — ``_alloc_pools``
+    Exactly the two device seams are overridden — ``_alloc_cache``
     (numpy pools: zero device memory, host pages untouched until a
-    migration writes them) and ``_ragged_launch`` (the oracle fills
-    the argmax vector; nothing compiles or executes) — plus the
-    host-staged migration scatter (in-place numpy writes, so the pools
-    stay numpy) and ``warmup()`` (nothing to compile).  Everything
+    migration writes them; kv_cache.py's page movement writes numpy
+    pools in place, so they stay numpy) and ``_ragged_launch`` (the
+    oracle fills the argmax vector; nothing compiles or executes) —
+    plus ``warmup()`` (nothing to compile).  Everything
     else, from the scheduler to retry/quarantine to page bookkeeping,
     is the real engine's code, which is what makes sim decisions
     trustworthy.
@@ -138,12 +139,8 @@ class SimEngine(LLMEngine):
         self.oracle = oracle if oracle is not None else SyntheticOracle()
         super().__init__(model, **kwargs)
 
-    def _alloc_pools(self, cache_shape, scale_shape):
-        self._kc = np.zeros(cache_shape, self._kv_dtype)
-        self._vc = np.zeros(cache_shape, self._kv_dtype)
-        if self._kv_quant:
-            self._ks = np.zeros(scale_shape, np.float32)
-            self._vs = np.zeros(scale_shape, np.float32)
+    def _alloc_cache(self):
+        return self.kv_spec.zeros(self.num_blocks, xp=np)
 
     def add_request(self, prompt_ids, max_new_tokens=16,
                     eos_token_id=None, temperature=0.0, request_id=None,
@@ -171,15 +168,7 @@ class SimEngine(LLMEngine):
         # fork COW data copies land in numpy (dst == num_blocks is the
         # dropped padding slot, same contract as the device executable)
         if cow_dst is not None:
-            live = np.asarray(cow_dst) < self.num_blocks
-            if live.any():
-                src = np.asarray(cow_src)[live]
-                dst = np.asarray(cow_dst)[live]
-                self._kc[:, dst] = self._kc[:, src]
-                self._vc[:, dst] = self._vc[:, src]
-                if self._kv_quant:
-                    self._ks[:, dst] = self._ks[:, src]
-                    self._vs[:, dst] = self._vs[:, src]
+            copy_pages(self.kv_cache, cow_src, cow_dst)
         # the oracle's argmax: for the query at absolute position p the
         # model predicts the true token at p + 1 — identical indexing
         # to the real executable's shifted argmax
@@ -192,17 +181,7 @@ class SimEngine(LLMEngine):
                 nxt[s0 + j] = self.oracle.next_token(req, p0 + j)
         # logits=None is safe: greedy-only traffic never reaches
         # _fetch_sampling_rows' logit indexing
-        return (nxt, None) + tuple(self._pools())
-
-    def _scatter_pages(self, block_ids, k_pages, v_pages):
-        idx = np.asarray(block_ids, np.int64)
-        self._kc[:, idx] = k_pages
-        self._vc[:, idx] = v_pages
-
-    def _scatter_scale_pages(self, block_ids, k_scales, v_scales):
-        idx = np.asarray(block_ids, np.int64)
-        self._ks[:, idx] = k_scales
-        self._vs[:, idx] = v_scales
+        return nxt, None, self.kv_cache
 
     def warmup(self):
         """Nothing compiles in simulation; Fleet.restart_replica and

@@ -568,7 +568,8 @@ class TestStepIsolation:
         eng.add_request([1, 2, 3], max_new_tokens=4)
         eng.step()                                 # prefill fine
         # simulate the donated pool having been consumed by the failure
-        eng._kc = types.SimpleNamespace(is_deleted=lambda: True)
+        eng.kv_cache = {"k": types.SimpleNamespace(
+            is_deleted=lambda: True)}
         with pytest.raises(PoolLostError, match="donated"):
             eng.step()
 

@@ -275,8 +275,8 @@ class TestFailover:
         fleet.step()                       # both replicas mid-flight
         # simulate replica 1's donated K/V pool having been consumed:
         # its next launch fails and step() surfaces PoolLostError
-        fleet.replicas[1].engine._kc = types.SimpleNamespace(
-            is_deleted=lambda: True)
+        fleet.replicas[1].engine.kv_cache = {"k": types.SimpleNamespace(
+            is_deleted=lambda: True)}
         with warnings.catch_warnings():
             warnings.simplefilter("ignore", RuntimeWarning)
             outs = _drive(fleet)

@@ -361,7 +361,7 @@ class TestHostSyncPipeline:
             "    def _launch_packed(self, rows):\n"
             "        out = self._ragged(rows)\n"
             "        jax.block_until_ready(out)\n"     # the bug
-            "        host = jax.device_get(self._kc)\n"  # and again
+            "        host = jax.device_get(self.kv_cache)\n"  # and again
             "        return host\n")
         fs = A.check_host_sync([str(bad)])
         cats = [f.category for f in fs]
@@ -374,9 +374,9 @@ class TestHostSyncPipeline:
             "import jax\n"
             "class Eng:\n"
             "    def warmup(self):\n"
-            "        jax.block_until_ready(self._kc)"
+            "        jax.block_until_ready(self.kv_cache)"
             "  # noqa: H001 (warmup timing)\n"
-            "        jax.device_get(self._kc)\n")       # still a bug
+            "        jax.device_get(self.kv_cache)\n")       # still a bug
         fs = A.check_host_sync([str(ok)])
         assert [f.category for f in fs] == ["explicit-sync"]
         assert fs[0].where.endswith(":5")

@@ -289,14 +289,14 @@ class TestPagedAttention:
     def test_xla_gather_matches_dense_ragged(self):
         import jax.numpy as jnp
 
-        from paddle_tpu.inference.llm import paged_decode_attention_xla
         from paddle_tpu.ops.pallas.decode_attention_kernel import (
             decode_attention_xla,
         )
+        from ragged_rows import rows_attention
 
         q, kp, vp, bt, lens = self._inputs()
-        out = paged_decode_attention_xla(*map(jnp.asarray,
-                                              (q, kp, vp, bt, lens)))
+        out = rows_attention(*map(jnp.asarray, (
+            q[:, None], kp, vp, bt, lens[:, None])))[:, 0]
         b, pages = bt.shape
         nkv, bs, d = kp.shape[1:]
         k = kp[bt].transpose(0, 1, 3, 2, 4).reshape(b, pages * bs, nkv, d)
@@ -308,11 +308,11 @@ class TestPagedAttention:
     def test_pallas_kernel_interpret_matches_xla(self):
         import jax.numpy as jnp
 
-        from paddle_tpu.inference.llm import paged_decode_attention_xla
         from paddle_tpu.ops.pallas.ragged_attention_kernel import (
             paged_ragged_attention_pallas,
             supports,
         )
+        from ragged_rows import rows_attention
 
         b, pages, bs, nq, nkv, d = 8, 4, 8, 4, 2, 16
         assert supports(bs, d, nq, nkv, b)
@@ -334,8 +334,8 @@ class TestPagedAttention:
             jnp.asarray((lens > 0).astype(np.int32)),
             jnp.asarray(np.maximum(lens - 1, 0)),
             interpret=True)
-        ref = paged_decode_attention_xla(*map(jnp.asarray,
-                                              (q, kp, vp, bt, lens)))
+        ref = rows_attention(*map(jnp.asarray, (
+            q[:, None], kp, vp, bt, lens[:, None])))[:, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -462,6 +462,144 @@ class TestEngineTokenExact:
             eng.add_request([], max_new_tokens=4)
         with pytest.raises(ValueError, match="cannot hold"):
             LLMEngine(m, block_size=8, num_blocks=2, max_model_len=32)
+
+
+# ---------------------------------------------------------------------------
+class TestServingModelSeam:
+    """The engine schedules, packs, owns the pools and launches; what
+    it launches is the serving model's (inference/llm/gpt2_block.py)
+    and what it launches ON is one cache pytree (kv_cache.py)."""
+
+    def test_engine_serves_a_block_that_is_not_gpt2s(self, monkeypatch):
+        """A test-local serving model with a PARALLEL-residual block
+        (attention and MLP both read the block input through ln_1/ln_2
+        and add to the same residual; GPT-2's MLP reads the
+        post-attention stream) goes through the unchanged engine —
+        chunked prefill, decode, the paged pool — and is token-exact
+        against the dense recomputation below.  Fails wherever the
+        engine computes the block itself."""
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.incubate.nn import _layernorm
+        from paddle_tpu.inference.llm import LLMEngine
+        from paddle_tpu.inference.llm import engine as engine_mod
+        from paddle_tpu.inference.llm.gpt2_block import GPT2ServingModel
+        from paddle_tpu.inference.llm.kv_cache import write_tokens
+        from paddle_tpu.inference.llm.paged_attention import (
+            paged_ragged_attention,
+        )
+
+        def mlp(p_l, x, eps):
+            h = _layernorm(x, p_l["ln_2.weight"], p_l["ln_2.bias"], eps)
+            ff = jax.nn.gelu(h @ p_l["mlp.fc_in.weight"]
+                             + p_l["mlp.fc_in.bias"], approximate=True)
+            return ff @ p_l["mlp.fc_out.weight"] + p_l["mlp.fc_out.bias"]
+
+        class ParallelResidual(GPT2ServingModel):
+            def block(self, p_l, x, cache_l, slots, paged, slots_t=None):
+                q, k, v = self.attn_proj(p_l, x)
+                cache_l = write_tokens(cache_l, slots, k[0], v[0])
+                att = paged_ragged_attention(q[0], cache_l, *paged)
+                att = att.astype(x.dtype).reshape(1, x.shape[1], -1)
+                return (x + att @ p_l["attn.proj.weight"]
+                        + p_l["attn.proj.bias"]
+                        + mlp(p_l, x, self.eps)), cache_l
+
+        def dense_next_token(params, cfg, ids):
+            """Full recomputation over the whole sequence, no cache."""
+            t, nh, hd = len(ids), cfg.num_attention_heads, cfg.head_dim
+            eps = cfg.layer_norm_epsilon
+            emb = params["embed"]
+            x = (emb["word_embeddings.weight"][jnp.asarray(ids)]
+                 + emb["position_embeddings.weight"][jnp.arange(t)])[None]
+            mask = jnp.tril(jnp.ones((t, t), bool))
+            blocks = params["blocks"]
+            for li in range(blocks["ln_1.weight"].shape[0]):
+                p_l = {k: w[li] for k, w in blocks.items()}
+                h = _layernorm(x, p_l["ln_1.weight"], p_l["ln_1.bias"],
+                               eps)
+                qkv = (h @ p_l["attn.qkv.weight"]
+                       + p_l["attn.qkv.bias"]).reshape(1, t, 3, nh, hd)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                sc = jnp.einsum("btnd,bsnd->bnts", q, k) / np.sqrt(hd)
+                sc = jnp.where(mask[None, None], sc, -1e30)
+                att = jnp.einsum("bnts,bsnd->btnd",
+                                 jax.nn.softmax(sc, -1), v)
+                x = (x + att.reshape(1, t, nh * hd)
+                     @ p_l["attn.proj.weight"] + p_l["attn.proj.bias"]
+                     + mlp(p_l, x, eps))
+            x = _layernorm(x, params["head"]["weight"],
+                           params["head"]["bias"], eps)
+            logits = x[0, -1] @ emb["word_embeddings.weight"].T
+            return int(jnp.argmax(logits))
+
+        monkeypatch.setattr(engine_mod, "GPT2ServingModel",
+                            ParallelResidual)
+        m = _make_model()
+        # at gpt_tiny's init scale the blocks barely move the residual
+        # stream and any block yields the same argmax; make them count
+        for name, w in m.named_parameters():
+            if ".h." in name and w.ndim == 2:
+                w.set_value(w.numpy() * 12.0)
+        rng = np.random.RandomState(5)
+        prompts = [rng.randint(0, 128, (n,)).astype(np.int32)
+                   for n in (3, 11, 20)]
+        # token_budget 8 < the longest prompt: its prefill is chunked,
+        # so later chunks and every decode read earlier K/V back
+        # through the pool
+        eng = LLMEngine(m, block_size=8, max_batch=4, max_model_len=64,
+                        token_budget=8)
+        outs = eng.generate(prompts, max_new_tokens=6)
+        params = jax.device_get(eng.params)
+        for p, out in zip(prompts, outs):
+            ids = list(p)
+            for _ in range(6):
+                ids.append(dense_next_token(params, m.config, ids))
+            np.testing.assert_array_equal(out, np.asarray(ids))
+        # and the block really is not GPT-2's: the stock engine
+        # generates something else from the same weights
+        stock = LLMEngine.__new__(LLMEngine)
+        monkeypatch.undo()
+        stock.__init__(m, block_size=8, max_batch=4, max_model_len=64,
+                       token_budget=8)
+        assert any(not np.array_equal(a, b) for a, b in
+                   zip(outs, stock.generate(prompts, max_new_tokens=6)))
+        assert eng.block_manager.num_free_blocks == eng.num_blocks
+
+    @pytest.mark.parametrize("kv", ["float", "int8"])
+    def test_one_step_body_over_either_cache(self, kv):
+        """Float and int8 KV go through the SAME step body: the cache
+        is one pytree operand whose leaves decide whether a page write
+        quantizes and attention dequantizes.  Same executable census,
+        zero compiles after warmup, and greedy output equal to the
+        dense teacher-forced argmax under the cache's own numerics
+        (quality.engine_logits applies the int8 round trip)."""
+        from paddle_tpu.inference.llm import LLMEngine
+        from paddle_tpu.inference.llm.quality import engine_logits
+
+        quantize = None if kv == "float" else {"weights": False,
+                                               "kv_cache": True}
+        leaves = {"k", "v"} if kv == "float" else \
+            {"k", "v", "k_scale", "v_scale"}
+        eng = LLMEngine(_make_model(), block_size=8, max_batch=4,
+                        max_model_len=64, token_budget=16,
+                        quantize=quantize)
+        assert set(eng.kv_cache) == leaves
+        for _kind, _tb, fn, args in eng.executable_grid():
+            assert fn is eng._ragged and set(args[2]) == leaves
+        watcher = eng.warmup()
+        assert eng._ragged._cache_size() == 2       # buckets 8, 16
+        rng = np.random.RandomState(6)
+        prompts = [rng.randint(0, 128, (n,)).astype(np.int32)
+                   for n in (4, 9, 21)]
+        outs = eng.generate(prompts, max_new_tokens=6)
+        watcher.assert_no_new_compiles()
+        for p, out in zip(prompts, outs):
+            dense = np.argmax(engine_logits(eng, out[:-1]), -1)
+            np.testing.assert_array_equal(out[len(p):],
+                                          dense[len(p) - 1:])
+        assert eng.block_manager.num_free_blocks == eng.num_blocks
 
 
 # ---------------------------------------------------------------------------
@@ -667,8 +805,8 @@ class TestPrefixCaching:
         chan = jnp.zeros((12, eng.vocab_size), jnp.float32)
         with pytest.raises(RecompileError, match="ragged") as ei:
             with compile_watcher(eng._ragged, labels=("ragged",)):
-                _, _, eng._kc, eng._vc = eng._ragged(
-                    eng.params, ids, eng._kc, eng._vc, tables,
+                _, _, eng.kv_cache = eng._ragged(
+                    eng.params, ids, eng.kv_cache, tables,
                     positions, rows, zr, zr, zr, zr, cow_dst,
                     *knobs, chan, chan)
         # the report names the offending cache KEY, not just a count —
@@ -754,7 +892,8 @@ class TestTensorParallel:
         tp = LLMEngine(m, block_size=8, max_batch=2, max_model_len=64,
                        tensor_parallel=4)
         # pool: [L, NB, Nkv/mp, bs, D] per shard — axis 2 carries 'mp'
-        assert tp._kc.sharding.spec == P(None, None, "mp", None, None)
+        assert tp.kv_cache["k"].sharding.spec == \
+            P(None, None, "mp", None, None)
         qkv = tp.params["blocks"]["attn.qkv.weight"]
         assert qkv.sharding.spec == P(None, None, "mp")
         proj = tp.params["blocks"]["attn.proj.weight"]
@@ -985,17 +1124,13 @@ class TestSpeculative:
         assert eng.spec_stats()["accepted_tokens"] > 0
 
     def test_verify_attention_matches_flattened_decode(self):
-        """paged_verify_attention_xla folds T query rows into the GQA
-        group axis to gather each sequence's pages once — its output
-        must be BITWISE the [B*T] flattened single-token decode batch
+        """A verify row's T query tokens share ONE block-table row on
+        the ragged XLA path — its output must be BITWISE the [B*T]
+        flattened single-token decode batch over replicated tables
         (that identity is what makes spec greedy == plain greedy)."""
         import jax.numpy as jnp
 
-        from paddle_tpu.inference.llm import (
-            paged_decode_attention_xla,
-            paged_verify_attention,
-            paged_verify_attention_xla,
-        )
+        from ragged_rows import rows_attention
 
         rng = np.random.RandomState(3)
         b, t, nq, nkv, d, bs, pages = 2, 3, 4, 2, 16, 8, 4
@@ -1009,16 +1144,14 @@ class TestSpeculative:
             .reshape(b, pages), jnp.int32)
         ctx = jnp.asarray([[5, 6, 7], [0, 1, 2]], jnp.int32)
 
-        out = paged_verify_attention_xla(q, kp, vp, tables, ctx)
-        flat = paged_decode_attention_xla(
-            q.reshape(b * t, nq, d), kp, vp,
-            jnp.repeat(tables, t, axis=0), ctx.reshape(b * t))
+        out = rows_attention(q, kp, vp, tables, ctx)
+        flat = rows_attention(
+            q.reshape(b * t, 1, nq, d), kp, vp,
+            jnp.repeat(tables, t, axis=0), ctx.reshape(b * t, 1))
         np.testing.assert_array_equal(
             np.asarray(out), np.asarray(flat).reshape(b, t, nq, d))
-        # the dispatcher's Pallas path (interpret mode on CPU) flattens
-        # into the decode kernel — same semantics
-        pal = paged_verify_attention(q, kp, vp, tables, ctx,
-                                     interpret=True)
+        # the dispatcher (interpret mode on CPU) — same semantics
+        pal = rows_attention(q, kp, vp, tables, ctx, interpret=True)
         np.testing.assert_allclose(np.asarray(pal), np.asarray(out),
                                    rtol=2e-5, atol=2e-5)
 

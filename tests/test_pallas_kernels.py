@@ -564,15 +564,13 @@ class TestRaggedAttention:
         got = self._check(q, kp, vp, bt, row_start, row_qlen, row_pos0)
         np.testing.assert_allclose(got[0], 0.0)      # empty slot
 
-        # the legacy public entry point must route through the ragged
-        # kernel and agree with ITS fallback bitwise-meaningfully too
-        from paddle_tpu.inference.llm.paged_attention import (
-            paged_decode_attention,
-            paged_decode_attention_xla,
-        )
-        via = paged_decode_attention(q, kp, vp, bt, jnp.asarray(lens),
-                                     interpret=True)
-        ref = paged_decode_attention_xla(q, kp, vp, bt, jnp.asarray(lens))
+        # the public entry point (decode batch as B rows of one slot)
+        # must route through the ragged kernel and agree with ITS
+        # fallback bitwise-meaningfully too
+        from ragged_rows import rows_attention
+        via = rows_attention(q[:, None], kp, vp, bt, lens[:, None],
+                             interpret=True)
+        ref = rows_attention(q[:, None], kp, vp, bt, lens[:, None])
         np.testing.assert_allclose(np.asarray(via), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -588,16 +586,16 @@ class TestRaggedAttention:
         for start in (0, 5):     # page-aligned and straddling starts
             self._check(q, kp, vp, bt, [0], [C], [start])
 
-        # the legacy chunk entry point (traced start included) rides
-        # the ragged kernel and must match its own XLA fallback
-        from paddle_tpu.inference.llm.paged_attention import (
-            paged_prefill_attention,
-            paged_prefill_attention_xla,
-        )
-        f = jax.jit(lambda s: paged_prefill_attention(
-            q[None], kp, vp, bt[0], s, interpret=True))
+        # the public entry point (one chunk row, traced start
+        # included) rides the ragged kernel and must match its own XLA
+        # fallback
+        from ragged_rows import rows_attention
+        f = jax.jit(lambda s: rows_attention(
+            q[None], kp, vp, bt, (s + 1 + jnp.arange(C))[None],
+            interpret=True))
         got = f(jnp.asarray(5, jnp.int32))
-        ref = paged_prefill_attention_xla(q[None], kp, vp, bt[0], 5)
+        ref = rows_attention(q[None], kp, vp, bt,
+                             (6 + jnp.arange(C))[None])
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
@@ -607,10 +605,7 @@ class TestRaggedAttention:
         path materialized jnp.repeat(block_tables, K+1, axis=0)), and
         per-token causality masks the later drafts already scattered
         into the pool."""
-        from paddle_tpu.inference.llm.paged_attention import (
-            paged_verify_attention,
-            paged_verify_attention_xla,
-        )
+        from ragged_rows import rows_attention
 
         NB, BS, NQ, NKV, D = 6, 8, 4, 2, 16
         B, TV = 4, 4                       # B*TV = 16 flat tokens
@@ -625,8 +620,8 @@ class TestRaggedAttention:
         ctx[1, :2] = 13 + np.arange(2)
         ctx[3, :3] = 5 + np.arange(3)
         ctx = jnp.asarray(ctx)
-        got = paged_verify_attention(q, kp, vp, bt, ctx, interpret=True)
-        ref = paged_verify_attention_xla(q, kp, vp, bt, ctx)
+        got = rows_attention(q, kp, vp, bt, ctx, interpret=True)
+        ref = rows_attention(q, kp, vp, bt, ctx)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
         np.testing.assert_allclose(np.asarray(got)[2], 0.0)  # dead row
@@ -791,10 +786,11 @@ class TestRaggedAttentionQuant:
                                    atol=2e-5, rtol=2e-5)
 
     def test_dispatcher_interpret_route(self):
-        """``paged_ragged_attention_quant`` with interpret=True takes
-        the Pallas route on CPU and agrees with its fallback."""
+        """``paged_ragged_attention`` over an int8 cache view (scale
+        leaves present) with interpret=True takes the quant Pallas
+        route on CPU and agrees with its fallback."""
         from paddle_tpu.inference.llm.paged_attention import (
-            paged_ragged_attention_quant,
+            paged_ragged_attention,
             paged_ragged_attention_quant_xla,
         )
 
@@ -808,9 +804,9 @@ class TestRaggedAttentionQuant:
         row_pos0 = np.asarray([3, 0, 9, 7, 1, 15, 4, 11], np.int32)
         ctx, rows = self._token_descriptors(
             T, row_start, row_qlen, row_pos0)
-        got = paged_ragged_attention_quant(
-            q, kq, vq, ks, vs, bt, ctx, rows,
-            jnp.asarray(row_start), jnp.asarray(row_qlen),
+        got = paged_ragged_attention(
+            q, {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}, bt,
+            ctx, rows, jnp.asarray(row_start), jnp.asarray(row_qlen),
             jnp.asarray(row_pos0), interpret=True)
         ref = paged_ragged_attention_quant_xla(q, kq, vq, ks, vs, bt,
                                                ctx, rows)

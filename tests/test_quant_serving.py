@@ -126,10 +126,11 @@ class TestQuantEngine:
             assert blocks[key].dtype == np.int8, key
             assert scale_key(key) in blocks, key
         # pool is int8 with f32 scale pools beside it
-        assert eng._kc.dtype == jnp.int8
-        assert eng._ks.dtype == jnp.float32
-        assert eng._ks.shape == (eng.num_layers, eng.num_blocks,
-                                 eng.num_heads, eng.block_size)
+        cache = eng.kv_cache
+        assert cache["k"].dtype == jnp.int8
+        assert cache["k_scale"].dtype == jnp.float32
+        assert cache["k_scale"].shape == (eng.num_layers, eng.num_blocks,
+                                          eng.num_heads, eng.block_size)
 
     def test_unquantized_engine_untouched(self):
         eng = _make_engine(quantize=None)
@@ -137,7 +138,7 @@ class TestQuantEngine:
         for key in QUANT_BLOCK_LEAVES:
             assert blocks[key].dtype == np.float32
             assert scale_key(key) not in blocks
-        assert eng._ks is None and eng._vs is None
+        assert set(eng.kv_cache) == {"k", "v"}
 
     def test_dequantized_weights_close_to_f32(self):
         m = _make_model()
@@ -173,8 +174,8 @@ class TestQuantEngine:
     def test_weight_only_mode_serves(self):
         eng = _make_engine(quantize={"weights": True,
                                      "kv_cache": False})
-        assert eng._kc.dtype == eng.dtype       # pool stays f32
-        assert eng._ks is None
+        assert eng.kv_cache["k"].dtype == eng.dtype   # pool stays f32
+        assert "k_scale" not in eng.kv_cache
         outs = eng.generate(_prompts(n=2), max_new_tokens=6)
         assert len(outs) == 2
 
@@ -183,7 +184,7 @@ class TestQuantEngine:
                                      "kv_cache": True})
         blocks = jax.device_get(eng.params)["blocks"]
         assert blocks["attn.qkv.weight"].dtype == np.float32
-        assert eng._kc.dtype == jnp.int8
+        assert eng.kv_cache["k"].dtype == jnp.int8
         outs = eng.generate(_prompts(n=2), max_new_tokens=6)
         assert len(outs) == 2
 
