@@ -30,6 +30,7 @@ The last line of stdout is one JSON object:
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
 """
 
+import collections
 import gc
 import http.client
 import json
@@ -464,7 +465,7 @@ def spmd_train_phase(report, build_model, *, batch, seq, steps, one_chip):
 
 
     from paddle_tpu.distributed.fleet.topology import build_mesh
-    from paddle_tpu.parallel import SpmdTrainStep
+    from paddle_tpu.parallel import SpmdTrainStep, compiled_collectives
 
     from paddle_tpu.ops.pallas import GSPMD_REASON, KernelFallbackWarning
 
@@ -495,6 +496,19 @@ def spmd_train_phase(report, build_model, *, batch, seq, steps, one_chip):
     print(f"  smoke timing: first step (compile + run) {compile_s:.1f} s, "
           f"{steps - 1} more steps {run_s:.2f} s", flush=True)
     _check_losses(report, phase, losses, model.config.vocab_size)
+    # the fused q|k|v is held by heads over mp: the chip's compiler, like
+    # the CPU's in tests/test_gpt_parallel.py, gathers no 3h-wide operand
+    # (whole, or a shard's contiguous half of the columns)
+    wide = {3 * model.config.hidden_size, 3 * model.config.hidden_size // 2}
+    found = compiled_collectives(
+        trainer.lower(ids, ids).compile().as_text())
+    kinds = collections.Counter(kind for kind, _ in found)
+    gathered = [shapes for kind, shapes in found if kind == "all-gather"
+                and any(wide & set(shape) for shape in shapes)]
+    report.check(phase, "the compiled step all-gathers no 3h-wide q|k|v",
+                 not gathered,
+                 f"collectives by kind {dict(sorted(kinds.items()))}; "
+                 f"3h-wide all-gathers {gathered or 'none'}")
     leaves = jax.tree_util.tree_leaves((trainer.params, trainer.opt_state))
     sets = {len(x.sharding.device_set) for x in leaves
             if getattr(x, "ndim", 0) >= 1}

@@ -19,7 +19,7 @@ from ..core.tensor import Tensor
 
 WHITE_LIST = {
     "matmul", "mm", "bmm", "mv", "linear", "conv1d", "conv2d", "conv3d",
-    "conv2d_transpose", "einsum", "addmm",
+    "conv2d_transpose", "einsum", "addmm", "gpt_qkv_projection",
 }
 
 BLACK_LIST = {

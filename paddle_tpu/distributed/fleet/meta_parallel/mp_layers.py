@@ -125,7 +125,10 @@ def _shard_hint_op(x, axes, dim):
     from jax.sharding import NamedSharding, PartitionSpec
     mesh = current_mesh()
     if mesh is not None and isinstance(x, jax.core.Tracer):
-        spec = [None] * x.ndim
+        # the other dims are left to GSPMD: ``None`` there says "NOT
+        # sharded", and gathered every column-parallel output (and its
+        # gradient) over ``dp`` (PERF.md section 6, PR 29)
+        spec = [PartitionSpec.UNCONSTRAINED] * x.ndim
         spec[dim] = axes[0]
         try:
             return lax.with_sharding_constraint(

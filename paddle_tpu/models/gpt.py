@@ -30,6 +30,20 @@ from ..nn.layer_base import ParamAttr
 from ..ops.registry import op
 
 
+@op("gpt_qkv_projection")
+def _qkv_by_heads(x, weight, bias):
+    """q, k, v ``[b, t, heads, head_dim]`` from the fused projection held
+    VIEWED: weight ``[h, 3, heads, head_dim]``, bias ``[3, heads,
+    head_dim]`` (``Parameter.mesh_view``: a reshape of the stored column
+    order, all of q, then k, then v, each head-major).  With the heads'
+    axis over ``mp`` q, k and v leave the matmul sharded by heads, as the
+    flash ``shard_map`` takes them, and no collective moves the
+    activation, its gradient or the weight; each output column is still
+    one full-``h`` dot on one chip."""
+    qkv = jnp.einsum("bth,hcnd->btcnd", x, weight) + bias
+    return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+
 @op("gpt_cp_attention")
 def _cp_attention(q, k, v, mesh=None, axis="sep", mode="ring"):
     """Context-parallel causal attention as a registered op (so the eager
@@ -77,6 +91,11 @@ class GPTAttention(nn.Layer):
         self.head_dim = config.head_dim
         self.qkv = ColumnParallelLinear(h, 3 * h, weight_attr=init,
                                         gather_output=False)
+        # a contiguous half of the 3h columns is no set of heads: over a
+        # mesh the leaves are held viewed, the heads' axis over mp
+        thirds = (3, self.num_heads, self.head_dim)
+        self.qkv.weight.mesh_view = ((h,) + thirds, (None, None, "mp", None))
+        self.qkv.bias.mesh_view = (thirds, (None, "mp", None))
         self.proj = RowParallelLinear(h, h, weight_attr=proj_init,
                                       input_is_parallel=True)
         self.dropout_p = config.attention_probs_dropout_prob
@@ -85,9 +104,17 @@ class GPTAttention(nn.Layer):
 
     def forward(self, x):
         b, t, _ = x.shape
-        qkv = self.qkv(x)
-        qkv = qkv.reshape([b, t, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv.unbind(axis=2)
+        if self.qkv.weight.ndim == 2:
+            # the stored leaf (one chip, eager, the serving engine's source)
+            qkv = self.qkv(x)
+            qkv = qkv.reshape([b, t, 3, self.num_heads, self.head_dim])
+            q, k, v = qkv.unbind(axis=2)
+        else:
+            # held viewed by a mesh trainer.  One path for both was tried
+            # and is not taken: on one chip XLA compiled the head-structured
+            # product to a step 1.1% slower (PERF.md section 6, PR 29)
+            with jax.named_scope("qkv"):
+                q, k, v = _qkv_by_heads(x, self.qkv.weight, self.qkv.bias)
         out = None
         # attention dropout is inactive in eval, so cp only yields to the
         # dense path when dropout would actually be applied
@@ -215,8 +242,11 @@ class GPTForCausalLM(nn.Layer):
         block params stacked on axis 0 (the 'pp' sharding axis).
 
         Returns dict with: params {'embed','blocks','head'}, fns
-        (embed_fn, block_fn, head_fn, loss_fn), and spec pytrees mapping each
-        leaf to mesh-axis names.
+        (embed_fn, block_fn, head_fn, loss_fn), spec pytrees mapping each
+        leaf to mesh-axis names, and ``block_views``: for each block leaf
+        whose layer declares a ``mesh_view``, the shape one layer's leaf
+        is held in over a mesh and the stacked leaf's mesh axes there
+        (``block_fn`` takes such a leaf stored or viewed).
         """
         from ..jit import functional_call
 
@@ -238,12 +268,15 @@ class GPTForCausalLM(nn.Layer):
 
         embed_specs = {k: axes_of(embed.state_dict(), k) for k in embed_params}
         head_specs = {k: None for k in head_params}
-        block_specs = {}
+        block_specs, block_views = {}, {}
         tsd = template.state_dict()
         for name in names:
             axes = getattr(tsd[name], "mesh_axes", None) or \
                 (None,) * len(tsd[name].shape)
             block_specs[name] = ("pp",) + tuple(axes)
+            view = getattr(tsd[name], "mesh_view", None)
+            if view is not None:
+                block_views[name] = (tuple(view[0]), ("pp",) + tuple(view[1]))
 
         training = self.training
 
@@ -279,6 +312,7 @@ class GPTForCausalLM(nn.Layer):
                        "head": head_params},
             "specs": {"embed": embed_specs, "blocks": block_specs,
                       "head": head_specs},
+            "block_views": block_views,
             "fns": (embed_fn, block_fn, head_fn, loss_fn),
             "num_layers": len(blocks),
         }

@@ -16,6 +16,7 @@ dissolves into GSPMD).
 """
 
 import functools
+import re
 
 import numpy as np
 
@@ -40,9 +41,10 @@ def _spec_from_axes(mesh, axes, ndim):
     return P(*spec)
 
 
-def _shard_opt_state_spec(mesh, param_spec, ndim, zero_axis="sharding"):
+def _shard_opt_state_spec(mesh, param_spec, shape, zero_axis="sharding"):
     """ZeRO stage-1: optimizer state sharded over ``zero_axis`` on the
-    first dim not already sharded (falls back to the param's own spec).
+    first dim not already sharded that the axis divides (a view's axis of
+    3 is none; falls back to the param's own spec).
 
     ``zero_axis="dp"`` folds sharding into the data-parallel axis — the
     reference's sharding group IS a subdivision of the dp replicas
@@ -51,12 +53,41 @@ def _shard_opt_state_spec(mesh, param_spec, ndim, zero_axis="sharding"):
     if not zero_axis or zero_axis not in mesh.axis_names or \
             mesh.shape.get(zero_axis, 1) == 1:
         return param_spec
-    spec = list(param_spec) + [None] * (ndim - len(param_spec))
+    spec = list(param_spec) + [None] * (len(shape) - len(param_spec))
     for i, s in enumerate(spec):
-        if s is None:
+        if s is None and shape[i] % mesh.shape[zero_axis] == 0:
             spec[i] = zero_axis
             return P(*spec)
     return param_spec
+
+
+_COLLECTIVE = re.compile(
+    r" = (.+?) (all-gather|all-reduce|all-to-all|collective-permute|"
+    r"reduce-scatter)(?:-start)?\(")
+
+
+def compiled_collectives(text):
+    """``[(kind, [result shape, ...])]`` for every collective in a compiled
+    step's HLO text (``trainer.lower(ids, labels).compile().as_text()``):
+    the ones GSPMD put in, which no jaxpr shows.  An asynchronous pair
+    counts once, at its start."""
+    return [(m.group(2), [tuple(int(d) for d in dims.split(",") if d)
+                          for dims in re.findall(r"\w+\[([\d,]*)\]",
+                                                 m.group(1))])
+            for m in _COLLECTIVE.finditer(text)]
+
+
+def _divides(mesh, spec, shape):
+    """Whether every sharded dim of ``shape`` splits evenly over its axis."""
+    return all(axis is None or dim % mesh.shape[axis] == 0
+               for dim, axis in zip(shape, spec))
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "sharding"))
+def _relayout(leaf, shape, sharding):
+    """``leaf`` reshaped and placed in one program: no whole copy of it
+    stands on a chip between the two."""
+    return jax.lax.with_sharding_constraint(leaf.reshape(shape), sharding)
 
 
 class SpmdTrainStep:
@@ -66,6 +97,13 @@ class SpmdTrainStep:
     Usage::
         trainer = SpmdTrainStep(model, opt, mesh, n_microbatches=4)
         loss = trainer.step(input_ids, labels)
+
+    A block leaf whose layer declares a ``mesh_view`` (the decomposition's
+    ``block_views``: GPT's fused q|k|v, whose contiguous column halves are
+    no set of heads) is HELD viewed, with its optimizer state, so that the
+    mesh axis lies on an axis the forward keeps; ``self.params`` and
+    ``self.opt_state`` carry that shape.  ``state_dict()`` and
+    ``sync_to_model()`` give the model's stored layout back.
     """
 
     def __init__(self, model, optimizer, mesh, n_microbatches=1,
@@ -129,6 +167,23 @@ class SpmdTrainStep:
             "blocks": shardings_for(params["blocks"], specs["blocks"]),
             "head": shardings_for(params["head"], specs["head"]),
         }
+        # viewed block leaves: held in the view's shape under the view's
+        # axes, reshaped and placed ONCE here like the layer permutation;
+        # ``_stored`` keeps what the boundary gives back.  A view the mesh
+        # does not divide is not taken (the stored spec stands, GSPMD
+        # gathers)
+        self._stored = {}
+        params = {**params, "blocks": dict(params["blocks"])}
+        for k, (shape, axes) in d.get("block_views", {}).items():
+            shape = (self.num_layers,) + shape
+            sharding = NamedSharding(
+                mesh, _spec_from_axes(mesh, axes, len(shape)))
+            if _divides(mesh, sharding.spec, shape):
+                leaf = params["blocks"][k]
+                self._stored[k] = (leaf.shape,
+                                   self.param_shardings["blocks"][k])
+                self.param_shardings["blocks"][k] = sharding
+                params["blocks"][k] = _relayout(leaf, shape, sharding)
         # place params
         self.params = jax.tree_util.tree_map(
             lambda v, s: jax.device_put(v, s), params, self.param_shardings)
@@ -142,7 +197,7 @@ class SpmdTrainStep:
                     sv, NamedSharding(
                         mesh,
                         _shard_opt_state_spec(
-                            mesh, path_sh.spec, sv.ndim, self.zero_axis)
+                            mesh, path_sh.spec, sv.shape, self.zero_axis)
                         if sv.ndim else P())),
                 state)
 
@@ -221,22 +276,36 @@ class SpmdTrainStep:
             train_step_scaled if scaler is not None else train_step,
             donate_argnums=(0, 1))
 
-    def step(self, input_ids, labels):
+    def _operands(self, step, key, input_ids, labels):
+        """The compiled step's argument tuple for one call."""
         if self._compiled is None:
             self._build()
+        ids = input_ids._data if isinstance(input_ids, Tensor) else input_ids
+        lbl = labels._data if isinstance(labels, Tensor) else labels
+        args = (self.params, self.opt_state, jnp.int32(step),
+                jnp.float32(self.optimizer.get_lr()), key,
+                jax.device_put(ids, self.batch_sharding),
+                jax.device_put(lbl, self.batch_sharding))
+        if self.scaler is not None:
+            args += (self.scaler._compiled_state,)
+        return args
+
+    def lower(self, input_ids, labels):
+        """jax's ``Lowered`` form of the step for these operands, as
+        ``jit.TrainStep.lower``: ``.compile().as_text()`` shows the
+        collectives GSPMD put in (:func:`compiled_collectives`).  Runs
+        nothing: no step is counted and no RNG key is drawn."""
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        args = self._operands(self._step_count + 1, key, input_ids, labels)
+        with use_mesh(self.mesh):
+            return self._compiled.lower(*args)
+
+    def step(self, input_ids, labels):
         self._step_count += 1
         step, trace = self._step_count, self._trace
         with RecordEvent(trace.STEP, step=step):
             with RecordEvent(trace.OPERANDS, step=step):
-                ids = input_ids._data if isinstance(input_ids, Tensor) \
-                    else input_ids
-                lbl = labels._data if isinstance(labels, Tensor) else labels
-                args = (self.params, self.opt_state, jnp.int32(step),
-                        jnp.float32(self.optimizer.get_lr()), get_rng_key(),
-                        jax.device_put(ids, self.batch_sharding),
-                        jax.device_put(lbl, self.batch_sharding))
-                if self.scaler is not None:
-                    args += (self.scaler._compiled_state,)
+                args = self._operands(step, get_rng_key(), input_ids, labels)
             # use_mesh, not a bare ``with mesh``: the kernel dispatchers read
             # fleet.spmd.current_mesh() to know GSPMD partitions this step
             with use_mesh(self.mesh):
@@ -254,21 +323,41 @@ class SpmdTrainStep:
         """``{"steps", "compiles"}``, as ``jit.TrainStep.stats``."""
         return {"steps": self._step_count, "compiles": self._trace.compiles}
 
+    def _canonical_blocks(self, tree):
+        """``tree`` (the block parameters, or their optimizer state) as the
+        model stores it: every held view undone, leaf by leaf, and the
+        stacked-layer dim in model order (the interleave permutation
+        undone) — the layout checkpoints and the model use."""
+        if not self._stored and self._layer_perm is None:
+            return tree
+        inv = None if self._layer_perm is None \
+            else np.argsort(self._layer_perm)
+
+        def stored(name, leaf):
+            if not leaf.ndim:       # a state's scalar (beta1_pow)
+                return leaf
+            if name in self._stored:
+                leaf = _relayout(leaf, *self._stored[name])
+            return leaf if inv is None else leaf[inv]
+
+        return {k: jax.tree_util.tree_map(functools.partial(stored, k), v)
+                for k, v in tree.items()}
+
     def _canonical_params(self):
-        """Params with the stacked-layer dim in model order (the interleave
-        permutation undone) — the layout checkpoints and the model use."""
-        if self._layer_perm is None:
-            return self.params
-        inv = np.argsort(self._layer_perm)
+        """``self.params`` in the model's stored layout."""
         out = dict(self.params)
-        out["blocks"] = jax.tree_util.tree_map(
-            lambda leaf: leaf[inv], self.params["blocks"])
+        out["blocks"] = self._canonical_blocks(self.params["blocks"])
         return out
 
     def sync_to_model(self):
         self.model.load_stacked(self._canonical_params())
 
     def state_dict(self):
+        """``params`` and ``opt_state`` in the model's stored layout
+        (``[L, h, 3h]`` for the fused q|k|v, whatever shape it is held
+        in), layers in model order."""
+        opt_state = dict(self.opt_state)
+        opt_state["blocks"] = self._canonical_blocks(self.opt_state["blocks"])
         return {"params": self._canonical_params(),
-                "opt_state": self.opt_state,
+                "opt_state": opt_state,
                 "step": self._step_count}

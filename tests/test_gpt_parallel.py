@@ -398,3 +398,215 @@ def test_dryrun_multichip_16_devices_dedicated_sharding_axis():
     import __graft_entry__ as g
 
     g.dryrun_multichip(16)  # asserts internally; raises on failure
+
+
+# ---- the fused q|k|v leaf over mp: held viewed, no gather (PR 29) ----
+
+def _moved_qkv(text, hidden, heads, mp):
+    """The all-gathers, all-to-alls and collective-permutes of a compiled
+    step whose result carries the fused projection's extent: ``3h`` wide
+    (whole, or a shard's contiguous ``3h / mp``) or ``[3, heads, head_dim]``
+    (whole, or a shard's heads) — the activation, its gradient or the
+    weight.  All-reduces move no layout and are not looked at."""
+    from paddle_tpu.parallel import compiled_collectives
+
+    wide = {3 * hidden, 3 * hidden // mp}
+    thirds = {(3, n, hidden // heads) for n in (heads, heads // mp)}
+
+    def carries(shape):
+        return bool(wide & set(shape)) or any(
+            shape[i:i + 3] in thirds for i in range(len(shape) - 2))
+
+    return [(kind, shapes) for kind, shapes in compiled_collectives(text)
+            if kind != "all-reduce" and any(carries(s) for s in shapes)]
+
+
+def _tiny_trainer(dp, mp, layers=2, model=None, **kw):
+    """(trainer, model) at ``gpt_tiny`` widths on a dp x mp mesh."""
+    mesh = build_mesh(dp=dp, pp=1, sharding=1, mp=mp,
+                      devices=jax.devices()[:dp * mp])
+    if model is None:
+        paddle.seed(0)
+        model = gpt_tiny(num_layers=layers)
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    return SpmdTrainStep(model, opt, mesh, **kw), model
+
+
+class TestFusedQkvOverMp:
+    @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+    @pytest.mark.parametrize("dp,mp", [(2, 2), (1, 4), (2, 1)])
+    def test_compiled_step_moves_no_qkv(self, dp, mp, remat):
+        """The projection leaves the matmul sharded by heads, as the flash
+        shard_map takes it: GSPMD gathers, exchanges or permutes neither
+        the q|k|v activation, nor its gradient, nor the weight."""
+        trainer, _ = _tiny_trainer(dp, mp, remat=remat)
+        w = trainer.params["blocks"]["attn.qkv.weight"]
+        assert w.shape == (2, 64, 3, 4, 16)
+        assert w.sharding.is_equivalent_to(jax.sharding.NamedSharding(
+            trainer.mesh,
+            jax.sharding.PartitionSpec("pp", None, None, "mp", None)),
+            w.ndim)
+        ids, labels = make_batch(batch=4)
+        text = trainer.lower(ids, labels).compile().as_text()
+        assert _moved_qkv(text, 64, 4, mp) == []
+
+    @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+    def test_column_parallel_hint_leaves_the_batch_to_gspmd(self, remat):
+        """``ColumnParallelLinear``'s output hint names ``mp`` on the
+        columns and leaves the other dims UNCONSTRAINED: with ``None``
+        there ("not sharded") GSPMD gathered the MLP's hidden activation
+        and its gradient over ``dp``.  The dp2 x mp2 step now holds
+        all-reduces only."""
+        from paddle_tpu.parallel import compiled_collectives
+
+        trainer, _ = _tiny_trainer(2, 2, remat=remat)
+        ids, labels = make_batch(batch=4)
+        found = compiled_collectives(
+            trainer.lower(ids, labels).compile().as_text())
+        assert found and {kind for kind, _ in found} == {"all-reduce"}
+
+    def test_guard_sees_contiguous_halves(self, monkeypatch):
+        """The control: the same leaf held ``[L, h, 3h]`` in contiguous
+        halves (no view taken) makes GSPMD move it, and the helper that
+        found nothing above finds that."""
+        paddle.seed(0)
+        model = gpt_tiny(num_layers=2)
+        d = model.functional_decompose()
+        monkeypatch.setattr(model, "functional_decompose",
+                            lambda: {**d, "block_views": {}})
+        trainer, _ = _tiny_trainer(2, 2, model=model, remat=True)
+        assert trainer.params["blocks"]["attn.qkv.weight"].shape \
+            == (2, 64, 192)
+        ids, labels = make_batch(batch=4)
+        text = trainer.lower(ids, labels).compile().as_text()
+        moved = _moved_qkv(text, 64, 4, 2)
+        assert any(kind == "all-gather" for kind, _ in moved), moved
+
+    def test_heads_the_mesh_does_not_divide_keep_the_stored_leaf(self):
+        """4 heads over mp=8: the view is not taken, the step runs."""
+        trainer, _ = _tiny_trainer(1, 8)
+        assert trainer.params["blocks"]["attn.qkv.weight"].shape \
+            == (2, 64, 192)
+        ids, labels = make_batch(batch=4)
+        assert np.isfinite(float(trainer.step(ids, labels).numpy()))
+
+    @pytest.mark.parametrize("leaf,stored", [
+        ("attn.qkv.weight", (4, 64, 192)), ("attn.qkv.bias", (4, 192))])
+    def test_state_dict_before_any_step_is_the_models(self, leaf, stored):
+        paddle.seed(0)
+        model = gpt_tiny(num_layers=4)
+        want = np.stack([blk.state_dict()[leaf].numpy()
+                         for blk in model.gpt.h])
+        sd = _tiny_trainer(2, 2, model=model)[0].state_dict()
+        got = sd["params"]["blocks"][leaf]
+        assert got.shape == stored
+        np.testing.assert_array_equal(np.asarray(got), want)
+        m1 = sd["opt_state"]["blocks"][leaf]["moment1"]
+        assert m1.shape == stored and not np.asarray(m1).any()
+        assert sd["opt_state"]["blocks"][leaf]["beta1_pow"].shape == ()
+
+
+def _three_steps(build, ids, labels):
+    step = build()
+    losses = [float(step(ids, labels).numpy()) for _ in range(3)]
+    return step, losses
+
+
+@pytest.fixture(scope="module")
+def after_three_steps():
+    """One device under ``jit.TrainStep`` and two meshes under
+    ``SpmdTrainStep`` (dp2 x mp2; pp2 x mp2 interleaved), same seed, three
+    steps: ``{name: (stacked params, stacked moment1, model)}``."""
+    from paddle_tpu.jit import TrainStep
+
+    ids, labels = make_batch(batch=8, seq=32)
+    leaves = ("attn.qkv.weight", "attn.qkv.bias")
+
+    def build(seed=21):
+        paddle.seed(seed)
+        m = gpt_tiny(num_layers=4)
+        return m, optimizer.AdamW(learning_rate=1e-3,
+                                  parameters=m.parameters())
+
+    out = {}
+    m, opt = build()
+    one = TrainStep(m, lambda lo, la: m.loss(lo, la), opt)
+    for _ in range(3):
+        one(ids, labels)
+    sd = one.state_dict()
+    out["one device"] = (
+        {k: np.stack([np.asarray(sd["params"][f"gpt.h.{i}.{k}"])
+                      for i in range(4)]) for k in leaves},
+        {k: np.stack([np.asarray(
+            sd["opt_state"][f"gpt.h.{i}.{k}"]["moment1"])
+            for i in range(4)]) for k in leaves}, None)
+    for name, mesh_kw, kw in (
+            ("dp2 x mp2", dict(dp=2, pp=1, mp=2), {}),
+            ("pp2 x mp2, virtual_pp=2", dict(dp=2, pp=2, mp=2),
+             dict(n_microbatches=4, virtual_pp=2))):
+        m, opt = build()
+        n = int(np.prod(list(mesh_kw.values())))
+        mesh = build_mesh(sharding=1, devices=jax.devices()[:n], **mesh_kw)
+        trainer = SpmdTrainStep(m, opt, mesh, **kw)
+        for _ in range(3):
+            trainer.step(ids, labels)
+        sd = trainer.state_dict()
+        out[name] = (
+            {k: np.asarray(sd["params"]["blocks"][k]) for k in leaves},
+            {k: np.asarray(sd["opt_state"]["blocks"][k]["moment1"])
+             for k in leaves}, (trainer, m))
+    return out
+
+
+class TestFusedQkvStateRoundTrip:
+    """After three steps the trainer's boundary gives the stored layout:
+    q, k and v THIRDS of weight, bias and first moment each match one
+    device (the tolerance ``test_hybrid_matches_single_device`` uses)."""
+
+    @staticmethod
+    def _close(got, want):
+        np.testing.assert_allclose(got, want, rtol=2e-3,
+                                   atol=2e-3 * np.abs(want).max())
+
+    @pytest.mark.parametrize("third", [0, 1, 2], ids=["q", "k", "v"])
+    @pytest.mark.parametrize("leaf", ["attn.qkv.weight", "attn.qkv.bias"])
+    @pytest.mark.parametrize("what", ["params", "moment1"])
+    @pytest.mark.parametrize("mesh", ["dp2 x mp2", "pp2 x mp2, virtual_pp=2"])
+    def test_state_dict_thirds_match_one_device(self, after_three_steps,
+                                                mesh, what, leaf, third):
+        i = 0 if what == "params" else 1
+        got = after_three_steps[mesh][i][leaf]
+        want = after_three_steps["one device"][i][leaf]
+        assert got.shape == want.shape
+        cols = slice(third * 64, (third + 1) * 64)
+        if (leaf, third) == ("attn.qkv.bias", 1):
+            # the key bias: softmax does not see it, its true gradient is
+            # zero and Adam makes whole steps of each side's rounding
+            # noise; the first moment says that it IS that third
+            assert np.isfinite(got[..., cols]).all()
+            if what == "moment1":
+                assert np.abs(got[..., cols]).max() \
+                    < 1e-3 * np.abs(got[..., :64]).max()
+            return
+        self._close(got[..., cols], want[..., cols])
+        if what == "params":    # and the three steps moved it
+            paddle.seed(21)
+            init = np.stack([b.state_dict()[leaf].numpy()
+                             for b in gpt_tiny(num_layers=4).gpt.h])
+            assert np.abs(want[..., cols] - init[..., cols]).max() > 1e-3
+
+    @pytest.mark.parametrize("leaf", ["attn.qkv.weight", "attn.qkv.bias"])
+    @pytest.mark.parametrize("mesh", ["dp2 x mp2", "pp2 x mp2, virtual_pp=2"])
+    def test_sync_to_model_gives_the_stored_layout(self, after_three_steps,
+                                                   mesh, leaf):
+        trainer, model = after_three_steps[mesh][2]
+        trainer.sync_to_model()
+        sd = model.state_dict()
+        want = after_three_steps["one device"][0][leaf]
+        for layer in range(4):
+            got = sd[f"gpt.h.{layer}.{leaf}"].numpy()
+            assert got.shape == want[layer].shape
+            for third in range(3):
+                if (leaf, third) != ("attn.qkv.bias", 1):   # noise, above
+                    cols = slice(third * 64, (third + 1) * 64)
+                    self._close(got[..., cols], want[layer][..., cols])

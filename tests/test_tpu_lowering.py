@@ -173,6 +173,98 @@ def test_flash_under_the_dp_mp_mesh_lowers_for_tpu(monkeypatch):
     assert text.count("tpu_custom_call") == 3
 
 
+def _gpt3_width(**kw):
+    """GPT-3 6.7B's widths (4096, 32 heads of 128, context 2048), amp O2."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPTConfig
+
+    paddle.seed(0)
+    return GPTConfig(vocab_size=512, hidden_size=4096, num_layers=1,
+                     num_attention_heads=32, max_position_embeddings=2048,
+                     hidden_dropout_prob=0.0,
+                     attention_probs_dropout_prob=0.0, **kw)
+
+
+def test_dp_mp_step_with_the_viewed_qkv_lowers_for_tpu(monkeypatch):
+    """The four-chip cell's whole step (one layer of it: the layers are a
+    scan) at ``[4, 2048]`` tokens under dp=2 x mp=2, rematerialised, on
+    the kernel path: the trainer holds the fused q|k|v viewed ``[L, h, 3,
+    heads, head_dim]``, heads over ``mp``, the TPU lowering takes the step
+    with the flash kernels inside, and NO tensor of the program is 12,288
+    wide any more: neither activation, gradient nor weight is ever in the
+    form whose halves are no set of heads."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import optimizer
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+    from paddle_tpu.distributed.fleet.topology import build_mesh
+    from paddle_tpu.models.gpt import GPTForCausalLM
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.parallel import SpmdTrainStep
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    model = paddle.amp.decorate(GPTForCausalLM(_gpt3_width()), level="O2",
+                                dtype="bfloat16")
+    opt = optimizer.AdamW(learning_rate=1.2e-4,
+                          parameters=model.parameters())
+    mesh = build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
+    trainer = SpmdTrainStep(model, opt, mesh, remat=True)
+    held = trainer.params["blocks"]["attn.qkv.weight"]
+    assert held.shape == (1, 4096, 3, 32, 128)
+    assert {s.data.shape for s in held.addressable_shards} \
+        == {(1, 4096, 3, 16, 128)}
+    ids = np.zeros((4, 2048), np.int32)
+    args = trainer._operands(1, jax.ShapeDtypeStruct((2,), jnp.uint32),
+                             ids, ids)
+    with use_mesh(mesh):
+        text = trainer._compiled.trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert f'kernel_name = "{kernel}"' in text, kernel
+    assert "1x4096x3x32x128xbf16" in text
+    assert "12288" not in text
+    # the boundary still speaks the stored layout
+    assert trainer.state_dict()["params"]["blocks"][
+        "attn.qkv.weight"].shape == (1, 4096, 12288)
+
+
+def test_one_chip_qkv_projection_stays_one_dot(monkeypatch):
+    """``GPTAttention`` forward + grad on one chip, the leaf stored ``[h,
+    3h]``: the projection is the single ``[.., 4096] x [4096, 12288]`` dot
+    it was (the head-structured product is for the viewed leaf alone: on
+    one chip XLA compiled it to a slower step, PERF.md section 6, PR 29),
+    and the layer has its five matmuls: q|k|v forward, input gradient and
+    weight gradient, the output projection's two gradients (its forward
+    falls out of a sum's gradient)."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.models.gpt import GPTAttention
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)  # flash: no dots
+    attn = paddle.amp.decorate(GPTAttention(_gpt3_width()), level="O2",
+                               dtype="bfloat16")
+    p = {k: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype)
+         for k, v in attn.state_dict().items()}
+    x = jax.ShapeDtypeStruct((4, 2048, 4096), jnp.bfloat16)
+
+    def loss(p, x):
+        return jnp.sum(functional_call(attn, p, x).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(p, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    dots = re.findall(r"stablehlo\.dot_general.*?: \((.*?)\) -> (\S+)",
+                      text)
+    assert len(dots) == 5, dots
+    assert dots.count(("tensor<4x2048x4096xbf16>, tensor<4096x12288xbf16>",
+                       "tensor<4x2048x12288xbf16>")) == 1, dots
+    assert "3x32x128x" not in "".join(d[0] for d in dots)
+
+
 @pytest.mark.slow
 def test_registry_compiles_for_v5e():
     """The real Mosaic compile, ahead of time, for a device this host
