@@ -7,8 +7,10 @@ Here no token is dropped whatever the imbalance, and the expert matmuls'
 work follows the rows that are really there:
 
 1. :func:`route_sigmoid_topk` -- the published ``noaux_tc`` router
-   (sigmoid scores, a selection bias, weights normed over the chosen few);
-   any other router that yields ``(idx [S, k], weights [S, k])`` serves.
+   (sigmoid scores, a selection bias, weights normed over the chosen few),
+   or :func:`route_softmax_topk` (softmax over all the experts, the largest
+   few, normed over them); any other router that yields ``(idx [S, k],
+   weights [S, k])`` serves.
 2. :func:`sort_by_expert` -- the ``S * k`` assignments in expert order, the
    ones whose expert does not live here (``expert_offset``,
    ``num_local_experts``: this chip's share of an expert-parallel layer)
@@ -19,8 +21,10 @@ work follows the rows that are really there:
    follow ``group_sizes``, with its transposes for the backward; elsewhere
    ``jax.lax.ragged_dot``), and a gather back with the routing weights.
 
-The buffers hold the worst case (every assignment local: ``S * k`` rows);
-the arithmetic does not: rows behind the last group belong to no group and
+The buffers hold the worst case (every assignment local: ``S * min(k,
+num_local)`` rows, :func:`sorted_rows`: a token's experts are distinct, so
+it has at most ``num_local`` of them here; ``S * k`` where the chip holds
+at least ``k`` experts); the arithmetic does not: rows behind the last group belong to no group and
 no tile of the grouped matmul visits them.  What such rows hold is never
 defined and never used: wherever sorted rows go back to their tokens, the
 assignments served elsewhere are masked inside that reduction.  The
@@ -53,6 +57,27 @@ def route_sigmoid_topk(logits, bias, top_k, scale, norm_topk=True):
     return idx.astype(jnp.int32), w * scale
 
 
+def route_softmax_topk(logits, top_k, scale, norm_topk=True):
+    """``logits [S, E]`` float32 -> ``(idx [S, k] int32, weights [S, k])``.
+
+    ``s = softmax(logits)`` over ALL the experts; the ``k`` largest are
+    chosen; weights are ``s`` at the chosen, normed over them, and scaled
+    (the softmax-routed family: ``norm_topk_prob``, a routed scaling
+    factor; no selection bias)."""
+    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, idx = jax.lax.top_k(s, top_k)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=1, keepdims=True)
+    return idx.astype(jnp.int32), w * scale
+
+
+def sorted_rows(tokens, top_k, num_local):
+    """Rows of the sorted buffers: the most assignments ``tokens`` tokens
+    can have among ``num_local`` experts, each token's ``top_k`` experts
+    being distinct.  Exact, not a capacity: nothing is ever dropped."""
+    return tokens * min(top_k, num_local)
+
+
 def sort_by_expert(idx, expert_offset, num_local):
     """The ``A = S * k`` assignments in the order the grouped matmul wants.
 
@@ -65,7 +90,11 @@ def sort_by_expert(idx, expert_offset, num_local):
     sorted row ``j``, local experts first in expert order, everything
     routed elsewhere behind them; ``inverse`` undoes it; ``counts
     [num_local]`` int32 are the group sizes, i.e. the tokens each expert
-    held here received."""
+    held here received.  ``order`` is cut to :func:`sorted_rows` rows (no
+    local assignment lies behind them); ``inverse`` keeps all ``A``
+    entries, and those of assignments served elsewhere may point past the
+    cut: :func:`_unsort` clamps them, and nobody reads what they fetch."""
+    tokens, top_k = idx.shape
     local = idx.T.reshape(-1) - expert_offset
     key = jnp.where((local >= 0) & (local < num_local), local, num_local)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
@@ -73,7 +102,20 @@ def sort_by_expert(idx, expert_offset, num_local):
         jnp.arange(order.shape[0], dtype=jnp.int32))
     counts = jnp.sum(key[:, None] == jnp.arange(num_local)[None, :], axis=0,
                      dtype=jnp.int32)
+    rows = sorted_rows(tokens, top_k, num_local)
+    if rows < order.shape[0]:
+        order = order[:rows]
     return order, inverse, counts
+
+
+def _unsort(ys, inverse):
+    """Sorted rows ``ys`` back in assignment order (slot-major, all ``A``
+    of them).  Where the sorted buffer was cut short of ``A`` the assignments
+    served elsewhere would read past its end: they are clamped to its last
+    row, and every consumer masks them (:func:`_slots`)."""
+    if ys.shape[0] < inverse.shape[0]:
+        inverse = jnp.minimum(inverse, ys.shape[0] - 1)
+    return ys[inverse]
 
 
 def _slots(by_slot, served):
@@ -110,7 +152,7 @@ def _dispatch_fwd(x, order, inverse, counts):
 
 def _dispatch_bwd(res, g):
     inverse, counts, tokens = res
-    dx = sum(_slots(g[inverse], _served(inverse, counts, tokens)))
+    dx = sum(_slots(_unsort(g, inverse), _served(inverse, counts, tokens)))
     return dx.astype(g.dtype), None, None, None
 
 
@@ -134,7 +176,7 @@ def combine(ys, weights, order, inverse, counts):
 
 
 def _combine_fwd(ys, weights, order, inverse, counts):
-    by_slot = ys[inverse]
+    by_slot = _unsort(ys, inverse)
     served = _served(inverse, counts, weights.shape[0])
     w32 = weights.astype(jnp.float32)
     out = sum(y * w32[:, j:j + 1]

@@ -156,33 +156,48 @@ class MoELayer(Layer):
 
 # ------------------------------------- routed and shared experts, dropless --
 
-class SigmoidTopKRouter(Layer):
-    """The ``noaux_tc`` router of the DeepSeek-V3 family: float32 sigmoid
-    scores over ALL ``num_experts`` (the published width, whatever part of
-    them this chip holds), the ``top_k`` largest of score + bias, weights
-    normed over the chosen and scaled.  ``e_score_correction_bias`` is a
-    buffer (the published training moves it outside the gradient)."""
+class TopKRouter(Layer):
+    """Float32 scores over ALL ``num_experts`` (the published width,
+    whatever part of them this chip holds), the ``top_k`` largest, weights
+    normed over the chosen and scaled.  ``score_func``:
+
+    - ``"sigmoid"``: the ``noaux_tc`` router of the DeepSeek-V3 family:
+      sigmoid scores, the largest of score + bias.
+      ``e_score_correction_bias`` is a buffer (the published training
+      moves it outside the gradient);
+    - ``"softmax"``: the softmax-routed family (``norm_topk_prob`` beside a
+      routed scaling factor): softmax over all the experts, the largest;
+      no bias, no buffer."""
 
     def __init__(self, d_model, num_experts, top_k, scale=1.0,
-                 norm_topk_prob=True, init_std=0.02):
+                 norm_topk_prob=True, init_std=0.02, score_func="sigmoid"):
         super().__init__()
+        if score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func {score_func!r}: sigmoid or softmax")
         self.top_k, self.scale = top_k, scale
-        self.norm_topk_prob = norm_topk_prob
+        self.norm_topk_prob, self.score_func = norm_topk_prob, score_func
         self.weight = self.create_parameter(
             (d_model, num_experts), default_initializer=Normal(0.0, init_std))
-        self.register_buffer("e_score_correction_bias",
-                             Tensor(jnp.zeros((num_experts,), jnp.float32)))
+        if score_func == "sigmoid":
+            self.register_buffer(
+                "e_score_correction_bias",
+                Tensor(jnp.zeros((num_experts,), jnp.float32)))
 
     def forward(self, x2d):
-        return _route(x2d, self.weight, self.e_score_correction_bias,
-                      top_k=self.top_k, scale=self.scale,
-                      norm_topk=self.norm_topk_prob)
+        bias = self.e_score_correction_bias \
+            if self.score_func == "sigmoid" else None
+        return _route(x2d, self.weight, bias, top_k=self.top_k,
+                      scale=self.scale, norm_topk=self.norm_topk_prob)
 
 
-@op("moe_route_sigmoid_topk")
+@op("moe_route_topk")
 def _route(x2d, wg, bias, *, top_k, scale, norm_topk):
+    """The one routing op: sigmoid scores where a selection ``bias`` comes
+    with them, softmax where none does."""
     logits = jnp.matmul(x2d.astype(jnp.float32), wg.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
+    if bias is None:
+        return _dl.route_softmax_topk(logits, top_k, scale, norm_topk)
     return _dl.route_sigmoid_topk(logits, bias, top_k, scale, norm_topk)
 
 
@@ -271,7 +286,8 @@ class DroplessMoELayer(Layer):
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  num_shared_experts=0, routed_scaling_factor=1.0,
                  norm_topk_prob=True, num_local_experts=None,
-                 expert_offset=0, init_std=0.02, down_std=None):
+                 expert_offset=0, init_std=0.02, down_std=None,
+                 score_func="sigmoid"):
         super().__init__()
         num_local = num_experts if num_local_experts is None \
             else num_local_experts
@@ -281,9 +297,9 @@ class DroplessMoELayer(Layer):
                 f"are not among the router's {num_experts}")
         self.d_model = d_model
         self.num_local_experts, self.expert_offset = num_local, expert_offset
-        self.router = SigmoidTopKRouter(d_model, num_experts, top_k,
-                                        routed_scaling_factor,
-                                        norm_topk_prob, init_std)
+        self.router = TopKRouter(d_model, num_experts, top_k,
+                                 routed_scaling_factor, norm_topk_prob,
+                                 init_std, score_func)
         self.experts = GroupedSwiGLUExperts(num_local, d_model, d_expert,
                                             init_std, down_std)
         self.shared_experts = SwiGLUMLP(

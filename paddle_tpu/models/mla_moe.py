@@ -1,10 +1,14 @@
 """Latent-attention decoder with routed and shared experts (the DeepSeek-V3
 layer family: ``model_type`` ``deepseek_v3``).
 
-Block: ``x <- x + attn(rms(x))``, ``x <- x + ffn(rms(x))``, a final RMSNorm
-and an untied ``lm_head``.  The first ``first_k_dense_replace`` layers' ffn
-is a dense SwiGLU MLP (``mlp``); every later layer's is the expert layer
-(``moe``: ``incubate/distributed/models/moe DroplessMoELayer``).
+The decoder itself (block, stack, untied head over a vocabulary slice,
+``loss``, ``step_counters``) is the shell of ``models/moe_decoder.py``, which
+``models/laguna.py`` shares; this file gives it its attention and says
+which layers are dense.  Block: ``x <- x + attn(rms(x))``, ``x <- x +
+ffn(rms(x))``, a final RMSNorm and an untied ``lm_head``.  The first
+``first_k_dense_replace`` layers' ffn is a dense SwiGLU MLP (``mlp``);
+every later layer's is the expert layer (``moe``:
+``incubate/distributed/models/moe DroplessMoELayer``).
 
 Attention is multi-head latent attention (MLA) in its expanded training
 form, without a query latent (``q_lora_rank`` null):
@@ -45,15 +49,14 @@ import jax.numpy as jnp
 
 from .. import nn
 from ..core.tensor import Tensor
-from ..incubate.distributed.models.moe import DroplessMoELayer, SwiGLUMLP
 from ..nn import functional as F
-from ..nn.initializer import Normal
-from ..nn.layer_base import ParamAttr
 from ..ops.registry import op
 from .llama import _rope_tables as rope_tables      # cos, sin [T, D/2]
+from .moe_decoder import (MoeDecoderConfig, MoeDecoderForCausalLM,
+                          linear as _linear)
 
 
-class MlaMoeConfig:
+class MlaMoeConfig(MoeDecoderConfig):
     """Keys as the source's ``config.json`` names them, where it has one."""
 
     def __init__(self, vocab_size=1024, hidden_size=256, num_hidden_layers=4,
@@ -100,6 +103,17 @@ class MlaMoeConfig:
     def qk_head_dim(self):
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    def make_attention(self, layer_idx):
+        return MLAttention(self)
+
+    def make_ffn(self, layer_idx):
+        if layer_idx < self.first_k_dense_replace:
+            return self.dense_mlp(self.intermediate_size)
+        return self.expert_layer(
+            self.moe_intermediate_size, self.n_routed_experts,
+            self.num_experts_per_tok, self.n_shared_experts,
+            self.routed_scaling_factor)
+
 
 def apply_rope(x, cos, sin, interleave):
     """``x [..., T, N, D]`` rotated by ``cos``/``sin [T, D/2]``.
@@ -130,11 +144,6 @@ def _expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
         [kv_b[..., :nope],
          jnp.broadcast_to(k_rot, q.shape[:3] + k_rot.shape[3:])], axis=-1)
     return q, k, kv_b[..., nope:]
-
-
-def _linear(d_in, d_out, std):
-    return nn.Linear(d_in, d_out, bias_attr=False,
-                     weight_attr=ParamAttr(initializer=Normal(0.0, std)))
 
 
 class MLAttention(nn.Layer):
@@ -172,94 +181,10 @@ class MLAttention(nn.Layer):
         return self.o_proj(out.reshape([b, t, n * self.v_dim]))
 
 
-class MlaMoeDecoderLayer(nn.Layer):
-    """One block.  Returns ``(x, tokens_per_expert)``; a dense layer's
-    count is an empty array, so every layer has the same outputs."""
-
-    def __init__(self, config, layer_idx):
-        super().__init__()
-        c = config
-        std = c.initializer_range
-        out_std = std / math.sqrt(2 * c.num_hidden_layers)
-        self.ln_1 = nn.RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps)
-        self.attn = MLAttention(c)
-        self.ln_2 = nn.RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps)
-        if layer_idx < c.first_k_dense_replace:
-            self.mlp = SwiGLUMLP(c.hidden_size, c.intermediate_size, std,
-                                 out_std)
-            self.moe = None
-        else:
-            self.mlp = None
-            self.moe = DroplessMoELayer(
-                c.hidden_size, c.moe_intermediate_size, c.n_routed_experts,
-                c.num_experts_per_tok, c.n_shared_experts,
-                c.routed_scaling_factor, c.norm_topk_prob,
-                c.num_local_experts, c.expert_offset, std, out_std)
-
-    def forward(self, x):
-        x = x + self.attn(self.ln_1(x))
-        if self.moe is None:
-            return x + self.mlp(self.ln_2(x)), \
-                Tensor(jnp.zeros((0,), jnp.int32))
-        x = x + self.moe(self.ln_2(x))
-        return x, self.moe.tokens_per_expert
-
-
-class MlaMoeModel(nn.Layer):
-    def __init__(self, config):
-        super().__init__()
-        self.config = config
-        self.embeddings = nn.Embedding(
-            config.vocab_size, config.hidden_size,
-            weight_attr=ParamAttr(initializer=Normal(
-                0.0, config.initializer_range)))
-        self.layers = nn.LayerList([
-            MlaMoeDecoderLayer(config, i)
-            for i in range(config.num_hidden_layers)])
-        self.ln_f = nn.RMSNorm(config.hidden_size,
-                               epsilon=config.rms_norm_eps)
-        self.tokens_per_expert = None
-
-    def forward(self, input_ids):
-        x = self.embeddings(input_ids)
-        counts = []
-        for layer in self.layers:
-            x, c = layer(x)
-            if layer.moe is not None:
-                counts.append(c._data if isinstance(c, Tensor) else c)
-        self.tokens_per_expert = jnp.stack(counts) if counts else None
-        return self.ln_f(x)
-
-
-class MlaMoeForCausalLM(nn.Layer):
-    """``forward`` returns logits over the vocabulary slice held, ``loss``
-    is the shifted-label cross entropy, as ``GPTForCausalLM``'s."""
-
-    def __init__(self, config):
-        super().__init__()
-        self.config = config
-        self.model = MlaMoeModel(config)
-        self.lm_head = _linear(config.hidden_size, config.vocab_size,
-                               config.initializer_range)
-
-    def forward(self, input_ids):
-        return self.lm_head(self.model(input_ids))
-
-    def loss(self, logits, labels):
-        shift_logits = logits[:, :-1, :]
-        shift_labels = labels[:, 1:]
-        return F.cross_entropy(
-            shift_logits.reshape([-1, logits.shape[-1]]),
-            shift_labels.reshape([-1]))
-
-    def step_counters(self):
-        """What the last forward counted, for ``jit.TrainStep`` to hand
-        back beside the loss (``docs/PROFILER.md``):
-        ``moe_tokens_per_expert`` int32 ``[expert layers, local experts]``,
-        the tokens each expert held here received.  Their sum is the
-        assignments served here; none is ever dropped."""
-        counts = self.model.tokens_per_expert
-        return {} if counts is None else {"moe_tokens_per_expert": counts}
+class MlaMoeForCausalLM(MoeDecoderForCausalLM):
+    """The shell of ``models/moe_decoder.py`` over :class:`MLAttention`
+    (the class keeps its name: it is the root of the step's scope
+    paths)."""
 
 
 def mla_moe_tiny(**kw):

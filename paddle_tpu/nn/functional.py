@@ -1011,11 +1011,14 @@ def ctc_loss(log_probs, labels, input_lengths=None, label_lengths=None,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True):
+                                 training=True, window=None):
     """SDPA on [batch, seq, heads, dim] (paddle layout,
     python/paddle/nn/functional/flash_attention.py:125).  Uses the Pallas
     flash kernel on TPU when available, else XLA attention.  Attention
-    dropout draws from the active key stream."""
+    dropout draws from the active key stream.  ``key`` and ``value`` may
+    have fewer heads than ``query`` (a whole divisor: grouped KV heads,
+    never expanded); ``window`` (with ``is_causal``): a query sees itself
+    and the ``window - 1`` keys before it."""
     from ..ops import pallas
     use_drop = dropout_p > 0.0 and training
     drop_key = get_rng_key() if use_drop else None
@@ -1024,7 +1027,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     def _sdpa(query, key, value, attn_mask):
         return pallas.flash_attention(
             query, key, value, attn_mask=attn_mask, is_causal=is_causal,
-            dropout_p=dropout_p if use_drop else 0.0, dropout_key=drop_key)
+            dropout_p=dropout_p if use_drop else 0.0, dropout_key=drop_key,
+            window=window)
 
     return _sdpa(query, key, value, attn_mask)
 

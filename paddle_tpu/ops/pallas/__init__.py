@@ -61,22 +61,39 @@ def warn_fallback(kernel, shape, reason):
 
 
 def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
-                   dropout_key=None, scale=None):
+                   dropout_key=None, scale=None, window=None):
     """Reference XLA attention on [B, T, N, H] (paddle flash-attn layout).
 
     Matmuls stay in the input dtype (bf16 on the MXU) with f32 accumulation
     via ``preferred_element_type``; only the softmax runs in f32.  Upcasting
     the operands themselves would push the score/context matmuls onto the
     4x-slower f32 MXU path — measured as the dominant per-step cost on v5e.
+
+    The same function as the flash kernels compute: k and v may have
+    fewer heads than q (q head ``n`` reads kv head ``n // group``), and
+    ``window`` (causal only) keeps the keys ``0 <= t - j < window``.
     """
     if scale is None:
         scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], dtype=jnp.float32))
-    logits = jnp.einsum("btnh,bsnh->bnts", q, k,
-                        preferred_element_type=jnp.float32) * scale
+    grouped = q.shape[2] != k.shape[2]
+    if grouped:
+        b, t, n, h = q.shape
+        nkv = k.shape[2]
+        logits = jnp.einsum(
+            "btkgh,bskh->bkgts", q.reshape(b, t, nkv, n // nkv, h), k,
+            preferred_element_type=jnp.float32).reshape(b, n, t, -1) * scale
+    else:
+        logits = jnp.einsum("btnh,bsnh->bnts", q, k,
+                            preferred_element_type=jnp.float32) * scale
     if is_causal:
         t, s = logits.shape[-2], logits.shape[-1]
         causal = jnp.tril(jnp.ones((t, s), dtype=bool), k=s - t)
+        if window is not None:
+            causal &= ~jnp.tril(jnp.ones((t, s), dtype=bool),
+                                k=s - t - window)
         logits = jnp.where(causal, logits, jnp.finfo(jnp.float32).min)
+    elif window is not None:
+        raise ValueError("a window needs is_causal")
     if attn_mask is not None:
         if attn_mask.dtype == jnp.bool_:
             logits = jnp.where(attn_mask, logits, jnp.finfo(jnp.float32).min)
@@ -86,8 +103,14 @@ def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     if dropout_p > 0.0 and dropout_key is not None:
         keep = jax.random.bernoulli(dropout_key, 1.0 - dropout_p, probs.shape)
         probs = jnp.where(keep, probs / (1.0 - dropout_p), 0.0)
-    out = jnp.einsum("bnts,bsnh->btnh", probs.astype(q.dtype), v,
-                     preferred_element_type=jnp.float32)
+    if grouped:
+        out = jnp.einsum(
+            "bkgts,bskh->btkgh",
+            probs.astype(q.dtype).reshape(b, nkv, n // nkv, t, -1), v,
+            preferred_element_type=jnp.float32).reshape(b, t, n, -1)
+    else:
+        out = jnp.einsum("bnts,bsnh->btnh", probs.astype(q.dtype), v,
+                         preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
 
 
@@ -102,9 +125,10 @@ def _mesh_split(mesh):
     return data_axes(mesh), head_axis
 
 
-def _sharded_refusal(q, mesh):
+def _sharded_refusal(q, mesh, kv_heads=None):
     """Why :func:`flash_attention_sharded` cannot serve ``q [batch, seq,
-    heads, head_dim]`` where GSPMD partitions the computation, or None."""
+    heads, head_dim]`` (over ``kv_heads`` heads of k and v, q's own where
+    not given) where GSPMD partitions the computation, or None."""
     am = jax.sharding.get_abstract_mesh()
     if not am.empty:
         # spmd_pipeline at pp > 1: a second shard_map over the axes left
@@ -119,19 +143,22 @@ def _sharded_refusal(q, mesh):
     if q.shape[0] % ways:
         return (f"batch {q.shape[0]} does not divide over mesh axes "
                 f"{batch_axes} of {ways}")
-    if head_axis and q.shape[2] % mesh.shape[head_axis]:
-        return (f"{q.shape[2]} heads do not divide over {head_axis} of "
-                f"{mesh.shape[head_axis]}")
+    for heads in {q.shape[2], kv_heads or q.shape[2]}:
+        if head_axis and heads % mesh.shape[head_axis]:
+            return (f"{heads} heads do not divide over {head_axis} of "
+                    f"{mesh.shape[head_axis]}")
     return None
 
 
-def flash_attention_sharded(q, k, v, is_causal, mesh, interpret=False):
+def flash_attention_sharded(q, k, v, is_causal, mesh, interpret=False,
+                            window=None):
     """The flash kernels where GSPMD partitions the step: a ``shard_map``
     that makes EVERY axis of ``mesh`` manual (what a Mosaic kernel needs),
     each shard running the kernels on its rows and heads.  Attention mixes
-    neither batch rows nor heads, so no collective is inside; the backward
-    is the kernels' ``custom_vjp``, transposed per shard.  The caller has
-    asked :func:`_sharded_refusal`."""
+    neither batch rows nor heads, so no collective is inside (grouped KV
+    heads: a shard holds whole groups, q's heads and kv's being split the
+    same number of ways); the backward is the kernels' ``custom_vjp``,
+    transposed per shard.  The caller has asked :func:`_sharded_refusal`."""
     from jax.sharding import PartitionSpec as P
 
     from .attention_kernel import flash_attention_pallas
@@ -140,14 +167,21 @@ def flash_attention_sharded(q, k, v, is_causal, mesh, interpret=False):
     spec = P(batch_axes or None, None, head_axis, None)
     return jax.shard_map(
         lambda q, k, v: flash_attention_pallas(q, k, v, is_causal,
-                                               interpret=interpret),
+                                               interpret=interpret,
+                                               window=window),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(q, k, v)
 
 
 def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
-                    dropout_key=None, scale=None):
+                    dropout_key=None, scale=None, window=None):
     """Flash attention on [batch, seq, num_heads, head_dim].
+
+    k and v may have fewer heads than q, a whole divisor (grouped KV
+    heads: q head ``n`` reads kv head ``n // group``; nobody expands K and
+    V).  ``window`` (with ``is_causal``): a query sees itself and the
+    ``window - 1`` keys before it.  Kernel and XLA composition compute the
+    same function of both.
 
     When ``dropout_p > 0`` and no explicit key is given, a key is drawn from
     the global RNG (paddle.seed-controlled) — attention dropout must not be
@@ -174,7 +208,8 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
             reason = mesh = None
             if attn_mask is not None or dropout_p > 0.0 \
                     or scale is not None:
-                reason = "the kernel takes no attn_mask, dropout or scale"
+                reason = ("the kernel takes no attn_mask, dropout or scale "
+                          "(a causal sliding window it takes as window=)")
             elif is_causal and q.shape[1] != k.shape[1]:
                 # causal masking in the kernel is top-left aligned; for
                 # seq_q != seq_k the paddle/XLA semantics are bottom-right
@@ -182,23 +217,27 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
                 # take the kernel path
                 reason = "causal with seq_q != seq_k"
             elif not supports(q.shape[1], k.shape[1], q.shape[3],
-                              v.shape[3]):
+                              v.shape[3], q.shape[2], k.shape[2], window,
+                              is_causal):
                 # sequence and head widths are whole in every shard, so
                 # this answers for the sharded launch too
                 reason = "attention_kernel.supports() refuses the shape"
             elif _partitioned_by_gspmd():
                 from ...distributed.fleet.spmd import current_mesh
                 mesh = current_mesh()
-                reason = _sharded_refusal(q, mesh)
+                reason = _sharded_refusal(q, mesh, k.shape[2])
             if reason is None:
+                # a call without a window is the call it was
+                kw = {} if window is None else {"window": window}
                 if mesh is not None:
-                    return flash_attention_sharded(q, k, v, is_causal, mesh)
-                return flash_attention_pallas(q, k, v, is_causal)
+                    return flash_attention_sharded(q, k, v, is_causal, mesh,
+                                                   **kw)
+                return flash_attention_pallas(q, k, v, is_causal, **kw)
             warn_fallback("flash_attention",
                           f"q{tuple(q.shape)} k{tuple(k.shape)}", reason)
     return _xla_attention(q, k, v, attn_mask=attn_mask, is_causal=is_causal,
                           dropout_p=dropout_p, dropout_key=dropout_key,
-                          scale=scale)
+                          scale=scale, window=window)
 
 
 def grouped_matmul(xs, w, group_sizes):

@@ -23,9 +23,29 @@ same three kernels with a wider score dot and a larger VMEM allowance.  The
 bf16 causal, PR 26: 15.03 / 43.56 ms against 15.10 / 43.86; the MXU takes
 two passes over the contraction either way).
 
+Grouped KV heads are read in place: q may have ``group`` times the heads
+of k and v, and q head ``h`` reads kv head ``h // group`` through the block
+index (forward and dq: K and V cross HBM once a kv head, since
+consecutive q heads ask for the block that is already there; dk/dv: a
+third grid axis runs over the group's q heads and sums their parts in a
+float32 VMEM scratch, so no caller expands K and V and no [q heads, seq,
+head] gradient is ever written).
+
+A sliding ``window`` (causal only): query ``t`` sees keys ``j`` with ``0 <=
+t - j < window``.  A q block visits only the key blocks its rows can see
+(``_key_blocks``; dk/dv likewise only the q blocks that see the k block,
+``_query_blocks``), so the work follows the window and not the sequence;
+the mask is applied in every visited block, as the causal one is.  Window
+calls are named ``flash_window<W>_attention_{fwd,bwd_dq,bwd_dkv}`` so that a
+trace tells them from the full calls (``flash_attention_*``).
+
+Without a window and with equal head counts the three kernels are the
+programs they were: Mosaic is handed the same modules, source locations
+aside (``tests/test_tpu_lowering.py``).
+
 Constraints (else the caller falls back to the XLA composition): seq divisible
-by the block size, head widths as :func:`supports` lists them.  Attention
-dropout and additive masks use the fallback path.
+by the block size, head widths and counts as :func:`supports` lists them.
+Attention dropout and additive masks use the fallback path.
 """
 
 import functools
@@ -54,13 +74,20 @@ def _pick_block(seq, preferred):
 _SPLIT_HEADS = {(192, 128)}
 
 
-def supports(seq_q, seq_k, head_dim, v_head_dim=None):
+def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
+             kv_heads=None, window=None, causal=True):
     """``head_dim`` is q's and k's width, ``v_head_dim`` v's (the same
-    where it is not given)."""
+    where it is not given).  ``q_heads`` over ``kv_heads``: any whole
+    multiple (grouped KV heads).  ``window``: at least 1, causal only, and
+    q and k of one length."""
     if v_head_dim is None or v_head_dim == head_dim:
         heads_ok = head_dim <= 128
     else:
         heads_ok = (head_dim, v_head_dim) in _SPLIT_HEADS
+    if q_heads is not None and kv_heads is not None:
+        heads_ok = heads_ok and kv_heads > 0 and q_heads % kv_heads == 0
+    if window is not None:
+        heads_ok = heads_ok and causal and window >= 1 and seq_q == seq_k
     return (heads_ok
             and _pick_block(seq_q, DEFAULT_BLOCK_Q) is not None
             and _pick_block(seq_k, DEFAULT_BLOCK_K) is not None)
@@ -113,26 +140,64 @@ def _rows(ref, i, block):
     return ref[0, pl.ds(pl.multiple_of(i * block, block), block), :]
 
 
-def _mask_below_diagonal(s, row0, col0, row_axis):
-    """Keep s where (row0 + row) >= (col0 + col); ``row_axis`` is the axis
-    of ``s`` that runs over queries."""
+def _mask_below_diagonal(s, row0, col0, row_axis, window=None):
+    """Keep s where (row0 + row) >= (col0 + col), and with a ``window``
+    where row - col < window besides; ``row_axis`` is the axis of ``s``
+    that runs over queries.  A row wholly masked in a block it visits
+    before its first visible key leaves m at -1e30 and p at 1; the first
+    block with a visible key (every row sees itself) rescales that to
+    nothing (alpha = exp(-1e30 - m) = 0)."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, row_axis)
     cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - row_axis)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+    keep = rows >= cols
+    if window is not None:
+        keep = keep & (rows - cols < window)
+    return jnp.where(keep, s, _NEG_INF)
 
 
-def _num_key_blocks(qi, block_q, block_k, seq_k, causal):
-    """Key blocks one q block attends to: causal, those past its last row
-    are fully masked and skipped."""
-    if causal:
-        return ((qi + 1) * block_q + block_k - 1) // block_k
-    return seq_k // block_k
+def _key_blocks(qi, block_q, block_k, seq_k, causal, window=None):
+    """``(first, end)``: the key blocks q block ``qi`` attends to.  Causal,
+    those past its last row are fully masked and skipped; with a window,
+    those before the first key its FIRST row sees as well."""
+    if not causal:
+        return 0, seq_k // block_k
+    end = ((qi + 1) * block_q + block_k - 1) // block_k
+    if window is None:
+        return 0, end
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k, end
+
+
+def _query_blocks(ki, block_q, block_k, num_qb, causal, window=None):
+    """``(first, end)``: the q blocks that see key block ``ki``.  Causal,
+    those before its diagonal contribute nothing; with a window, those
+    after the last row that sees its LAST key neither."""
+    if not causal:
+        return 0, num_qb
+    first = (ki * block_k) // block_q
+    if window is None:
+        return first, num_qb
+    last_row = (ki + 1) * block_k - 1 + (window - 1)
+    return first, jnp.minimum(last_row // block_q + 1, num_qb)
+
+
+def _kernel_name(kernel, window):
+    return (f"flash_attention_{kernel}" if window is None
+            else f"flash_window{window}_attention_{kernel}")
+
+
+def _kv_head(group):
+    """Index map of a whole-sequence K or V block for q program ``b``:
+    kv head ``b // group`` of the flattened [batch * heads] axis (heads
+    are innermost there, and q's are ``group`` times kv's)."""
+    if group == 1:
+        return lambda b, i: (b, 0, 0)
+    return lambda b, i: (b // group, 0, 0)
 
 
 # ---------------------------------------------------------------- forward --
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                scale):
+                scale, window=None):
     """One (batch*head, q-block) program: online softmax over key blocks."""
     q = q_ref[0]                                       # [Bq, H]
     block_q = q.shape[0]
@@ -144,7 +209,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         v = _rows(v_ref, j, block_k)
         s = _dot(q, k, _NT) * scale                    # [Bq, Bk]
         if causal:
-            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0)
+            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0, window)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -155,8 +220,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     o0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    num_kb = _num_key_blocks(qi, block_q, block_k, k_ref.shape[1], causal)
-    o_acc, m, l = jax.lax.fori_loop(0, num_kb, body, (o0, m0, l0))
+    first, end = _key_blocks(qi, block_q, block_k, k_ref.shape[1], causal,
+                             window)
+    o_acc, m, l = jax.lax.fori_loop(first, end, body, (o0, m0, l0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (o_acc / l).astype(o_ref.dtype)
     # lse is [bn, seq, 1]: a (1, block_q, 1) block per program satisfies the
@@ -164,19 +230,21 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     lse_ref[0] = m + jnp.log(l)
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+               window=None):
     bn, seq_q, head = q.shape
     seq_k, head_v = k.shape[1], v.shape[2]
+    kv_head = _kv_head(bn // k.shape[0])
     grid = (bn, seq_q // block_q)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        name="flash_attention_fwd",
+                          scale=scale, window=window),
+        name=_kernel_name("fwd", window),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_k, head_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, seq_k, head), kv_head),
+            pl.BlockSpec((1, seq_k, head_v), kv_head),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, head_v), lambda b, i: (b, i, 0)),
@@ -195,7 +263,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 # --------------------------------------------------------------- backward --
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, block_k, causal, scale):
+                   *, block_k, causal, scale, window=None):
     q = q_ref[0]
     do = do_ref[0]
     block_q = q.shape[0]
@@ -208,26 +276,34 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         v = _rows(v_ref, j, block_k)
         s = _dot(q, k, _NT) * scale
         if causal:
-            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0)
+            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0, window)
         p = jnp.exp(s - lse)                                   # [Bq, Bk]
         ds = p * (_dot(do, v, _NT) - delta)
         return dq_acc + _dot(ds.astype(k.dtype), k, _NN)
 
-    num_kb = _num_key_blocks(qi, block_q, block_k, k_ref.shape[1], causal)
-    dq = jax.lax.fori_loop(0, num_kb, body, jnp.zeros(q.shape, jnp.float32))
+    first, end = _key_blocks(qi, block_q, block_k, k_ref.shape[1], causal,
+                             window)
+    dq = jax.lax.fori_loop(first, end, body, jnp.zeros(q.shape, jnp.float32))
     # dS = P * (dP - delta) * scale: the scale goes on once, at the end
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, block_q, causal, scale):
-    """One (batch*head, k-block) program over the q blocks that see it.
+                    dk_ref, dv_ref, *acc, block_q, causal, scale,
+                    window=None, group=1):
+    """One (batch*kv head, k-block) program over the q blocks that see it.
 
     The scores are formed transposed, [Bk, Bq] = K Q^T, so that P^T dO and
     dS^T Q are plain matmuls: a dot that contracts dimension 0 of both
     operands would have Mosaic transpose a [Bq, Bk] tile for each.  lse and
     delta then come as rows, one [1, Bq] row of a [num_qb, Bq] block per q
-    block (a [seq_q, 1] block would be lane-padded 128x in VMEM)."""
+    block (a [seq_q, 1] block would be lane-padded 128x in VMEM).
+
+    Grouped KV heads: the grid has a third, innermost axis over the
+    ``group`` q heads that read this kv head; each step holds ONE q head's
+    Q, dO and statistics, and the parts are summed in the float32 scratch
+    ``acc`` (the output block stays where it is while that axis runs, and
+    is written once, from float32, at its last step)."""
     k = k_ref[0]                                               # [Bk, H]
     v = v_ref[0]
     block_k = k.shape[0]
@@ -242,7 +318,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         delta = delta_ref[0, pl.ds(i, 1), :]
         st = _dot(k, q, _NT) * scale                           # [Bk, Bq]
         if causal:
-            st = _mask_below_diagonal(st, i * block_q, ki * block_k, 1)
+            st = _mask_below_diagonal(st, i * block_q, ki * block_k, 1,
+                                      window)
         pt = jnp.exp(st - lse)
         dv_new = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
         dst = pt * (_dot(v, do, _NT) - delta)
@@ -252,16 +329,50 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
     # q blocks before this k block's diagonal contribute nothing
-    first = (ki * block_k) // block_q if causal else 0
-    dk, dv = jax.lax.fori_loop(first, num_qb, body, (dk0, dv0))
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    first, end = _query_blocks(ki, block_q, block_k, num_qb, causal, window)
+    dk, dv = jax.lax.fori_loop(first, end, body, (dk0, dv0))
+    if group == 1:
+        dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    dk_sum, dv_sum = acc
+    g = pl.program_id(2)
+
+    @pl.when(g == 0)
+    def _():
+        dk_sum[...] = dk
+        dv_sum[...] = dv
+
+    @pl.when(g > 0)
+    def _():
+        dk_sum[...] += dk
+        dv_sum[...] += dv
+
+    @pl.when(g == group - 1)
+    def _():
+        dk_ref[0] = (dk_sum[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0] = dv_sum[...].astype(dv_ref.dtype)
+
+
+def _dkv_launch(bn_kv, num_kb, group, block_k, head, head_v):
+    """(grid, index maps, scratch) of the dk/dv call: ``q_head`` places a
+    whole-sequence block of the q head a step reads, ``k_block`` the k
+    block a program owns."""
+    if group == 1:
+        return ((bn_kv, num_kb), lambda b, j: (b, 0, 0),
+                lambda b, j: (b, j, 0), {})
+    scratch = [pltpu.VMEM((block_k, head), jnp.float32),
+               pltpu.VMEM((block_k, head_v), jnp.float32)]
+    return ((bn_kv, num_kb, group), lambda b, j, g: (b * group + g, 0, 0),
+            lambda b, j, g: (b, j, 0), {"scratch_shapes": scratch})
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
-               interpret):
+               interpret, window=None):
     bn, seq_q, head = q.shape
     seq_k, head_v = k.shape[1], v.shape[2]
+    group = bn // k.shape[0]
+    kv_head = _kv_head(group)
     num_qb = seq_q // block_q
     params = _compiler_params(head, head_v)
     # delta = rowsum(dO * O) — cheap elementwise, leave to XLA fusion
@@ -270,13 +381,13 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale),
-        name="flash_attention_bwd_dq",
+                          scale=scale, window=window),
+        name=_kernel_name("bwd_dq", window),
         grid=(bn, num_qb),
         in_specs=[
             pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, head), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, seq_k, head_v), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, seq_k, head), kv_head),
+            pl.BlockSpec((1, seq_k, head_v), kv_head),
             pl.BlockSpec((1, block_q, head_v), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
@@ -288,28 +399,31 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     )(q, k, v, do, lse, delta)
 
     rows = (bn, num_qb, block_q)
+    grid, q_head, k_block, scratch = _dkv_launch(
+        k.shape[0], seq_k // block_k, group, block_k, head, head_v)
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale),
-        name="flash_attention_bwd_dkv",
-        grid=(bn, seq_k // block_k),
+                          scale=scale, window=window, group=group),
+        name=_kernel_name("bwd_dkv", window),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((1, seq_q, head), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, head_v), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, seq_q, head_v), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, num_qb, block_q), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, num_qb, block_q), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, seq_q, head), q_head),
+            pl.BlockSpec((1, block_k, head), k_block),
+            pl.BlockSpec((1, block_k, head_v), k_block),
+            pl.BlockSpec((1, seq_q, head_v), q_head),
+            pl.BlockSpec((1, num_qb, block_q), q_head),
+            pl.BlockSpec((1, num_qb, block_q), q_head),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, head), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, head_v), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, head), k_block),
+            pl.BlockSpec((1, block_k, head_v), k_block),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        **scratch,
         **params,
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
@@ -317,9 +431,9 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
 
 # ------------------------------------------------------------- public API --
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_bnsh(q, k, v, causal, scale, interpret):
-    out, _ = _fwd_rule(q, k, v, causal, scale, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_bnsh(q, k, v, causal, scale, interpret, window=None):
+    out, _ = _fwd_rule(q, k, v, causal, scale, interpret, window)
     return out
 
 
@@ -377,15 +491,21 @@ def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None):
     return validate
 
 
-def _tuned_blocks(q, k, v, causal, scale, interpret):
+def _tuned_blocks(q, k, v, causal, scale, interpret, window=None):
     """Autotuned (block_q, block_k) for this shape (FLAGS_use_autotune);
     the heuristic (128-preferred divisor) wins with the flag off."""
     from . import autotune
 
     bn, seq_q, head = q.shape
     seq_k, head_v = k.shape[1], v.shape[2]
+    group = bn // k.shape[0]
     # one head width keeps the key it had; a second width joins it
     heads = head if head_v == head else (head, head_v)
+    key = (seq_q, seq_k, heads, str(q.dtype), causal)
+    if window is not None or group > 1:
+        # a window or grouped heads join the key; a call with neither
+        # keeps the one it had
+        key += (window, group)
     cands = _block_candidates(seq_q, seq_k)
 
     def measure(cand):
@@ -393,22 +513,23 @@ def _tuned_blocks(q, k, v, causal, scale, interpret):
         import numpy as _np
 
         rng = _np.random.RandomState(0)
-        shape_q = (min(bn, 8), seq_q, head)
-        shape_k = (min(bn, 8), seq_k, head)
+        kv_heads = max(min(bn, 8) // group, 1)
+        shape_q = (kv_heads * group, seq_q, head)
+        shape_k = (kv_heads, seq_k, head)
         qq = jnp.asarray(rng.rand(*shape_q), q.dtype)
         kk = jnp.asarray(rng.rand(*shape_k), q.dtype)
         vv = jnp.asarray(rng.rand(*shape_k[:2], head_v), q.dtype)
-        out, lse = _flash_fwd(qq, kk, vv, causal, scale, bq, bk, interpret)
+        out, lse = _flash_fwd(qq, kk, vv, causal, scale, bq, bk, interpret,
+                              window)
         # measure (and VMEM-validate) the backward too: a candidate that
         # fits the fwd can overflow the bwd's working set, and training
         # pays both
         grads = _flash_bwd(qq, kk, vv, out, lse, out, causal, scale,
-                           bq, bk, interpret)
+                           bq, bk, interpret, window)
         jax.block_until_ready((out, grads))  # noqa: H001 (autotune timing sync — measurement, not a serving path)
 
     return autotune.pick(
-        "flash_attention",
-        (seq_q, seq_k, heads, str(q.dtype), causal),
+        "flash_attention", key,
         cands, measure=measure,
         validate=_vmem_validate(seq_q, seq_k, head, q.dtype, head_v=head_v))
 
@@ -420,19 +541,22 @@ def _tuned_blocks(q, k, v, causal, scale, interpret):
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
-def _fwd_rule(q, k, v, causal, scale, interpret):
-    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret)
-    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret)
+def _fwd_rule(q, k, v, causal, scale, interpret, window=None):
+    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret,
+                                     window)
+    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+                          window)
     out = checkpoint_name(out, SAVED_BY_NAME[0])
     lse = checkpoint_name(lse, SAVED_BY_NAME[1])
     return out, (q, k, v, out, lse)
 
 
-def _bwd_rule(causal, scale, interpret, res, do):
+def _bwd_rule(causal, scale, interpret, window, res, do):
     q, k, v, out, lse = res
-    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret)
+    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret,
+                                     window)
     return _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
-                      interpret)
+                      interpret, window)
 
 
 _flash_attention_bnsh.defvjp(_fwd_rule, _bwd_rule)
@@ -460,6 +584,20 @@ def _engine_cases(engine):
 
     yield registry.KernelCase(f"fwd[s{seq}]", fwd, (x, x, x), None)
     yield registry.KernelCase(f"vjp[s{seq}]", vjp, (x, x, x), None)
+    # a sliding window (an eighth of the context) over grouped KV heads
+    # (every q head on one kv head): the block ranges that follow the
+    # window, the kv block index, dk/dv's third grid axis and its scratch
+    window = max(seq // 8, 1)
+    one_kv = sds((engine.max_batch, seq, 1, h), engine.dtype)
+
+    def vjp_window(q, k, v):
+        def loss(*a):
+            return jnp.sum(flash_attention_pallas(
+                *a, is_causal=True, window=window).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    yield registry.KernelCase(f"vjp_window[s{seq},w{window},{n}over1]",
+                              vjp_window, (x, one_kv, one_kv), None)
     # latent attention's expanded form: q and k carry a rotary part half
     # as wide again as the head (128 + 64 over 128)
     wide = h + h // 2
@@ -479,20 +617,33 @@ def _engine_cases(engine):
     supports=supports,
     grad=True)
 def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
-                           interpret=False):
-    """q, k: [batch, seq, num_heads, head_dim]; v: [batch, seq, num_heads,
-    v_head_dim] (paddle flash-attn layout; the two widths as
-    :func:`supports` lists them).
+                           interpret=False, window=None):
+    """q: [batch, seq, num_heads, head_dim]; k: [batch, seq, kv_heads,
+    head_dim]; v: [batch, seq, kv_heads, v_head_dim] (paddle flash-attn
+    layout; the two widths as :func:`supports` lists them).  ``kv_heads``
+    divides ``num_heads``: q head ``h`` attends with kv head ``h //
+    (num_heads / kv_heads)``, and K and V are never expanded.  ``window``
+    (causal only): a query sees itself and the ``window - 1`` keys before
+    it; one that covers the sequence is plain causal attention and runs as
+    such.
 
     Returns [batch, seq, num_heads, v_head_dim]; differentiable.
     """
     b, sq, n, h = q.shape
-    sk, hv = k.shape[1], v.shape[3]
+    sk, nkv, hv = k.shape[1], k.shape[2], v.shape[3]
+    if n % nkv or (window is not None
+                   and not (is_causal and window >= 1 and sq == sk)):
+        raise ValueError(
+            f"flash attention does not serve q{tuple(q.shape)} "
+            f"k{tuple(k.shape)} causal={is_causal} window={window}: kv "
+            f"heads must divide q heads, a window needs causal "
+            f"self-attention")
+    window = None if window is None or window >= sk else int(window)
     if scale is None:
         scale = 1.0 / (h ** 0.5)
     qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, h)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * n, sk, h)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * n, sk, hv)
+    kt = k.transpose(0, 2, 1, 3).reshape(b * nkv, sk, h)
+    vt = v.transpose(0, 2, 1, 3).reshape(b * nkv, sk, hv)
     out = _flash_attention_bnsh(qt, kt, vt, bool(is_causal), float(scale),
-                                interpret)
+                                interpret, window)
     return out.reshape(b, n, sq, hv).transpose(0, 2, 1, 3)

@@ -88,9 +88,9 @@ def test_registry_lowers_for_tpu_where_supported():
         lowered += 1
     # ragged + ragged_quant over 6 buckets x 3 engines, flash and
     # layernorm fwd+vjp x 3, flash at 192 | 128 (latent attention's
-    # expanded form) fwd+vjp on the head_dim-128 engine, the two
-    # training layernorm shapes
-    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 2 * 3 + 2
+    # expanded form) fwd+vjp on the head_dim-128 engine, flash under a
+    # window over one kv head vjp x 3, the two training layernorm shapes
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 2 * 3 + 2
 
 
 def test_refusals_are_declared_only_where_needed():
@@ -263,6 +263,97 @@ def test_one_chip_qkv_projection_stays_one_dot(monkeypatch):
     assert dots.count(("tensor<4x2048x4096xbf16>, tensor<4096x12288xbf16>",
                        "tensor<4x2048x12288xbf16>")) == 1, dots
     assert "3x32x128x" not in "".join(d[0] for d in dots)
+
+
+# The three flash kernels as Mosaic gets them (the module inside each
+# ``tpu_custom_call``, its source locations stripped: they shift with
+# every edit to the kernel file and are no part of the program), hashed, at
+# the two shapes the benchmark's cells call them with.  Read at PR 29's tree
+# (eff4393) and unchanged by PR 30, which taught the kernels windows and
+# grouped KV heads: a call with neither is the program it was.  A new JAX
+# may print a module differently; then read them again at a tree whose
+# kernels are known to be these (``_mosaic_bodies`` of the same calls).
+_PLAIN_FLASH_BODIES = {
+    # gpt3-6.7b-train*.seq2048: [4, 2048, 32, 128] bf16, causal
+    ((4, 2048, 32, 128), 128): {
+        "flash_attention_fwd": "96c8f2b52eef27b1",
+        "flash_attention_bwd_dq": "486445c074163aa2",
+        "flash_attention_bwd_dkv": "37049469e0b88a0e"},
+    # kanana-2-30b-a3b-train-ep8.seq8192: [2, 8192, 32, 192 | 128]
+    ((2, 8192, 32, 192), 128): {
+        "flash_attention_fwd": "ca2d4efac45765b2",
+        "flash_attention_bwd_dq": "cf8a1bb56f8d69dc",
+        "flash_attention_bwd_dkv": "705ff53ab5f0e5cd"},
+}
+
+
+def _mosaic_bodies(text):
+    """{kernel name: hash of its Mosaic module without locations} of a
+    lowered text's ``tpu_custom_call``s."""
+    import base64
+    import hashlib
+    import re
+
+    from jaxlib.mlir import ir
+
+    out = {}
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line:
+            continue
+        name = re.search(r'kernel_name = "([^"]+)"', line).group(1)
+        blob = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                         line).group(1)
+        ctx = ir.Context()
+        ctx.allow_unregistered_dialects = True
+        asm = ir.Module.parse(base64.b64decode(blob), ctx).operation.get_asm(
+            enable_debug_info=False)
+        out[name] = hashlib.sha256(asm.encode()).hexdigest()[:16]
+    return out
+
+
+def _flash_grads_lowered(q, k, v, **kw):
+    from paddle_tpu.ops.pallas.attention_kernel import flash_attention_pallas
+
+    def f(q, k, v):
+        def loss(*a):
+            return jnp.sum(flash_attention_pallas(
+                *a, is_causal=True, **kw).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    return jax.jit(f).trace(q, k, v).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("shape,v_dim", list(_PLAIN_FLASH_BODIES))
+def test_plain_flash_calls_lower_as_at_the_parent(shape, v_dim):
+    """No window, equal head counts: the GPT cells' and kanana's flash
+    calls hand Mosaic the modules they handed it before the kernels knew
+    of windows and groups (the whole steps' lowered texts were compared
+    with the parent's by script, PR 30: CHANGES.md)."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct(shape[:3] + (v_dim,), jnp.bfloat16)
+    assert _mosaic_bodies(_flash_grads_lowered(q, q, v)) \
+        == _PLAIN_FLASH_BODIES[(shape, v_dim)]
+    # a window that covers the sequence is no window
+    assert _mosaic_bodies(_flash_grads_lowered(q, q, v, window=shape[1])) \
+        == _PLAIN_FLASH_BODIES[(shape, v_dim)]
+
+
+def test_window_and_grouped_calls_are_other_programs():
+    """The new cell's two calls: their own names and their own modules,
+    and K and V at eight heads all the way into the custom calls."""
+    q72 = jax.ShapeDtypeStruct((1, 8192, 72, 128), jnp.bfloat16)
+    q48 = jax.ShapeDtypeStruct((1, 8192, 48, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
+    window = _mosaic_bodies(_flash_grads_lowered(q72, kv, kv, window=512))
+    full = _mosaic_bodies(_flash_grads_lowered(q48, kv, kv))
+    assert set(window) == {f"flash_window512_attention_{k}"
+                           for k in ("fwd", "bwd_dq", "bwd_dkv")}
+    assert set(full) == {f"flash_attention_{k}"
+                         for k in ("fwd", "bwd_dq", "bwd_dkv")}
+    known = {h for bodies in _PLAIN_FLASH_BODIES.values()
+             for h in bodies.values()}
+    assert not known & (set(window.values()) | set(full.values()))
+    assert "tensor<8x8192x128xbf16>" in _flash_grads_lowered(q48, kv, kv)
 
 
 @pytest.mark.slow

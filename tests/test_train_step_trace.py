@@ -161,6 +161,52 @@ def test_train_step_remat_is_by_block_and_changes_no_value(compiled_names):
             float(plain(t, t)._data), rel=1e-6)
 
 
+@pytest.fixture(scope="module")
+def laguna_names():
+    """Op names of a compiled step of the decoder whose layers differ in
+    attention (``models/laguna.py``), layers rematerialised with the flash
+    forward's results kept, as its cell runs it."""
+    from paddle_tpu.models.laguna import laguna_tiny
+
+    paddle.seed(0)
+    model = laguna_tiny(num_local_experts=4, expert_offset=2)
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                     remat=["flash_attention_out", "flash_attention_lse"])
+    t = paddle.to_tensor(_batch(rows=2, length=32))
+    return _op_names(step.lower(t, t).compile().as_text())
+
+
+@pytest.mark.parametrize("inner,layers", [
+    ("attn_full", {"layers.0", "layers.4"}),
+    ("attn_window", {"layers.1", "layers.2", "layers.3"}),
+    ("attn_gate", {f"layers.{i}" for i in range(5)})])
+def test_attention_names_its_kind_and_its_gate_inside_attn(laguna_names,
+                                                           inner, layers):
+    """``attn`` as ever round the whole of attention; inside it
+    ``attn_window`` or ``attn_full`` by the layer's kind, and ``attn_gate``
+    round the gate's matmul and product, forward and backward."""
+    hits = [(n, segs) for n, segs in laguna_names if inner in segs]
+    assert hits, f"no op is scoped {inner!r}"
+    for n, segs in hits:
+        assert "attn" in segs and segs.index("attn") < segs.index(inner), n
+        if inner == "attn_gate":
+            assert {"attn_full", "attn_window"} & set(segs), n
+    assert {seg for _, segs in hits for seg in segs
+            if seg.startswith("layers.")} == layers
+    assert any("transpose(" in n for n, _ in hits)
+    assert any("transpose(" not in n for n, _ in hits)
+
+
+@pytest.mark.parametrize("part", ["attn", "mlp", "ln_1", "ln_2", "ln_f",
+                                  "embeddings", "lm_head", "loss",
+                                  "optimizer", "router", "dispatch",
+                                  "combine", "experts", "shared_experts"])
+def test_the_shell_and_the_expert_layer_keep_their_scopes(laguna_names,
+                                                          part):
+    assert any(part in segs for _, segs in laguna_names), part
+
+
 def test_scopes_change_nothing_but_metadata(monkeypatch):
     """The optimized HLO with the scopes every ``with jax.named_scope``
     opens (the Layer tree's, ``lm_head``, ``loss``, ``grad_clip``) is the
