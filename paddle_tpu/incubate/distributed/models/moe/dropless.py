@@ -21,15 +21,31 @@ work follows the rows that are really there:
    follow ``group_sizes``, with its transposes for the backward; elsewhere
    ``jax.lax.ragged_dot``), and a gather back with the routing weights.
 
-The buffers hold the worst case (every assignment local: ``S * min(k,
-num_local)`` rows, :func:`sorted_rows`: a token's experts are distinct, so
-it has at most ``num_local`` of them here; ``S * k`` where the chip holds
-at least ``k`` experts); the arithmetic does not: rows behind the last group belong to no group and
-no tile of the grouped matmul visits them.  What such rows hold is never
-defined and never used: wherever sorted rows go back to their tokens, the
-assignments served elsewhere are masked inside that reduction.  The
-gathers are permutations whose inverse is known, so their transposes are
-gathers too (``custom_vjp``): no scatter-add in either direction.
+The buffers hold the rows of a BUCKET, not the worst case's
+(:func:`routed_swiglu_experts`, the layer's routed block).  The worst case
+is every assignment local: ``S * min(k, num_local)`` rows
+(:func:`sorted_rows`: a token's experts are distinct, so it has at most
+``num_local`` of them here).  What a chip that holds ``num_local`` of ``E``
+experts expects is ``S * k * num_local / E``, 3% to 12% of that where the
+layer is cut 32 or 8 ways.  :func:`row_buckets` states ONE static row
+count between the two (twice the expectation, in whole tiles of the
+grouped matmul) before the worst case itself; the compiled step takes it
+where it holds ``sum(counts)`` (``lax.switch`` on the device, the host
+reads no count) and every buffer between the tokens and the tokens again
+has that many rows.  Dropless stays dropless: the last bucket is the worst
+case, so no routing, however skewed, loses a row.
+Where nothing is cut (``num_local == E``) the expectation IS the worst
+case, there is one bucket and no switch.
+
+Within a bucket rows behind the last group belong to no group and no tile
+of the grouped matmul visits them.  What such rows hold is never defined
+and never used: wherever sorted rows go back to their tokens, they are
+masked inside that reduction.  The gathers are permutations whose inverse
+is known, so their transposes are gathers too (``custom_vjp``): no
+scatter-add in either direction.  Back at the tokens a small bucket's rows
+are summed BY RUN (:func:`_sum_by_runs`: the rows in token order, each run
+of a token added up, one row gathered a token: ``S + R`` rows move); the
+worst case's one row a SLOT (:func:`_sum_by_slots`: ``k * S`` rows).
 
 On one chip the layer runs without its exchange: the tokens whose experts
 live elsewhere would be sent there, and theirs would arrive here.  Nothing
@@ -37,6 +53,7 @@ stands in for that traffic; a token none of whose experts is local leaves
 this layer with nothing from the routed experts.
 """
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +153,18 @@ def _served(inverse, counts, tokens):
     return (inverse < jnp.sum(counts)).reshape(-1, tokens)
 
 
+def _sum_by_slots(rows, weights, inverse, counts, tokens):
+    """Sorted ``rows [R, H]`` -> ``[S, H]``: each token's sum over its
+    slots served here, times ``weights [S, k]`` where given.  One row is
+    gathered a SLOT, ``k * S`` in all; products and the sum in float32,
+    rounded once."""
+    slots = _slots(_unsort(rows, inverse), _served(inverse, counts, tokens))
+    if weights is not None:
+        w32 = weights.astype(jnp.float32)
+        slots = [y * w32[:, j:j + 1] for j, y in enumerate(slots)]
+    return sum(slots).astype(rows.dtype)
+
+
 @jax.custom_vjp
 def dispatch(x, order, inverse, counts):
     """``x [S, H]`` -> ``[S * k, H]`` in sorted order: row ``j`` is the
@@ -152,18 +181,18 @@ def _dispatch_fwd(x, order, inverse, counts):
 
 def _dispatch_bwd(res, g):
     inverse, counts, tokens = res
-    dx = sum(_slots(_unsort(g, inverse), _served(inverse, counts, tokens)))
-    return dx.astype(g.dtype), None, None, None
+    return _sum_by_slots(g, None, inverse, counts, tokens), None, None, None
 
 
 dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-def grouped_matmul(xs, w, counts):
-    """``xs [A, K]`` @ ``w [G, K, N]`` by groups of ``counts`` rows."""
+def grouped_matmul(xs, w, counts, transpose_w=False):
+    """``xs [A, K]`` @ ``w [G, K, N]`` by groups of ``counts`` rows (``w
+    [G, N, K]`` with ``transpose_w``)."""
     from .....ops import pallas
 
-    return pallas.grouped_matmul(xs, w, counts)
+    return pallas.grouped_matmul(xs, w, counts, transpose_w)
 
 
 @jax.custom_vjp
@@ -200,10 +229,205 @@ def _combine_bwd(res, g):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+def _gate(gu):
+    inter = gu.shape[1] // 2
+    return jax.nn.silu(gu[:, :inter]) * gu[:, inter:]
+
+
 def swiglu_experts(xs, w_gate_up, w_down, counts):
     """Every local expert's gated MLP on its own rows: ``w_gate_up [G, H,
     2I]`` (gate | up), ``w_down [G, I, H]``."""
-    inter = w_down.shape[1]
+    return grouped_matmul(_gate(grouped_matmul(xs, w_gate_up, counts)),
+                          w_down, counts)
+
+
+def _swiglu_experts_vjp(xs, w_gate_up, w_down, counts):
+    """``(ys, d_ys -> (d_xs, d_gate_up, d_down))``: what ``jax.vjp`` of
+    :func:`swiglu_experts` gives, the grouped matmuls' transposes called
+    by name (``ops.pallas.grouped_matmul_dw`` says why)."""
+    from .....ops.pallas import grouped_matmul_dw
+
     gu = grouped_matmul(xs, w_gate_up, counts)
-    h = jax.nn.silu(gu[:, :inter]) * gu[:, inter:]
-    return grouped_matmul(h, w_down, counts)
+    h, gate_vjp = jax.vjp(_gate, gu)
+
+    def vjp(d_ys):
+        d_gu, = gate_vjp(grouped_matmul(d_ys, w_down, counts, True))
+        return (grouped_matmul(d_gu, w_gate_up, counts, True),
+                grouped_matmul_dw(xs, d_gu, counts),
+                grouped_matmul_dw(h, d_ys, counts))
+
+    return grouped_matmul(h, w_down, counts), vjp
+
+
+# ------------------------------------------- the routed block, by bucket --
+
+ROW_TILE = 512      # the grouped matmul's row tile (``ops.pallas``)
+
+
+def row_buckets(tokens, top_k, num_local, num_experts):
+    """The static row counts a layer's buffers may have, ascending; the
+    last is the worst case (:func:`sorted_rows`).
+
+    ``tokens * top_k * num_local / num_experts`` rows are expected here;
+    the small bucket holds twice that, in whole row tiles of the grouped
+    matmul.  It is left out where it would reach the worst case: where
+    nothing is cut (``num_local == num_experts``) the worst case is the
+    only bucket.
+
+    One small bucket, not a ladder (PR 31, on the v5e): every bucket is a
+    branch of two switches a layer, and each cost both expert cells about
+    2 s of set-up (tracing, and 27 MB of program to load) on 41; with
+    buckets at 1.25, 2 and 4 times the expectation a seeded router with no
+    balancing term took the first in 99% of layer steps and never passed
+    1.6 times; laguna's step was 0.9% faster than with this one bucket,
+    kanana's 0.5% slower."""
+    worst = sorted_rows(tokens, top_k, num_local)
+    rows = -(-2 * tokens * top_k * num_local // num_experts)
+    rows = -(-rows // ROW_TILE) * ROW_TILE
+    return (rows, worst) if rows < worst else (worst,)
+
+
+def bucket_of(counts, buckets):
+    """Index (int32 scalar, on the device) of the smallest of ``buckets``
+    that holds the ``sum(counts)`` rows served here."""
+    return jnp.sum(jnp.sum(counts) > jnp.asarray(buckets[:-1], jnp.int32),
+                   dtype=jnp.int32)
+
+
+def _run_passes(top_k, num_local):
+    """Doubling passes that cover a token's longest run of rows here."""
+    return (min(top_k, num_local) - 1).bit_length()
+
+
+def _by_runs(rows, tokens, top_k, num_local):
+    """Whether a bucket of ``rows`` goes back to its tokens by runs or by
+    slots.  Timed alone on the v5e (PR 31, ms a call): by slots is one
+    gather that WRITES ``k * S`` rows and a sum that reads them, whatever
+    the bucket: 1.57 at 81,920 x 3,072 (laguna's layer) and 1.32 at 98,304
+    x 2,048 (kanana's), where the worst case's took 4.8 and 4.0 (a small
+    bucket's clamped reads hit one row).  By runs moves ``S + R`` rows and
+    makes three float32 passes over ``[R, H]``, each a shifted copy: 0.53
+    at 3,584 x 3,072, 1.11 at 5,120, 3.59 at 10,240; 4.0 at 15,360 x
+    2,048.  The crossover lies near a twelfth of the slots' rows in both
+    layers: laguna's bucket of 5,120 goes by runs, kanana's of 24,576 by
+    slots."""
+    return 4 * max(_run_passes(top_k, num_local), 1) * rows < top_k * tokens
+
+
+def _sum_by_runs(rows, row_weights, order, inverse, counts, tokens):
+    """What :func:`_sum_by_slots` gives, formed over the ``R`` rows of a
+    bucket instead of the ``k * S`` slots: the rows in token order (a sort
+    of ``R`` keys), each run of one token added up (a token's experts are
+    distinct, so a run is at most ``min(k, num_local)`` long: doubling
+    shifted, masked float32 adds cover it), and ONE row gathered a token,
+    zero where a token has none here.  ``S + R`` rows move.  ``row_weights
+    [R]`` float32 or ``None``."""
+    n, top_k = rows.shape[0], inverse.shape[0] // tokens
+    valid = jnp.arange(n) < jnp.sum(counts)
+    token = jnp.where(valid, order % tokens, tokens)
+    by_token = jnp.argsort(token).astype(jnp.int32)
+    token = token[by_token]
+    acc = rows[by_token].astype(jnp.float32)
+    if row_weights is not None:
+        acc = acc * row_weights[by_token][:, None]
+    acc = jnp.where((token < tokens)[:, None], acc, 0.0)
+    # after the pass at ``step``, acc[i] holds rows i .. i + 2 * step - 1
+    # of i's run
+    for step in (1 << p for p in range(_run_passes(top_k, counts.shape[0]))):
+        same = jnp.pad(token[step:] == token[:-step], (0, step))
+        acc = acc + jnp.where(same[:, None],
+                              jnp.pad(acc[step:], ((0, step), (0, 0))), 0.0)
+    acc = acc.astype(rows.dtype)
+    here = jnp.sum(_served(inverse, counts, tokens), axis=0, dtype=jnp.int32)
+    first = jnp.minimum(jnp.cumsum(here) - here, n - 1)
+    return jnp.where((here > 0)[:, None], acc[first], 0)
+
+
+def _to_tokens(rows, weights, order, inverse, counts, tokens):
+    """Sorted ``rows [R, H]`` of a bucket -> ``[S, H]``: each token's sum
+    over its rows, times its ``weights [S, k]`` where given."""
+    top_k = inverse.shape[0] // tokens
+    if not _by_runs(rows.shape[0], tokens, top_k, counts.shape[0]):
+        return _sum_by_slots(rows, weights, inverse, counts, tokens)
+    row_weights = None if weights is None else \
+        weights.astype(jnp.float32).T.reshape(-1)[order]
+    return _sum_by_runs(rows, row_weights, order, inverse, counts, tokens)
+
+
+def _routed_fwd_rows(rows, x, weights, w_gate_up, w_down, order, inverse,
+                     counts):
+    """The routed block in a bucket of ``rows`` rows."""
+    tokens, order = x.shape[0], order[:rows]
+    with jax.named_scope("dispatch"):
+        xs = x[order % tokens]
+    with jax.named_scope("experts"):
+        ys = swiglu_experts(xs, w_gate_up, w_down, counts)
+    with jax.named_scope("combine"):
+        return _to_tokens(ys, weights, order, inverse, counts, tokens)
+
+
+def _routed_bwd_rows(rows, x, weights, w_gate_up, w_down, order, inverse,
+                     counts, g):
+    """Its transpose in the same bucket, from the block's INPUTS: ``xs`` and
+    the experts' intermediate values are rebuilt at ``rows`` rows."""
+    tokens, order = x.shape[0], order[:rows]
+    token = order % tokens
+    with jax.named_scope("dispatch"):
+        xs = x[token]
+    with jax.named_scope("experts"):
+        ys, experts_vjp = _swiglu_experts_vjp(xs, w_gate_up, w_down, counts)
+    with jax.named_scope("combine"):
+        # ONE gather of the tokens' gradient: times the row's weight for
+        # d ys, dotted with ys for the weight's own
+        g32 = g[token].astype(jnp.float32)
+        w32 = weights.astype(jnp.float32).T.reshape(-1)[order]
+        d_ys = (g32 * w32[:, None]).astype(ys.dtype)
+        d_w = jnp.sum(ys.astype(jnp.float32) * g32, axis=-1)
+        served = inverse < jnp.sum(counts)
+        d_w = jnp.where(served, d_w[jnp.minimum(inverse, rows - 1)], 0.0)
+        d_w = d_w.reshape(-1, tokens).T.astype(weights.dtype)
+    with jax.named_scope("experts"):
+        d_xs, d_gate_up, d_down = experts_vjp(d_ys)
+    with jax.named_scope("dispatch"):
+        d_x = _to_tokens(d_xs, None, order, inverse, counts, tokens)
+    return d_x, d_w, d_gate_up, d_down
+
+
+def _in_bucket(body, buckets, counts, *operands):
+    """``body(rows, *operands)`` at the bucket ``counts`` asks for."""
+    if len(buckets) == 1:
+        return body(buckets[0], *operands)
+    return jax.lax.switch(
+        bucket_of(counts, buckets),
+        [functools.partial(body, rows) for rows in buckets], *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def routed_swiglu_experts(x, weights, w_gate_up, w_down, order, inverse,
+                          counts, buckets):
+    """``x [S, H]`` -> ``[S, H]``: dispatch, the experts held here
+    (:func:`swiglu_experts`) and combine, every buffer in between at the
+    rows of the smallest of ``buckets`` (:func:`row_buckets`) that holds
+    ``sum(counts)``.
+
+    ONE ``custom_vjp`` round the three, because the choice is a
+    ``lax.switch``: differentiated THROUGH, each branch would write zeros
+    for every other branch's residuals, the worst case's among them.  The
+    residuals here are the block's inputs, whose shapes no bucket changes;
+    the backward opens its own switch."""
+    return _routed_fwd(x, weights, w_gate_up, w_down, order, inverse, counts,
+                       buckets)[0]
+
+
+def _routed_fwd(x, weights, w_gate_up, w_down, order, inverse, counts,
+                buckets):
+    operands = (x, weights, w_gate_up, w_down, order, inverse, counts)
+    return _in_bucket(_routed_fwd_rows, buckets, counts, *operands), operands
+
+
+def _routed_bwd(buckets, operands, g):
+    grads = _in_bucket(_routed_bwd_rows, buckets, operands[-1], *operands, g)
+    return (*grads, None, None, None)
+
+
+routed_swiglu_experts.defvjp(_routed_fwd, _routed_bwd)

@@ -201,30 +201,27 @@ def _route(x2d, wg, bias, *, top_k, scale, norm_topk):
     return _dl.route_sigmoid_topk(logits, bias, top_k, scale, norm_topk)
 
 
-@op("moe_dispatch_dropless")
-def _dispatch(x2d, idx, *, expert_offset, num_local):
+@op("moe_routed_experts_dropless")
+def _routed_experts(x2d, idx, weights, gate_up, down, *, expert_offset,
+                    num_experts):
+    """The routed block (``dropless.routed_swiglu_experts``) -> ``(out,
+    counts, rows_buffered)``: the tokens each expert held here received,
+    and the rows of the bucket the buffers took this step."""
+    num_local = gate_up.shape[0]
     with jax.named_scope("dispatch"):
         order, inverse, counts = _dl.sort_by_expert(idx, expert_offset,
                                                     num_local)
-        return (_dl.dispatch(x2d, order, inverse, counts), order, inverse,
-                counts)
-
-
-@op("moe_combine_dropless")
-def _combine(ys, weights, order, inverse, counts):
-    with jax.named_scope("combine"):
-        return _dl.combine(ys, weights, order, inverse, counts)
-
-
-@op("moe_swiglu_experts")
-def _swiglu_experts(xs, gate_up, down, counts):
-    return _dl.swiglu_experts(xs, gate_up, down, counts)
+        buckets = _dl.row_buckets(*idx.shape, num_local, num_experts)
+        rows = jnp.asarray(buckets, jnp.int32)[_dl.bucket_of(counts, buckets)]
+    out = _dl.routed_swiglu_experts(x2d, weights, gate_up, down, order,
+                                    inverse, counts, buckets)
+    return out, counts, rows
 
 
 class GroupedSwiGLUExperts(Layer):
     """The experts held here, stacked: ``gate_up [G, H, 2I]`` (gate | up),
-    ``down [G, I, H]``; forward takes rows sorted by expert and the rows
-    each expert has."""
+    ``down [G, I, H]``.  It holds them and no more: the layer's routed
+    block multiplies them (``dropless.routed_swiglu_experts``)."""
 
     def __init__(self, num_local, d_model, d_expert, init_std=0.02,
                  down_std=None):
@@ -238,9 +235,6 @@ class GroupedSwiGLUExperts(Layer):
         for p_ in (self.gate_up, self.down):
             p_.mesh_axes = ("ep", None, None)
             p_.expert = True
-
-    def forward(self, xs, counts):
-        return _swiglu_experts(xs, self.gate_up, self.down, counts)
 
 
 class SwiGLUMLP(Layer):
@@ -278,7 +272,11 @@ class DroplessMoELayer(Layer):
     left out, and a token none of whose experts is local gets the shared
     expert only.  No token is dropped.  After a forward,
     ``tokens_per_expert`` holds the tokens each local expert received
-    (int32 ``[num_local_experts]``).
+    (int32 ``[num_local_experts]``) and ``rows_buffered`` (an int32
+    scalar) the rows the routed block's buffers had: the smallest of
+    ``dropless.row_buckets``, which follow the share of the router's width
+    that is held here, that holds those tokens.  Both stay on the device;
+    the worst case's rows mean the fallback ran.
 
     Scopes: ``router``, ``dispatch``, ``experts``, ``combine``,
     ``shared_experts`` (``docs/PROFILER.md``)."""
@@ -295,7 +293,7 @@ class DroplessMoELayer(Layer):
             raise ValueError(
                 f"experts [{expert_offset}, {expert_offset + num_local}) "
                 f"are not among the router's {num_experts}")
-        self.d_model = d_model
+        self.d_model, self.num_experts = d_model, num_experts
         self.num_local_experts, self.expert_offset = num_local, expert_offset
         self.router = TopKRouter(d_model, num_experts, top_k,
                                  routed_scaling_factor, norm_topk_prob,
@@ -305,20 +303,17 @@ class DroplessMoELayer(Layer):
         self.shared_experts = SwiGLUMLP(
             d_model, num_shared_experts * d_expert, init_std, down_std) \
             if num_shared_experts else None
-        self.tokens_per_expert = None
+        self.tokens_per_expert = self.rows_buffered = None
 
     def forward(self, x):
         shape = x.shape
         x2d = x.reshape([-1, self.d_model])
         idx, weights = self.router(x2d)
-        xs, order, inverse, counts = _dispatch(
-            x2d, idx, expert_offset=self.expert_offset,
-            num_local=self.num_local_experts)
-        out = _combine(self.experts(xs, counts), weights, order, inverse,
-                       counts)
+        out, self.tokens_per_expert, self.rows_buffered = _routed_experts(
+            x2d, idx, weights, self.experts.gate_up, self.experts.down,
+            expert_offset=self.expert_offset, num_experts=self.num_experts)
         if self.shared_experts is not None:
             out = out + self.shared_experts(x2d)
-        self.tokens_per_expert = counts
         return out.reshape(shape)
 
 

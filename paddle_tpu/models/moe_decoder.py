@@ -18,7 +18,7 @@ One chip's share of an expert-parallel layer is stated by
 ``num_local_experts`` and ``expert_offset`` (see ``DroplessMoELayer``);
 ``vocab_size`` is whatever slice of the vocabulary is held.
 
-A decoder layer hands its expert counts on as an OUTPUT, so that
+A decoder layer hands its expert counters on as OUTPUTS, so that
 ``jit.TrainStep(remat=...)`` can rematerialise each layer in the backward
 pass.  Scopes: ``embeddings`` / ``layers.i`` / ``ln_1`` / ``attn`` /
 ``ln_2`` / ``mlp`` | ``moe`` / ``ln_f`` / ``lm_head`` (``docs/PROFILER.md``).
@@ -74,8 +74,9 @@ class MoeDecoderConfig:
 
 
 class MoeDecoderLayer(nn.Layer):
-    """One block.  Returns ``(x, tokens_per_expert)``; a dense layer's
-    count is an empty array, so every layer has the same outputs."""
+    """One block.  Returns ``(x, tokens_per_expert, rows_buffered)``; a
+    dense layer's counters are empty arrays, so every layer has the same
+    outputs."""
 
     def __init__(self, config, layer_idx):
         super().__init__()
@@ -91,10 +92,10 @@ class MoeDecoderLayer(nn.Layer):
     def forward(self, x):
         x = x + self.attn(self.ln_1(x))
         if self.moe is None:
-            return x + self.mlp(self.ln_2(x)), \
-                Tensor(jnp.zeros((0,), jnp.int32))
+            none = Tensor(jnp.zeros((0,), jnp.int32))
+            return x + self.mlp(self.ln_2(x)), none, none
         x = x + self.moe(self.ln_2(x))
-        return x, self.moe.tokens_per_expert
+        return x, self.moe.tokens_per_expert, self.moe.rows_buffered
 
 
 class MoeDecoderModel(nn.Layer):
@@ -110,16 +111,18 @@ class MoeDecoderModel(nn.Layer):
             for i in range(config.num_hidden_layers)])
         self.ln_f = nn.RMSNorm(config.hidden_size,
                                epsilon=config.rms_norm_eps)
-        self.tokens_per_expert = None
+        self.tokens_per_expert = self.rows_buffered = None
 
     def forward(self, input_ids):
         x = self.embeddings(input_ids)
-        counts = []
+        counters = []
         for layer in self.layers:
-            x, c = layer(x)
+            x, *made = layer(x)
             if layer.moe is not None:
-                counts.append(c._data if isinstance(c, Tensor) else c)
-        self.tokens_per_expert = jnp.stack(counts) if counts else None
+                counters.append([c._data if isinstance(c, Tensor) else c
+                                 for c in made])
+        self.tokens_per_expert, self.rows_buffered = \
+            [jnp.stack(c) for c in zip(*counters)] or (None, None)
         return self.ln_f(x)
 
 
@@ -149,6 +152,11 @@ class MoeDecoderForCausalLM(nn.Layer):
         back beside the loss (``docs/PROFILER.md``):
         ``moe_tokens_per_expert`` int32 ``[expert layers, local experts]``,
         the tokens each expert held here received.  Their sum is the
-        assignments served here; none is ever dropped."""
+        assignments served here; none is ever dropped.
+        ``moe_rows_buffered`` int32 ``[expert layers]``, the rows of the
+        bucket each layer's buffers took (``dropless.row_buckets``): at
+        least that sum; the worst case's rows mean the fallback ran."""
         counts = self.model.tokens_per_expert
-        return {} if counts is None else {"moe_tokens_per_expert": counts}
+        return {} if counts is None else {
+            "moe_tokens_per_expert": counts,
+            "moe_rows_buffered": self.model.rows_buffered}

@@ -240,11 +240,28 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
                           scale=scale, window=window)
 
 
-def grouped_matmul(xs, w, group_sizes):
+def _grouped_row_tile(shape):
+    """The row tile of JAX's grouped-matmul Pallas kernels for ``shape
+    [M, K]`` rows on the TPU, or ``None`` where the XLA forms serve: off
+    the TPU, and (aloud) under GSPMD or where no tile divides the rows."""
+    if not _use_pallas():
+        return None
+    tm = next((t for t in (512, 256, 128) if shape[0] % t == 0), None)
+    if _partitioned_by_gspmd():
+        warn_fallback("grouped_matmul", tuple(shape), GSPMD_REASON)
+        return None
+    if tm is None:
+        warn_fallback("grouped_matmul", tuple(shape),
+                      "rows not a multiple of 128")
+    return tm
+
+
+def grouped_matmul(xs, w, group_sizes, transpose_w=False):
     """``xs [M, K]`` @ ``w [G, K, N]`` by consecutive groups of rows:
     rows ``sum(group_sizes[:g])`` to ``sum(group_sizes[:g + 1])`` meet
-    ``w[g]``; rows behind the last group meet nothing and are not read
-    back by any caller (``incubate/distributed/models/moe/dropless.py``
+    ``w[g]`` (``w [G, N, K]`` with ``transpose_w``: the product's gradient
+    for its rows); rows behind the last group meet nothing and are not
+    read back by any caller (``incubate/distributed/models/moe/dropless.py``
     masks them).  The work follows the rows that are there, not ``M``.
 
     On the TPU: JAX's own grouped-matmul Pallas kernel (``megablox``
@@ -256,20 +273,34 @@ def grouped_matmul(xs, w, group_sizes):
     path, so a trace could not say whose time they are; the Pallas calls
     keep both (``gmm``, ``tgmm`` under ``.../experts/...``).  Elsewhere,
     and under GSPMD: ``jax.lax.ragged_dot``."""
-    if _use_pallas():
-        tm = next((t for t in (512, 256, 128) if xs.shape[0] % t == 0), None)
-        if _partitioned_by_gspmd():
-            warn_fallback("grouped_matmul", tuple(xs.shape), GSPMD_REASON)
-        elif tm is None:
-            warn_fallback("grouped_matmul", tuple(xs.shape),
-                          "rows not a multiple of 128")
-        else:
-            from jax.experimental.pallas.ops.tpu.megablox import ops
-            return ops.gmm(xs, w, group_sizes.astype(jnp.int32),
-                           preferred_element_type=xs.dtype,
-                           tiling=(tm, 512, 512))
-    return jax.lax.ragged_dot(xs, w, group_sizes,
-                              preferred_element_type=xs.dtype)
+    tm = _grouped_row_tile(xs.shape)
+    if tm is not None:
+        from jax.experimental.pallas.ops.tpu.megablox import ops
+        return ops.gmm(xs, w, group_sizes.astype(jnp.int32),
+                       preferred_element_type=xs.dtype,
+                       tiling=(tm, 512, 512), transpose_rhs=transpose_w)
+    return jax.lax.ragged_dot(xs, w.swapaxes(1, 2) if transpose_w else w,
+                              group_sizes, preferred_element_type=xs.dtype)
+
+
+def grouped_matmul_dw(xs, g, group_sizes):
+    """:func:`grouped_matmul`'s gradient for its matrices: ``xs [M, K]``
+    and ``g [M, N]`` -> ``[G, K, N]``, group by group ``xs_g^T @ g_g``
+    (zero for an empty group; rows behind the last group are not read).
+    The call ``megablox``'s own backward rule makes (``tgmm``), for a
+    caller that writes its backward by hand: differentiated inside another
+    rule the kernels lose their names (``jvp_jit_gmm__`` in a trace), and
+    a reader that counts ``gmm`` / ``tgmm`` calls loses them."""
+    tm = _grouped_row_tile(xs.shape)
+    if tm is not None:
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+        return tgmm(xs.swapaxes(0, 1), g, group_sizes.astype(jnp.int32),
+                    xs.dtype, (tm, 512, 512), None, group_sizes.shape[0])
+    return jax.lax.ragged_dot_general(
+        xs, g, group_sizes, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(([0], [0]), ([], [])),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        preferred_element_type=xs.dtype)
 
 
 def pick_block(size, preferred, candidates=(512, 256, 128, 64, 32, 16, 8)):

@@ -400,6 +400,10 @@ def test_train_step_hands_back_the_counters_and_the_loss_falls():
     counts = step.counters["moe_tokens_per_expert"]
     assert counts.shape == (4, 4) and counts.dtype == jnp.int32
     assert 0 < int(counts.sum()) <= 4 * 64 * 3
+    # 64 tokens: the worst case's rows are every layer's only bucket
+    rows = step.counters["moe_rows_buffered"]
+    assert rows.dtype == jnp.int32 and rows.tolist() == [
+        dropless.sorted_rows(64, model.config.num_experts_per_tok, 4)] * 4
 
 
 def test_preset_is_the_published_model_and_a_bad_config_is_refused():

@@ -140,6 +140,9 @@ def test_train_step_hands_back_the_counters_beside_the_loss():
     # a share serves part of the 3 assignments a token makes, never more
     served = np.asarray(counts).sum(axis=1)
     assert ((served > 0) & (served <= ids.size * 3)).all()
+    # 64 tokens: the worst case's 192 rows are the only bucket
+    rows = step.counters["moe_rows_buffered"]
+    assert rows.dtype == jnp.int32 and rows.tolist() == [192, 192]
     assert step.stats() == {"steps": 1, "compiles": 1}
 
 
@@ -230,6 +233,230 @@ def test_dropless_dispatch_against_a_loop_over_experts_skewed(offset, held):
     for a, b, name in zip(g1, g2, ("x", "gate_up", "down", "weights")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-3,
                                    atol=2e-4, err_msg=name)
+
+
+# -------------------------------------------------- the routed block ----
+# what each expert cell's layer holds: (tokens, top_k, held, router width)
+CELL_LAYERS = {"laguna-s-2.1-train-ep32.seq8192": (8192, 10, 8, 256),
+               "kanana-2-30b-a3b-train-ep8.seq8192": (16384, 6, 16, 128)}
+
+
+@pytest.mark.parametrize("cell,want", [
+    ("laguna-s-2.1-train-ep32.seq8192", (5120, 65536)),
+    ("kanana-2-30b-a3b-train-ep8.seq8192", (24576, 98304))])
+def test_row_buckets_at_the_cells_shapes(cell, want):
+    """Twice the rows expected here, then the worst case; every bucket
+    whole row tiles, so the grouped matmul keeps its 512-row tile and warns
+    no fallback."""
+    tokens, top_k, held, experts = CELL_LAYERS[cell]
+    assert dropless.row_buckets(tokens, top_k, held, experts) == want
+    assert want[-1] == dropless.sorted_rows(tokens, top_k, held)
+    assert all(rows % 512 == 0 for rows in want)
+    expected = tokens * top_k * held / experts
+    assert 2 * expected <= want[0] < 2 * expected + 512
+
+
+@pytest.mark.parametrize("tokens,top_k,held,experts", [
+    (64, 3, 16, 16), (96, 3, 8, 8), (8192, 6, 128, 128), (24, 10, 16, 16)])
+def test_nothing_cut_means_one_bucket(tokens, top_k, held, experts):
+    """``held == E``: what is expected IS the worst case."""
+    assert dropless.row_buckets(tokens, top_k, held, experts) \
+        == (dropless.sorted_rows(tokens, top_k, held),)
+
+
+# 8192 tokens, 4 a token, experts 5 and 6 of 64 held: 1,024 rows expected
+BLOCK = dict(s=8192, k=4, e=64, held=2, offset=5, h=16, inter=8)
+BLOCK_BUCKETS = (2048, 16384)
+
+
+def _block_case(hot, seed=0):
+    """Seeded operands of the routed block; ``hot`` of the tokens choose
+    the first expert held."""
+    c = BLOCK
+    rng = np.random.RandomState(seed)
+    idx = np.argsort(rng.rand(c["s"], c["e"]), axis=1)[:, :c["k"]]
+    chosen = (rng.rand(c["s"]) < hot) & ~(idx == c["offset"]).any(axis=1)
+    idx[chosen, 0] = c["offset"]
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    return (jnp.asarray(idx, jnp.int32),
+            f32(rng.randn(c["s"], c["h"])),
+            f32(rng.rand(c["s"], c["k"]) + 0.1),
+            f32(rng.randn(c["held"], c["h"], 2 * c["inter"]) * 0.3),
+            f32(rng.randn(c["held"], c["inter"], c["h"]) * 0.3),
+            f32(rng.randn(c["s"], c["h"])))
+
+
+def _block_routed(idx, x, w, gate_up, down, buckets=None):
+    """The layer's routed block -> (out, counts, rows buffered)."""
+    c = BLOCK
+    order, inverse, counts = dropless.sort_by_expert(idx, c["offset"],
+                                                     c["held"])
+    if buckets is None:
+        buckets = dropless.row_buckets(c["s"], c["k"], c["held"], c["e"])
+    out = dropless.routed_swiglu_experts(x, w, gate_up, down, order,
+                                         inverse, counts, buckets)
+    return out, counts, \
+        jnp.asarray(buckets)[dropless.bucket_of(counts, buckets)]
+
+
+def _block_parent(idx, x, w, gate_up, down):
+    """The three pieces one after the other over the worst case's rows."""
+    c = BLOCK
+    order, inverse, counts = dropless.sort_by_expert(idx, c["offset"],
+                                                     c["held"])
+    xs = dropless.dispatch(x, order, inverse, counts)
+    ys = dropless.swiglu_experts(xs, gate_up, down, counts)
+    return dropless.combine(ys, w, order, inverse, counts)
+
+
+def _block_loop(idx, x, w, gate_up, down):
+    c = BLOCK
+    out = 0.0
+    for j in range(c["held"]):
+        w_e = jnp.sum(jnp.where(idx == j + c["offset"], w, 0.0), axis=1)
+        gu = x @ gate_up[j]
+        out = out + w_e[:, None] * ((jax.nn.silu(gu[:, :c["inter"]])
+                                     * gu[:, c["inter"]:]) @ down[j])
+    return out
+
+
+# the share of the tokens sent to ONE held expert -> the bucket they need;
+# nine tenths of them reach the worst case, which is the parent's path
+@pytest.mark.parametrize("hot,bucket", [(0.0, 2048), (0.08, 2048),
+                                        (0.3, 16384), (0.9, 16384)])
+def test_routed_block_in_every_bucket(hot, bucket):
+    idx, x, w, gate_up, down, probe = _block_case(hot)
+    assert dropless.row_buckets(
+        BLOCK["s"], BLOCK["k"], BLOCK["held"], BLOCK["e"]) == BLOCK_BUCKETS
+    got, counts, rows = jax.jit(_block_routed)(idx, x, w, gate_up, down)
+    # the bucket taken is named, holds every row, and is the smallest such
+    local = (np.asarray(idx) >= BLOCK["offset"]) \
+        & (np.asarray(idx) < BLOCK["offset"] + BLOCK["held"])
+    assert int(counts.sum()) == int(local.sum())        # nothing dropped
+    assert int(rows) == bucket >= int(counts.sum())
+    assert all(b < int(counts.sum()) for b in BLOCK_BUCKETS if b < bucket)
+    args = (x, w, gate_up, down)
+    want = _block_loop(idx, *args)
+    parent = _block_parent(idx, *args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4,
+                               atol=2e-5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(parent),
+                               rtol=1e-5, atol=1e-6)
+    grads = [jax.jit(jax.grad(lambda *a: jnp.sum(f(idx, *a) * probe),
+                              argnums=(0, 1, 2, 3)))(*args)
+             for f in (lambda *a: _block_routed(*a)[0], _block_parent,
+                       _block_loop)]
+    for a, b, c, name in zip(*grads, ("x", "weights", "gate_up", "down")):
+        scale = float(jnp.max(jnp.abs(c)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c), rtol=2e-3,
+                                   atol=2e-5 * scale, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=2e-6 * scale, err_msg=name)
+
+
+@pytest.mark.parametrize("rows", (1536, 2048, 4096, 16384))
+def test_a_larger_bucket_than_needed_gives_the_same(rows):
+    """The rows behind the last group are masked wherever rows go back to
+    their tokens: ANY bucket that holds the rows gives the value and the
+    gradients of the smallest, whichever way it sums (by runs up to 4,096
+    rows here, by slots in the worst case)."""
+    idx, x, w, gate_up, down, probe = _block_case(0.0)
+    by_runs = dropless._by_runs(rows, BLOCK["s"], BLOCK["k"], BLOCK["held"])
+    assert by_runs == (rows <= 4096)
+
+    def f(*a, buckets):
+        return jnp.sum(_block_routed(idx, *a, buckets=buckets)[0] * probe)
+
+    args = (x, w, gate_up, down)
+    got = jax.jit(jax.value_and_grad(
+        lambda *a: f(*a, buckets=(rows,)), argnums=(0, 1, 2, 3)))(*args)
+    want = jax.jit(jax.value_and_grad(
+        lambda *a: f(*a, buckets=(16384,)),
+        argnums=(0, 1, 2, 3)))(*args)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=2e-6 * float(jnp.max(jnp.abs(b))))
+
+
+@pytest.mark.parametrize("top_k,held,experts", [(8, 8, 8), (6, 16, 16),
+                                                (3, 5, 7), (10, 8, 12)])
+@pytest.mark.parametrize("weighted", [False, True])
+def test_sum_by_runs_is_the_sum_by_slots(top_k, held, experts, weighted):
+    """Runs of up to ``min(k, held)`` rows a token (most experts held, so
+    the doubling passes all have work), rows behind the last group holding
+    NaN: both forms give each token's float32 sum."""
+    tokens, h, offset = 40, 8, experts - held
+    rng = np.random.RandomState(top_k)
+    idx = jnp.asarray(np.argsort(rng.rand(tokens, experts), axis=1)
+                      [:, :top_k], jnp.int32)
+    order, inverse, counts = dropless.sort_by_expert(idx, offset, held)
+    total = int(counts.sum())
+    rows = np.full((order.shape[0], h), np.nan, np.float32)
+    rows[:total] = rng.randn(total, h)
+    rows = jnp.asarray(rows)
+    weights = jnp.asarray(rng.rand(tokens, top_k) + 0.1, jnp.float32) \
+        if weighted else None
+    row_weights = weights.T.reshape(-1)[order] if weighted else None
+    got = dropless._sum_by_runs(rows, row_weights, order, inverse, counts,
+                                tokens)
+    want = dropless._sum_by_slots(rows, weights, inverse, counts, tokens)
+    assert np.isfinite(np.asarray(want)).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+    # the longest run is as long as it may be, or nearly
+    here = np.bincount(np.asarray(order[:total]) % tokens, minlength=tokens)
+    assert here.max() >= min(top_k, held) - 1
+
+
+@pytest.mark.parametrize("num_local,conds", [(16, 0), (4, 2)])
+def test_the_switch_is_traced_only_where_the_layer_is_cut(num_local, conds):
+    """``held == E``: one bucket, no ``cond`` in the jaxpr, forward or
+    backward; a cut layer: one switch each way, whose branches hand back
+    arrays at the TOKENS' rows alone."""
+    s, k, e, h = 2048, 3, 16, 16
+    paddle.seed(0)
+    layer = DroplessMoELayer(h, 8, e, k, num_local_experts=num_local,
+                             expert_offset=e - num_local)
+    buckets = dropless.row_buckets(s, k, num_local, e)
+    assert len(buckets) == (1 if num_local == e else 2)
+    params = {n: p._data for n, p in layer.named_parameters()}
+    frozen = {n: b._data for n, b in layer.named_buffers()}
+
+    def loss(p, x):
+        out = functional_call(layer, {**p, **frozen}, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    x = jnp.asarray(np.random.RandomState(0).randn(1, s, h), jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(loss))(params, x))
+    assert text.count(" cond[") == conds
+    assert np.isfinite(float(jax.jit(loss)(params, x)))
+
+
+def test_remat_by_block_changes_no_value_under_row_buckets():
+    """1,024 tokens: the two expert layers choose between 1,536 and 3,072
+    rows inside the compiled step, with and without rematerialisation."""
+    ids = paddle.to_tensor(_ids(6, rows=2, seq=512))
+    losses, rows = [], []
+    for remat in (False, True):
+        m = runner.model_group({**BASE, **SHARES["share-4-of-16-from-4"],
+                                "max_position_embeddings": 512})
+        paddle.seed(0)
+        model = MlaMoeForCausalLM(runner.model_config(m))
+        runner.load_seeded(model, ref.init_params(7, m, jnp.float32),
+                           m["first_k_dense_replace"])
+        opt = optimizer.AdamW(learning_rate=1e-3,
+                              parameters=model.parameters())
+        step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                         remat=remat)
+        losses.append([float(step(ids, ids)._data) for _ in range(2)])
+        rows.append(np.asarray(step.counters["moe_rows_buffered"]))
+        served = np.asarray(step.counters["moe_tokens_per_expert"]).sum(1)
+        assert rows[-1].shape == (2,) and (rows[-1] >= served).all()
+        assert set(rows[-1].tolist()) <= {1536, 3072}
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+    np.testing.assert_array_equal(rows[0], rows[1])
+    assert losses[0][1] < losses[0][0]
 
 
 def test_router_norms_over_the_chosen_and_the_bias_only_steers():
@@ -468,6 +695,27 @@ def test_grouped_matmul_is_ragged_dot_off_the_tpu():
     np.testing.assert_allclose(got[10:19], np.asarray(xs[10:19] @ w[2]),
                                rtol=1e-5, atol=1e-5)
     assert not got[19:].any()
+
+
+def test_grouped_matmul_transposes_are_its_gradients_off_the_tpu():
+    """``transpose_w`` and ``grouped_matmul_dw`` are what ``jax.vjp`` of the
+    product gives for its rows and its matrices (the routed block's
+    backward calls them by name)."""
+    from paddle_tpu.ops import pallas as pk
+
+    rng = np.random.RandomState(1)
+    xs = jnp.asarray(rng.randn(24, 8), jnp.float32)
+    w = jnp.asarray(rng.randn(3, 8, 5), jnp.float32)
+    sizes = jnp.asarray([10, 0, 9], jnp.int32)      # 5 rows belong to nobody
+    g = jnp.asarray(rng.randn(24, 5), jnp.float32).at[19:].set(0.0)
+    _, vjp = jax.vjp(lambda xs, w: pk.grouped_matmul(xs, w, sizes), xs, w)
+    d_xs, d_w = vjp(g)
+    np.testing.assert_allclose(
+        np.asarray(pk.grouped_matmul(g, w, sizes, transpose_w=True)),
+        np.asarray(d_xs), rtol=1e-5, atol=1e-5)
+    got = np.asarray(pk.grouped_matmul_dw(xs, g, sizes))
+    np.testing.assert_allclose(got, np.asarray(d_w), rtol=1e-5, atol=1e-5)
+    assert got.shape == (3, 8, 5) and not got[1].any()
 
 
 def test_grouped_matmul_lowers_to_the_pallas_kernels_for_the_tpu(monkeypatch):

@@ -265,6 +265,76 @@ def test_one_chip_qkv_projection_stays_one_dot(monkeypatch):
     assert "3x32x128x" not in "".join(d[0] for d in dots)
 
 
+def _case_results(text):
+    """The result types of every ``stablehlo.case`` of a lowered text."""
+    import re
+
+    return re.findall(r"^ *\}\) : \(tensor<i32>\) -> (.*)$", text, re.M)
+
+
+def test_a_small_buckets_branch_holds_no_worst_case_residual(monkeypatch):
+    """The expert layer's routed block, forward + grad, lowered for the
+    TPU at a small width: 4,096 tokens, 4 of 48 experts held, a bucket of
+    3,072 rows before the worst case's 16,384.  What leaves
+    the two switches (one each way) has the TOKENS' rows or a parameter's
+    shape: no branch hands a ``[bucket rows, .]`` array on, the worst
+    case's least of all, and the grouped matmuls are the Pallas calls in
+    every branch.  Differentiated THROUGH, the same switch hands on every
+    branch's residuals at once (each branch writes zeros for the others'):
+    that is what the one ``custom_vjp`` round the block is for."""
+    import functools
+
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.distributed.models.moe import (
+        DroplessMoELayer, dropless)
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    tokens, top_k, experts, held, h = 4096, 4, 48, 4, 128
+    buckets = dropless.row_buckets(tokens, top_k, held, experts)
+    assert buckets == (3072, 16384)
+    paddle.seed(0)
+    layer = paddle.amp.decorate(
+        DroplessMoELayer(h, 128, experts, top_k, num_local_experts=held,
+                         expert_offset=3), level="O2", dtype="bfloat16")
+    p = {k: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype)
+         for k, v in layer.state_dict().items()}
+    x = jax.ShapeDtypeStruct((1, tokens, h), jnp.bfloat16)
+
+    def loss(p, x):
+        return jnp.sum(functional_call(layer, p, x).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(p, x).lower(
+        lowering_platforms=("tpu",)).as_text()
+    results = _case_results(text)
+    assert len(results) == 2, results
+    assert not any(f"<{rows}x" in r for rows in buckets for r in results), \
+        results
+    assert f"tensor<{tokens}x{h}xbf16>" in results[0]
+    # 2 grouped matmuls forward and 2 + 4 backward, in each branch
+    assert text.count("tpu_custom_call") == 8 * len(buckets)
+    assert "ragged_dot" not in text
+
+    def through(x, weights, gate_up, down, order, inverse, counts):
+        operands = (x, weights, gate_up, down, order, inverse, counts)
+        return jnp.sum(jax.lax.switch(
+            dropless.bucket_of(counts, buckets),
+            [functools.partial(dropless._routed_fwd_rows, rows)
+             for rows in buckets], *operands).astype(jnp.float32))
+
+    sds = jax.ShapeDtypeStruct
+    operands = (sds((tokens, h), jnp.bfloat16),
+                sds((tokens, top_k), jnp.float32),
+                p["experts.gate_up"], p["experts.down"],
+                sds((buckets[-1],), jnp.int32),
+                sds((tokens * top_k,), jnp.int32), sds((held,), jnp.int32))
+    naive = _case_results(jax.jit(jax.grad(through, argnums=(0, 2, 3)))
+                          .trace(*operands).lower(
+                              lowering_platforms=("tpu",)).as_text())
+    assert any(f"<{buckets[-1]}x" in r for r in naive), naive
+
+
 # The three flash kernels as Mosaic gets them (the module inside each
 # ``tpu_custom_call``, its source locations stripped: they shift with
 # every edit to the kernel file and are no part of the program), hashed, at
