@@ -18,6 +18,12 @@ from .llama import (  # noqa: F401
     llama_160m,
     llama_7b,
 )
+from .evabyte import (  # noqa: F401
+    EvaByteConfig,
+    EvaByteForCausalLM,
+    evabyte_6_5b,
+    evabyte_tiny,
+)
 from .wide_deep import WideDeep  # noqa: F401
 from .deepfm import DeepFM  # noqa: F401
 from .deepspeech import DeepSpeech2, deepspeech2_tiny  # noqa: F401

@@ -18,6 +18,19 @@ One chip's share of an expert-parallel layer is stated by
 ``num_local_experts`` and ``expert_offset`` (see ``DroplessMoELayer``);
 ``vocab_size`` is whatever slice of the vocabulary is held.
 
+Three options of the shell, each off unless the config says otherwise (a
+model that sets none computes what it computed without them):
+
+- ``fp32_skip_add``: the residual stream, and every add into it, in
+  float32 whatever the parameters' dtype; a norm's output goes on in the
+  parameters' dtype;
+- ``norm_add_unit_offset``: an RMSNorm gain is stored as its offset from
+  one, ``rms(x) * (1 + g)``, ``g`` starting at 0 (:class:`UnitOffsetRMSNorm`);
+- ``num_pred_heads`` ``P > 1``: the head gives ``P`` sets of logits a
+  position, ``[B, T, P, V]`` in float32, head ``p`` at position ``t``
+  predicting token ``t + 1 + p``; the loss is the mean over the heads of
+  each head's mean cross entropy over the positions that have its target.
+
 A decoder layer hands its expert counters on as OUTPUTS, so that
 ``jit.TrainStep(remat=...)`` can rematerialise each layer in the backward
 pass.  Scopes: ``embeddings`` / ``layers.i`` / ``ln_1`` / ``attn`` /
@@ -32,7 +45,7 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..incubate.distributed.models.moe import DroplessMoELayer, SwiGLUMLP
 from ..nn import functional as F
-from ..nn.initializer import Normal
+from ..nn.initializer import Constant, Normal
 from ..nn.layer_base import ParamAttr
 
 
@@ -41,11 +54,37 @@ def linear(d_in, d_out, std):
                      weight_attr=ParamAttr(initializer=Normal(0.0, std)))
 
 
+class UnitOffsetRMSNorm(nn.Layer):
+    """``rms(x) * (1 + weight)``: the gain is held as its offset from one
+    (``norm_add_unit_offset``), so that in bfloat16 it keeps, near one, the
+    resolution a bfloat16 has near zero.  Statistics and product in
+    float32, the result in ``x``'s dtype."""
+
+    def __init__(self, hidden_size, epsilon=1e-6):
+        super().__init__()
+        self.epsilon = epsilon
+        self.weight = self.create_parameter(
+            (hidden_size,), default_initializer=Constant(0.0))
+
+    def forward(self, x):
+        out = F.rms_norm(x.astype("float32"), epsilon=self.epsilon) \
+            * (self.weight.astype("float32") + 1.0)
+        return out.astype(x.dtype)
+
+
 class MoeDecoderConfig:
     """What the shell reads of a config: ``vocab_size``, ``hidden_size``,
     ``num_hidden_layers``, ``rms_norm_eps``, ``initializer_range``,
-    ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, and the
-    two factories."""
+    ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, the two
+    factories, and the three options of the module's docstring."""
+
+    fp32_skip_add = False
+    norm_add_unit_offset = False
+    num_pred_heads = 1
+
+    def make_norm(self):
+        cls = UnitOffsetRMSNorm if self.norm_add_unit_offset else nn.RMSNorm
+        return cls(self.hidden_size, epsilon=self.rms_norm_eps)
 
     @property
     def out_std(self):
@@ -81,20 +120,28 @@ class MoeDecoderLayer(nn.Layer):
     def __init__(self, config, layer_idx):
         super().__init__()
         c = config
-        self.ln_1 = nn.RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps)
+        self.ln_1 = c.make_norm()
         self.attn = c.make_attention(layer_idx)
-        self.ln_2 = nn.RMSNorm(c.hidden_size, epsilon=c.rms_norm_eps)
+        self.ln_2 = c.make_norm()
         ffn = c.make_ffn(layer_idx)
         is_moe = isinstance(ffn, DroplessMoELayer)
         self.mlp = None if is_moe else ffn
         self.moe = ffn if is_moe else None
+        self._fp32_skip_add = c.fp32_skip_add
+
+    def _branch(self, x, norm, f):
+        """``x + f(norm(x))``; under ``fp32_skip_add`` the sum in float32
+        and ``f`` on the parameters' dtype."""
+        if not self._fp32_skip_add:
+            return x + f(norm(x))
+        return x + f(norm(x).astype(norm.weight.dtype)).astype("float32")
 
     def forward(self, x):
-        x = x + self.attn(self.ln_1(x))
+        x = self._branch(x, self.ln_1, self.attn)
         if self.moe is None:
             none = Tensor(jnp.zeros((0,), jnp.int32))
-            return x + self.mlp(self.ln_2(x)), none, none
-        x = x + self.moe(self.ln_2(x))
+            return self._branch(x, self.ln_2, self.mlp), none, none
+        x = self._branch(x, self.ln_2, self.moe)
         return x, self.moe.tokens_per_expert, self.moe.rows_buffered
 
 
@@ -109,12 +156,13 @@ class MoeDecoderModel(nn.Layer):
         self.layers = nn.LayerList([
             MoeDecoderLayer(config, i)
             for i in range(config.num_hidden_layers)])
-        self.ln_f = nn.RMSNorm(config.hidden_size,
-                               epsilon=config.rms_norm_eps)
+        self.ln_f = config.make_norm()
         self.tokens_per_expert = self.rows_buffered = None
 
     def forward(self, input_ids):
         x = self.embeddings(input_ids)
+        if self.config.fp32_skip_add:
+            x = x.astype("float32")
         counters = []
         for layer in self.layers:
             x, *made = layer(x)
@@ -123,24 +171,52 @@ class MoeDecoderModel(nn.Layer):
                                  for c in made])
         self.tokens_per_expert, self.rows_buffered = \
             [jnp.stack(c) for c in zip(*counters)] or (None, None)
-        return self.ln_f(x)
+        x = self.ln_f(x)
+        if self.config.fp32_skip_add:
+            x = x.astype(self.ln_f.weight.dtype)
+        return x
 
 
 class MoeDecoderForCausalLM(nn.Layer):
     """``forward`` returns logits over the vocabulary slice held, ``loss``
-    is the shifted-label cross entropy, as ``GPTForCausalLM``'s."""
+    is the shifted-label cross entropy, as ``GPTForCausalLM``'s.  With
+    ``num_pred_heads`` ``P > 1``: logits ``[B, T, P, V]`` in float32 and
+    :meth:`multi_head_loss`."""
 
     def __init__(self, config):
         super().__init__()
         self.config = config
         self.model = MoeDecoderModel(config)
-        self.lm_head = linear(config.hidden_size, config.vocab_size,
+        self.lm_head = linear(config.hidden_size,
+                              config.num_pred_heads * config.vocab_size,
                               config.initializer_range)
 
     def forward(self, input_ids):
-        return self.lm_head(self.model(input_ids))
+        logits = self.lm_head(self.model(input_ids))
+        heads = self.config.num_pred_heads
+        if heads == 1:
+            return logits
+        b, t, _ = logits.shape
+        return logits.reshape([b, t, heads, -1]).astype("float32")
+
+    def multi_head_loss(self, logits, labels):
+        """``logits [B, T, P, V]``, ``labels [B, T]`` (the ids): head ``p``
+        at position ``t`` is held to ``labels[t + 1 + p]``; the mean over
+        the heads of each head's mean over its ``B (T - 1 - p)`` targets."""
+        b, t, heads, vocab = logits.shape
+        ids = labels._data if isinstance(labels, Tensor) else labels
+        ahead = jnp.arange(t)[:, None] + 1 + jnp.arange(heads)[None, :]
+        target = jnp.where(ahead < t, ids[:, jnp.minimum(ahead, t - 1)],
+                           -100)                            # [B, T, P]
+        each = F.cross_entropy(logits.reshape([-1, vocab]),
+                               Tensor(target.reshape(-1)), reduction="none")
+        weight = 1.0 / (heads * b * (t - 1 - jnp.arange(heads)))
+        return (each.reshape([b * t, heads])
+                * Tensor(weight.astype(jnp.float32))).sum()
 
     def loss(self, logits, labels):
+        if self.config.num_pred_heads > 1:
+            return self.multi_head_loss(logits, labels)
         shift_logits = logits[:, :-1, :]
         shift_labels = labels[:, 1:]
         return F.cross_entropy(

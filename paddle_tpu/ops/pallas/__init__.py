@@ -190,7 +190,16 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     inference/bf16 pretrain settings).
 
     Under a mesh that GSPMD partitions (``SpmdTrainStep``, ``use_mesh``)
-    the kernels run inside :func:`flash_attention_sharded`."""
+    the kernels run inside :func:`flash_attention_sharded`.
+
+    The masks the kernels take: none, causal (top-left aligned, so
+    self-attention-shaped inputs only), and causal under a SLIDING window;
+    an ``attn_mask``, dropout or a ``scale`` takes the XLA composition,
+    aloud.  A window that is a BLOCK (query ``t`` sees the keys of ``t //
+    W`` up to itself) with pooled chunk summaries of the earlier windows
+    under the same softmax is not a mask of this function: it has two
+    kinds of key, its own kernels and its own dispatcher,
+    :func:`eva_attention`."""
     if dropout_p > 0.0 and dropout_key is None:
         from ...framework.random import get_rng_key
         dropout_key = get_rng_key()
@@ -238,6 +247,82 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     return _xla_attention(q, k, v, attn_mask=attn_mask, is_causal=is_causal,
                           dropout_p=dropout_p, dropout_key=dropout_key,
                           scale=scale, window=window)
+
+
+def _xla_eva_attention(q, k, v, kt, vt, window, chunk):
+    """The XLA composition of ``eva_attention_kernel``: q, k, v ``[B, T, N,
+    D]``, summaries kt, vt ``[B, T / chunk, N, D]``.  ONE softmax over the
+    DENSE ``[T, T + T / chunk]`` scores under an explicit mask: the exact
+    keys of a query's own block-aligned window up to itself, and the
+    summaries of the chunks of every earlier window.  Operands in the
+    dtype they come in, float32 accumulation and softmax, as
+    :func:`_xla_attention`."""
+    t = q.shape[1]
+    scale = float(q.shape[-1]) ** -0.5
+    exact = jnp.einsum("btnh,bsnh->bnts", q, k,
+                       preferred_element_type=jnp.float32) * scale
+    pooled = jnp.einsum("btnh,bcnh->bntc", q, kt,
+                        preferred_element_type=jnp.float32) * scale
+    pos = jnp.arange(t)
+    own = (pos[:, None] // window == pos[None, :] // window) \
+        & (pos[None, :] <= pos[:, None])
+    earlier = (jnp.arange(t // chunk)[None, :] * chunk) // window \
+        < pos[:, None] // window
+    low = jnp.finfo(jnp.float32).min
+    probs = jax.nn.softmax(jnp.concatenate(
+        [jnp.where(own, exact, low), jnp.where(earlier, pooled, low)],
+        axis=-1), axis=-1).astype(q.dtype)
+    out = jnp.einsum("bnts,bsnh->btnh", probs[..., :t], v,
+                     preferred_element_type=jnp.float32) \
+        + jnp.einsum("bntc,bcnh->btnh", probs[..., t:], vt,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _eva_refusal(seq, head_dim, window, chunk):
+    """Why the ``eva_attention_*`` kernels do not serve this call where
+    kernels are on, or None."""
+    from .eva_attention_kernel import supports
+
+    if not supports(seq, head_dim, window, chunk):
+        return "eva_attention_kernel.supports() refuses the shape"
+    if _partitioned_by_gspmd():
+        return GSPMD_REASON + "; these kernels have no sharded launch"
+    return None
+
+
+def eva_attention(q, k, v, kt, vt, window, chunk):
+    """Attention over the exact keys of a query's own block-aligned window
+    (causally) and the chunk summaries kt, vt of every earlier window, under
+    one softmax.  q, k, v ``[batch, T, heads, D]``, kt, vt ``[batch, T /
+    chunk, heads, D]``; returns ``[batch, T, heads, D]``.
+
+    On the TPU the ``eva_attention_*`` kernels (``eva_attention_kernel``),
+    whose work follows the mask; elsewhere, and aloud where
+    ``eva_attention_kernel.supports`` refuses the shape or GSPMD partitions
+    the step, the dense XLA composition."""
+    if _use_pallas():
+        reason = _eva_refusal(q.shape[1], q.shape[3], window, chunk)
+        if reason is None:
+            from .eva_attention_kernel import eva_attention_pallas
+            return eva_attention_pallas(q, k, v, kt, vt, window, chunk)
+        warn_fallback("eva_attention",
+                      f"q{tuple(q.shape)} window={window} chunk={chunk}",
+                      reason)
+    return _xla_eva_attention(q, k, v, kt, vt, window, chunk)
+
+
+def eva_pairs_scored(seq, head_dim, window, chunk):
+    """The (query, key-or-summary) pairs :func:`eva_attention` forms scores
+    for over one row and head, by the path it takes where this is asked
+    (the same trace) and the block sizes that path runs: the kernels'
+    grids, or the composition's whole ``T x (T + T / chunk)`` matrix, so
+    that a fallback shows in the count."""
+    from . import eva_attention_kernel as eva
+
+    if _use_pallas() and _eva_refusal(seq, head_dim, window, chunk) is None:
+        return sum(eva.pairs_scored(seq, window, chunk))
+    return eva.pairs_dense(seq, chunk)
 
 
 def _grouped_row_tile(shape):

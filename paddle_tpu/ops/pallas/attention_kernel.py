@@ -76,10 +76,10 @@ _SPLIT_HEADS = {(192, 128)}
 
 def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
              kv_heads=None, window=None, causal=True):
-    """``head_dim`` is q's and k's width, ``v_head_dim`` v's (the same
-    where it is not given).  ``q_heads`` over ``kv_heads``: any whole
-    multiple (grouped KV heads).  ``window``: at least 1, causal only, and
-    q and k of one length."""
+    """``head_dim``: q's and k's width; ``v_head_dim``: v's (else the same).
+    ``q_heads`` over ``kv_heads``: any whole multiple.  ``window`` (SLIDING;
+    a BLOCK window with summaries of the windows before it is asked of
+    ``eva_attention_kernel.supports``): >= 1, causal, q and k of one length."""
     if v_head_dim is None or v_head_dim == head_dim:
         heads_ok = head_dim <= 128
     else:

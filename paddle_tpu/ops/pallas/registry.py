@@ -50,6 +50,7 @@ KernelCase = namedtuple("KernelCase",
 # registry consumer sees every entry without importing the whole tree.
 KERNEL_MODULES = (
     "attention_kernel",
+    "eva_attention_kernel",
     "decode_attention_kernel",
     "ragged_attention_kernel",
     "layernorm_kernel",
