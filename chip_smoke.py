@@ -151,8 +151,7 @@ def train_phase(report, build_model, *, batch, seq, steps, on_chip):
     if on_chip:
         kernels = _mosaic_kernels(step.lower(ids, ids).as_text())
         want = {"layernorm_fwd", "layernorm_bwd"}
-        flash = {"flash_attention_fwd", "flash_attention_bwd_dq",
-                 "flash_attention_bwd_dkv"}
+        flash = {"flash_attention_fwd", "flash_attention_bwd_dq_dkv"}
         min_seq = int(get_flags("FLAGS_flash_min_seqlen")
                       ["FLAGS_flash_min_seqlen"])
         if seq >= min_seq:

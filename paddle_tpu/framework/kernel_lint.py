@@ -27,8 +27,12 @@ Rule catalog (Findings in the analysis.py style; docs/ANALYSIS.md):
 - **K002 VMEM residency** — per grid step the kernel holds every
   input/output block twice (Pallas double-buffers the DMAs) plus its
   scratch once; the total is checked against the ``vmem_bytes`` entry
-  of the device profiles in :mod:`paddle_tpu.framework.cost`, and the
-  finding names the binding buffer.  :func:`estimate_residency` /
+  of the device profiles in :mod:`paddle_tpu.framework.cost` (Mosaic's
+  default allowance), or against the ``vmem_limit_bytes`` the call itself
+  asks of Mosaic where that is more (the flash backward keeps a q head's
+  Q, dO and float32 dQ whole and reckons its allowance from its blocks;
+  past the chip's physical VMEM Mosaic refuses the compile itself), and
+  the finding names the binding buffer.  :func:`estimate_residency` /
   :func:`vmem_fits` expose the same model to ``autotune.pick`` so
   VMEM-overflowing block candidates are rejected before they are ever
   compiled.
@@ -125,15 +129,17 @@ class KernelInfo:
     """Everything the rules need about one ``pallas_call``."""
 
     __slots__ = ("name", "grid", "blocks", "scratch", "num_prefetch",
-                 "body")
+                 "body", "vmem_limit")
 
-    def __init__(self, name, grid, blocks, scratch, num_prefetch, body):
+    def __init__(self, name, grid, blocks, scratch, num_prefetch, body,
+                 vmem_limit=None):
         self.name = name
         self.grid = grid                    # tuple of ints
         self.blocks = blocks                # list[BlockInfo], ins then outs
         self.scratch = scratch              # list[(shape, dtype)]
         self.num_prefetch = num_prefetch
         self.body = body                    # raw kernel jaxpr
+        self.vmem_limit = vmem_limit        # the call's own, or None
 
     def __repr__(self):
         return (f"KernelInfo({self.name} grid={self.grid} "
@@ -170,7 +176,9 @@ def _kernel_info(eqn):
     for v in body.invars[num_prefetch + len(blocks):]:
         scratch.append(_ref_shape_dtype(v.aval))
     name = eqn.params.get("name") or "pallas_call"
-    return KernelInfo(name, grid, blocks, scratch, num_prefetch, body)
+    mosaic = (eqn.params.get("compiler_params") or {}).get("mosaic_tpu")
+    return KernelInfo(name, grid, blocks, scratch, num_prefetch, body,
+                      getattr(mosaic, "vmem_limit_bytes", None))
 
 
 def introspect_kernels(fn, *args):
@@ -461,6 +469,7 @@ def _check_vmem(ki, loc, profile, findings):
     limit = _vmem_limit(profile)
     if not limit:
         return
+    limit = max(limit, ki.vmem_limit or 0)
     contributors = [(2 * _nbytes(b.block_shape, b.dtype),
                      f"{b.origin} block {b.block_shape} (x2 double-buffer)")
                     for b in ki.blocks]

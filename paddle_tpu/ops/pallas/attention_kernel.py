@@ -6,8 +6,9 @@ paddle/phi/backends/dynload/flashattn.h).  Here the kernel is written
 TPU-native in Pallas: online-softmax over key blocks (never materializes the
 [T, T] score matrix), MXU operands in the dtype q, k, v are stored in with
 float32 accumulation and a float32 softmax between the dots, and a
-recompute-based backward (dq and dk/dv as separate kernels), wired up as a
-jax.custom_vjp.
+recompute-based backward that is ONE kernel (S, the mask, P and dP are
+formed once a block pair and dQ, dK and dV all take their part from them),
+wired up as a jax.custom_vjp.
 
 Layouts: paddle's flash-attn API is [batch, seq, num_heads, head_dim]
 (python/paddle/nn/functional/flash_attention.py:125); kernels run on
@@ -17,7 +18,7 @@ Head widths: q and k share one width, v and the output another.  Served
 are one width <= 128 for all three (GPT, Llama), and latent attention's
 expanded training form (MLA): q and k 192 wide (128 + a 64-wide rotary
 part), v 128 wide.  The kernels see only the shapes; the second form is the
-same three kernels with a wider score dot and a larger VMEM allowance.  The
+same two kernels with a wider score dot and a larger VMEM allowance.  The
 192-wide score is ONE dot: split into a 128-wide and a 64-wide dot it ran
 0.4% (forward) and 0.7% (backward) slower on the v5e ([64, 8192, 192 | 128]
 bf16 causal, PR 26: 15.03 / 43.56 ms against 15.10 / 43.86; the MXU takes
@@ -25,23 +26,29 @@ two passes over the contraction either way).
 
 Grouped KV heads are read in place: q may have ``group`` times the heads
 of k and v, and q head ``h`` reads kv head ``h // group`` through the block
-index (forward and dq: K and V cross HBM once a kv head, since
-consecutive q heads ask for the block that is already there; dk/dv: a
-third grid axis runs over the group's q heads and sums their parts in a
-float32 VMEM scratch, so no caller expands K and V and no [q heads, seq,
-head] gradient is ever written).
+index (forward: K and V cross HBM once a kv head, since consecutive q
+heads ask for the block that is already there; backward: the grid runs
+``(kv head, q head of its group, k block)``, a q head's Q, dO and float32 dQ
+stay in VMEM while its k blocks run, and the group's dK and dV parts are
+summed in float32 VMEM scratch, so no caller expands K and V and no [q
+heads, seq, head] dK or dV is ever written).
 
 A sliding ``window`` (causal only): query ``t`` sees keys ``j`` with ``0 <=
 t - j < window``.  A q block visits only the key blocks its rows can see
-(``_key_blocks``; dk/dv likewise only the q blocks that see the k block,
-``_query_blocks``), so the work follows the window and not the sequence;
-the mask is applied in every visited block, as the causal one is.  Window
-calls are named ``flash_window<W>_attention_{fwd,bwd_dq,bwd_dkv}`` so that a
-trace tells them from the full calls (``flash_attention_*``).
+(``_key_blocks``; the backward's k block likewise only the q blocks that
+see it, ``_query_blocks``), so the work follows the window and not the
+sequence; the mask is applied in every visited block, as the causal one is.
 
-Without a window and with equal head counts the three kernels are the
-programs they were: Mosaic is handed the same modules, source locations
-aside (``tests/test_tpu_lowering.py``).
+Names: ``flash_attention_fwd`` and ``flash_attention_bwd_dq_dkv``
+(``flash_window<W>_attention_*`` under a window, so that a trace tells the
+two apart).  The benchmark counts a backward call as an event whose name
+holds ``_bwd_dq``, a forward call as one that holds ``_fwd``, and charges
+the kernels the time of every event whose name holds ``flash_attention`` /
+``flash_window``: a call this file adds keeps that true.
+
+Without a window and with equal head counts the forward kernel is the
+program it was: Mosaic is handed the same module, source locations aside
+(``tests/test_tpu_lowering.py``).
 
 Constraints (else the caller falls back to the XLA composition): seq divisible
 by the block size, head widths and counts as :func:`supports` lists them.
@@ -79,7 +86,14 @@ def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
     """``head_dim``: q's and k's width; ``v_head_dim``: v's (else the same).
     ``q_heads`` over ``kv_heads``: any whole multiple.  ``window`` (SLIDING;
     a BLOCK window with summaries of the windows before it is asked of
-    ``eva_attention_kernel.supports``): >= 1, causal, q and k of one length."""
+    ``eva_attention_kernel.supports``): >= 1, causal, q and k of one length.
+
+    How long a sequence Mosaic takes is the forward's to say, which holds K
+    and V whole: 8192 in bf16 and 4096 in float32 at one width (its default
+    16 MB), 32,768 at 192 | 128 (64 MB asked).  The backward, which holds a
+    q head's Q, dO and float32 dQ whole, fits further: 32,768 in bf16
+    (16,384 grouped, where whole-sequence dK and dV join them) and 16,384
+    in float32 (ahead of time for the v5e, 512 x 512 blocks, PR 33)."""
     if v_head_dim is None or v_head_dim == head_dim:
         heads_ok = head_dim <= 128
     else:
@@ -94,11 +108,12 @@ def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
 
 
 def _compiler_params(head_qk, head_v):
-    """Nothing for one head width (the program Mosaic built before PR 26
-    is built again, to the byte).  K, V, Q and dO are held whole in VMEM,
-    double-buffered: at seq 8192 a 192-wide bf16 operand is lane-padded to
-    256 and takes 4 MB a buffer, which passes the 16 MB Mosaic allows a
-    kernel by default on a chip that has 128."""
+    """The forward's: nothing for one head width (the program Mosaic built
+    before PR 26 is built again, to the byte).  K and V are held whole in
+    VMEM, double-buffered: at seq 8192 a 192-wide bf16 operand is
+    lane-padded to 256 and takes 4 MB a buffer, which passes the 16 MB
+    Mosaic allows a kernel by default on a chip that has 128.  (The backward
+    reckons its allowance from its blocks' bytes, ``_flash_bwd``.)"""
     if head_qk == head_v:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
@@ -127,6 +142,7 @@ def _compiler_params(head_qk, head_v):
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 
 def _dot(a, b, dims):
@@ -134,10 +150,15 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _block_rows(i, block):
+    """The slice of rows [i*block, (i+1)*block).  Mosaic has to prove the
+    alignment of a dynamic row slice of a packed dtype."""
+    return pl.ds(pl.multiple_of(i * block, block), block)
+
+
 def _rows(ref, i, block):
-    """Rows [i*block, (i+1)*block) of a [1, seq, head] ref.  Mosaic has to
-    prove the alignment of a dynamic row slice of a packed dtype."""
-    return ref[0, pl.ds(pl.multiple_of(i * block, block), block), :]
+    """Rows [i*block, (i+1)*block) of a [1, seq, head] ref."""
+    return ref[0, _block_rows(i, block), :]
 
 
 def _mask_below_diagonal(s, row0, col0, row_axis, window=None):
@@ -262,53 +283,46 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 
 # --------------------------------------------------------------- backward --
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, block_k, causal, scale, window=None):
-    q = q_ref[0]
-    do = do_ref[0]
-    block_q = q.shape[0]
-    qi = pl.program_id(1)
-    lse = lse_ref[0]                                           # [Bq, 1]
-    delta = delta_ref[0]
-
-    def body(j, dq_acc):
-        k = _rows(k_ref, j, block_k)
-        v = _rows(v_ref, j, block_k)
-        s = _dot(q, k, _NT) * scale
-        if causal:
-            s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0, window)
-        p = jnp.exp(s - lse)                                   # [Bq, Bk]
-        ds = p * (_dot(do, v, _NT) - delta)
-        return dq_acc + _dot(ds.astype(k.dtype), k, _NN)
-
-    first, end = _key_blocks(qi, block_q, block_k, k_ref.shape[1], causal,
-                             window)
-    dq = jax.lax.fori_loop(first, end, body, jnp.zeros(q.shape, jnp.float32))
-    # dS = P * (dP - delta) * scale: the scale goes on once, at the end
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *acc, block_q, causal, scale,
-                    window=None, group=1):
-    """One (batch*kv head, k-block) program over the q blocks that see it.
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, block_q, causal,
+                scale, window=None, group=1):
+    """One (kv head, q head of its group, k block) program over the q
+    blocks that see the k block: S, the mask, P and dP are formed ONCE a
+    block pair and all three gradients take their part from them.
 
     The scores are formed transposed, [Bk, Bq] = K Q^T, so that P^T dO and
-    dS^T Q are plain matmuls: a dot that contracts dimension 0 of both
-    operands would have Mosaic transpose a [Bq, Bk] tile for each.  lse and
-    delta then come as rows, one [1, Bq] row of a [num_qb, Bq] block per q
-    block (a [seq_q, 1] block would be lane-padded 128x in VMEM).
+    dS^T Q are plain matmuls; dQ's part, dS K, is then the one dot that
+    contracts dimension 0 of both operands (Mosaic transposes the dS tile
+    for it; it hangs off dS beside dK's dot and is not on the dot ->
+    softmax -> dot chain).  lse and delta come as rows, one [1, Bq] row of a
+    [num_qb, Bq] block per q block (a [seq_q, 1] block would be lane-padded
+    128x in VMEM).
 
-    Grouped KV heads: the grid has a third, innermost axis over the
-    ``group`` q heads that read this kv head; each step holds ONE q head's
-    Q, dO and statistics, and the parts are summed in the float32 scratch
-    ``acc`` (the output block stays where it is while that axis runs, and
-    is written once, from float32, at its last step)."""
+    dQ: the q head's whole-sequence float32 ``dq_acc`` stays in VMEM while
+    the head's k blocks run (innermost axis), takes a q block's part at a
+    time, in the order of the k blocks, and is written once, scaled and in
+    the input dtype, at the head's last k block (the output block's index
+    does not move before).
+
+    dK, dV: with one q head a kv head the program owns its k block's rows
+    and writes them.  Grouped KV heads: the q heads of the group run one
+    after the other OUTSIDE the k blocks (Q and dO cross HBM once a q
+    head), so a k block's parts are summed over the group in whole-sequence
+    float32 scratch ``kv_acc`` and the whole-sequence output blocks, which
+    stay where they are for the kv head, are written a k block at a time
+    at the group's last head."""
     k = k_ref[0]                                               # [Bk, H]
     v = v_ref[0]
     block_k = k.shape[0]
     num_qb = q_ref.shape[1] // block_q
-    ki = pl.program_id(1)
+    g, ki = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _():
+        def zero(i, _):
+            dq_acc[_block_rows(i, block_q), :] = jnp.zeros(
+                (block_q, dq_acc.shape[1]), jnp.float32)
+        jax.lax.fori_loop(0, num_qb, zero, None)
 
     def body(i, carry):
         dk_acc, dv_acc = carry
@@ -322,8 +336,9 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                       window)
         pt = jnp.exp(st - lse)
         dv_new = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
-        dst = pt * (_dot(v, do, _NT) - delta)
-        dk_new = dk_acc + _dot(dst.astype(q.dtype), q, _NN)
+        dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
+        dk_new = dk_acc + _dot(dst, q, _NN)
+        dq_acc[_block_rows(i, block_q), :] += _dot(dst, k, _TN)   # [Bq, H]
         return dk_new, dv_new
 
     dk0 = jnp.zeros(k.shape, jnp.float32)
@@ -331,100 +346,110 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # q blocks before this k block's diagonal contribute nothing
     first, end = _query_blocks(ki, block_q, block_k, num_qb, causal, window)
     dk, dv = jax.lax.fori_loop(first, end, body, (dk0, dv0))
+
+    # dS = P * (dP - delta) * scale: the scale goes on once, at the end
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        def write(i, _):
+            rows = _block_rows(i, block_q)
+            dq_ref[0, rows, :] = (dq_acc[rows, :] * scale).astype(
+                dq_ref.dtype)
+        jax.lax.fori_loop(0, num_qb, write, None)
+
     if group == 1:
         dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv.astype(dv_ref.dtype)
         return
-    dk_sum, dv_sum = acc
-    g = pl.program_id(2)
+    dk_sum, dv_sum = kv_acc
+    k_rows = _block_rows(ki, block_k)
 
     @pl.when(g == 0)
     def _():
-        dk_sum[...] = dk
-        dv_sum[...] = dv
+        dk_sum[k_rows, :] = dk
+        dv_sum[k_rows, :] = dv
 
     @pl.when(g > 0)
     def _():
-        dk_sum[...] += dk
-        dv_sum[...] += dv
+        dk_sum[k_rows, :] += dk
+        dv_sum[k_rows, :] += dv
 
     @pl.when(g == group - 1)
     def _():
-        dk_ref[0] = (dk_sum[...] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_sum[...].astype(dv_ref.dtype)
+        dk_ref[0, k_rows, :] = (dk_sum[k_rows, :] * scale).astype(
+            dk_ref.dtype)
+        dv_ref[0, k_rows, :] = dv_sum[k_rows, :].astype(dv_ref.dtype)
 
 
-def _dkv_launch(bn_kv, num_kb, group, block_k, head, head_v):
-    """(grid, index maps, scratch) of the dk/dv call: ``q_head`` places a
-    whole-sequence block of the q head a step reads, ``k_block`` the k
-    block a program owns."""
+def _vmem_bytes(shape, dtype):
+    """Bytes of a block in VMEM: the last dimension padded to 128 lanes,
+    the one before to the dtype's sublane tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    tile = 8 * 4 // itemsize
+    n = (-(-rows // tile) * tile) * (-(-lanes // 128) * 128) * itemsize
+    for d in lead:
+        n *= d
+    return n
+
+
+def _bwd_blocks(group, seq_q, seq_k, head, head_v, block_q, block_k, dtype):
+    """``(ins, outs, scratch)`` of the backward call, from the shapes
+    alone: its input and output blocks as ``(shape, dtype, index map)``
+    over the grid ``(kv head, q head of its group, k block)``, its VMEM
+    scratch as ``(shape, dtype)``."""
+    f32 = jnp.float32
+    num_qb = seq_q // block_q
+    q_head = lambda b, g, j: (b * group + g, 0, 0)          # noqa: E731
+    k_block = lambda b, g, j: (b, j, 0)                      # noqa: E731
+    scratch = [((seq_q, head), f32)]                         # dQ
     if group == 1:
-        return ((bn_kv, num_kb), lambda b, j: (b, 0, 0),
-                lambda b, j: (b, j, 0), {})
-    scratch = [pltpu.VMEM((block_k, head), jnp.float32),
-               pltpu.VMEM((block_k, head_v), jnp.float32)]
-    return ((bn_kv, num_kb, group), lambda b, j, g: (b * group + g, 0, 0),
-            lambda b, j, g: (b, j, 0), {"scratch_shapes": scratch})
+        kv_rows, kv_out = block_k, k_block
+    else:
+        kv_rows, kv_out = seq_k, lambda b, g, j: (b, 0, 0)
+        scratch += [((seq_k, head), f32), ((seq_k, head_v), f32)]
+    ins = [((1, seq_q, head), dtype, q_head),
+           ((1, block_k, head), dtype, k_block),
+           ((1, block_k, head_v), dtype, k_block),
+           ((1, seq_q, head_v), dtype, q_head),
+           ((1, num_qb, block_q), f32, q_head),
+           ((1, num_qb, block_q), f32, q_head)]
+    outs = [((1, seq_q, head), dtype, q_head),
+            ((1, kv_rows, head), dtype, kv_out),
+            ((1, kv_rows, head_v), dtype, kv_out)]
+    return ins, outs, scratch
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
                interpret, window=None):
     bn, seq_q, head = q.shape
-    seq_k, head_v = k.shape[1], v.shape[2]
-    group = bn // k.shape[0]
-    kv_head = _kv_head(group)
-    num_qb = seq_q // block_q
-    params = _compiler_params(head, head_v)
+    bn_kv, seq_k, head_v = k.shape[0], k.shape[1], v.shape[2]
+    group = bn // bn_kv
     # delta = rowsum(dO * O) — cheap elementwise, leave to XLA fusion
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale, window=window),
-        name=_kernel_name("bwd_dq", window),
-        grid=(bn, num_qb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, head), kv_head),
-            pl.BlockSpec((1, seq_k, head_v), kv_head),
-            pl.BlockSpec((1, block_q, head_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-        **params,
-    )(q, k, v, do, lse, delta)
-
-    rows = (bn, num_qb, block_q)
-    grid, q_head, k_block, scratch = _dkv_launch(
-        k.shape[0], seq_k // block_k, group, block_k, head, head_v)
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, causal=causal,
+    rows = (bn, seq_q // block_q, block_q)
+    ins, outs, scratch = _bwd_blocks(group, seq_q, seq_k, head, head_v,
+                                     block_q, block_k, q.dtype)
+    # the allowance, reckoned from the blocks' bytes: what is resident
+    # (every block twice, Pallas double-buffers them; the scratch once:
+    # 28 MB at [8192, 192 | 128] bf16, 32 MB grouped at [8192, 128]) and
+    # Mosaic's own default of 16 MB on top, for the [Bk, Bq] float32 tiles
+    # between the dots
+    resident = (2 * sum(_vmem_bytes(s, dt) for s, dt, _ in ins + outs)
+                + sum(_vmem_bytes(s, dt) for s, dt in scratch))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, block_q=block_q, causal=causal,
                           scale=scale, window=window, group=group),
-        name=_kernel_name("bwd_dkv", window),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, seq_q, head), q_head),
-            pl.BlockSpec((1, block_k, head), k_block),
-            pl.BlockSpec((1, block_k, head_v), k_block),
-            pl.BlockSpec((1, seq_q, head_v), q_head),
-            pl.BlockSpec((1, num_qb, block_q), q_head),
-            pl.BlockSpec((1, num_qb, block_q), q_head),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, head), k_block),
-            pl.BlockSpec((1, block_k, head_v), k_block),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
+        name=_kernel_name("bwd_dq_dkv", window),
+        grid=(bn_kv, group, seq_k // block_k),
+        in_specs=[pl.BlockSpec(s, at) for s, _, at in ins],
+        out_specs=[pl.BlockSpec(s, at) for s, _, at in outs],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM(s, dt) for s, dt in scratch],
         interpret=interpret,
-        **scratch,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=resident + 16 * 1024 * 1024),
     )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
     return dq, dk, dv
 
@@ -449,10 +474,33 @@ def _block_candidates(seq_q, seq_k):
     to 8.2: the diagonal blocks compute more masked scores than the longer
     block saves), at seq 4096 too (11.1 against 10.9).  head_dim 64 at seq
     1024 orders the shapes the same way (4.8, 2.8, 2.3 ms), so the head
-    size does not enter the choice.  Mosaic takes all three kernels at 512 x
-    512 for head_dim 64 and 128 as far as it took them at 128 x 128: seq
-    8192 in bf16, 4096 in float32 (AOT for the v5e; beyond, K and V no
-    longer fit VMEM whole)."""
+    size does not enter the choice.  Mosaic takes the forward at 512 x 512
+    for head_dim 64 and 128 as far as it took it at 128 x 128: seq 8192 in
+    bf16, 4096 in float32 (AOT for the v5e; beyond, K and V no longer fit
+    VMEM whole).
+
+    The ONE backward kernel (PR 33, the kernels alone on the v5e, bf16
+    causal; forward | forward + backward of a call, ms; in brackets the two
+    backward kernels it replaced, 512 x 512):
+
+    ==========  ============  ============  ============  ===========
+    blocks      [64, 8192,    [48 over 8,   the same, 72  [128, 2048,
+    q x k       192 | 128]    8192, 128]    q heads,      128]
+                                            window 512
+    ==========  ============  ============  ============  ===========
+    512 x 512   15.01 | 44.98 7.89 | 21.54  3.88 | 9.86   2.02 | 4.93
+                [| 56.52]     [| 27.34]     [| 14.21]     [| 6.33]
+    256 x 512   15.32 | 48.05 8.19 | 25.78  3.95 | 11.09  2.05 | 5.77
+    512 x 256   20.32 | 51.10 11.79 | 27.68 5.14 | 11.88  2.81 | 6.12
+    1024 x 512  16.19 | 45.85 8.43 | 21.94  5.16 | 12.59  2.35 | 5.60
+    512 x 1024  14.87 | 45.38 7.86 | 21.84  4.75 | 12.25  2.22 | 5.61
+    256 x 256   24.11 | 57.16 14.63 | 38.35 4.96 | 12.64  3.10 | 7.72
+    ==========  ============  ============  ============  ===========
+
+    512 x 512 stays.  The backward alone, 41.5 -> 30.0, 19.5 -> 13.6, 10.3
+    -> 6.0 and 4.30 -> 2.91 ms, is now within a fifth of what the MXU takes
+    for the five dots as it runs them (a 192-wide side costs it 256); the
+    transposed dS tile of dQ's dot does not show beside that."""
     qs = [b for b in (512, 256, 128, 64) if seq_q % b == 0]
     ks = [b for b in (512, 256, 128, 64) if seq_k % b == 0]
     if not qs:
@@ -464,29 +512,26 @@ def _block_candidates(seq_q, seq_k):
     return head + rest
 
 
-def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None):
+def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None,
+                   group=1):
     """Candidate screen for autotune.pick: reject (block_q, block_k) whose
-    per-grid-step residency (kernel_lint's K002 model — double-buffered
-    blocks) cannot fit VMEM for the forward, dq, or dkv kernel."""
+    per-grid-step residency (kernel_lint's K002 model: double-buffered
+    blocks, the scratch once) cannot fit the profile's VMEM for the
+    forward or the backward kernel."""
     from ...framework.kernel_lint import vmem_fits
 
-    f32 = jnp.float32
     hv = head if head_v is None else head_v
 
     def validate(cand):
         bq, bk = cand
         fwd = [((1, bq, head), dtype), ((1, seq_k, head), dtype),
                ((1, seq_k, hv), dtype), ((1, bq, hv), dtype),
-               ((1, bq, 1), f32)]
-        dq = [((1, bq, head), dtype), ((1, seq_k, head), dtype),
-              ((1, seq_k, hv), dtype), ((1, bq, hv), dtype),
-              ((1, bq, 1), f32), ((1, bq, 1), f32), ((1, bq, head), dtype)]
-        dkv = [((1, seq_q, head), dtype), ((1, bk, head), dtype),
-               ((1, bk, hv), dtype), ((1, seq_q, hv), dtype),
-               ((1, seq_q // bq, bq), f32), ((1, seq_q // bq, bq), f32),
-               ((1, bk, head), dtype), ((1, bk, hv), dtype)]
-        return all(vmem_fits(blocks, profile=profile)
-                   for blocks in (fwd, dq, dkv))
+               ((1, bq, 1), jnp.float32)]
+        ins, outs, scratch = _bwd_blocks(group, seq_q, seq_k, head, hv, bq,
+                                         bk, dtype)
+        bwd = [(s, dt) for s, dt, _ in ins + outs]
+        return (vmem_fits(fwd, profile=profile)
+                and vmem_fits(bwd, scratch, profile=profile))
 
     return validate
 
@@ -531,7 +576,8 @@ def _tuned_blocks(q, k, v, causal, scale, interpret, window=None):
     return autotune.pick(
         "flash_attention", key,
         cands, measure=measure,
-        validate=_vmem_validate(seq_q, seq_k, head, q.dtype, head_v=head_v))
+        validate=_vmem_validate(seq_q, seq_k, head, q.dtype, head_v=head_v,
+                                group=group))
 
 
 # the forward's own results among the residuals, by the names a
@@ -565,7 +611,7 @@ _flash_attention_bnsh.defvjp(_fwd_rule, _bwd_rule)
 def _engine_cases(engine):
     """Sweep flash at the engine's full-context envelope with per-shard
     head counts; the vjp case traces jax.grad through the custom_vjp so
-    the lint sees the backward kernels (_bwd_dq/_bwd_dkv) too."""
+    the lint sees the backward kernel too."""
     n = max(engine.num_heads // engine.tp, 1)
     h = engine.head_dim
     seq = engine.max_model_len
@@ -586,7 +632,8 @@ def _engine_cases(engine):
     yield registry.KernelCase(f"vjp[s{seq}]", vjp, (x, x, x), None)
     # a sliding window (an eighth of the context) over grouped KV heads
     # (every q head on one kv head): the block ranges that follow the
-    # window, the kv block index, dk/dv's third grid axis and its scratch
+    # window, the kv block index, the backward's group axis and the
+    # whole-sequence dK and dV it sums in scratch
     window = max(seq // 8, 1)
     one_kv = sds((engine.max_batch, seq, 1, h), engine.dtype)
 

@@ -35,8 +35,10 @@ kernels, by what a program OWNS:
 - ``eva_attention_fwd``      a q block: o and the row statistics (lse);
 - ``eva_attention_bwd_dq``   a q block: dq, over the same two loops;
 - ``eva_attention_bwd_dkv``  a block of exact keys: dk, dv over the q blocks
-  of its window from its diagonal on (``attention_kernel._bwd_dkv_kernel``
-  itself, on the folded rows, with the joint statistics);
+  of its window from its diagonal on (plain causal flash dk/dv on the
+  folded rows, with the joint statistics; the flash backward proper has
+  fused its dq into this loop since PR 33, which the joint softmax over two
+  kinds of key does not allow here);
 - ``eva_attention_bwd_dkv_summaries``  a block of summaries: dkt, dvt over
   the q blocks of every LATER window; a third grid axis runs over those
   windows and sums their parts in a float32 VMEM scratch, as the flash
@@ -66,7 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
-from .attention_kernel import (_NEG_INF, _NN, _NT, _bwd_dkv_kernel, _dot,
+from .attention_kernel import (_NEG_INF, _NN, _NT, _dot,
                                _mask_below_diagonal, _rows)
 
 SAVED_BY_NAME = ("eva_attention_out", "eva_attention_lse")
@@ -215,6 +217,40 @@ def _first_window(s, block_s, per_window):
     return (s * block_s) // per_window + 1
 
 
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, *, block_q, scale):
+    """One (row, block of exact keys) program over the q blocks of the
+    window from the block's diagonal on.  The scores are formed transposed,
+    [Bk, Bq] = K Q^T, so that P^T dO and dS^T Q are plain matmuls; lse and
+    delta come as rows, one [1, Bq] row of a [num_qb, Bq] block per q block
+    (a [W, 1] block would be lane-padded 128x in VMEM)."""
+    k = k_ref[0]                                               # [Bk, H]
+    v = v_ref[0]
+    block_k = k.shape[0]
+    ki = pl.program_id(1)
+
+    def body(i, carry):
+        dk_acc, dv_acc = carry
+        q = _rows(q_ref, i, block_q)
+        do = _rows(do_ref, i, block_q)
+        lse = lse_ref[0, pl.ds(i, 1), :]                       # [1, Bq]
+        delta = delta_ref[0, pl.ds(i, 1), :]
+        st = _dot(k, q, _NT) * scale                           # [Bk, Bq]
+        st = _mask_below_diagonal(st, i * block_q, ki * block_k, 1)
+        pt = jnp.exp(st - lse)
+        dv_new = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
+        dst = pt * (_dot(v, do, _NT) - delta)
+        dk_new = dk_acc + _dot(dst.astype(q.dtype), q, _NN)
+        return dk_new, dv_new
+
+    zero = jnp.zeros(k.shape, jnp.float32)
+    # q blocks before this k block's diagonal contribute nothing
+    dk, dv = jax.lax.fori_loop((ki * block_k) // block_q,
+                               q_ref.shape[1] // block_q, body, (zero, zero))
+    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv.astype(dv_ref.dtype)
+
+
 def _bwd_dkv_summaries_kernel(q_ref, kt_ref, vt_ref, do_ref, lse_ref,
                               delta_ref, dkt_ref, dvt_ref, dk_sum, dv_sum,
                               *, block_q, nw, per_window, scale):
@@ -321,8 +357,7 @@ def _eva_bwd(q, k, v, kt, vt, out, lse_rows, do, nw, per_window, scale,
     k_block = pl.BlockSpec((1, block_k, head), lambda r, j: (r, j, 0))
     whole = lambda r, j: (r, 0, 0)                          # noqa: E731
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=block_q, causal=True,
-                          scale=scale),
+        functools.partial(_bwd_dkv_kernel, block_q=block_q, scale=scale),
         name="eva_attention_bwd_dkv",
         grid=(rows, window // block_k),
         in_specs=[pl.BlockSpec((1, window, head), whole), k_block, k_block,

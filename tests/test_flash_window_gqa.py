@@ -84,6 +84,56 @@ def test_kernels_against_dense_masked_attention(small_blocks, window, group,
                                    atol=2e-5, err_msg=name)
 
 
+# (q/k width, v width, q heads over kv heads, window, causal, dtype): one
+# width; 192 | 128; grouped 2 and 6; a window narrower than, equal to and
+# wider than a block; non-causal (every k block visits every q block);
+# the chip's dtype
+FUSED_BACKWARD_CASES = {
+    "one-width": (32, 32, (2, 2), None, True, jnp.float32),
+    "mla192|128": (192, 128, (2, 2), None, True, jnp.float32),
+    "grouped2": (32, 32, (4, 2), None, True, jnp.float32),
+    "grouped6": (32, 32, (6, 1), None, True, jnp.float32),
+    "window16": (32, 32, (2, 2), 16, True, jnp.float32),
+    "window64-grouped2": (32, 32, (4, 2), BLOCK, True, jnp.float32),
+    "window100-grouped6": (32, 32, (6, 1), 100, True, jnp.float32),
+    "non-causal": (32, 32, (2, 2), None, False, jnp.float32),
+    "non-causal-grouped2": (32, 32, (4, 2), None, False, jnp.float32),
+    "bf16-mla192|128": (192, 128, (2, 2), None, True, jnp.bfloat16),
+    "bf16-window100-grouped6": (32, 32, (6, 1), 100, True, jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", list(FUSED_BACKWARD_CASES))
+def test_the_one_backward_kernel_against_the_xla_composition(small_blocks,
+                                                             case):
+    """The backward is ONE kernel: S, the mask, P and dP formed once a block
+    pair, dQ summed in VMEM over the q head's four k blocks, dK and dV over
+    the group.  All three gradients against ``_xla_attention``'s."""
+    head, head_v, (n, nkv), window, causal, dtype = FUSED_BACKWARD_CASES[case]
+    q = _rand((2, SEQ, n, head), 11, dtype)
+    k = _rand((2, SEQ, nkv, head), 12, dtype)
+    v = _rand((2, SEQ, nkv, head_v), 13, dtype)
+    do = _rand((2, SEQ, n, head_v), 14, dtype)
+    got = _out_and_grads(
+        lambda q, k, v: ak.flash_attention_pallas(
+            q, k, v, is_causal=causal, interpret=True, window=window),
+        q, k, v, do)
+    f32 = lambda x: x.astype(jnp.float32)                   # noqa: E731
+    want = _out_and_grads(
+        lambda q, k, v: pk._xla_attention(f32(q), f32(k), f32(v),
+                                          is_causal=causal, window=window),
+        q, k, v, do)
+    for g, w, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert g.shape == w.shape and g.dtype == dtype, name
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(g, w, rtol=5e-5, atol=5e-5,
+                                       err_msg=name)
+        else:           # two bf16 steps of the tensor's largest value
+            assert np.abs(g - w).max() <= 2 * 2.0 ** -8 * np.abs(w).max(), \
+                name
+
+
 @pytest.mark.parametrize("group", [1, 6])
 def test_a_window_as_long_as_the_sequence_is_plain_causal(small_blocks,
                                                           group):
@@ -171,20 +221,20 @@ def test_window_calls_are_named_apart_and_plain_calls_as_ever():
                 for e in sub.eqns if e.primitive.name == "pallas_call"}
 
     plain = calls(x, None)
-    assert set(plain) == {"flash_attention_fwd", "flash_attention_bwd_dq",
-                          "flash_attention_bwd_dkv"}
-    assert set(calls(x, 64)) == {
-        "flash_window64_attention_fwd", "flash_window64_attention_bwd_dq",
-        "flash_window64_attention_bwd_dkv"}
+    assert set(plain) == {"flash_attention_fwd", "flash_attention_bwd_dq_dkv"}
+    assert set(calls(x, 64)) == {"flash_window64_attention_fwd",
+                                 "flash_window64_attention_bwd_dq_dkv"}
     grouped = calls(kv, None)
     assert set(grouped) == set(plain)
+    # the backward's grid: (kv head, q head of its group, k block); the q
+    # head is OUTSIDE the k blocks, so its dQ stays in VMEM while they run
     grid = lambda e: tuple(e.params["grid_mapping"].grid)   # noqa: E731
-    assert len(grid(plain["flash_attention_bwd_dkv"])) == 2
-    assert grid(grouped["flash_attention_bwd_dkv"])[2] == 2   # the group
-    assert plain["flash_attention_bwd_dkv"].params[
-        "grid_mapping"].num_scratch_operands == 0
-    assert grouped["flash_attention_bwd_dkv"].params[
-        "grid_mapping"].num_scratch_operands == 2
+    bwd = "flash_attention_bwd_dq_dkv"
+    assert grid(plain[bwd])[:2] == (2 * 4, 1)
+    assert grid(grouped[bwd])[:2] == (2 * 2, 2)
+    # scratch: float32 dQ of the q head; grouped, whole-sequence dK and dV
+    assert plain[bwd].params["grid_mapping"].num_scratch_operands == 1
+    assert grouped[bwd].params["grid_mapping"].num_scratch_operands == 3
 
 
 @pytest.mark.parametrize("group", [1, 3])
@@ -251,12 +301,12 @@ def test_dispatch_takes_the_kernels_for_window_and_groups(monkeypatch):
                 lowering_platforms=("tpu",)).as_text()
 
     text = lowered(q72, 512)
-    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+    for kernel in ("fwd", "bwd_dq_dkv"):
         assert f'kernel_name = "flash_window512_attention_{kernel}"' in text
     assert "dot_general" not in text and "8192x8192" not in text
     assert "1x8192x72x128" in text and "72x8192x8192" not in text
     text = lowered(q48, None)
-    for kernel in ("fwd", "bwd_dq", "bwd_dkv"):
+    for kernel in ("fwd", "bwd_dq_dkv"):
         assert f'kernel_name = "flash_attention_{kernel}"' in text
     assert "dot_general" not in text
 

@@ -19,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 import paddle_tpu as paddle
 from paddle_tpu.framework import analysis as A
@@ -103,6 +104,24 @@ class TestSeededKernelBugs:
                 and x.severity == "error"]
         assert hits and "binding buffer: x_ref" in hits[0].message
         assert str(16 * 1024 * 1024) in hits[0].message
+
+    def test_k002_holds_a_call_to_the_allowance_it_asks(self):
+        # the same copy under a vmem_limit_bytes of its own: 64 MiB
+        # resident fits 80 MiB asked, and overflows 48
+        def f(limit):
+            return lambda x: pl.pallas_call(
+                _copy_kernel, grid=(1,),
+                in_specs=[pl.BlockSpec((8, 524288), lambda i: (0, 0))],
+                out_specs=pl.BlockSpec((8, 524288), lambda i: (0, 0)),
+                out_shape=SDS((8, 524288), jnp.float32),
+                compiler_params=pltpu.CompilerParams(
+                    vmem_limit_bytes=limit << 20))(x)
+        errors = lambda limit: [                       # noqa: E731
+            x for x in KL.analyze_kernel(f(limit), SDS((8, 524288),
+                                                       jnp.float32))
+            if x.rule == "K002" and x.severity == "error"]
+        assert not errors(80)
+        assert errors(48) and str(48 << 20) in errors(48)[0].message
 
     def test_k002_respects_profile(self):
         blocks = [((8, 524288), jnp.float32)]
@@ -418,6 +437,39 @@ class TestSupportsConsistency:
                     lambda q, k, v: flash_attention_pallas(
                         q, k, v, is_causal=True), x, x, x)
                 self._no_errors(fs, f"flash seq={seq} h={h}")
+
+    @pytest.mark.parametrize("q_heads,kv_heads,head,head_v,window", [
+        (32, 32, 192, 128, None), (48, 8, 128, 128, None),
+        (72, 8, 128, 128, 512)])
+    def test_flash_backward_at_the_cells_shapes(self, q_heads, kv_heads,
+                                                head, head_v, window):
+        """The ONE backward kernel at seq 8192 bf16 as kanana's and
+        laguna's cells call it: the q head's dQ block is revisited on
+        purpose, one k block after the other, and (grouped) the kv head's
+        whole-sequence dK and dV over the whole group: K004 takes both;
+        K002 holds the residency to the allowance the call reckons from
+        its blocks, which is past the profile's 16 MiB."""
+        from paddle_tpu.ops.pallas.attention_kernel import (
+            flash_attention_pallas)
+
+        q = SDS((1, 8192, q_heads, head), jnp.bfloat16)
+        k = SDS((1, 8192, kv_heads, head), jnp.bfloat16)
+        v = SDS((1, 8192, kv_heads, head_v), jnp.bfloat16)
+
+        def vjp(q, k, v):
+            def loss(*a):
+                return jnp.sum(flash_attention_pallas(
+                    *a, is_causal=True, window=window).astype(jnp.float32))
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        self._no_errors(KL.analyze_kernel(vjp, q, k, v), "flash vjp")
+        bwd, = [ki for ki in KL.introspect_kernels(vjp, q, k, v)
+                if "_bwd_" in ki.name]
+        assert bwd.name.endswith("_bwd_dq_dkv")
+        assert bwd.grid == (kv_heads, q_heads // kv_heads, 16)
+        resident = KL.estimate_residency(
+            [(b.block_shape, b.dtype) for b in bwd.blocks], bwd.scratch)
+        assert 16 << 20 < resident < bwd.vmem_limit <= 64 << 20
 
     def test_decode_attention_sweep(self):
         from paddle_tpu.ops.pallas.decode_attention_kernel import (

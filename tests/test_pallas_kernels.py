@@ -209,10 +209,10 @@ def test_flash_attention_dots_take_the_input_dtype(dtype):
              dot.params["preferred_element_type"])
             for dot in _eqns(call.params["jaxpr"], "dot_general")]
         for call in _eqns(jax.make_jaxpr(fwd_bwd)(x, x, x, x), "pallas_call")}
-    # fwd QK^T, PV; dq QK^T, dO V^T, dS K; dkv KQ^T, P^T dO, V dO^T, dS^T Q
+    # fwd QK^T, PV; bwd KQ^T, P^T dO, V dO^T, dS^T Q, dS K: the five the
+    # mathematics needs, S and dP formed once a block pair
     assert {name: len(d) for name, d in dots.items()} == {
-        "flash_attention_fwd": 2, "flash_attention_bwd_dq": 3,
-        "flash_attention_bwd_dkv": 4}
+        "flash_attention_fwd": 2, "flash_attention_bwd_dq_dkv": 5}
     want = ((jnp.dtype(dtype),) * 2, jnp.dtype(jnp.float32))
     for name, found in dots.items():
         for operands, acc in found:

@@ -168,10 +168,9 @@ def test_flash_under_the_dp_mp_mesh_lowers_for_tpu(monkeypatch):
                            match="cannot be automatically partitioned"):
             grads(lambda q, k, v: flash_attention_pallas(
                 q, k, v, True)).lower(lowering_platforms=("tpu",))
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert f'kernel_name = "{kernel}"' in text, kernel
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
 
 
 def _gpt3_width(**kw):
@@ -221,8 +220,7 @@ def test_dp_mp_step_with_the_viewed_qkv_lowers_for_tpu(monkeypatch):
     with use_mesh(mesh):
         text = trainer._compiled.trace(*args).lower(
             lowering_platforms=("tpu",)).as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
-                   "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
         assert f'kernel_name = "{kernel}"' in text, kernel
     assert "1x4096x3x32x128xbf16" in text
     assert "12288" not in text
@@ -336,25 +334,27 @@ def test_a_small_buckets_branch_holds_no_worst_case_residual(monkeypatch):
     assert any(f"<{buckets[-1]}x" in r for r in naive), naive
 
 
-# The three flash kernels as Mosaic gets them (the module inside each
+# The flash kernels as Mosaic gets them (the module inside each
 # ``tpu_custom_call``, its source locations stripped: they shift with
 # every edit to the kernel file and are no part of the program), hashed, at
-# the two shapes the benchmark's cells call them with.  Read at PR 29's tree
-# (eff4393) and unchanged by PR 30, which taught the kernels windows and
-# grouped KV heads: a call with neither is the program it was.  A new JAX
-# may print a module differently; then read them again at a tree whose
-# kernels are known to be these (``_mosaic_bodies`` of the same calls).
+# the two shapes the benchmark's cells call them with.  The FORWARD's were
+# read at PR 29's tree (eff4393) and are unchanged by PR 30, which taught
+# the kernels windows and grouped KV heads, and by PR 33, which made the
+# backward ONE kernel and did not touch the forward: a call with neither
+# window nor groups runs the forward program it ran.  The backward's were
+# read at PR 33's tree: the module that replaced ``flash_attention_bwd_dq``
+# and ``flash_attention_bwd_dkv``.  A new JAX may print a module
+# differently; then read them again at a tree whose kernels are known to be
+# these (``_mosaic_bodies`` of the same calls).
 _PLAIN_FLASH_BODIES = {
     # gpt3-6.7b-train*.seq2048: [4, 2048, 32, 128] bf16, causal
     ((4, 2048, 32, 128), 128): {
         "flash_attention_fwd": "96c8f2b52eef27b1",
-        "flash_attention_bwd_dq": "486445c074163aa2",
-        "flash_attention_bwd_dkv": "37049469e0b88a0e"},
+        "flash_attention_bwd_dq_dkv": "4ed72c333b785416"},
     # kanana-2-30b-a3b-train-ep8.seq8192: [2, 8192, 32, 192 | 128]
     ((2, 8192, 32, 192), 128): {
         "flash_attention_fwd": "ca2d4efac45765b2",
-        "flash_attention_bwd_dq": "cf8a1bb56f8d69dc",
-        "flash_attention_bwd_dkv": "705ff53ab5f0e5cd"},
+        "flash_attention_bwd_dq_dkv": "342e0ec67b6eeb2c"},
 }
 
 
@@ -410,7 +410,7 @@ def test_plain_flash_calls_lower_as_at_the_parent(shape, v_dim):
 
 
 def test_window_and_grouped_calls_are_other_programs():
-    """The new cell's two calls: their own names and their own modules,
+    """Laguna's two calls: their own names and their own modules,
     and K and V at eight heads all the way into the custom calls."""
     q72 = jax.ShapeDtypeStruct((1, 8192, 72, 128), jnp.bfloat16)
     q48 = jax.ShapeDtypeStruct((1, 8192, 48, 128), jnp.bfloat16)
@@ -418,13 +418,113 @@ def test_window_and_grouped_calls_are_other_programs():
     window = _mosaic_bodies(_flash_grads_lowered(q72, kv, kv, window=512))
     full = _mosaic_bodies(_flash_grads_lowered(q48, kv, kv))
     assert set(window) == {f"flash_window512_attention_{k}"
-                           for k in ("fwd", "bwd_dq", "bwd_dkv")}
+                           for k in ("fwd", "bwd_dq_dkv")}
     assert set(full) == {f"flash_attention_{k}"
-                         for k in ("fwd", "bwd_dq", "bwd_dkv")}
+                         for k in ("fwd", "bwd_dq_dkv")}
     known = {h for bodies in _PLAIN_FLASH_BODIES.values()
              for h in bodies.values()}
     assert not known & (set(window.values()) | set(full.values()))
     assert "tensor<8x8192x128xbf16>" in _flash_grads_lowered(q48, kv, kv)
+
+
+def test_eva_kernels_lower_as_before_the_flash_backward_was_one_kernel():
+    """``eva_attention_bwd_dkv`` ran ``attention_kernel._bwd_dkv_kernel``,
+    which PR 33 took away with the flash dq kernel; the block-window
+    kernels keep a dk/dv kernel of their own (their dq runs a joint softmax
+    over exact keys and summaries) and hand Mosaic the four modules they
+    did at evabyte's shape (read at PR 32's tree, 0522594)."""
+    from paddle_tpu.ops.pallas.eva_attention_kernel import (
+        eva_attention_pallas)
+
+    x = jax.ShapeDtypeStruct((1, 16384, 32, 128), jnp.bfloat16)
+    s = jax.ShapeDtypeStruct((1, 1024, 32, 128), jnp.bfloat16)
+
+    def f(*operands):
+        def loss(*a):
+            return jnp.sum(eva_attention_pallas(
+                *a, window=2048, chunk=16).astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*operands)
+
+    text = jax.jit(f).trace(x, x, x, s, s).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert _mosaic_bodies(text) == {
+        "eva_attention_fwd": "723736c00c92473e",
+        "eva_attention_bwd_dq": "1e689f20db398c30",
+        "eva_attention_bwd_dkv": "b5dfebcf1290a243",
+        "eva_attention_bwd_dkv_summaries": "9debdc4d878fceb5"}
+
+
+# the flash calls of the benchmark's cells: (q, kv, v width, window, the
+# benchmark's counter of calls and the mark it looks for)
+_CELL_FLASH_CALLS = {
+    "gpt3-6.7b": ((4, 2048, 32, 128), 32, 128, None, "mla", None),
+    "kanana-mla": ((2, 8192, 32, 192), 32, 128, None, "mla", None),
+    "laguna-full": ((1, 8192, 48, 128), 8, 128, None, "gqa",
+                    "flash_attention"),
+    "laguna-window": ((1, 8192, 72, 128), 8, 128, 512, "gqa",
+                      "flash_window"),
+}
+
+
+def _cell_flash_lowered(case):
+    shape, kv_heads, v_dim, window, _, _ = _CELL_FLASH_CALLS[case]
+    b, t, _, h = shape
+    return _flash_grads_lowered(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, t, kv_heads, h), jnp.bfloat16),
+        jax.ShapeDtypeStruct((b, t, kv_heads, v_dim), jnp.bfloat16),
+        window=window)
+
+
+@pytest.mark.parametrize("case", list(_CELL_FLASH_CALLS))
+def test_a_flash_backward_is_one_custom_call(case):
+    """Forward and gradients of one flash call, lowered for the TPU: TWO
+    ``tpu_custom_call``s, the forward and the ONE backward (it was three:
+    dq and dk/dv formed the scores and dP a second time)."""
+    import re
+
+    text = _cell_flash_lowered(case)
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    stem = ("flash_attention" if _CELL_FLASH_CALLS[case][3] is None
+            else "flash_window512_attention")
+    assert sorted(names) == [f"{stem}_bwd_dq_dkv", f"{stem}_fwd"]
+    assert text.count("tpu_custom_call") == 2
+
+
+@pytest.mark.parametrize("case", list(_CELL_FLASH_CALLS))
+def test_the_benchmark_counts_one_backward_call_by_the_kernels_names(case):
+    """The naming contract with ``chipbench/kernel_costs``: a trace names
+    a kernel's event ``<kernel_name>.<n> <shape>``; the rooflines count
+    events that hold ``_fwd`` as forward calls and events that hold
+    ``_bwd_dq`` as backward calls, and ``trace.kernel_seconds`` charges
+    every event whose name holds the metric's pattern.  Fed the names this
+    tree lowers to, the benchmark's own counters find one forward and one
+    backward a flash call, and every event's time is charged."""
+    import re
+    import types
+
+    from chipbench.kernel_costs import (flash_attention_gqa,
+                                        flash_attention_mla)
+
+    _, _, _, window, counter, mark = _CELL_FLASH_CALLS[case]
+    names = re.findall(r'kernel_name = "([^"]+)"', _cell_flash_lowered(case))
+    calls = 3
+    events = [(f"{name}.{i} bf16[8,8192,128]", 0.0, 1.0)
+              for i in range(calls) for name in names]
+    events.append(("fusion.7 f32[8,8192,1]", 0.0, 1.0))
+    env = types.SimpleNamespace(traced={"devices": {0: events}})
+    if counter == "mla":
+        counted = flash_attention_mla.calls_in_window(env)
+    else:
+        counted = flash_attention_gqa.calls_in_window(env, mark)
+    assert counted == (calls, calls)
+    pattern = "flash_window" if window else "flash_attention"
+    assert all(pattern in name for name in names)
+    if window is None:      # a full call is no window call, nor the reverse
+        assert flash_attention_gqa.calls_in_window(
+            env, "flash_window") == (0, 0)
+    else:
+        assert flash_attention_mla.calls_in_window(env) == (0, 0)
 
 
 @pytest.mark.slow
