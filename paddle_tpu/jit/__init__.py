@@ -308,6 +308,7 @@ class TrainStep:
     them and read them when the timing is over (``docs/PROFILER.md``).
     """
 
+    @StepTrace.init
     def __init__(self, model, loss_fn, optimizer, donate=True, remat=False,
                  scaler=None):
         self.model = model
@@ -444,6 +445,14 @@ class TrainStep:
         compiled (1 after the first; more means a shape or a dtype changed
         under way -- the trace marks which step, ``train_step::compiled``)."""
         return {"steps": self._step, "compiles": self._trace.compiles}
+
+    def compile_account(self):
+        """The compile log's record of the newest call that compiled
+        (``profiler.compile_log``, docs/PROFILER.md): what building the
+        step took, whether the persistent cache served it, and the
+        compiler's memory account of the executable; ``None`` before the
+        first call."""
+        return self._trace.account
 
     def sync_to_model(self):
         """Rebind updated device arrays into the model's Parameters."""

@@ -106,6 +106,7 @@ class SpmdTrainStep:
     ``sync_to_model()`` give the model's stored layout back.
     """
 
+    @StepTrace.init
     def __init__(self, model, optimizer, mesh, n_microbatches=1,
                  sequence_parallel=False, remat=False, zero_stage=1,
                  virtual_pp=1, scaler=None, zero_axis=None):
@@ -322,6 +323,11 @@ class SpmdTrainStep:
     def stats(self):
         """``{"steps", "compiles"}``, as ``jit.TrainStep.stats``."""
         return {"steps": self._step_count, "compiles": self._trace.compiles}
+
+    def compile_account(self):
+        """The newest compile's record, as ``jit.TrainStep
+        .compile_account``; the bytes are ONE chip's."""
+        return self._trace.account
 
     def _canonical_blocks(self, tree):
         """``tree`` (the block parameters, or their optimizer state) as the

@@ -10,11 +10,14 @@ eager dispatch when a profiler is active) and aggregated into the reference's
 summary-table shape.
 """
 
+import collections
 import contextlib
+import functools
 import json
 import os
 import threading
 import time
+import warnings
 from enum import Enum
 
 import jax
@@ -112,48 +115,190 @@ class RecordEvent:
 
 
 class _ExecutablesBuilt:
-    """How many executables this process has compiled or loaded from the
-    persistent cache so far: jax reports each to its monitoring
-    listeners.  One listener for the process, registered by the first
-    ``StepTrace`` (jax offers no public way to take one away again)."""
+    """The process's compile log: one small record for every executable
+    this process has compiled or loaded from the persistent cache.  jax
+    reports each to its monitoring listeners, the seconds as duration
+    events and the cache's answer as plain ones; this class is the ONE
+    listener of both kinds, registered when this module is imported (jax
+    offers no public way to take one away again) and run only when
+    something compiles.
 
+    A record is closed by the backend's event: ``at`` (``perf_counter``
+    when it came), ``since`` (when its tracing began), ``program`` (jax's
+    name, ``jit(train_step)``), ``trace_s`` and ``lower_s`` (the program's
+    own trace, which holds those of the jits it calls, and its lowering,
+    which holds what its rules trace), ``backend_s`` (the compile, or the
+    cache's retrieval where there was one) and ``cache``: ``hit``, ``miss``, or
+    ``off`` where no persistent cache was asked.  ``count`` is the number
+    of records ever closed; ``log`` keeps the newest ``KEEP``."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
     EVENT = "/jax/core/compile/backend_compile_duration"
+    CACHE = {"/jax/compilation_cache/cache_hits": "hit",
+             "/jax/compilation_cache/cache_misses": "miss"}
+    KEEP = 4096
     count = 0
-    _listening = False
+    log = collections.deque(maxlen=KEEP)
+    _lock = threading.Lock()
+
+    class _Open(threading.local):
+        """What this thread's next record has gathered so far (one thread
+        traces, lowers and compiles a program), and its last record."""
+
+        NONE = (float("inf"), 0.0)      # (start, seconds) of no event
+        last = None
+
+        def __init__(self):
+            self.reset()
+
+        def reset(self):
+            self.traces = []        # (start, seconds), none inside another
+            self.trace = self.lower = self.NONE     # the program's own
+            self.cache = "off"
+
+    _open = _Open()
 
     @classmethod
-    def listen(cls):
-        if not cls._listening:
-            cls._listening = True
-            jax.monitoring.register_event_duration_secs_listener(cls._on)
+    def _on_cache(cls, event, **_kw):
+        if event in cls.CACHE:
+            cls._open.cache = cls.CACHE[event]
 
     @classmethod
-    def _on(cls, event, _seconds, **_kw):
-        if event == cls.EVENT:
-            cls.count += 1
+    def _on(cls, event, seconds, fun_name="", **_kw):
+        mine, now = cls._open, time.perf_counter()
+        start = now - seconds
+        if event == cls.TRACE:
+            # every jit called while another is traced reports its own
+            # trace first: the one that holds them replaces them
+            while mine.traces and mine.traces[-1][0] >= start:
+                mine.traces.pop()
+            mine.traces.append((start, seconds))
+        elif event == cls.LOWER:
+            # the program's trace is the last that began before its
+            # lowering did; what a lowering rule traced is in ``lower_s``
+            own = [t for t in mine.traces if t[0] < start]
+            mine.trace = own[-1] if own else mine.NONE
+            mine.lower = (start, seconds)
+            mine.traces = []
+        elif event == cls.EVENT:
+            cls.add({"kind": "executable", "program": fun_name, "at": now,
+                     "since": min(mine.trace[0], mine.lower[0], start),
+                     "trace_s": mine.trace[1], "lower_s": mine.lower[1],
+                     "backend_s": seconds, "cache": mine.cache})
+            mine.reset()
+
+    @classmethod
+    def add(cls, record):
+        with cls._lock:
+            cls.log.append(record)
+            if record["kind"] == "executable":
+                cls.count += 1
+                cls._open.last = record
+
+    @classmethod
+    def account(cls, compiled, args, step):
+        """The record of the executable the call ``compiled(*args)`` just
+        built, with the step's account attached: ``name``, ``step``,
+        ``call_s`` (from the start of its tracing to the call's return,
+        now), the compiler's memory account of that executable on ONE
+        chip, ``argument`` / ``output`` / ``alias`` / ``temp`` /
+        ``generated_code`` ``_bytes`` and ``reserved_bytes`` = argument +
+        output - alias + temp + code, and ``account_s``: what taking this
+        account cost.
+
+        The executable is reached, never built again: lowering the jitted
+        step for the abstract operands of that very call (donated buffers
+        keep their shape, dtype and sharding) is answered from jax's
+        caches, with no backend event."""
+        began = time.perf_counter()
+        record, built = cls._open.last, cls.count
+        record.update(name=StepTrace.STEP, step=step,
+                      call_s=began - record["since"])
+
+        def described(a):
+            if not isinstance(a, jax.Array):
+                return a
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, weak_type=a.weak_type,
+                sharding=a.sharding if a.committed else None)
+
+        memory = compiled.lower(
+            *jax.tree_util.tree_map(described, args)).compile(
+            ).memory_analysis()
+        cls._open.reset()       # the lowering's own (cached) trace event
+        if cls.count != built:
+            warnings.warn(
+                "taking the compiled step's account built an executable a "
+                "second time: the operands of the call no longer describe "
+                "the program jax cached for it", RuntimeWarning)
+        if memory is not None:      # a backend that keeps no account
+            sizes = {k: int(getattr(memory, f"{k}_size_in_bytes"))
+                     for k in ("argument", "output", "alias", "temp",
+                               "generated_code")}
+            record.update({f"{k}_bytes": v for k, v in sizes.items()})
+            record["reserved_bytes"] = (
+                sizes["argument"] + sizes["output"] - sizes["alias"]
+                + sizes["temp"] + sizes["generated_code"])
+        record["account_s"] = time.perf_counter() - began
+        return record
+
+
+jax.monitoring.register_event_duration_secs_listener(_ExecutablesBuilt._on)
+jax.monitoring.register_event_listener(_ExecutablesBuilt._on_cache)
+
+
+def compile_log():
+    """The records of the process's compile log, oldest first: every
+    executable built or loaded (``kind`` ``executable``; a train step's
+    carries its account, ``_ExecutablesBuilt.account``) and every train
+    step object's construction (``kind`` ``init``: ``name``, ``at``,
+    ``seconds``).  docs/PROFILER.md, "The compile's account"."""
+    return list(_ExecutablesBuilt.log)
 
 
 class StepTrace:
-    """The host spans and the compile count of a compiled train step:
-    ``jit.TrainStep`` and ``parallel.SpmdTrainStep`` mark every call the
-    same way, so one reader of the trace serves both (docs/PROFILER.md).
+    """The host spans, the compile count and the compile's account of a
+    compiled train step: ``jit.TrainStep`` and ``parallel.SpmdTrainStep``
+    mark every call the same way, so one reader of the trace serves both
+    (docs/PROFILER.md).
 
     ``train_step`` covers the whole call; inside it ``::operands`` (RNG
     key, step and learning-rate scalars, the batch's placement),
     ``::dispatch`` (the call of the compiled step and nothing else) and,
     where the trainer rebinds its model, ``::sync_to_model``.  A dispatch
     that added an executable to the step's cache is followed by the
-    zero-length marker ``::compiled``.  Every span carries ``step``."""
+    zero-length marker ``::compiled``, which says what the compile was
+    (``cache``, ``backend_s``, ``temp_bytes``); ``account`` is that
+    call's record of the compile log, the newest.  Every span carries
+    ``step``.  ``::init`` covers the trainer's construction."""
 
     STEP = "train_step"
     OPERANDS = "train_step::operands"
     DISPATCH = "train_step::dispatch"
     SYNC = "train_step::sync_to_model"
     COMPILED = "train_step::compiled"
+    INIT = "train_step::init"
 
     def __init__(self):
         self.compiles = 0
-        _ExecutablesBuilt.listen()
+        self.account = None
+
+    @staticmethod
+    def init(constructor):
+        """Decorates a trainer's ``__init__``: the whole of it (state
+        split, optimizer state, placement) runs under the span ``::init``,
+        and its seconds go into the compile log as a record of kind
+        ``init``, read whether or not a profiler session is on."""
+        @functools.wraps(constructor)
+        def timed(self, *args, **kwargs):
+            t0 = time.perf_counter()
+            with RecordEvent(StepTrace.INIT):
+                constructor(self, *args, **kwargs)
+            now = time.perf_counter()
+            _ExecutablesBuilt.add({"kind": "init", "name": StepTrace.INIT,
+                                   "at": now, "seconds": now - t0})
+        return timed
 
     def dispatch(self, compiled, args, step):
         """``compiled(*args)`` under its span; counts and marks the call
@@ -162,13 +307,18 @@ class StepTrace:
         was built meanwhile.  The cache alone also grows when operands
         merely come back described differently (``SpmdTrainStep``'s second
         call: its outputs drop the mesh axes of size 1 from their specs),
-        which builds nothing."""
+        which builds nothing.  Only a call that compiled takes the
+        account; any other does what it always did."""
         known, built = compiled._cache_size(), _ExecutablesBuilt.count
         with RecordEvent(self.DISPATCH, step=step):
             out = compiled(*args)
         if compiled._cache_size() > known and _ExecutablesBuilt.count > built:
             self.compiles += 1
-            with RecordEvent(self.COMPILED, step=step):
+            self.account = rec = _ExecutablesBuilt.account(compiled, args,
+                                                           step)
+            with RecordEvent(self.COMPILED, step=step, cache=rec["cache"],
+                             backend_s=rec["backend_s"],
+                             temp_bytes=rec.get("temp_bytes")):
                 pass
         return out
 
@@ -218,16 +368,23 @@ class Profiler:
         try:
             jax.profiler.start_trace(self._trace_dir)
             self._device_tracing = True
-        except Exception:
+        except Exception as e:     # the host events are still recorded
             self._device_tracing = False
+            warnings.warn(
+                f"the device trace did not start ({type(e).__name__}: {e}): "
+                f"{self._trace_dir} will hold no trace of this window",
+                RuntimeWarning)
 
     def _stop_device_trace(self):
         if self._device_tracing:
+            self._device_tracing = False
             try:
                 jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._device_tracing = False
+            except Exception as e:
+                warnings.warn(
+                    f"the device trace did not stop cleanly "
+                    f"({type(e).__name__}: {e}): {self._trace_dir} may hold "
+                    f"no trace of this window", RuntimeWarning)
 
     def _apply_state(self, state):
         recording = state in (ProfilerState.RECORD,

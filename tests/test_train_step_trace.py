@@ -4,8 +4,12 @@ mark round every call, and ``stats()`` (docs/PROFILER.md)."""
 
 import contextlib
 import copy
+import gc
 import pickle
 import re
+import threading
+import time
+import warnings
 
 import numpy as np
 import pytest
@@ -13,7 +17,7 @@ import pytest
 import jax
 
 import paddle_tpu as paddle
-from paddle_tpu import nn, optimizer
+from paddle_tpu import nn, optimizer, profiler
 from paddle_tpu.distributed.fleet.topology import build_mesh
 from paddle_tpu.jit import TrainStep
 from paddle_tpu.models.gpt import gpt_tiny
@@ -278,3 +282,215 @@ def test_record_event_hands_its_attributes_to_the_annotation(monkeypatch):
     with RecordEvent("train_step", step=7):
         pass
     assert seen == [("train_step", {"step": 7})]
+
+
+# ---- the compile's account (docs/PROFILER.md) ------------------------------
+BYTES = ["argument_bytes", "output_bytes", "alias_bytes", "temp_bytes",
+         "generated_code_bytes"]
+
+
+def _step_and_batches(which):
+    step = _train_step() if which == "train" else _spmd_step()
+    put = paddle.to_tensor if which == "train" else (lambda a: a)
+    return step, put(_batch()), put(_batch(length=8))
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_the_first_call_takes_the_account_and_builds_nothing_for_it(
+        which, monkeypatch):
+    step, ids, _ = _step_and_batches(which)
+    assert step.compile_account() is None
+    counts, take = [], profiler._ExecutablesBuilt.account.__func__
+
+    def watched(cls, *args):
+        counts.append(cls.count)
+        record = take(cls, *args)
+        counts.append(cls.count)
+        return record
+
+    monkeypatch.setattr(profiler._ExecutablesBuilt, "account",
+                        classmethod(watched))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # a second build would warn
+        step(ids, ids)
+    acc = step.compile_account()
+    assert len(counts) == 2 and counts[0] == counts[1]
+    assert acc["name"] == "train_step" and acc["step"] == 1
+    assert acc["program"] == "jit(train_step)" and acc["cache"] == "off"
+    assert all(isinstance(acc[k], int) and acc[k] >= 0 for k in BYTES)
+    assert acc["argument_bytes"] > 0 and acc["temp_bytes"] > 0
+    assert acc["alias_bytes"] > 0           # the state is donated
+    assert acc["reserved_bytes"] == (
+        acc["argument_bytes"] + acc["output_bytes"] - acc["alias_bytes"]
+        + acc["temp_bytes"] + acc["generated_code_bytes"])
+    assert acc["trace_s"] > 0 and acc["lower_s"] > 0 and acc["backend_s"] > 0
+    # a nested trace is counted once: the parts fit inside the call
+    assert acc["trace_s"] + acc["lower_s"] + acc["backend_s"] <= acc["call_s"]
+    assert acc["since"] < acc["at"]
+    assert 0 < acc["account_s"] < 0.25      # answered from jax's caches
+    assert step.stats() == {"steps": 1, "compiles": 1}
+
+
+def test_the_account_is_one_chips_share_under_a_mesh():
+    """The compiler's account of a partitioned step is a chip's: the
+    donated state of the dp2 x mp2 trainer is under the whole state."""
+    one, ids, _ = _step_and_batches("train")
+    four, _, _ = _step_and_batches("spmd")
+    one(ids, ids)
+    four(_batch(), _batch())
+    assert four.compile_account()["alias_bytes"] \
+        < 0.75 * one.compile_account()["alias_bytes"]
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_only_a_call_that_compiled_adds_a_record(which):
+    step, ids, short = _step_and_batches(which)
+    step(ids, ids)
+    first, log = step.compile_account(), profiler.compile_log()
+    assert log[-1] is first
+    step(ids, ids)
+    step(ids, ids)
+    assert step.compile_account() is first
+    assert len(profiler.compile_log()) == len(log)
+    step(short, short)                      # another shape: a second record
+    second = step.compile_account()
+    assert second is not first and second["step"] == 4
+    assert second is profiler.compile_log()[-1]
+    assert first["step"] == 1
+    assert second["argument_bytes"] < first["argument_bytes"]
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_the_record_outlives_the_step_object(which):
+    step, ids, _ = _step_and_batches(which)
+    step(ids, ids)
+    step_id = id(step.compile_account())
+    del step
+    gc.collect()
+    kept = [r for r in profiler.compile_log() if id(r) == step_id]
+    assert len(kept) == 1 and kept[0]["reserved_bytes"] > 0
+
+
+@pytest.fixture
+def fresh_persistent_cache(tmp_path):
+    """jax's persistent compilation cache in a directory of its own, every
+    program worth caching; what was set before comes back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ["jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes"]
+    before = [getattr(jax.config, n) for n in names]
+    for n, v in zip(names, [str(tmp_path), 0.0, 0]):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    yield tmp_path
+    for n, v in zip(names, before):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_the_record_says_whether_the_persistent_cache_served_the_build(
+        which, fresh_persistent_cache):
+    """A twin built from the same seed compiles the same program (the same
+    step object's NEXT call need not: ``SpmdTrainStep``'s operands come
+    back described differently)."""
+    step, ids, _ = _step_and_batches(which)
+    step(ids, ids)
+    assert step.compile_account()["cache"] == "miss"
+    jax.clear_caches()          # the process forgets; the directory does not
+    twin, ids, _ = _step_and_batches(which)
+    twin(ids, ids)
+    again = twin.compile_account()
+    assert again is not step.compile_account()
+    assert again["cache"] == "hit" and again["step"] == 1
+    assert again["temp_bytes"] == step.compile_account()["temp_bytes"]
+    assert again["backend_s"] < step.compile_account()["backend_s"]
+
+
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_the_trainers_construction_is_recorded_without_a_session(which):
+    before = len(profiler.compile_log())
+    t0 = time.perf_counter()
+    _step_and_batches(which)
+    took = time.perf_counter() - t0
+    inits = [r for r in profiler.compile_log()[before:]
+             if r["kind"] == "init"]
+    assert len(inits) == 1 and inits[0]["name"] == StepTrace.INIT
+    assert 0 < inits[0]["seconds"] <= took and inits[0]["at"] >= t0
+    with Profiler(timer_only=True) as p:    # and as a span where one is on
+        _step_and_batches(which)
+        assert p.aggregated_events()[StepTrace.INIT][1] == 1
+
+
+def test_the_compile_mark_says_what_the_compile_was(monkeypatch):
+    seen = []
+
+    class Spy(contextlib.nullcontext):
+        def __init__(self, name, **kw):
+            super().__init__()
+            seen.append((name, kw))
+
+    step, ids, _ = _step_and_batches("train")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    step(ids, ids)
+    step(ids, ids)
+    marks = [kw for name, kw in seen if name == StepTrace.COMPILED]
+    acc = step.compile_account()
+    assert marks == [{"step": 1, "cache": "off",
+                      "backend_s": acc["backend_s"],
+                      "temp_bytes": acc["temp_bytes"]}]
+
+
+def test_the_log_counts_a_nested_trace_once_and_keeps_threads_apart():
+    """The listener on made-up events: the jits a program calls report
+    their traces before the trace that holds them; a trace that led to no
+    build is stale; what a lowering rule traces is inside the lowering;
+    another thread's events belong to another record."""
+    log = profiler._ExecutablesBuilt
+    before = log.count
+    other = threading.Thread(target=lambda: log._on(log.TRACE, 3.0))
+    other.start()
+    other.join(timeout=10)
+    assert not other.is_alive()
+    log._on(log.TRACE, 0.001, fun_name="stale")
+    time.sleep(0.05)
+    log._on(log.TRACE, 0.002, fun_name="inner")
+    log._on(log.TRACE, 0.002, fun_name="inner")
+    log._on(log.TRACE, 0.03, fun_name="f")
+    time.sleep(0.02)
+    log._on(log.TRACE, 0.001, fun_name="traced_by_a_lowering_rule")
+    log._on(log.LOWER, 0.015, fun_name="jit(f)")
+    log._on_cache("/jax/compilation_cache/cache_misses")
+    log._on(log.EVENT, 0.5, fun_name="jit(f)")
+    rec = profiler.compile_log()[-1]
+    assert log.count == before + 1
+    assert (rec["program"], rec["trace_s"], rec["lower_s"], rec["backend_s"],
+            rec["cache"]) == ("jit(f)", 0.03, 0.015, 0.5, "miss")
+    assert rec["at"] - rec["since"] == pytest.approx(0.5, abs=0.01)
+    log._on(log.EVENT, 0.5, fun_name="jit(g)")     # nothing is carried over
+    last = profiler.compile_log()[-1]
+    assert (last["trace_s"], last["lower_s"], last["cache"]) \
+        == (0.0, 0.0, "off")
+    for made_up in (rec, last):
+        log.log.remove(made_up)
+
+
+@pytest.mark.parametrize("fails", ["start_trace", "stop_trace"])
+def test_a_device_trace_that_fails_says_so(monkeypatch, tmp_path, fails):
+    def broken(*a, **kw):
+        raise RuntimeError("only one profile at a time")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **kw: None)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(jax.profiler, fails, broken)
+    p = Profiler(trace_dir=str(tmp_path))
+    with pytest.warns(RuntimeWarning,
+                      match="did not st.*only one profile at a time"):
+        p.start()
+        with RecordEvent("x"):
+            pass
+        p.stop()
+    assert not p._device_tracing
+    assert p.aggregated_events()["x"][1] == 1   # the host events are kept
