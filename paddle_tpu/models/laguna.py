@@ -140,9 +140,21 @@ def _rope(q, k, cos, sin):
 @op("headwise_gate")
 def _gate(out, g):
     """``out [B, T, N, D]`` times ``sigmoid(g) [B, T, N]``, a scalar a
-    head and token; the sigmoid in float32."""
-    return out * jax.nn.sigmoid(g.astype(jnp.float32))[..., None] \
-        .astype(out.dtype)
+    head and token; the sigmoid in float32.
+
+    The product is formed on the view ``[B, T / 8, 8, N, D]``: the flash
+    kernels write ``out`` where ``o_proj`` reads it, ``[B, T, N * D]`` in
+    (8, 128) tiles, and that view keeps a tile's eight rows together, so it
+    is the array as it lies.  On the plain ``[B, T, N, D]`` view the
+    compiler lays the gate's broadcast out whole and copies ``out`` and its
+    gradient between two layouts (the v5e compiler's account of the
+    laguna cell's step, PR 35: 5.9 GB more through HBM a step)."""
+    gate = jax.nn.sigmoid(g.astype(jnp.float32)).astype(out.dtype)
+    b, t, n, d = out.shape
+    if t % 8:
+        return out * gate[..., None]
+    return (out.reshape(b, t // 8, 8, n, d)
+            * gate.reshape(b, t // 8, 8, n, 1)).reshape(b, t, n, d)
 
 
 class LagunaConfig(MoeDecoderConfig):

@@ -13,6 +13,7 @@ cannot serve calls :func:`warn_fallback`, which warns once per distinct
 can turn it into an error (``chip_smoke.py`` does).
 """
 
+import collections
 import math
 import warnings
 
@@ -58,6 +59,41 @@ def warn_fallback(kernel, shape, reason):
         warnings.warn(
             f"{kernel}: XLA composition taken on TPU for {shape}: {reason}",
             KernelFallbackWarning, stacklevel=3)
+
+
+# Which operands of the flash calls traced so far crossed between XLA and
+# the kernels in place, and which as copies: the newest calls' records, and
+# the running sums a compiled step takes its own share from
+# (``profiler.StepTrace.dispatch``: what was traced while it compiled).
+_flash_layouts = collections.deque(maxlen=256)
+_flash_layout_sums = {"flash_calls": 0, "flash_operands_in_place": 0,
+                      "flash_operands_copied": 0}
+
+
+def record_flash_layout(kernel, shapes, in_place, copied):
+    """``flash_attention_pallas`` says of one traced call which of its
+    eight operands (q, k, v, o and the backward's do, dq, dk, dv) the
+    kernels read or write where XLA holds them, and which cross as copies
+    ``[batch * heads, seq, width]`` and why (``{name: reason}``)."""
+    _flash_layouts.append({"kernel": kernel, "shapes": shapes,
+                           "in_place": tuple(in_place),
+                           "copied": dict(copied)})
+    _flash_layout_sums["flash_calls"] += 1
+    _flash_layout_sums["flash_operands_in_place"] += len(in_place)
+    _flash_layout_sums["flash_operands_copied"] += len(copied)
+
+
+def flash_layout_log():
+    """The records of the newest traced flash calls (at most 256), oldest
+    first: ``kernel``, ``shapes``, ``in_place``, ``copied``."""
+    return list(_flash_layouts)
+
+
+def flash_layout_sums():
+    """``flash_calls``, ``flash_operands_in_place`` and
+    ``flash_operands_copied`` over every flash call traced in this
+    process."""
+    return dict(_flash_layout_sums)
 
 
 def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,

@@ -11,8 +11,29 @@ formed once a block pair and dQ, dK and dV all take their part from them),
 wired up as a jax.custom_vjp.
 
 Layouts: paddle's flash-attn API is [batch, seq, num_heads, head_dim]
-(python/paddle/nn/functional/flash_attention.py:125); kernels run on
-[batch*heads, seq, head_dim].
+(python/paddle/nn/functional/flash_attention.py:125), and a projection
+leaves q, k or v in HBM as [batch, seq, heads * width].  The kernels have
+TWO HBM layouts for an operand, chosen per operand by what the call shows
+(``_in_place``; one kernel, two index maps, ``_head_spec``):
+
+- in place, [batch, seq, heads * width] (a reshape that moves nothing; a
+  block's index map picks the head on the lane axis; O, dQ, dK and dV come
+  out the same way, so O goes on to the next projection as it lies): where
+  the width is a whole number of 128 lanes AND the kv heads are grouped
+  (laguna: every operand);
+- the copy [batch * heads, seq, width], as before PR 35: any other width
+  (64; latent attention's 192-wide q and k, whose heads no block rule can
+  cut at lane 192 n) and every call with one kv head a q head (GPT,
+  kanana), where in place measured slower (``_in_place`` says why).  XLA
+  folds this layout's transposes into the producers where it can.
+
+The kernels' blocks are the shapes they were in either layout, so the
+bodies know nothing of it.  The row statistics (log-sum-exp, and the
+backward's delta) cross lane-dense in every call, as rows [batch * heads,
+seq / block_q, block_q] (a [seq, 1] column is lane-padded 128x in HBM as
+in VMEM).  What XLA makes of either layout round the calls (it gives no
+4-D [batch, seq, heads, width] intermediate the in-place layout's tiles):
+PERF.md section 6, PR 35.
 
 Head widths: q and k share one width, v and the output another.  Served
 are one width <= 128 for all three (GPT, Llama), and latent attention's
@@ -206,13 +227,96 @@ def _kernel_name(kernel, window):
             else f"flash_window{window}_attention_{kernel}")
 
 
-def _kv_head(group):
-    """Index map of a whole-sequence K or V block for q program ``b``:
-    kv head ``b // group`` of the flattened [batch * heads] axis (heads
-    are innermost there, and q's are ``group`` times kv's)."""
-    if group == 1:
-        return lambda b, i: (b, 0, 0)
-    return lambda b, i: (b // group, 0, 0)
+# ------------------------------------------------------- HBM interface --
+
+def _in_place(width, group):
+    """An operand ``[batch, seq, heads, width]`` of a call whose q heads
+    are ``group`` times its kv heads is read where it lies, as ``[batch,
+    seq, heads * width]`` with the head picked on the lane axis, if
+
+    - a head is a whole number of 128-lane tiles (no block rule cuts a lane
+      axis anywhere else), and
+    - the call's kv heads are grouped.  A kernel pays for the in-place
+      layout: a head's rows are 4 KB tiles gathered at a stride, not one
+      run (the kernels' roofline shares fell 1-3% on the v5e, PR 35).
+      Grouped, K and V are fetched once for the ``group`` q heads that read
+      them and XLA saves more round the call than the kernels lose (one
+      laguna attention layer, forward + backward: 33.4 -> 32.5 and 39.4 ->
+      37.6 ms).  One kv head a q head, every program fetches its own K and
+      V and the layer LOSES: GPT's 27.8 -> 28.4 ms, kanana's (v-side only)
+      71.7 -> 72.4, and the four-chip GPT step 1.2% (PERF.md section 6)."""
+    return width % 128 == 0 and group > 1
+
+
+def operand_layouts(head, head_v, group):
+    """``(in place, copied)`` of a call's eight operands, its backward's
+    among them: the names that cross where XLA holds them, and ``{name:
+    why}`` of those that cross as copies.  A gradient has the width, and so
+    the layout, of what it is the gradient of; O and dO have v's."""
+    in_place, copied = (), {}
+    for names, width in ((("q", "k", "dq", "dk"), head),
+                         (("v", "o", "do", "dv"), head_v)):
+        if _in_place(width, group):
+            in_place += names
+        else:
+            copied.update(dict.fromkeys(
+                names, f"width {width} % 128" if width % 128
+                else "kv heads not grouped"))
+    return in_place, copied
+
+
+def _layout_shape(batch, seq, heads, width, group):
+    """The shape in which a ``[batch, seq, heads, width]`` operand crosses
+    between XLA and the kernels."""
+    if _in_place(width, group):
+        return batch, seq, heads * width
+    return batch * heads, seq, width
+
+
+def _to_kernel(x, group):
+    """``[batch, seq, heads, width]`` in its kernel layout: a reshape that
+    moves nothing, or the copy ``[batch * heads, seq, width]`` (which XLA
+    folds into the producer where it can: a projection writes heads-first
+    as readily as not)."""
+    if not _in_place(x.shape[3], group):
+        x = x.transpose(0, 2, 1, 3)
+        return x.reshape(-1, *x.shape[2:])
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _copied(x, batch, heads):
+    """Whether kernel-layout operand ``x`` is the copy ``[batch * heads,
+    seq, width]``.  With one head the two layouts are the same array."""
+    return x.shape[0] == batch * heads
+
+
+def _from_kernel(x, batch, heads):
+    """A kernel-layout operand back as ``[batch, seq, heads, width]``."""
+    if _copied(x, batch, heads):
+        return x.reshape(batch, heads, x.shape[1], -1).transpose(0, 2, 1, 3)
+    return x.reshape(batch, x.shape[1], heads, -1)
+
+
+def _width(x, batch, heads):
+    """The head width of kernel-layout operand ``x``."""
+    return x.shape[2] if _copied(x, batch, heads) else x.shape[2] // heads
+
+
+def _head_spec(x, batch, heads, rows, at):
+    """The BlockSpec of ``rows`` rows of ONE head of kernel-layout operand
+    ``x``, whichever of the two layouts it is in: ``at(*grid ids) -> (bn,
+    row block)``, ``bn`` the head on the flattened ``[batch * heads]`` axis
+    (heads innermost), which the copy has as its leading axis and the
+    in-place form divides into ``(bn // heads, row block, bn % heads)``."""
+    if _copied(x, batch, heads):
+        return pl.BlockSpec((1, rows, x.shape[2]),
+                            lambda *ids: (*at(*ids), 0))
+
+    def on_the_lanes(*ids):
+        bn, i = at(*ids)
+        return bn // heads, i, bn % heads
+
+    return pl.BlockSpec((1, rows, x.shape[2] // heads), on_the_lanes)
 
 
 # ---------------------------------------------------------------- forward --
@@ -246,34 +350,47 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     o_acc, m, l = jax.lax.fori_loop(first, end, body, (o0, m0, l0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (o_acc / l).astype(o_ref.dtype)
-    # lse is [bn, seq, 1]: a (1, block_q, 1) block per program satisfies the
-    # Mosaic tile constraint (trailing dim equals the full array dim).
-    lse_ref[0] = m + jnp.log(l)
+    # lse leaves as the ROW [1, block_q] of the head's [seq / block_q,
+    # block_q] block, which stays in VMEM while the head's q blocks run (a
+    # [block_q, 1] column is lane-padded 128x in HBM): the column spread
+    # over 128 lanes, that tile transposed, its first row
+    lse = jnp.broadcast_to(m + jnp.log(l), (block_q, 128))
+    lse_ref[0, pl.ds(qi, 1), :] = lse.T[:1]
 
 
-def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
+def _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k, interpret,
                window=None):
-    bn, seq_q, head = q.shape
-    seq_k, head_v = k.shape[1], v.shape[2]
-    kv_head = _kv_head(bn // k.shape[0])
-    grid = (bn, seq_q // block_q)
+    """q, k, v in their kernel layouts (``_to_kernel``), ``dims = (batch,
+    q heads, kv heads)``.  Returns O in the layout of its width and the
+    log-sum-exp as rows ``[batch * q heads, seq_q / block_q, block_q]``."""
+    batch, heads, kv_heads = dims
+    group = heads // kv_heads
+    bn, seq_q, seq_k = batch * heads, q.shape[1], k.shape[1]
+    head, head_v = _width(q, batch, heads), _width(v, batch, kv_heads)
+    out_shape = jax.ShapeDtypeStruct(
+        _layout_shape(batch, seq_q, heads, head_v, group), q.dtype)
+    q_block = lambda b, i: (b, i)                            # noqa: E731
+    # q head b reads kv head b // group of the flattened axis (heads are
+    # innermost there, and q's are ``group`` times kv's), whole
+    kv_head = lambda b, i: (b // group, 0)                   # noqa: E731
+    num_qb = seq_q // block_q
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
                           scale=scale, window=window),
         name=_kernel_name("fwd", window),
-        grid=grid,
+        grid=(bn, num_qb),
         in_specs=[
-            pl.BlockSpec((1, block_q, head), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, seq_k, head), kv_head),
-            pl.BlockSpec((1, seq_k, head_v), kv_head),
+            _head_spec(q, batch, heads, block_q, q_block),
+            _head_spec(k, batch, kv_heads, seq_k, kv_head),
+            _head_spec(v, batch, kv_heads, seq_k, kv_head),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, head_v), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            _head_spec(out_shape, batch, heads, block_q, q_block),
+            pl.BlockSpec((1, num_qb, block_q), lambda b, i: (b, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, seq_q, head_v), q.dtype),
-            jax.ShapeDtypeStruct((bn, seq_q, 1), jnp.float32),
+            out_shape,
+            jax.ShapeDtypeStruct((bn, num_qb, block_q), jnp.float32),
         ],
         interpret=interpret,
         **_compiler_params(head, head_v),
@@ -394,18 +511,19 @@ def _vmem_bytes(shape, dtype):
 
 def _bwd_blocks(group, seq_q, seq_k, head, head_v, block_q, block_k, dtype):
     """``(ins, outs, scratch)`` of the backward call, from the shapes
-    alone: its input and output blocks as ``(shape, dtype, index map)``
-    over the grid ``(kv head, q head of its group, k block)``, its VMEM
-    scratch as ``(shape, dtype)``."""
+    alone: its input and output blocks as ``(shape, dtype, at)`` over the
+    grid ``(kv head, q head of its group, k block)`` (``shape`` is ONE
+    head's ``(1, rows, width)``, ``at`` as ``_head_spec`` takes it), its
+    VMEM scratch as ``(shape, dtype)``."""
     f32 = jnp.float32
     num_qb = seq_q // block_q
-    q_head = lambda b, g, j: (b * group + g, 0, 0)          # noqa: E731
-    k_block = lambda b, g, j: (b, j, 0)                      # noqa: E731
+    q_head = lambda b, g, j: (b * group + g, 0)             # noqa: E731
+    k_block = lambda b, g, j: (b, j)                         # noqa: E731
     scratch = [((seq_q, head), f32)]                         # dQ
     if group == 1:
         kv_rows, kv_out = block_k, k_block
     else:
-        kv_rows, kv_out = seq_k, lambda b, g, j: (b, 0, 0)
+        kv_rows, kv_out = seq_k, lambda b, g, j: (b, 0)
         scratch += [((seq_k, head), f32), ((seq_k, head_v), f32)]
     ins = [((1, seq_q, head), dtype, q_head),
            ((1, block_k, head), dtype, k_block),
@@ -419,15 +537,29 @@ def _bwd_blocks(group, seq_q, seq_k, head, head_v, block_q, block_k, dtype):
     return ins, outs, scratch
 
 
-def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
+def _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q, block_k,
                interpret, window=None):
-    bn, seq_q, head = q.shape
-    bn_kv, seq_k, head_v = k.shape[0], k.shape[1], v.shape[2]
-    group = bn // bn_kv
-    # delta = rowsum(dO * O) — cheap elementwise, leave to XLA fusion
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    rows = (bn, seq_q // block_q, block_q)
+    """Operands in their kernel layouts (``out`` and ``do`` in the layout
+    of v's width), ``lse`` as the forward left it, ``dims = (batch, q
+    heads, kv heads)``; dQ, dK, dV come back in q's, k's and v's layouts."""
+    batch, heads, kv_heads = dims
+    group = heads // kv_heads
+    seq_q, seq_k = q.shape[1], k.shape[1]
+    head, head_v = _width(q, batch, heads), _width(v, batch, kv_heads)
+    # delta = rowsum(dO * O) over a head's width -- cheap elementwise, left
+    # to XLA fusion; formed as the rows the kernel reads, lse's
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    if _copied(do, batch, heads):
+        delta = jnp.sum(prod, axis=-1)
+    else:
+        # [batch, seq, heads * width]: the view that keeps the eight rows of
+        # an (8, 128) tile together is the array as it lies (a plain
+        # [seq, heads, width] view costs a copy of the float32 product, 302
+        # MB at [8192, 72 x 128]: the compiler, ahead of time, PR 35); what
+        # is moved to heads-first is the [seq, heads] result
+        delta = jnp.sum(prod.reshape(batch, seq_q // 8, 8, heads, head_v),
+                        axis=-1).transpose(0, 3, 1, 2)
+    delta = delta.reshape(lse.shape)
     ins, outs, scratch = _bwd_blocks(group, seq_q, seq_k, head, head_v,
                                      block_q, block_k, q.dtype)
     # the allowance, reckoned from the blocks' bytes: what is resident
@@ -437,28 +569,37 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
     # between the dots
     resident = (2 * sum(_vmem_bytes(s, dt) for s, dt, _ in ins + outs)
                 + sum(_vmem_bytes(s, dt) for s, dt in scratch))
+    # the outputs dQ, dK, dV are laid out as the first three inputs are
+    operands = (q, k, v, do, lse, delta)
+    of_heads = (heads, kv_heads, kv_heads, heads, heads, heads)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, block_q=block_q, causal=causal,
                           scale=scale, window=window, group=group),
         name=_kernel_name("bwd_dq_dkv", window),
-        grid=(bn_kv, group, seq_k // block_k),
-        in_specs=[pl.BlockSpec(s, at) for s, _, at in ins],
-        out_specs=[pl.BlockSpec(s, at) for s, _, at in outs],
+        grid=(batch * kv_heads, group, seq_k // block_k),
+        in_specs=[_head_spec(x, batch, n, s[1], at)
+                  for x, n, (s, _, at) in zip(operands, of_heads, ins)],
+        out_specs=[_head_spec(x, batch, n, s[1], at)
+                   for x, n, (s, _, at) in zip(operands, of_heads, outs)],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
                    for x in (q, k, v)],
         scratch_shapes=[pltpu.VMEM(s, dt) for s, dt in scratch],
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=resident + 16 * 1024 * 1024),
-    )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
+    )(*operands)
     return dq, dk, dv
 
 
 # ------------------------------------------------------------- public API --
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention_bnsh(q, k, v, causal, scale, interpret, window=None):
-    out, _ = _fwd_rule(q, k, v, causal, scale, interpret, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention_kernels(q, k, v, dims, causal, scale, interpret,
+                             window=None):
+    """q, k, v and the result in their kernel layouts (``_to_kernel``),
+    which are the residuals too: an operand that had to be copied is
+    copied once, as it always was."""
+    out, _ = _fwd_rule(q, k, v, dims, causal, scale, interpret, window)
     return out
 
 
@@ -526,7 +667,7 @@ def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None,
         bq, bk = cand
         fwd = [((1, bq, head), dtype), ((1, seq_k, head), dtype),
                ((1, seq_k, hv), dtype), ((1, bq, hv), dtype),
-               ((1, bq, 1), jnp.float32)]
+               ((1, seq_q // bq, bq), jnp.float32)]
         ins, outs, scratch = _bwd_blocks(group, seq_q, seq_k, head, hv, bq,
                                          bk, dtype)
         bwd = [(s, dt) for s, dt, _ in ins + outs]
@@ -536,17 +677,18 @@ def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None,
     return validate
 
 
-def _tuned_blocks(q, k, v, causal, scale, interpret, window=None):
+def _tuned_blocks(q, k, v, dims, causal, scale, interpret, window=None):
     """Autotuned (block_q, block_k) for this shape (FLAGS_use_autotune);
     the heuristic (128-preferred divisor) wins with the flag off."""
     from . import autotune
 
-    bn, seq_q, head = q.shape
-    seq_k, head_v = k.shape[1], v.shape[2]
-    group = bn // k.shape[0]
+    batch, heads, kv_heads = dims
+    seq_q, seq_k = q.shape[1], k.shape[1]
+    head, head_v = _width(q, batch, heads), _width(v, batch, kv_heads)
+    group = heads // kv_heads
     # one head width keeps the key it had; a second width joins it
-    heads = head if head_v == head else (head, head_v)
-    key = (seq_q, seq_k, heads, str(q.dtype), causal)
+    widths = head if head_v == head else (head, head_v)
+    key = (seq_q, seq_k, widths, str(q.dtype), causal)
     if window is not None or group > 1:
         # a window or grouped heads join the key; a call with neither
         # keeps the one it had
@@ -558,18 +700,20 @@ def _tuned_blocks(q, k, v, causal, scale, interpret, window=None):
         import numpy as _np
 
         rng = _np.random.RandomState(0)
-        kv_heads = max(min(bn, 8) // group, 1)
-        shape_q = (kv_heads * group, seq_q, head)
-        shape_k = (kv_heads, seq_k, head)
-        qq = jnp.asarray(rng.rand(*shape_q), q.dtype)
-        kk = jnp.asarray(rng.rand(*shape_k), q.dtype)
-        vv = jnp.asarray(rng.rand(*shape_k[:2], head_v), q.dtype)
-        out, lse = _flash_fwd(qq, kk, vv, causal, scale, bq, bk, interpret,
-                              window)
+        kv_few = max(min(batch * heads, 8) // group, 1)
+        few = (1, kv_few * group, kv_few)
+        qq, kk, vv = (
+            jnp.asarray(rng.rand(*_layout_shape(1, seq, n, width, group)),
+                        q.dtype)
+            for seq, n, width in ((seq_q, few[1], head),
+                                  (seq_k, few[2], head),
+                                  (seq_k, few[2], head_v)))
+        out, lse = _flash_fwd(qq, kk, vv, few, causal, scale, bq, bk,
+                              interpret, window)
         # measure (and VMEM-validate) the backward too: a candidate that
         # fits the fwd can overflow the bwd's working set, and training
         # pays both
-        grads = _flash_bwd(qq, kk, vv, out, lse, out, causal, scale,
+        grads = _flash_bwd(qq, kk, vv, out, lse, out, few, causal, scale,
                            bq, bk, interpret, window)
         jax.block_until_ready((out, grads))  # noqa: H001 (autotune timing sync — measurement, not a serving path)
 
@@ -587,25 +731,25 @@ def _tuned_blocks(q, k, v, causal, scale, interpret, window=None):
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
-def _fwd_rule(q, k, v, causal, scale, interpret, window=None):
-    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret,
+def _fwd_rule(q, k, v, dims, causal, scale, interpret, window=None):
+    block_q, block_k = _tuned_blocks(q, k, v, dims, causal, scale, interpret,
                                      window)
-    out, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-                          window)
+    out, lse = _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k,
+                          interpret, window)
     out = checkpoint_name(out, SAVED_BY_NAME[0])
     lse = checkpoint_name(lse, SAVED_BY_NAME[1])
     return out, (q, k, v, out, lse)
 
 
-def _bwd_rule(causal, scale, interpret, window, res, do):
+def _bwd_rule(dims, causal, scale, interpret, window, res, do):
     q, k, v, out, lse = res
-    block_q, block_k = _tuned_blocks(q, k, v, causal, scale, interpret,
+    block_q, block_k = _tuned_blocks(q, k, v, dims, causal, scale, interpret,
                                      window)
-    return _flash_bwd(q, k, v, out, lse, do, causal, scale, block_q, block_k,
-                      interpret, window)
+    return _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q,
+                      block_k, interpret, window)
 
 
-_flash_attention_bnsh.defvjp(_fwd_rule, _bwd_rule)
+_flash_attention_kernels.defvjp(_fwd_rule, _bwd_rule)
 
 
 def _engine_cases(engine):
@@ -674,10 +818,22 @@ def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
     it; one that covers the sequence is plain causal attention and runs as
     such.
 
+    What the kernels read and write in HBM (the module's docstring,
+    "Layouts"): where the kv heads are grouped, an operand whose width is a
+    whole number of 128 lanes as ``[batch, seq, heads * width]``, a reshape
+    of what it is given that moves nothing, so that a projection's output
+    goes in and ``out`` goes on to the next projection as it lies; any
+    other operand as the copy ``[batch * heads, seq, width]``.  Which it
+    was for each operand of each traced call, and why, is in
+    ``ops.pallas.flash_layout_log()``.  A head-wise elementwise op between
+    a projection and an in-place call works without a copy on the view
+    ``[batch, seq / 8, 8, heads, width]`` (the (8, 128) tiles of ``[seq,
+    heads * width]``: ``models/laguna.py _gate``).
+
     Returns [batch, seq, num_heads, v_head_dim]; differentiable.
     """
     b, sq, n, h = q.shape
-    sk, nkv, hv = k.shape[1], k.shape[2], v.shape[3]
+    sk, nkv = k.shape[1], k.shape[2]
     if n % nkv or (window is not None
                    and not (is_causal and window >= 1 and sq == sk)):
         raise ValueError(
@@ -688,9 +844,13 @@ def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
     window = None if window is None or window >= sk else int(window)
     if scale is None:
         scale = 1.0 / (h ** 0.5)
-    qt = q.transpose(0, 2, 1, 3).reshape(b * n, sq, h)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * nkv, sk, h)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * nkv, sk, hv)
-    out = _flash_attention_bnsh(qt, kt, vt, bool(is_causal), float(scale),
-                                interpret, window)
-    return out.reshape(b, n, sq, hv).transpose(0, 2, 1, 3)
+    from . import record_flash_layout
+
+    record_flash_layout(_kernel_name("fwd", window).removesuffix("_fwd"),
+                        f"q{tuple(q.shape)} k{tuple(k.shape)} "
+                        f"v{tuple(v.shape)}",
+                        *operand_layouts(h, v.shape[3], n // nkv))
+    out = _flash_attention_kernels(
+        *(_to_kernel(x, n // nkv) for x in (q, k, v)), (b, n, nkv),
+        bool(is_causal), float(scale), interpret, window)
+    return _from_kernel(out, b, n)

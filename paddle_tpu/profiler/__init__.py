@@ -270,8 +270,10 @@ class StepTrace:
     that added an executable to the step's cache is followed by the
     zero-length marker ``::compiled``, which says what the compile was
     (``cache``, ``backend_s``, ``temp_bytes``); ``account`` is that
-    call's record of the compile log, the newest.  Every span carries
-    ``step``.  ``::init`` covers the trainer's construction."""
+    call's record of the compile log, the newest, with the step's
+    ``flash_calls``, ``flash_operands_in_place`` and
+    ``flash_operands_copied`` (``ops.pallas.record_flash_layout``).  Every
+    span carries ``step``.  ``::init`` covers the trainer's construction."""
 
     STEP = "train_step"
     OPERANDS = "train_step::operands"
@@ -308,14 +310,24 @@ class StepTrace:
         merely come back described differently (``SpmdTrainStep``'s second
         call: its outputs drop the mesh axes of size 1 from their specs),
         which builds nothing.  Only a call that compiled takes the
-        account; any other does what it always did."""
+        account; any other does what it always did, but for reading the
+        three sums of the flash layout record beforehand (what was traced
+        DURING the call is the step's own)."""
+        from ..ops.pallas import flash_layout_sums
+
         known, built = compiled._cache_size(), _ExecutablesBuilt.count
+        flash = flash_layout_sums()
         with RecordEvent(self.DISPATCH, step=step):
             out = compiled(*args)
         if compiled._cache_size() > known and _ExecutablesBuilt.count > built:
             self.compiles += 1
             self.account = rec = _ExecutablesBuilt.account(compiled, args,
                                                            step)
+            # the flash calls traced while the step was built are the
+            # step's own: how many of their operands cross between XLA
+            # and the kernels in place, how many as copies
+            rec.update({k: n - flash[k]
+                        for k, n in flash_layout_sums().items()})
             with RecordEvent(self.COMPILED, step=step, cache=rec["cache"],
                              backend_s=rec["backend_s"],
                              temp_bytes=rec.get("temp_bytes")):

@@ -149,14 +149,14 @@ def test_a_window_as_long_as_the_sequence_is_plain_causal(small_blocks,
         for a, b in zip(run(w), plain):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # the kernels themselves, below the public call's short cut
-    flat = lambda x: x.transpose(0, 2, 1, 3).reshape(     # noqa: E731
-        -1, x.shape[1], x.shape[3])
     scale = 1.0 / np.sqrt(HEAD)
+    dims = (q.shape[0], q.shape[2], k.shape[2])
+    qk, kk, vk, dok = (ak._to_kernel(x, group) for x in (q, k, v, do))
     for w in (None, SEQ):
-        out, lse = ak._flash_fwd(flat(q), flat(k), flat(v), True, scale,
-                                 BLOCK, BLOCK, True, w)
-        grads = ak._flash_bwd(flat(q), flat(k), flat(v), out, lse, flat(do),
-                              True, scale, BLOCK, BLOCK, True, w)
+        out, lse = ak._flash_fwd(qk, kk, vk, dims, True, scale, BLOCK, BLOCK,
+                                 True, w)
+        grads = ak._flash_bwd(qk, kk, vk, out, lse, dok, dims, True, scale,
+                              BLOCK, BLOCK, True, w)
         if w is None:
             first = (out,) + tuple(grads)
         else:

@@ -236,6 +236,33 @@ def test_partial_rotary_rotates_the_leading_dims_only():
     assert float(got[0, t, 1, 4 + i]) == pytest.approx(float(want), rel=1e-6)
 
 
+@pytest.mark.parametrize("seq", [16, 13])
+def test_the_gate_on_the_tile_view_is_the_plain_product(seq):
+    """``_gate`` multiplies on the view ``[B, T / 8, 8, N, D]`` (where the
+    flash kernels leave ``out``, that view is the array as it lies) and on
+    the plain one where 8 does not divide the sequence: bit for bit the
+    same numbers, and the same gradients."""
+    rng = np.random.RandomState(0)
+    out = jnp.asarray(rng.randn(2, seq, 3, 16), jnp.bfloat16)
+    g = jnp.asarray(rng.randn(2, seq, 3), jnp.bfloat16)
+
+    def plain(out, g):
+        return out * jax.nn.sigmoid(g.astype(jnp.float32))[..., None] \
+            .astype(out.dtype)
+
+    def total(f):
+        return lambda out, g: jnp.sum(f(out, g).astype(jnp.float32) ** 2)
+
+    gate = lambda out, g: laguna._gate(Tensor(out), Tensor(g))._data  # noqa: E731
+    np.testing.assert_array_equal(
+        np.asarray(gate(out, g), np.float32),
+        np.asarray(plain(out, g), np.float32))
+    for got, want in zip(jax.grad(total(gate), argnums=(0, 1))(out, g),
+                         jax.grad(total(plain), argnums=(0, 1))(out, g)):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+
+
 # ------------------------------------------------------- expert layer ----
 def test_softmax_router_norms_over_the_chosen():
     logits = jnp.asarray(np.random.RandomState(0).randn(6, 16), jnp.float32)

@@ -149,12 +149,25 @@ def _dense_attention_f32(q, k, v, causal):
 
 
 def _flash_fwd_bwd(q, k, v, do, causal, block_q, block_k):
+    """``_flash_fwd`` and ``_flash_bwd`` themselves on ``[heads, seq,
+    head_dim]`` arrays, ONE batch row and a kv head a q head: every operand
+    crosses as the copy ``[heads, seq, width]``, the log-sum-exp as
+    lane-dense rows."""
     scale = 1.0 / np.sqrt(q.shape[-1])
-    out, lse = attention_kernel._flash_fwd(q, k, v, causal, scale, block_q,
-                                           block_k, True)
-    grads = attention_kernel._flash_bwd(q, k, v, out, lse, do, causal, scale,
-                                        block_q, block_k, True)
-    return (out,) + tuple(grads)
+    heads = q.shape[0]
+    dims = (1, heads, k.shape[0])
+    to_kernel = lambda x: attention_kernel._to_kernel(      # noqa: E731
+        x.transpose(1, 0, 2)[None], 1)
+    q, k, v, do = (to_kernel(x) for x in (q, k, v, do))
+    out, lse = attention_kernel._flash_fwd(q, k, v, dims, causal, scale,
+                                           block_q, block_k, True)
+    assert lse.shape == (heads, q.shape[1] // block_q, block_q)
+    grads = attention_kernel._flash_bwd(q, k, v, out, lse, do, dims, causal,
+                                        scale, block_q, block_k, True)
+    return tuple(
+        attention_kernel._from_kernel(x, 1, x_heads)[0].transpose(1, 0, 2)
+        for x, x_heads in zip((out,) + tuple(grads),
+                              (heads, heads) + dims[2:] * 2))
 
 
 # seq 512 at 128 x 64 and 64 x 128: per q block some key blocks lie wholly

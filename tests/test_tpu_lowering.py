@@ -343,17 +343,23 @@ def test_a_small_buckets_branch_holds_no_worst_case_residual(monkeypatch):
 # backward ONE kernel and did not touch the forward: a call with neither
 # window nor groups runs the forward program it ran.  The backward's were
 # read at PR 33's tree: the module that replaced ``flash_attention_bwd_dq``
-# and ``flash_attention_bwd_dkv``.  A new JAX may print a module
+# and ``flash_attention_bwd_dkv``.  PR 35 moved the two FORWARD modules: the
+# forward writes the log-sum-exp as a lane-dense row of a ``[seq / block_q,
+# block_q]`` block where it wrote a ``[block_q, 1]`` column (the dots, the
+# masks and the accumulation are the ones they were).  The backward's are
+# PR 33's still: a call with one kv head a q head keeps the copy layout and
+# its index maps (``tests/test_flash_layout.py``).  A new JAX may print a
+# module
 # differently; then read them again at a tree whose kernels are known to be
 # these (``_mosaic_bodies`` of the same calls).
 _PLAIN_FLASH_BODIES = {
     # gpt3-6.7b-train*.seq2048: [4, 2048, 32, 128] bf16, causal
     ((4, 2048, 32, 128), 128): {
-        "flash_attention_fwd": "96c8f2b52eef27b1",
+        "flash_attention_fwd": "821740d3ac794335",
         "flash_attention_bwd_dq_dkv": "4ed72c333b785416"},
     # kanana-2-30b-a3b-train-ep8.seq8192: [2, 8192, 32, 192 | 128]
     ((2, 8192, 32, 192), 128): {
-        "flash_attention_fwd": "ca2d4efac45765b2",
+        "flash_attention_fwd": "093204ef0e2897a9",
         "flash_attention_bwd_dq_dkv": "342e0ec67b6eeb2c"},
 }
 
@@ -397,9 +403,9 @@ def _flash_grads_lowered(q, k, v, **kw):
 @pytest.mark.parametrize("shape,v_dim", list(_PLAIN_FLASH_BODIES))
 def test_plain_flash_calls_lower_as_at_the_parent(shape, v_dim):
     """No window, equal head counts: the GPT cells' and kanana's flash
-    calls hand Mosaic the modules they handed it before the kernels knew
-    of windows and groups (the whole steps' lowered texts were compared
-    with the parent's by script, PR 30: CHANGES.md)."""
+    calls hand Mosaic the pinned modules (the backward's as before the
+    kernels knew of windows and groups; the forward's as PR 35 left them,
+    which changed how the statistics leave)."""
     q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
     v = jax.ShapeDtypeStruct(shape[:3] + (v_dim,), jnp.bfloat16)
     assert _mosaic_bodies(_flash_grads_lowered(q, q, v)) \
@@ -411,7 +417,8 @@ def test_plain_flash_calls_lower_as_at_the_parent(shape, v_dim):
 
 def test_window_and_grouped_calls_are_other_programs():
     """Laguna's two calls: their own names and their own modules,
-    and K and V at eight heads all the way into the custom calls."""
+    and K and V at eight heads (``[1, 8192, 8 * 128]``, where ``k_proj``
+    and ``v_proj`` leave them) all the way into the custom calls."""
     q72 = jax.ShapeDtypeStruct((1, 8192, 72, 128), jnp.bfloat16)
     q48 = jax.ShapeDtypeStruct((1, 8192, 48, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 8192, 8, 128), jnp.bfloat16)
@@ -424,7 +431,7 @@ def test_window_and_grouped_calls_are_other_programs():
     known = {h for bodies in _PLAIN_FLASH_BODIES.values()
              for h in bodies.values()}
     assert not known & (set(window.values()) | set(full.values()))
-    assert "tensor<8x8192x128xbf16>" in _flash_grads_lowered(q48, kv, kv)
+    assert "tensor<1x8192x1024xbf16>" in _flash_grads_lowered(q48, kv, kv)
 
 
 def test_eva_kernels_lower_as_before_the_flash_backward_was_one_kernel():
