@@ -100,14 +100,18 @@ amp_guard = auto_cast
 def decorate(models, optimizers=None, level="O1", dtype="bfloat16",
              master_weight=None, save_dtype=None):
     """O2 decoration: cast model params to the AMP dtype (master weights stay
-    fp32 inside optimizer state — see Adam._init_state)."""
+    fp32 inside optimizer state — see Adam._init_state).  A parameter its
+    layer marked ``amp_keep_float32`` stays as it is: small state that is
+    read in float32 anyway and whose updates the AMP dtype's grid would
+    swallow (a state-space layer's ``A_log``, ``D``, ``dt_bias``)."""
     if level == "O2":
         target = "bfloat16" if dtype in ("bfloat16", "bf16") else "float16"
-        if isinstance(models, (list, tuple)):
-            for m in models:
-                m.to(dtype=target)
-        else:
-            models.to(dtype=target)
+        for m in models if isinstance(models, (list, tuple)) else [models]:
+            kept = [(p, p._data) for p in m.parameters()
+                    if getattr(p, "amp_keep_float32", False)]
+            m.to(dtype=target)
+            for p, data in kept:
+                p._rebind(data)
     if optimizers is None:
         return models
     return models, optimizers

@@ -22,7 +22,7 @@ work follows the rows that are really there:
    ``jax.lax.ragged_dot``), and a gather back with the routing weights.
 
 The buffers hold the rows of a BUCKET, not the worst case's
-(:func:`routed_swiglu_experts`, the layer's routed block).  The worst case
+(:func:`routed_experts`, the layer's routed block).  The worst case
 is every assignment local: ``S * min(k, num_local)`` rows
 (:func:`sorted_rows`: a token's experts are distinct, so it has at most
 ``num_local`` of them here).  What a chip that holds ``num_local`` of ``E``
@@ -46,6 +46,13 @@ scatter-add in either direction.  Back at the tokens a small bucket's rows
 are summed BY RUN (:func:`_sum_by_runs`: the rows in token order, each run
 of a token added up, one row gathered a token: ``S + R`` rows move); the
 worst case's one row a SLOT (:func:`_sum_by_slots`: ``k * S`` rows).
+
+What an expert IS stays a parameter of that one path: its BODY
+(:data:`BODIES`: ``swiglu``, ``down(silu(gate) * up)`` with gate | up in one
+matrix; ``relu2``, ``down(relu(up) ** 2)``, not gated) and the width ``K``
+of the rows it works on, which need not be the router's input width (experts
+in a latent: the layer projects the tokens down before the dispatch and up
+after the combine, and every buffer here is ``K`` wide).
 
 On one chip the layer runs without its exchange: the tokens whose experts
 live elsewhere would be sent there, and theirs would arrive here.  Nothing
@@ -229,34 +236,46 @@ def _combine_bwd(res, g):
 combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def _gate(gu):
+def _swiglu(gu):
+    """``silu(gate) * up`` of ``gu [R, 2I]`` (gate | up)."""
     inter = gu.shape[1] // 2
     return jax.nn.silu(gu[:, :inter]) * gu[:, inter:]
 
 
-def swiglu_experts(xs, w_gate_up, w_down, counts):
-    """Every local expert's gated MLP on its own rows: ``w_gate_up [G, H,
-    2I]`` (gate | up), ``w_down [G, I, H]``."""
-    return grouped_matmul(_gate(grouped_matmul(xs, w_gate_up, counts)),
-                          w_down, counts)
+def _relu2(h):
+    """``relu(h) ** 2`` of ``h [R, I]``: no gate."""
+    return jnp.square(jax.nn.relu(h))
 
 
-def _swiglu_experts_vjp(xs, w_gate_up, w_down, counts):
-    """``(ys, d_ys -> (d_xs, d_gate_up, d_down))``: what ``jax.vjp`` of
-    :func:`swiglu_experts` gives, the grouped matmuls' transposes called
-    by name (``ops.pallas.grouped_matmul_dw`` says why)."""
+# an expert's BODY: what stands between its two matrices, and so how wide
+# the first is for an inner width ``I`` (``w_in [G, K, in_width * I]``)
+BODIES = {"swiglu": (_swiglu, 2), "relu2": (_relu2, 1)}
+
+
+def experts_mlp(xs, w_in, w_out, counts, body="swiglu"):
+    """Every local expert's MLP on its own rows: ``w_in [G, K, 2I]`` (gate
+    | up) under ``swiglu``, ``[G, K, I]`` under ``relu2``; ``w_out [G, I,
+    K]``.  ``K`` is the rows' width, whatever the router read."""
+    return grouped_matmul(BODIES[body][0](grouped_matmul(xs, w_in, counts)),
+                          w_out, counts)
+
+
+def _experts_mlp_vjp(xs, w_in, w_out, counts, body):
+    """``(ys, d_ys -> (d_xs, d_in, d_out))``: what ``jax.vjp`` of
+    :func:`experts_mlp` gives, the grouped matmuls' transposes called by
+    name (``ops.pallas.grouped_matmul_dw`` says why)."""
     from .....ops.pallas import grouped_matmul_dw
 
-    gu = grouped_matmul(xs, w_gate_up, counts)
-    h, gate_vjp = jax.vjp(_gate, gu)
+    pre = grouped_matmul(xs, w_in, counts)
+    h, body_vjp = jax.vjp(BODIES[body][0], pre)
 
     def vjp(d_ys):
-        d_gu, = gate_vjp(grouped_matmul(d_ys, w_down, counts, True))
-        return (grouped_matmul(d_gu, w_gate_up, counts, True),
-                grouped_matmul_dw(xs, d_gu, counts),
+        d_pre, = body_vjp(grouped_matmul(d_ys, w_out, counts, True))
+        return (grouped_matmul(d_pre, w_in, counts, True),
+                grouped_matmul_dw(xs, d_pre, counts),
                 grouped_matmul_dw(h, d_ys, counts))
 
-    return grouped_matmul(h, w_down, counts), vjp
+    return grouped_matmul(h, w_out, counts), vjp
 
 
 # ------------------------------------------- the routed block, by bucket --
@@ -264,13 +283,13 @@ def _swiglu_experts_vjp(xs, w_gate_up, w_down, counts):
 ROW_TILE = 512      # the grouped matmul's row tile (``ops.pallas``)
 
 
-def row_buckets(tokens, top_k, num_local, num_experts):
+def row_buckets(tokens, top_k, num_local, num_experts, headroom=2):
     """The static row counts a layer's buffers may have, ascending; the
     last is the worst case (:func:`sorted_rows`).
 
     ``tokens * top_k * num_local / num_experts`` rows are expected here;
-    the small bucket holds twice that, in whole row tiles of the grouped
-    matmul.  It is left out where it would reach the worst case: where
+    the small bucket holds ``headroom`` times that (twice, unless the
+    layer says otherwise), in whole row tiles of the grouped matmul.  It is left out where it would reach the worst case: where
     nothing is cut (``num_local == num_experts``) the worst case is the
     only bucket.
 
@@ -282,7 +301,7 @@ def row_buckets(tokens, top_k, num_local, num_experts):
     1.6 times; laguna's step was 0.9% faster than with this one bucket,
     kanana's 0.5% slower."""
     worst = sorted_rows(tokens, top_k, num_local)
-    rows = -(-2 * tokens * top_k * num_local // num_experts)
+    rows = -(-headroom * tokens * top_k * num_local // num_experts)
     rows = -(-rows // ROW_TILE) * ROW_TILE
     return (rows, worst) if rows < worst else (worst,)
 
@@ -354,19 +373,20 @@ def _to_tokens(rows, weights, order, inverse, counts, tokens):
     return _sum_by_runs(rows, row_weights, order, inverse, counts, tokens)
 
 
-def _routed_fwd_rows(rows, x, weights, w_gate_up, w_down, order, inverse,
+def _routed_fwd_rows(body, rows, x, weights, w_in, w_out, order, inverse,
                      counts):
-    """The routed block in a bucket of ``rows`` rows."""
+    """The routed block, its experts of ``body``, in a bucket of ``rows``
+    rows."""
     tokens, order = x.shape[0], order[:rows]
     with jax.named_scope("dispatch"):
         xs = x[order % tokens]
     with jax.named_scope("experts"):
-        ys = swiglu_experts(xs, w_gate_up, w_down, counts)
+        ys = experts_mlp(xs, w_in, w_out, counts, body)
     with jax.named_scope("combine"):
         return _to_tokens(ys, weights, order, inverse, counts, tokens)
 
 
-def _routed_bwd_rows(rows, x, weights, w_gate_up, w_down, order, inverse,
+def _routed_bwd_rows(body, rows, x, weights, w_in, w_out, order, inverse,
                      counts, g):
     """Its transpose in the same bucket, from the block's INPUTS: ``xs`` and
     the experts' intermediate values are rebuilt at ``rows`` rows."""
@@ -375,7 +395,7 @@ def _routed_bwd_rows(rows, x, weights, w_gate_up, w_down, order, inverse,
     with jax.named_scope("dispatch"):
         xs = x[token]
     with jax.named_scope("experts"):
-        ys, experts_vjp = _swiglu_experts_vjp(xs, w_gate_up, w_down, counts)
+        ys, experts_vjp = _experts_mlp_vjp(xs, w_in, w_out, counts, body)
     with jax.named_scope("combine"):
         # ONE gather of the tokens' gradient: times the row's weight for
         # d ys, dotted with ys for the weight's own
@@ -387,47 +407,49 @@ def _routed_bwd_rows(rows, x, weights, w_gate_up, w_down, order, inverse,
         d_w = jnp.where(served, d_w[jnp.minimum(inverse, rows - 1)], 0.0)
         d_w = d_w.reshape(-1, tokens).T.astype(weights.dtype)
     with jax.named_scope("experts"):
-        d_xs, d_gate_up, d_down = experts_vjp(d_ys)
+        d_xs, d_in, d_out = experts_vjp(d_ys)
     with jax.named_scope("dispatch"):
         d_x = _to_tokens(d_xs, None, order, inverse, counts, tokens)
-    return d_x, d_w, d_gate_up, d_down
+    return d_x, d_w, d_in, d_out
 
 
-def _in_bucket(body, buckets, counts, *operands):
-    """``body(rows, *operands)`` at the bucket ``counts`` asks for."""
+def _in_bucket(rows_fn, buckets, counts, *operands):
+    """``rows_fn(rows, *operands)`` at the bucket ``counts`` asks for."""
     if len(buckets) == 1:
-        return body(buckets[0], *operands)
+        return rows_fn(buckets[0], *operands)
     return jax.lax.switch(
         bucket_of(counts, buckets),
-        [functools.partial(body, rows) for rows in buckets], *operands)
+        [functools.partial(rows_fn, rows) for rows in buckets], *operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def routed_swiglu_experts(x, weights, w_gate_up, w_down, order, inverse,
-                          counts, buckets):
-    """``x [S, H]`` -> ``[S, H]``: dispatch, the experts held here
-    (:func:`swiglu_experts`) and combine, every buffer in between at the
-    rows of the smallest of ``buckets`` (:func:`row_buckets`) that holds
-    ``sum(counts)``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def routed_experts(x, weights, w_in, w_out, order, inverse, counts, buckets,
+                   body="swiglu"):
+    """``x [S, K]`` -> ``[S, K]``: dispatch, the experts held here
+    (:func:`experts_mlp` with ``body``) and combine, every buffer in
+    between at the rows of the smallest of ``buckets``
+    (:func:`row_buckets`) that holds ``sum(counts)``.
 
     ONE ``custom_vjp`` round the three, because the choice is a
     ``lax.switch``: differentiated THROUGH, each branch would write zeros
     for every other branch's residuals, the worst case's among them.  The
     residuals here are the block's inputs, whose shapes no bucket changes;
     the backward opens its own switch."""
-    return _routed_fwd(x, weights, w_gate_up, w_down, order, inverse, counts,
-                       buckets)[0]
+    return _routed_fwd(x, weights, w_in, w_out, order, inverse, counts,
+                       buckets, body)[0]
 
 
-def _routed_fwd(x, weights, w_gate_up, w_down, order, inverse, counts,
-                buckets):
-    operands = (x, weights, w_gate_up, w_down, order, inverse, counts)
-    return _in_bucket(_routed_fwd_rows, buckets, counts, *operands), operands
+def _routed_fwd(x, weights, w_in, w_out, order, inverse, counts, buckets,
+                body):
+    operands = (x, weights, w_in, w_out, order, inverse, counts)
+    return _in_bucket(functools.partial(_routed_fwd_rows, body),
+                      buckets, counts, *operands), operands
 
 
-def _routed_bwd(buckets, operands, g):
-    grads = _in_bucket(_routed_bwd_rows, buckets, operands[-1], *operands, g)
+def _routed_bwd(buckets, body, operands, g):
+    grads = _in_bucket(functools.partial(_routed_bwd_rows, body),
+                       buckets, operands[-1], *operands, g)
     return (*grads, None, None, None)
 
 
-routed_swiglu_experts.defvjp(_routed_fwd, _routed_bwd)
+routed_experts.defvjp(_routed_fwd, _routed_bwd)

@@ -202,39 +202,55 @@ def _route(x2d, wg, bias, *, top_k, scale, norm_topk):
 
 
 @op("moe_routed_experts_dropless")
-def _routed_experts(x2d, idx, weights, gate_up, down, *, expert_offset,
-                    num_experts):
-    """The routed block (``dropless.routed_swiglu_experts``) -> ``(out,
-    counts, rows_buffered)``: the tokens each expert held here received,
-    and the rows of the bucket the buffers took this step."""
-    num_local = gate_up.shape[0]
+def _routed_experts(rows2d, idx, weights, w_in, w_out, *, expert_offset,
+                    buckets, body="swiglu"):
+    """The routed block (``dropless.routed_experts``) on ``rows2d [S, K]``
+    -> ``(out [S, K], counts, rows_buffered)``: the tokens each expert held
+    here received, and the rows of the one of ``buckets`` the buffers took
+    this step."""
+    num_local = w_in.shape[0]
     with jax.named_scope("dispatch"):
         order, inverse, counts = _dl.sort_by_expert(idx, expert_offset,
                                                     num_local)
-        buckets = _dl.row_buckets(*idx.shape, num_local, num_experts)
         rows = jnp.asarray(buckets, jnp.int32)[_dl.bucket_of(counts, buckets)]
-    out = _dl.routed_swiglu_experts(x2d, weights, gate_up, down, order,
-                                    inverse, counts, buckets)
+    out = _dl.routed_experts(rows2d, weights, w_in, w_out, order, inverse,
+                             counts, buckets, body)
     return out, counts, rows
 
 
-class GroupedSwiGLUExperts(Layer):
-    """The experts held here, stacked: ``gate_up [G, H, 2I]`` (gate | up),
-    ``down [G, I, H]``.  It holds them and no more: the layer's routed
-    block multiplies them (``dropless.routed_swiglu_experts``)."""
+def _linear(d_in, d_out, std):
+    from .....nn.common import Linear
+    from .....nn.layer_base import ParamAttr
 
-    def __init__(self, num_local, d_model, d_expert, init_std=0.02,
-                 down_std=None):
+    return Linear(d_in, d_out, bias_attr=False,
+                  weight_attr=ParamAttr(initializer=Normal(0.0, std)))
+
+
+class GroupedExperts(Layer):
+    """The experts held here, stacked: the first matrix ``[G, K, 2I]``
+    (gate | up, held as ``gate_up``) under the ``swiglu`` body, ``[G, K,
+    I]`` (held as ``up``) under ``relu2``; ``down [G, I, K]``.  ``K`` is the
+    width of the rows the experts work on.  It holds them and no more: the
+    layer's routed block multiplies them (``dropless.routed_experts``)."""
+
+    def __init__(self, num_local, d_rows, d_expert, init_std=0.02,
+                 down_std=None, body="swiglu"):
         super().__init__()
-        self.gate_up = self.create_parameter(
-            (num_local, d_model, 2 * d_expert),
-            default_initializer=Normal(0.0, init_std))
+        self.body = body
+        self._in_name = "gate_up" if body == "swiglu" else "up"
+        setattr(self, self._in_name, self.create_parameter(
+            (num_local, d_rows, _dl.BODIES[body][1] * d_expert),
+            default_initializer=Normal(0.0, init_std)))
         self.down = self.create_parameter(
-            (num_local, d_expert, d_model),
+            (num_local, d_expert, d_rows),
             default_initializer=Normal(0.0, down_std or init_std))
-        for p_ in (self.gate_up, self.down):
+        for p_ in (self.w_in, self.down):
             p_.mesh_axes = ("ep", None, None)
             p_.expert = True
+
+    @property
+    def w_in(self):
+        return getattr(self, self._in_name)
 
 
 class SwiGLUMLP(Layer):
@@ -243,15 +259,8 @@ class SwiGLUMLP(Layer):
 
     def __init__(self, d_model, d_hidden, init_std=0.02, down_std=None):
         super().__init__()
-        from .....nn.common import Linear
-        from .....nn.layer_base import ParamAttr
-
-        self.gate_up = Linear(d_model, 2 * d_hidden, bias_attr=False,
-                              weight_attr=ParamAttr(
-                                  initializer=Normal(0.0, init_std)))
-        self.down = Linear(d_hidden, d_model, bias_attr=False,
-                           weight_attr=ParamAttr(initializer=Normal(
-                               0.0, down_std or init_std)))
+        self.gate_up = _linear(d_model, 2 * d_hidden, init_std)
+        self.down = _linear(d_hidden, d_model, down_std or init_std)
         self._hidden = d_hidden
 
     def forward(self, x):
@@ -260,6 +269,23 @@ class SwiGLUMLP(Layer):
         gu = self.gate_up(x)
         return self.down(swiglu(gu[..., :self._hidden],
                                 gu[..., self._hidden:]))
+
+
+class Relu2MLP(Layer):
+    """``down(relu(up(x)) ** 2)``, no gate, no bias: the shared expert of
+    the ``relu2`` family."""
+
+    def __init__(self, d_model, d_hidden, init_std=0.02, down_std=None):
+        super().__init__()
+        self.up = _linear(d_model, d_hidden, init_std)
+        self.down = _linear(d_hidden, d_model, down_std or init_std)
+
+    def forward(self, x):
+        h = F.relu(self.up(x))
+        return self.down(h * h)
+
+
+_MLPS = {"swiglu": SwiGLUMLP, "relu2": Relu2MLP}
 
 
 class DroplessMoELayer(Layer):
@@ -278,14 +304,29 @@ class DroplessMoELayer(Layer):
     that is held here, that holds those tokens.  Both stay on the device;
     the worst case's rows mean the fallback ran.
 
-    Scopes: ``router``, ``dispatch``, ``experts``, ``combine``,
-    ``shared_experts`` (``docs/PROFILER.md``)."""
+    ``body`` is the experts' (``dropless.BODIES``: ``swiglu`` gated,
+    ``relu2`` not), routed and shared alike.  With ``d_latent`` the routed
+    experts work in a LATENT: ``latent_down [d_model, d_latent]`` before
+    the dispatch and ``latent_up [d_latent, d_model]`` after the combine,
+    so the rows that are sorted, gathered and summed are ``d_latent`` wide;
+    the router and the shared expert read ``x`` itself.  ``d_shared`` is
+    the shared expert's own width (``num_shared_experts * d_expert``
+    without it).  ``bucket_headroom`` (an attribute, 2): the small bucket's
+    rows over the rows expected here (``dropless.row_buckets``); a trainer
+    whose router is NOT balanced sets it on the layer before the first step.
+
+    Scopes: ``router``, ``latent_down``, ``dispatch``, ``experts``,
+    ``combine``, ``latent_up``, ``shared_experts`` (``docs/PROFILER.md``).
+    """
+
+    bucket_headroom = 2
 
     def __init__(self, d_model, d_expert, num_experts, top_k,
                  num_shared_experts=0, routed_scaling_factor=1.0,
                  norm_topk_prob=True, num_local_experts=None,
                  expert_offset=0, init_std=0.02, down_std=None,
-                 score_func="sigmoid"):
+                 score_func="sigmoid", body="swiglu", d_latent=None,
+                 d_shared=None):
         super().__init__()
         num_local = num_experts if num_local_experts is None \
             else num_local_experts
@@ -298,20 +339,36 @@ class DroplessMoELayer(Layer):
         self.router = TopKRouter(d_model, num_experts, top_k,
                                  routed_scaling_factor, norm_topk_prob,
                                  init_std, score_func)
-        self.experts = GroupedSwiGLUExperts(num_local, d_model, d_expert,
-                                            init_std, down_std)
-        self.shared_experts = SwiGLUMLP(
-            d_model, num_shared_experts * d_expert, init_std, down_std) \
-            if num_shared_experts else None
+        # in a latent the residual projection is ``latent_up``, not the
+        # experts' own second matrix
+        self.latent_down = self.latent_up = None
+        if d_latent:
+            self.latent_down = _linear(d_model, d_latent, init_std)
+        self.experts = GroupedExperts(
+            num_local, d_latent or d_model, d_expert, init_std,
+            None if d_latent else down_std, body)
+        if d_latent:
+            self.latent_up = _linear(d_latent, d_model,
+                                     down_std or init_std)
+        if d_shared is None:
+            d_shared = num_shared_experts * d_expert
+        self.shared_experts = _MLPS[body](
+            d_model, d_shared, init_std, down_std) if d_shared else None
         self.tokens_per_expert = self.rows_buffered = None
 
     def forward(self, x):
         shape = x.shape
         x2d = x.reshape([-1, self.d_model])
         idx, weights = self.router(x2d)
+        rows = x2d if self.latent_down is None else self.latent_down(x2d)
         out, self.tokens_per_expert, self.rows_buffered = _routed_experts(
-            x2d, idx, weights, self.experts.gate_up, self.experts.down,
-            expert_offset=self.expert_offset, num_experts=self.num_experts)
+            rows, idx, weights, self.experts.w_in, self.experts.down,
+            expert_offset=self.expert_offset,
+            buckets=_dl.row_buckets(*idx.shape, self.num_local_experts,
+                                    self.num_experts, self.bucket_headroom),
+            body=self.experts.body)
+        if self.latent_up is not None:
+            out = self.latent_up(out)
         if self.shared_experts is not None:
             out = out + self.shared_experts(x2d)
         return out.reshape(shape)

@@ -24,6 +24,12 @@ from .evabyte import (  # noqa: F401
     evabyte_6_5b,
     evabyte_tiny,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHForCausalLM,
+    nemotron_3_super_120b_a12b,
+    nemotron_h_tiny,
+)
 from .wide_deep import WideDeep  # noqa: F401
 from .deepfm import DeepFM  # noqa: F401
 from .deepspeech import DeepSpeech2, deepspeech2_tiny  # noqa: F401

@@ -1,18 +1,25 @@
 """The decoder shell the expert models share (``models/mla_moe.py``,
-``models/laguna.py``): a pre-norm residual block, the stack, a final
-RMSNorm, an untied ``lm_head`` over whatever slice of the vocabulary is
-held, the shifted-label loss and the step's counters.
+``models/laguna.py``, ``models/evabyte.py``, ``models/nemotron_h.py``):
+pre-norm residual blocks, the stack, a final RMSNorm, an untied
+``lm_head`` over whatever slice of the vocabulary is held, the
+shifted-label loss and the step's counters.  A block has two branches,
 
     x <- x + attn(rms(x));  x <- x + ffn(rms(x));  logits = W_head rms(x)
 
-What differs between the families is what a LAYER is made of, and the
-shell asks the model's config for it, layer by layer:
+or ONE, ``x <- x + mixer(rms(x))``.  What differs between the families is
+what a LAYER is made of, and the shell asks the model's config for it,
+layer by layer:
 
 - ``config.make_attention(layer_idx)``: the layer's attention module
   (``MLAttention``; ``GroupedGatedAttention`` with the layer's kind, head
   count and rotary table), ``[B, T, H] -> [B, T, H]``;
 - ``config.make_ffn(layer_idx)``: a dense ``SwiGLUMLP`` (held as ``mlp``)
-  or the expert layer ``DroplessMoELayer`` (held as ``moe``).
+  or the expert layer ``DroplessMoELayer`` (held as ``moe``);
+- or ``config.make_mixer(layer_idx)`` -> ``(name, module)``: the block's
+  ONLY module, held (and scoped) under ``name``: ``mamba``, ``attn`` or
+  ``moe`` (the hybrid family, whose pattern gives a layer one of the
+  three).  A config that returns nothing there (the default) has the two
+  factories above asked, as before.
 
 One chip's share of an expert-parallel layer is stated by
 ``num_local_experts`` and ``expert_offset`` (see ``DroplessMoELayer``);
@@ -34,7 +41,9 @@ model that sets none computes what it computed without them):
 A decoder layer hands its expert counters on as OUTPUTS, so that
 ``jit.TrainStep(remat=...)`` can rematerialise each layer in the backward
 pass.  Scopes: ``embeddings`` / ``layers.i`` / ``ln_1`` / ``attn`` /
-``ln_2`` / ``mlp`` | ``moe`` / ``ln_f`` / ``lm_head`` (``docs/PROFILER.md``).
+``ln_2`` / ``mlp`` | ``moe`` / ``ln_f`` / ``lm_head``; a block of one
+branch ``layers.i`` / ``ln_1`` / ``mamba`` | ``attn`` | ``moe``
+(``docs/PROFILER.md``).
 """
 
 import math
@@ -75,12 +84,14 @@ class UnitOffsetRMSNorm(nn.Layer):
 class MoeDecoderConfig:
     """What the shell reads of a config: ``vocab_size``, ``hidden_size``,
     ``num_hidden_layers``, ``rms_norm_eps``, ``initializer_range``,
-    ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, the two
-    factories, and the three options of the module's docstring."""
+    ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, the
+    factories (two for a block of two branches, ``make_mixer`` for a block
+    of one), and the three options of the module's docstring."""
 
     fp32_skip_add = False
     norm_add_unit_offset = False
     num_pred_heads = 1
+    branches_per_layer = 2      # residual adds a block makes (``out_std``)
 
     def make_norm(self):
         cls = UnitOffsetRMSNorm if self.norm_add_unit_offset else nn.RMSNorm
@@ -88,8 +99,15 @@ class MoeDecoderConfig:
 
     @property
     def out_std(self):
-        """The two residual projections' (``o_proj``, ``down``)."""
-        return self.initializer_range / math.sqrt(2 * self.num_hidden_layers)
+        """The residual projections' (``o_proj``, ``down``): the range over
+        the root of the residual adds the stack makes."""
+        return self.initializer_range / math.sqrt(
+            self.branches_per_layer * self.num_hidden_layers)
+
+    def make_mixer(self, layer_idx):
+        """``(name, module)`` of a block of ONE branch; nothing for a block
+        of two."""
+        return None
 
     def make_attention(self, layer_idx):
         raise NotImplementedError
@@ -102,32 +120,40 @@ class MoeDecoderConfig:
                          self.out_std)
 
     def expert_layer(self, width, router_experts, top_k, shared_experts,
-                     scale, score_func="sigmoid"):
+                     scale, score_func="sigmoid", **body_and_latent):
         """``router_experts`` is the router's width; the experts held
-        here are ``num_local_experts`` from ``expert_offset`` on."""
+        here are ``num_local_experts`` from ``expert_offset`` on.
+        ``body_and_latent``: ``DroplessMoELayer``'s ``body``, ``d_latent``,
+        ``d_shared``."""
         return DroplessMoELayer(
             self.hidden_size, width, router_experts, top_k, shared_experts,
             scale, self.norm_topk_prob, self.num_local_experts,
             self.expert_offset, self.initializer_range, self.out_std,
-            score_func)
+            score_func, **body_and_latent)
 
 
 class MoeDecoderLayer(nn.Layer):
-    """One block.  Returns ``(x, tokens_per_expert, rows_buffered)``; a
-    dense layer's counters are empty arrays, so every layer has the same
+    """One block, of two branches or of one (the module's docstring).
+    Returns ``(x, tokens_per_expert, rows_buffered)``; the counters of a
+    layer without experts are empty arrays, so every layer has the same
     outputs."""
 
     def __init__(self, config, layer_idx):
         super().__init__()
         c = config
         self.ln_1 = c.make_norm()
+        self._fp32_skip_add = c.fp32_skip_add
+        self._mixer, module = c.make_mixer(layer_idx) or (None, None)
+        if module is not None:
+            self.ln_2 = self.attn = self.mlp = self.moe = None
+            setattr(self, self._mixer, module)
+            return
         self.attn = c.make_attention(layer_idx)
         self.ln_2 = c.make_norm()
         ffn = c.make_ffn(layer_idx)
         is_moe = isinstance(ffn, DroplessMoELayer)
         self.mlp = None if is_moe else ffn
         self.moe = ffn if is_moe else None
-        self._fp32_skip_add = c.fp32_skip_add
 
     def _branch(self, x, norm, f):
         """``x + f(norm(x))``; under ``fp32_skip_add`` the sum in float32
@@ -137,6 +163,12 @@ class MoeDecoderLayer(nn.Layer):
         return x + f(norm(x).astype(norm.weight.dtype)).astype("float32")
 
     def forward(self, x):
+        if self._mixer is not None:
+            x = self._branch(x, self.ln_1, getattr(self, self._mixer))
+            if self.moe is None:
+                none = Tensor(jnp.zeros((0,), jnp.int32))
+                return x, none, none
+            return x, self.moe.tokens_per_expert, self.moe.rows_buffered
         x = self._branch(x, self.ln_1, self.attn)
         if self.moe is None:
             none = Tensor(jnp.zeros((0,), jnp.int32))

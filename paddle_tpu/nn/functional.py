@@ -1007,6 +1007,105 @@ def ctc_loss(log_probs, labels, input_lengths=None, label_lengths=None,
     return loss
 
 
+# ---------------- state-space (Mamba-2) ----------------
+
+@op()
+def causal_conv1d(x, weight, bias=None):
+    """Causal depthwise convolution over time: ``x [B, T, C]``, ``weight [C,
+    K]`` (tap ``k`` reads position ``t - (K - 1) + k``: the last tap is the
+    position itself), ``bias [C]``; positions before the row's first read
+    zero.  A sum of ``K`` shifted products in float32, rounded once to
+    ``x``'s dtype: at ``K`` = 4 one fused pass over the row, no
+    convolution op."""
+    taps = weight.shape[1]
+    x32 = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
+    w32 = weight.astype(jnp.float32)
+    t = x.shape[1]
+    out = sum(x32[:, k:k + t] * w32[:, k] for k in range(taps))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+def _ssd_scan_row(x, dt, a_head, b, c, d_head, chunk):
+    """One row of :func:`ssd_scan`: ``x [T, nh, P]``, ``dt [T, nh]``, ``b``,
+    ``c`` ``[T, G, N]``, ``T`` a whole number of chunks."""
+    f32 = jnp.float32
+    t, nh, p = x.shape
+    g, n = b.shape[1:]
+    hg, nc = nh // g, t // chunk
+    dt = dt.astype(f32).reshape(nc, chunk, g, hg)
+    alpha = jnp.cumsum(dt * a_head.astype(f32).reshape(g, hg), axis=1)
+    xc = x.reshape(nc, chunk, g, hg, p)
+    bc, cc = b.reshape(nc, chunk, g, n), c.reshape(nc, chunk, g, n)
+    # inside a chunk: (C_i . B_j) exp(alpha_i - alpha_j) dt_j over j <= i;
+    # the difference is masked BEFORE the exp (above the diagonal it is
+    # positive and may overflow)
+    cb = jnp.einsum("cign,cjgn->cgij", cc, bc, preferred_element_type=f32)
+    by_head = alpha.transpose(0, 2, 3, 1)                   # [c, g, h, q]
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(
+        causal, by_head[..., :, None] - by_head[..., None, :], -jnp.inf))
+    m = cb[:, :, None] * decay * dt.transpose(0, 2, 3, 1)[..., None, :]
+    y = jnp.einsum("cghij,cjghp->cighp", m.astype(x.dtype), xc,
+                   preferred_element_type=f32)
+    # what a chunk adds to the state at its end, and the state each chunk
+    # starts from: the one recurrence left, over T / chunk steps
+    to_end = jnp.exp(alpha[:, -1:] - alpha) * dt            # [c, q, g, h]
+    local = jnp.einsum(
+        "cqghp,cqgn->cghpn",
+        (xc.astype(f32) * to_end[..., None]).astype(x.dtype), bc,
+        preferred_element_type=f32)
+
+    def carry(state, chunk_in):
+        keep, add = chunk_in
+        return keep[..., None, None] * state + add, state
+
+    _, entering = lax.scan(carry, jnp.zeros_like(local[0]),
+                           (jnp.exp(alpha[:, -1]), local))
+    y = y + jnp.exp(alpha)[..., None] * jnp.einsum(
+        "cqgn,cghpn->cqghp", cc, entering.astype(x.dtype),
+        preferred_element_type=f32)
+    y = y + d_head.astype(f32).reshape(g, hg, 1) * xc.astype(f32)
+    return y.astype(x.dtype).reshape(t, nh, p)
+
+
+@op()
+def ssd_scan(x, dt, A, B, C, D, chunk=128):
+    """The selective state-space recurrence of Mamba-2 (arXiv:2405.21060)
+    in its CHUNKED form.  ``x [B, T, nh, P]``, ``dt [B, T, nh]`` (the step
+    sizes, already positive), ``A [nh]`` (negative), ``B``, ``C`` ``[B, T,
+    G, N]`` (head ``h`` reads group ``h // (nh / G)``), ``D [nh]``:
+
+        S[t] = exp(dt[t] A) S[t-1] + dt[t] x[t] (outer) B[t];  S[-1] = 0
+        y[t] = S[t] C[t] + D x[t]
+
+    computed a chunk of ``chunk`` positions at a time, with ``alpha_i`` the
+    running sum of ``dt A`` inside the chunk: inside it ``sum_{j <= i} (C_i
+    . B_j) exp(alpha_i - alpha_j) dt_j x_j`` as three batched matmuls; one
+    ``[P, N]`` state a head carried from chunk to chunk by a ``lax.scan``
+    over ``T / chunk`` steps, read by ``exp(alpha_i) S C_i``.  Never a loop
+    over positions, never a ``[T, T]`` matrix.  The decay sums, every
+    ``exp`` and the carried states are float32; the matmuls take their
+    operands in ``x``'s dtype and accumulate in float32; ``y`` comes back
+    in ``x``'s dtype.  A ``T`` that is no whole number of chunks is padded
+    with steps of size zero, which leave the state as it is.
+
+    The backward is this form differentiated.  It keeps, per row, the
+    masked decay matrix and ``C B^T`` ``[T / chunk, nh, chunk, chunk]``,
+    the chunks' local and entering states ``[T / chunk, nh, P, N]`` and the
+    operands: under ``jit.TrainStep(remat=...)`` only while its own block
+    is differentiated."""
+    t = x.shape[1]
+    pad = -t % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                       for a in (x, dt, B, C))
+    y = jax.vmap(lambda x_, dt_, b_, c_: _ssd_scan_row(
+        x_, dt_, A, b_, c_, D, chunk))(x, dt, B, C)
+    return y[:, :t] if pad else y
+
+
 # ---------------- attention ----------------
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -305,7 +305,7 @@ def test_sorted_buffers_hold_min_of_top_k_and_held_rows_a_token(held, top_k,
         order, inverse, counts = dropless.sort_by_expert(idx, offset, held)
         assert order.shape[0] == tokens * rows
         xs = dropless.dispatch(x, order, inverse, counts)
-        ys = dropless.swiglu_experts(xs, gate_up, down, counts)
+        ys = dropless.experts_mlp(xs, gate_up, down, counts)
         return dropless.combine(ys, w, order, inverse, counts), counts
 
     def loop(x, w, gate_up, down):

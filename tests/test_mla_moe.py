@@ -202,7 +202,7 @@ def test_dropless_dispatch_against_a_loop_over_experts_skewed(offset, held):
     def routed(x, gate_up, down, w):
         order, inverse, counts = dropless.sort_by_expert(idx, offset, held)
         xs = dropless.dispatch(x, order, inverse, counts)
-        ys = dropless.swiglu_experts(xs, gate_up, down, counts)
+        ys = dropless.experts_mlp(xs, gate_up, down, counts)
         return dropless.combine(ys, w, order, inverse, counts), counts
 
     got, counts = routed(x, gate_up, down, w)
@@ -293,7 +293,7 @@ def _block_routed(idx, x, w, gate_up, down, buckets=None):
                                                      c["held"])
     if buckets is None:
         buckets = dropless.row_buckets(c["s"], c["k"], c["held"], c["e"])
-    out = dropless.routed_swiglu_experts(x, w, gate_up, down, order,
+    out = dropless.routed_experts(x, w, gate_up, down, order,
                                          inverse, counts, buckets)
     return out, counts, \
         jnp.asarray(buckets)[dropless.bucket_of(counts, buckets)]
@@ -305,7 +305,7 @@ def _block_parent(idx, x, w, gate_up, down):
     order, inverse, counts = dropless.sort_by_expert(idx, c["offset"],
                                                      c["held"])
     xs = dropless.dispatch(x, order, inverse, counts)
-    ys = dropless.swiglu_experts(xs, gate_up, down, counts)
+    ys = dropless.experts_mlp(xs, gate_up, down, counts)
     return dropless.combine(ys, w, order, inverse, counts)
 
 
@@ -728,7 +728,7 @@ def test_grouped_matmul_lowers_to_the_pallas_kernels_for_the_tpu(monkeypatch):
     sds = jax.ShapeDtypeStruct
 
     def loss(xs, gate_up, down, sizes):
-        ys = dropless.swiglu_experts(xs, gate_up, down, sizes)
+        ys = dropless.experts_mlp(xs, gate_up, down, sizes)
         return jnp.sum(ys.astype(jnp.float32))
 
     args = (sds((98304, 2048), jnp.bfloat16),

@@ -319,7 +319,7 @@ def test_a_small_buckets_branch_holds_no_worst_case_residual(monkeypatch):
         operands = (x, weights, gate_up, down, order, inverse, counts)
         return jnp.sum(jax.lax.switch(
             dropless.bucket_of(counts, buckets),
-            [functools.partial(dropless._routed_fwd_rows, rows)
+            [functools.partial(dropless._routed_fwd_rows, "swiglu", rows)
              for rows in buckets], *operands).astype(jnp.float32))
 
     sds = jax.ShapeDtypeStruct
