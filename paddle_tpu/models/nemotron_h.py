@@ -23,7 +23,15 @@ groups of B and C at state ``N`` = ``ssm_state_size``, head ``h`` on group
     y <- rms_groups(y * silu(z)) * g_norm      the gate BEFORE the norm; statistics over each of the G groups of d / G
     out = W_out y
 
-  the recurrence in its chunked form, ``F.ssd_scan`` at ``chunk_size``.
+  the recurrence in its chunked form, ``F.ssd_scan`` at ``chunk_size``.  On
+  the TPU, at shapes ``ops/pallas/ssd_scan_kernel.py supports`` takes (the
+  published ones: chunk and state 128, 16 heads of 64 a group), that is the
+  ``ssd_scan_fwd`` / ``ssd_scan_bwd`` kernels: a chunk's decay matrices, ``C
+  B^T`` and the carried state stay in VMEM, and a block's backward keeps
+  x, B, C, the step sizes and the chunks' entering states (float32 ``[T /
+  chunk, nh, P, N]``), nothing ``[chunk, chunk]``.  Elsewhere (off the TPU,
+  the tiny test config's chunk of 16, a GSPMD mesh) the XLA composition
+  ``F._ssd_scan_row`` runs, differentiated as it stands.
 
 ``*``, attention: ``num_attention_heads`` q heads over
 ``num_key_value_heads`` kv heads of ``head_dim``, causal, scale ``head_dim

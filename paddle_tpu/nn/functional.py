@@ -1070,6 +1070,13 @@ def _ssd_scan_row(x, dt, a_head, b, c, d_head, chunk):
     return y.astype(x.dtype).reshape(t, nh, p)
 
 
+def _ssd_scan_rows(x, dt, A, B, C, D, chunk=128):
+    """:func:`ssd_scan` on rows of whole chunks as the XLA composition:
+    :func:`_ssd_scan_row` over the batch."""
+    return jax.vmap(lambda x_, dt_, b_, c_: _ssd_scan_row(
+        x_, dt_, A, b_, c_, D, chunk))(x, dt, B, C)
+
+
 @op()
 def ssd_scan(x, dt, A, B, C, D, chunk=128):
     """The selective state-space recurrence of Mamba-2 (arXiv:2405.21060)
@@ -1082,27 +1089,39 @@ def ssd_scan(x, dt, A, B, C, D, chunk=128):
 
     computed a chunk of ``chunk`` positions at a time, with ``alpha_i`` the
     running sum of ``dt A`` inside the chunk: inside it ``sum_{j <= i} (C_i
-    . B_j) exp(alpha_i - alpha_j) dt_j x_j`` as three batched matmuls; one
-    ``[P, N]`` state a head carried from chunk to chunk by a ``lax.scan``
-    over ``T / chunk`` steps, read by ``exp(alpha_i) S C_i``.  Never a loop
-    over positions, never a ``[T, T]`` matrix.  The decay sums, every
+    . B_j) exp(alpha_i - alpha_j) dt_j x_j``; one ``[P, N]`` state a head
+    carried from chunk to chunk, read by ``exp(alpha_i) S C_i``.  Never a
+    loop over positions, never a ``[T, T]`` matrix.  The decay sums, every
     ``exp`` and the carried states are float32; the matmuls take their
     operands in ``x``'s dtype and accumulate in float32; ``y`` comes back
     in ``x``'s dtype.  A ``T`` that is no whole number of chunks is padded
     with steps of size zero, which leave the state as it is.
 
-    The backward is this form differentiated.  It keeps, per row, the
-    masked decay matrix and ``C B^T`` ``[T / chunk, nh, chunk, chunk]``,
-    the chunks' local and entering states ``[T / chunk, nh, P, N]`` and the
-    operands: under ``jit.TrainStep(remat=...)`` only while its own block
+    What runs (``ops.pallas.ssd_scan`` decides, by the shapes):
+
+    - on the TPU, where ``ssd_scan_kernel.supports`` takes the shapes
+      (chunk and state whole 128-lane tiles, a group's heads whole tiles):
+      the ``ssd_scan_fwd`` / ``ssd_scan_bwd`` kernels.  The chunk's decay
+      matrices, ``C B^T`` and the carried state live in VMEM; the backward
+      keeps the operands and the chunks' ENTERING states ``[T / chunk, nh,
+      P, N]`` (float32) and forms every matrix again;
+    - elsewhere (off the TPU; aloud on it, ``KernelFallbackWarning``, for
+      other shapes or under a GSPMD mesh): :func:`_ssd_scan_row`, three
+      batched matmuls and a ``lax.scan`` over ``T / chunk`` steps,
+      differentiated as it stands: its backward keeps, per row, the masked
+      decay matrix and ``C B^T`` ``[T / chunk, nh, chunk, chunk]``, the
+      chunks' local and entering states and the operands.
+
+    Either way under ``jit.TrainStep(remat=...)`` only while its own block
     is differentiated."""
+    from ..ops import pallas
+
     t = x.shape[1]
     pad = -t % chunk
     if pad:
         x, dt, B, C = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
                        for a in (x, dt, B, C))
-    y = jax.vmap(lambda x_, dt_, b_, c_: _ssd_scan_row(
-        x_, dt_, A, b_, c_, D, chunk))(x, dt, B, C)
+    y = pallas.ssd_scan(x, dt, A, B, C, D, chunk)
     return y[:, :t] if pad else y
 
 

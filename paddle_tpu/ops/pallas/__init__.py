@@ -361,6 +361,71 @@ def eva_pairs_scored(seq, head_dim, window, chunk):
     return eva.pairs_dense(seq, chunk)
 
 
+# The state-space scans traced so far, as the flash calls above: the newest
+# calls' records, and the running sums a compiled step takes its share of.
+_ssd_scans = collections.deque(maxlen=256)
+_ssd_scan_sums = {"ssd_calls": 0, "ssd_calls_composed": 0}
+
+
+def ssd_scan_log():
+    """The records of the newest traced :func:`ssd_scan` calls (at most
+    256), oldest first: ``shapes`` (x, B), ``chunk``, ``path`` (``kernel``
+    or ``composition``) and ``reason`` (why the composition; None for the
+    kernels)."""
+    return list(_ssd_scans)
+
+
+def traced_call_sums():
+    """What a compiled step's account takes its own share of
+    (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`, and
+    ``ssd_calls`` / ``ssd_calls_composed``, the :func:`ssd_scan` calls
+    traced in this process and those of them the composition served."""
+    return {**_flash_layout_sums, **_ssd_scan_sums}
+
+
+def _ssd_refusal(x, B, C, chunk):
+    """Why the ``ssd_scan_*`` kernels do not serve this call where kernels
+    are on, or None."""
+    from .ssd_scan_kernel import supports
+
+    if B.dtype != x.dtype or C.dtype != x.dtype:
+        return f"B {B.dtype}, C {C.dtype} beside x {x.dtype}"
+    if not supports(x.shape[1], x.shape[2], x.shape[3], B.shape[2],
+                    B.shape[3], chunk, x.dtype):
+        return "ssd_scan_kernel.supports() refuses the shape"
+    if _partitioned_by_gspmd():
+        return GSPMD_REASON + "; these kernels have no sharded launch"
+    return None
+
+
+def ssd_scan(x, dt, A, B, C, D, chunk):
+    """``nn/functional.py ssd_scan`` on rows of whole chunks: ``x [batch,
+    T, heads, P]``, ``dt [batch, T, heads]``, ``B``, ``C`` ``[batch, T,
+    groups, N]``.  On the TPU the ``ssd_scan_fwd`` / ``ssd_scan_bwd``
+    kernels (``ssd_scan_kernel``); elsewhere, and aloud where
+    ``ssd_scan_kernel.supports`` refuses the shapes or GSPMD partitions the
+    step, the XLA composition ``nn/functional.py _ssd_scan_rows``.  Every
+    traced call is recorded (:func:`ssd_scan_log`)."""
+    kernels_on = _use_pallas()
+    reason = _ssd_refusal(x, B, C, chunk) if kernels_on else \
+        "no TPU backend (or FLAGS_use_pallas_kernels off)"
+    _ssd_scans.append({
+        "shapes": (tuple(x.shape), tuple(B.shape)), "chunk": chunk,
+        "path": "kernel" if reason is None else "composition",
+        "reason": reason})
+    _ssd_scan_sums["ssd_calls"] += 1
+    if reason is None:
+        from .ssd_scan_kernel import ssd_scan_pallas
+        return ssd_scan_pallas(x, dt, A, B, C, D, chunk)
+    _ssd_scan_sums["ssd_calls_composed"] += 1
+    if kernels_on:
+        warn_fallback("ssd_scan",
+                      f"x{tuple(x.shape)} B{tuple(B.shape)} chunk={chunk}",
+                      reason)
+    from ...nn.functional import _ssd_scan_rows
+    return _ssd_scan_rows(x, dt, A, B, C, D, chunk)
+
+
 def _grouped_row_tile(shape):
     """The row tile of JAX's grouped-matmul Pallas kernels for ``shape
     [M, K]`` rows on the TPU, or ``None`` where the XLA forms serve: off

@@ -51,6 +51,7 @@ KernelCase = namedtuple("KernelCase",
 KERNEL_MODULES = (
     "attention_kernel",
     "eva_attention_kernel",
+    "ssd_scan_kernel",
     "decode_attention_kernel",
     "ragged_attention_kernel",
     "layernorm_kernel",

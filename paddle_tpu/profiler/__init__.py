@@ -272,7 +272,8 @@ class StepTrace:
     (``cache``, ``backend_s``, ``temp_bytes``); ``account`` is that
     call's record of the compile log, the newest, with the step's
     ``flash_calls``, ``flash_operands_in_place`` and
-    ``flash_operands_copied`` (``ops.pallas.record_flash_layout``).  Every
+    ``flash_operands_copied`` (``ops.pallas.record_flash_layout``) and its
+    ``ssd_calls`` / ``ssd_calls_composed`` (``ops.pallas.ssd_scan``).  Every
     span carries ``step``.  ``::init`` covers the trainer's construction."""
 
     STEP = "train_step"
@@ -311,12 +312,13 @@ class StepTrace:
         call: its outputs drop the mesh axes of size 1 from their specs),
         which builds nothing.  Only a call that compiled takes the
         account; any other does what it always did, but for reading the
-        three sums of the flash layout record beforehand (what was traced
-        DURING the call is the step's own)."""
-        from ..ops.pallas import flash_layout_sums
+        dispatchers' sums (the flash layout record's three, the scan's
+        two) beforehand (what was traced DURING the call is the step's
+        own)."""
+        from ..ops.pallas import traced_call_sums
 
         known, built = compiled._cache_size(), _ExecutablesBuilt.count
-        flash = flash_layout_sums()
+        traced = traced_call_sums()
         with RecordEvent(self.DISPATCH, step=step):
             out = compiled(*args)
         if compiled._cache_size() > known and _ExecutablesBuilt.count > built:
@@ -325,9 +327,10 @@ class StepTrace:
                                                            step)
             # the flash calls traced while the step was built are the
             # step's own: how many of their operands cross between XLA
-            # and the kernels in place, how many as copies
-            rec.update({k: n - flash[k]
-                        for k, n in flash_layout_sums().items()})
+            # and the kernels in place, how many as copies; and its
+            # state-space scans, and those the composition served
+            rec.update({k: n - traced[k]
+                        for k, n in traced_call_sums().items()})
             with RecordEvent(self.COMPILED, step=step, cache=rec["cache"],
                              backend_s=rec["backend_s"],
                              temp_bytes=rec.get("temp_bytes")):

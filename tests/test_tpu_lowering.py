@@ -90,8 +90,9 @@ def test_registry_lowers_for_tpu_where_supported():
     # layernorm fwd+vjp x 3, flash at 192 | 128 (latent attention's
     # expanded form) fwd+vjp on the head_dim-128 engine, flash under a
     # window over one kv head vjp x 3, the block-window-plus-summaries
-    # (eva) kernels vjp x 3, the two training layernorm shapes
-    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 2 * 3 + 2
+    # (eva) kernels vjp x 3, the two training layernorm shapes, the
+    # state-space scan's kernels vjp x 3
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 2 * 3 + 2 + 3
 
 
 def test_refusals_are_declared_only_where_needed():
