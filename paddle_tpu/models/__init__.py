@@ -30,6 +30,12 @@ from .nemotron_h import (  # noqa: F401
     nemotron_3_super_120b_a12b,
     nemotron_h_tiny,
 )
+from .ouro import (  # noqa: F401
+    OuroConfig,
+    OuroForCausalLM,
+    ouro_2_6b,
+    ouro_tiny,
+)
 from .wide_deep import WideDeep  # noqa: F401
 from .deepfm import DeepFM  # noqa: F401
 from .deepspeech import DeepSpeech2, deepspeech2_tiny  # noqa: F401

@@ -38,24 +38,41 @@ model that sets none computes what it computed without them):
   predicting token ``t + 1 + p``; the loss is the mean over the heads of
   each head's mean cross entropy over the positions that have its target.
 
+Two more, off by default too (the looped family, ``models/ouro.py``):
+
+- ``post_branch_norm``: a second RMSNorm on what a branch made, before the
+  add, ``x <- x + norm_b(f(norm_a(x)))`` (held as ``ln_1b`` / ``ln_2b``);
+- ``total_ut_steps`` ``R > 1``: the WHOLE stack and ``ln_f`` run ``R``
+  times over the same parameters, the normed state of one pass the input
+  of the next.  Head, cross entropy and exit gate run INSIDE each pass
+  (one pass's logits live at a time), and ``forward(ids, labels)`` returns,
+  in float32, each pass's per-token cross entropy, the exit distribution
+  and its entropy (:meth:`MoeDecoderForCausalLM.looped`); ``loss`` is
+  their expectation less ``exit_entropy_beta`` times the entropy.
+
 A decoder layer hands its expert counters on as OUTPUTS, so that
 ``jit.TrainStep(remat=...)`` can rematerialise each layer in the backward
 pass.  Scopes: ``embeddings`` / ``layers.i`` / ``ln_1`` / ``attn`` /
 ``ln_2`` / ``mlp`` | ``moe`` / ``ln_f`` / ``lm_head``; a block of one
-branch ``layers.i`` / ``ln_1`` / ``mamba`` | ``attn`` | ``moe``
-(``docs/PROFILER.md``).
+branch ``layers.i`` / ``ln_1`` / ``mamba`` | ``attn`` | ``moe``; the
+post-branch norms ``ln_1b`` / ``ln_2b``; in a looped model ``lm_head``,
+``loss`` and ``exit_gate`` inside a pass and ``exit_gate`` round the exit
+distribution (``docs/PROFILER.md``).
 """
 
 import math
 
+import jax
 import jax.numpy as jnp
 
 from .. import nn
 from ..core.tensor import Tensor
+from ..framework import mode
 from ..incubate.distributed.models.moe import DroplessMoELayer, SwiGLUMLP
 from ..nn import functional as F
 from ..nn.initializer import Constant, Normal
-from ..nn.layer_base import ParamAttr
+from ..nn.layer_base import ParamAttr, run_as_block
+from ..ops.registry import op
 
 
 def linear(d_in, d_out, std):
@@ -86,11 +103,14 @@ class MoeDecoderConfig:
     ``num_hidden_layers``, ``rms_norm_eps``, ``initializer_range``,
     ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, the
     factories (two for a block of two branches, ``make_mixer`` for a block
-    of one), and the three options of the module's docstring."""
+    of one), and the five options of the module's docstring."""
 
     fp32_skip_add = False
     norm_add_unit_offset = False
     num_pred_heads = 1
+    post_branch_norm = False
+    total_ut_steps = 1          # passes of the stack over the same weights
+    exit_entropy_beta = 0.0     # read where ``total_ut_steps`` > 1
     branches_per_layer = 2      # residual adds a block makes (``out_std``)
 
     def make_norm(self):
@@ -100,7 +120,12 @@ class MoeDecoderConfig:
     @property
     def out_std(self):
         """The residual projections' (``o_proj``, ``down``): the range over
-        the root of the residual adds the stack makes."""
+        the root of the residual adds the layers HELD make, ``branches_per_
+        layer * num_hidden_layers``.  A stack that runs ``total_ut_steps``
+        times makes ``R`` times as many adds and the count does NOT grow
+        by ``R``: a looped model norms a branch before the add
+        (``post_branch_norm``), so the projection's scale does not reach
+        the residual stream, and ``ln_f`` norms the state between passes."""
         return self.initializer_range / math.sqrt(
             self.branches_per_layer * self.num_hidden_layers)
 
@@ -142,38 +167,50 @@ class MoeDecoderLayer(nn.Layer):
         super().__init__()
         c = config
         self.ln_1 = c.make_norm()
+        self.ln_1b = c.make_norm() if c.post_branch_norm else None
         self._fp32_skip_add = c.fp32_skip_add
         self._mixer, module = c.make_mixer(layer_idx) or (None, None)
         if module is not None:
-            self.ln_2 = self.attn = self.mlp = self.moe = None
+            self.ln_2 = self.ln_2b = None
+            self.attn = self.mlp = self.moe = None
             setattr(self, self._mixer, module)
             return
         self.attn = c.make_attention(layer_idx)
         self.ln_2 = c.make_norm()
+        self.ln_2b = c.make_norm() if c.post_branch_norm else None
         ffn = c.make_ffn(layer_idx)
         is_moe = isinstance(ffn, DroplessMoELayer)
         self.mlp = None if is_moe else ffn
         self.moe = ffn if is_moe else None
 
-    def _branch(self, x, norm, f):
-        """``x + f(norm(x))``; under ``fp32_skip_add`` the sum in float32
-        and ``f`` on the parameters' dtype."""
-        if not self._fp32_skip_add:
-            return x + f(norm(x))
-        return x + f(norm(x).astype(norm.weight.dtype)).astype("float32")
+    def _branch(self, x, norm, f, post):
+        """``x + f(norm(x))``, or ``x + post(f(norm(x)))`` with a norm
+        after the branch; under ``fp32_skip_add`` the sum in float32 and
+        ``f`` on the parameters' dtype."""
+        a = norm(x)
+        if self._fp32_skip_add:
+            a = a.astype(norm.weight.dtype)
+        made = f(a)
+        if post is not None:
+            made = post(made)
+        if self._fp32_skip_add:
+            made = made.astype("float32")
+        return x + made
 
     def forward(self, x):
         if self._mixer is not None:
-            x = self._branch(x, self.ln_1, getattr(self, self._mixer))
+            x = self._branch(x, self.ln_1, getattr(self, self._mixer),
+                             self.ln_1b)
             if self.moe is None:
                 none = Tensor(jnp.zeros((0,), jnp.int32))
                 return x, none, none
             return x, self.moe.tokens_per_expert, self.moe.rows_buffered
-        x = self._branch(x, self.ln_1, self.attn)
+        x = self._branch(x, self.ln_1, self.attn, self.ln_1b)
         if self.moe is None:
             none = Tensor(jnp.zeros((0,), jnp.int32))
-            return self._branch(x, self.ln_2, self.mlp), none, none
-        x = self._branch(x, self.ln_2, self.moe)
+            return self._branch(x, self.ln_2, self.mlp, self.ln_2b), \
+                none, none
+        x = self._branch(x, self.ln_2, self.moe, self.ln_2b)
         return x, self.moe.tokens_per_expert, self.moe.rows_buffered
 
 
@@ -192,9 +229,16 @@ class MoeDecoderModel(nn.Layer):
         self.tokens_per_expert = self.rows_buffered = None
 
     def forward(self, input_ids):
+        return self.stack(self.embed(input_ids))
+
+    def embed(self, input_ids):
         x = self.embeddings(input_ids)
         if self.config.fp32_skip_add:
             x = x.astype("float32")
+        return x
+
+    def stack(self, x):
+        """The layers and ``ln_f`` once, on the residual stream ``x``."""
         counters = []
         for layer in self.layers:
             x, *made = layer(x)
@@ -209,11 +253,34 @@ class MoeDecoderModel(nn.Layer):
         return x
 
 
+@op("looped_exit_gate")
+def _exit_gate(h, weight, bias):
+    """``sigmoid(w . h + b)`` ``[B, T]``: the product, the sum over the
+    hidden width and the sigmoid in float32 whatever the operands' dtype
+    (a one-column matmul would round its operands on the MXU)."""
+    f32 = jnp.float32
+    return jax.nn.sigmoid(
+        jnp.sum(h.astype(f32) * weight.astype(f32)[:, 0], axis=-1)
+        + bias.astype(f32)[0])
+
+
+@op("looped_exit_distribution")
+def _exit_distribution(lam):
+    """``lam [R, B, T]``, pass ``r``'s probability of leaving GIVEN that
+    the token is still there (the last pass's is not read) -> ``(p [R, B,
+    T], entropy [B, T])``: ``p_r = lam_r prod_{j<r} (1 - lam_j)``, ``p_R``
+    what is left; ``H = - sum_r p_r log max(p_r, 1e-30)``."""
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)      # still there after r
+    p = jnp.concatenate([lam[:1], lam[1:-1] * stay[:-1], stay[-1:]], axis=0)
+    return p, -jnp.sum(p * jnp.log(jnp.maximum(p, 1e-30)), axis=0)
+
+
 class MoeDecoderForCausalLM(nn.Layer):
     """``forward`` returns logits over the vocabulary slice held, ``loss``
     is the shifted-label cross entropy, as ``GPTForCausalLM``'s.  With
     ``num_pred_heads`` ``P > 1``: logits ``[B, T, P, V]`` in float32 and
-    :meth:`multi_head_loss`."""
+    :meth:`multi_head_loss`.  With ``total_ut_steps`` ``R > 1``:
+    :meth:`looped` and :meth:`looped_loss`."""
 
     def __init__(self, config):
         super().__init__()
@@ -222,8 +289,21 @@ class MoeDecoderForCausalLM(nn.Layer):
         self.lm_head = linear(config.hidden_size,
                               config.num_pred_heads * config.vocab_size,
                               config.initializer_range)
+        self.pass_loss = self.exit_mass = None
+        if config.total_ut_steps > 1:
+            if config.fp32_skip_add or config.num_pred_heads > 1 or any(
+                    layer.moe is not None for layer in self.model.layers):
+                raise NotImplementedError(
+                    "a looped stack with a float32 residual, several "
+                    "heads or experts (their counters a pass) is not built")
+            self.exit_gate = nn.Linear(
+                config.hidden_size, 1, weight_attr=ParamAttr(
+                    initializer=Normal(0.0, config.initializer_range)))
 
-    def forward(self, input_ids):
+    def forward(self, input_ids, labels=None):
+        if self.config.total_ut_steps > 1:
+            return self.looped(input_ids,
+                               input_ids if labels is None else labels)
         logits = self.lm_head(self.model(input_ids))
         heads = self.config.num_pred_heads
         if heads == 1:
@@ -246,7 +326,70 @@ class MoeDecoderForCausalLM(nn.Layer):
         return (each.reshape([b * t, heads])
                 * Tensor(weight.astype(jnp.float32))).sum()
 
+    def looped(self, input_ids, labels):
+        """The stack ``R`` = ``total_ut_steps`` times over the same
+        parameters; ``labels [B, T]`` are the ids (position ``t`` is held to
+        ``labels[t + 1]``; ``-100`` there leaves it out).  Returns float32
+        ``(l [R, B, T], p [R, B, T], entropy [B, T], weight [B, T])``: pass
+        ``r``'s cross entropy a position (0 where it has no target), the
+        exit distribution over the passes and its entropy, and ``1 /
+        (positions with a target)`` there, 0 elsewhere.
+
+        The passes are ONE ``lax.scan`` of length ``R`` whose body is the
+        stack, ``ln_f``, then exit gate, head and cross entropy: the body is
+        traced and compiled once, the weights are closed over and the
+        scan's transpose sums a weight's ``R`` gradients, in the weight's
+        dtype.  Under ``jit.TrainStep(remat=...)`` every block is
+        rematerialised as in any model, and so are head and loss of a pass
+        (``run_as_block``): a pass saves its blocks' inputs and its normed
+        state, its logits live only while it runs.  The body keeps no
+        tape: the gradient is the enclosing trace's (``jit.TrainStep``,
+        ``jax.grad`` over ``functional_call``)."""
+        model, head, gate = self.model, self.lm_head, self.exit_gate
+        ids = labels._data if isinstance(labels, Tensor) else labels
+        target = jnp.concatenate(
+            [ids[:, 1:], jnp.full_like(ids[:, :1], -100)], axis=1)
+
+        def exit_head_loss(h, target):
+            """One pass's ``(l [B, T], lam [B, T])`` of its normed state."""
+            with jax.named_scope("exit_gate"):
+                lam = _exit_gate(h, gate.weight, gate.bias)
+            logits = head(h)
+            with jax.named_scope("loss"):
+                each = F.cross_entropy(
+                    logits.reshape([-1, logits.shape[-1]]),
+                    target.reshape([-1]), reduction="none")
+            return each.reshape(target.shape), lam
+
+        def one_pass(h, _):
+            with mode.grad_enabled(False):
+                h = model.stack(Tensor(h))
+                each, lam = run_as_block(exit_head_loss, h, Tensor(target))
+            return h._data, (each._data, lam._data)
+
+        _, (each, lam) = jax.lax.scan(
+            one_pass, model.embed(input_ids)._data, None,
+            length=self.config.total_ut_steps)
+        with jax.named_scope("exit_gate"):
+            p, entropy = _exit_distribution(Tensor(lam))
+            valid = (target != -100).astype(jnp.float32)
+            weight = valid / jnp.maximum(jnp.sum(valid), 1.0)
+            self.pass_loss = jnp.sum(each * weight, axis=(1, 2))
+            self.exit_mass = jnp.sum(p._data * weight, axis=(1, 2))
+        return Tensor(each), p, entropy, Tensor(weight)
+
+    def looped_loss(self, out):
+        """The expected cross entropy under the exit distribution less
+        ``exit_entropy_beta`` times its entropy, the mean over the positions
+        that have a target."""
+        each, p, entropy, weight = out
+        expected = (each * p).sum(axis=0)
+        return ((expected - entropy * self.config.exit_entropy_beta)
+                * weight).sum()
+
     def loss(self, logits, labels):
+        if self.config.total_ut_steps > 1:
+            return self.looped_loss(logits)
         if self.config.num_pred_heads > 1:
             return self.multi_head_loss(logits, labels)
         shift_logits = logits[:, :-1, :]
@@ -263,7 +406,14 @@ class MoeDecoderForCausalLM(nn.Layer):
         assignments served here; none is ever dropped.
         ``moe_rows_buffered`` int32 ``[expert layers]``, the rows of the
         bucket each layer's buffers took (``dropless.row_buckets``): at
-        least that sum; the worst case's rows mean the fallback ran."""
+        least that sum; the worst case's rows mean the fallback ran.
+        A looped model (``total_ut_steps`` ``R > 1``): ``ouro_pass_loss``
+        float32 ``[R]``, each pass's mean cross entropy, and
+        ``ouro_exit_mass`` float32 ``[R]``, the mean over the positions
+        with a target of the exit distribution (sums to 1)."""
+        if self.pass_loss is not None:
+            return {"ouro_pass_loss": self.pass_loss,
+                    "ouro_exit_mass": self.exit_mass}
         counts = self.model.tokens_per_expert
         return {} if counts is None else {
             "moe_tokens_per_expert": counts,

@@ -74,6 +74,19 @@ def block_remat(policy):
         _block_remat = before
 
 
+def run_as_block(function, *inputs, **kwargs):
+    """``function(*inputs)`` as a block of a ``LayerList`` runs: under
+    ``fleet.recompute`` with the policy that is open, plainly where none
+    is (what a model's forward hands a part that is no Layer of a list,
+    e.g. the head and loss of one pass of a looped stack)."""
+    if _block_remat is None:
+        return function(*inputs, **kwargs)
+    from ..distributed.fleet.recompute import recompute
+    policy = _block_remat
+    with block_remat(None):
+        return recompute(function, *inputs, policy=policy, **kwargs)
+
+
 class Layer:
     # held by a container (``add_sublayer`` of a LayerList / LayerDict
     # says so): one of a model's repeated blocks
@@ -386,12 +399,8 @@ class Layer:
             result = hook(self, inputs)
             if result is not None:
                 inputs = result if isinstance(result, tuple) else (result,)
-        if self._block and _block_remat is not None:
-            from ..distributed.fleet.recompute import recompute
-            policy = _block_remat
-            with block_remat(None):
-                outputs = recompute(self._scoped_forward, *inputs,
-                                    policy=policy, **kwargs)
+        if self._block:
+            outputs = run_as_block(self._scoped_forward, *inputs, **kwargs)
         else:
             outputs = self._scoped_forward(*inputs, **kwargs)
         for hook in self._forward_post_hooks.values():
