@@ -501,8 +501,8 @@ def spmd_train_phase(report, build_model, *, batch, seq, steps, one_chip):
     wide = {3 * model.config.hidden_size, 3 * model.config.hidden_size // 2}
     found = compiled_collectives(
         trainer.lower(ids, ids).compile().as_text())
-    kinds = collections.Counter(kind for kind, _ in found)
-    gathered = [shapes for kind, shapes in found if kind == "all-gather"
+    kinds = collections.Counter(kind for kind, _, _ in found)
+    gathered = [shapes for kind, shapes, _ in found if kind == "all-gather"
                 and any(wide & set(shape) for shape in shapes)]
     report.check(phase, "the compiled step all-gathers no 3h-wide q|k|v",
                  not gathered,

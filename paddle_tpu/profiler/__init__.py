@@ -204,8 +204,10 @@ class _ExecutablesBuilt:
         now), the compiler's memory account of that executable on ONE
         chip, ``argument`` / ``output`` / ``alias`` / ``temp`` /
         ``generated_code`` ``_bytes`` and ``reserved_bytes`` = argument +
-        output - alias + temp + code, and ``account_s``: what taking this
-        account cost.
+        output - alias + temp + code, ``collectives_async`` /
+        ``collectives_sync`` (``parallel.compiled_collectives`` over the
+        executable's text), and ``account_s``: what taking this account
+        cost.
 
         The executable is reached, never built again: lowering the jitted
         step for the abstract operands of that very call (donated buffers
@@ -223,9 +225,9 @@ class _ExecutablesBuilt:
                 a.shape, a.dtype, weak_type=a.weak_type,
                 sharding=a.sharding if a.committed else None)
 
-        memory = compiled.lower(
-            *jax.tree_util.tree_map(described, args)).compile(
-            ).memory_analysis()
+        executable = compiled.lower(
+            *jax.tree_util.tree_map(described, args)).compile()
+        memory = executable.memory_analysis()
         cls._open.reset()       # the lowering's own (cached) trace event
         if cls.count != built:
             warnings.warn(
@@ -240,6 +242,17 @@ class _ExecutablesBuilt:
             record["reserved_bytes"] = (
                 sizes["argument"] + sizes["output"] - sizes["alias"]
                 + sizes["temp"] + sizes["generated_code"])
+        # the collectives IN THE COMPILED TEXT (a scan's body stands once):
+        # how many the compiler made asynchronous, how many run in line.
+        # A program over one device holds none: its text is not read
+        from ..parallel.trainer import compiled_collectives
+
+        meshed = any(isinstance(a, jax.Array)
+                     and len(a.sharding.device_set) > 1
+                     for a in jax.tree_util.tree_leaves(args))
+        found = compiled_collectives(executable.as_text()) if meshed else []
+        record["collectives_async"] = sum(1 for c in found if c[2])
+        record["collectives_sync"] = len(found) - record["collectives_async"]
         record["account_s"] = time.perf_counter() - began
         return record
 
@@ -272,8 +285,9 @@ class StepTrace:
     (``cache``, ``backend_s``, ``temp_bytes``); ``account`` is that
     call's record of the compile log, the newest, with the step's
     ``flash_calls``, ``flash_operands_in_place`` and
-    ``flash_operands_copied`` (``ops.pallas.record_flash_layout``) and its
-    ``ssd_calls`` / ``ssd_calls_composed`` (``ops.pallas.ssd_scan``).  Every
+    ``flash_operands_copied`` (``ops.pallas.record_flash_layout``), its
+    ``ssd_calls`` / ``ssd_calls_composed`` (``ops.pallas.ssd_scan``) and the
+    compiled text's ``collectives_async`` / ``collectives_sync``.  Every
     span carries ``step``.  ``::init`` covers the trainer's construction."""
 
     STEP = "train_step"

@@ -560,3 +560,69 @@ def test_registry_compiles_for_v5e():
         except Exception as e:      # noqa: BLE001 — name the case
             pytest.fail(f"{cid}: Mosaic refuses: "
                         f"{str(e).splitlines()[0]}")
+
+
+@pytest.mark.slow
+def test_the_trainers_options_make_all_reduces_asynchronous_for_v5e():
+    """``parallel.trainer.ASYNC_ALL_REDUCE`` against the compiler it is
+    written for, ahead of time: a scan of two small rematerialised blocks
+    (column- then row-parallel matmuls over ``mp``, the batch over ``dp``)
+    with its update, on a ``("dp", "mp")`` 2 x 2 mesh of described v5e
+    chips.  Without the options every all-reduce runs in line; with them
+    the backward body holds an async collective fusion.  A libtpu that
+    renames an option fails HERE ("No such compile option") and not in a
+    cell; where no topology can be described, skip."""
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.parallel.trainer import (ASYNC_ALL_REDUCE,
+                                             compile_options,
+                                             compiled_collectives)
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # noqa: BLE001 — no usable libtpu
+        pytest.skip(f"no compile-only TPU topology: {e}")
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
+    assert compile_options(mesh) == ASYNC_ALL_REDUCE
+
+    def sds(shape, *spec):
+        return jax.ShapeDtypeStruct(
+            shape, jnp.bfloat16, sharding=NamedSharding(mesh, P(*spec)))
+
+    layers, hidden, inner = 2, 1024, 4096
+    params = {"w1": sds((layers, hidden, inner), None, None, "mp"),
+              "w2": sds((layers, inner, hidden), None, "mp", None)}
+    x = sds((4, 512, hidden), "dp", None, None)
+
+    @jax.checkpoint
+    def block(p, h):
+        y = jax.nn.gelu(jnp.einsum("bth,hi->bti", h, p["w1"]))
+        return h + jnp.einsum("bti,ih->bth", y, p["w2"])
+
+    def loss(params, x):
+        h, _ = jax.lax.scan(lambda h, p: (block(p, h), None), x, params)
+        return jnp.mean(h.astype(jnp.float32) ** 2)
+
+    def step(params, x):
+        value, grads = jax.value_and_grad(loss)(params, x)
+        return value, jax.tree_util.tree_map(
+            lambda p, g: p - 1e-3 * g, params, grads)
+
+    def compiled_text(options):
+        return jax.jit(step, donate_argnums=0, compiler_options=options
+                       ).lower(params, x).compile().as_text()
+
+    plain = compiled_collectives(compiled_text(None))
+    assert plain and not any(asynchronous for _, _, asynchronous in plain)
+    text = compiled_text(compile_options(mesh))
+    found = compiled_collectives(text)
+    assert len(found) == len(plain)     # the same collectives, each once
+    hidden_ones = [shapes for _, shapes, asynchronous in found
+                   if asynchronous]
+    # the mp reduction of a block's input gradient rides the weight
+    # gradient's matmul, inside the scan's backward body (not in ENTRY)
+    assert [(2, 512, hidden)] in hidden_ones
+    assert "async-collective-start" in text.split("\nENTRY ")[0]

@@ -331,6 +331,52 @@ def test_the_first_call_takes_the_account_and_builds_nothing_for_it(
     assert step.stats() == {"steps": 1, "compiles": 1}
 
 
+@pytest.mark.parametrize("which", ["train", "spmd"])
+def test_the_account_counts_the_compiled_collectives(which, monkeypatch):
+    """``collectives_async`` / ``collectives_sync``: what ``parallel
+    .compiled_collectives`` finds IN THE COMPILED TEXT of the step that
+    was built.  The CPU's compiler makes none asynchronous; a step over one
+    device holds none, and its text is not even read."""
+    from paddle_tpu.parallel import compiled_collectives, trainer
+
+    read = []
+    monkeypatch.setattr(
+        trainer, "compiled_collectives",
+        lambda text: read.append(text) or compiled_collectives(text))
+    step, ids, _ = _step_and_batches(which)
+    step(ids, ids)
+    acc = step.compile_account()
+    assert acc["collectives_async"] == 0
+    if which == "train":
+        assert acc["collectives_sync"] == 0 and not read
+    else:
+        found = compiled_collectives(
+            step.lower(ids, ids).compile().as_text())
+        assert len(read) == 1 and found == compiled_collectives(read[0])
+        assert acc["collectives_sync"] == len(found) > 0
+
+
+def test_the_account_of_a_step_with_compile_options_builds_nothing(
+        monkeypatch):
+    """The options ``SpmdTrainStep`` gives ``jax.jit`` are part of the
+    jitted function: the account's lowering of it is still answered from
+    jax's caches (an option the CPU's compiler knows stands in for the
+    TPU's, which it refuses)."""
+    from paddle_tpu.parallel import trainer
+
+    monkeypatch.setattr(trainer, "compile_options",
+                        lambda mesh: {"xla_embed_ir_in_executable": True})
+    step, ids, _ = _step_and_batches("spmd")
+    built = profiler._ExecutablesBuilt.count
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # a second build would warn
+        step(ids, ids)
+    assert profiler._ExecutablesBuilt.count == built + 1
+    acc = step.compile_account()
+    assert acc["collectives_sync"] > 0 and 0 < acc["account_s"] < 0.25
+    assert step.stats() == {"steps": 1, "compiles": 1}
+
+
 def test_the_account_is_one_chips_share_under_a_mesh():
     """The compiler's account of a partitioned step is a chip's: the
     donated state of the dp2 x mp2 trainer is under the whole state."""
