@@ -50,6 +50,8 @@ import jax.numpy as jnp
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import functional as F
+from ..ops import pallas
+from ..ops.pallas import mla_rope as apply_rope     # noqa: F401 (the tests')
 from ..ops.registry import op
 from .llama import _rope_tables as rope_tables      # cos, sin [T, D/2]
 from .moe_decoder import (MoeDecoderConfig, MoeDecoderForCausalLM,
@@ -115,35 +117,17 @@ class MlaMoeConfig(MoeDecoderConfig):
             self.routed_scaling_factor)
 
 
-def apply_rope(x, cos, sin, interleave):
-    """``x [..., T, N, D]`` rotated by ``cos``/``sin [T, D/2]``.
-    ``interleave``: the published layout keeps a pair in neighbouring
-    lanes; it is de-interleaved into halves first, and the result stays in
-    halves (q and k are permuted alike, so the scores are those of the
-    pairwise rotation)."""
-    dt = x.dtype
-    x = x.astype(jnp.float32)
-    if interleave:
-        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    c, s = cos[:, None, :], sin[:, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
-                           axis=-1).astype(dt)
-
-
 @op("mla_expand_qkv")
 def _expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
     """``q [B, T, N, nope + rope]``, ``kv_b [B, T, N, nope + v]``, ``k_rope
     [B, T, rope]`` -> q, k ``[B, T, N, nope + rope]`` and v ``[B, T, N,
-    v]`` with the rotary parts rotated and ``k_rope`` given to every head."""
-    q_rot = apply_rope(q[..., nope:], cos, sin, interleave)
-    k_rot = apply_rope(k_rope[:, :, None, :], cos, sin, interleave)
-    q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
-    k = jnp.concatenate(
-        [kv_b[..., :nope],
-         jnp.broadcast_to(k_rot, q.shape[:3] + k_rot.shape[3:])], axis=-1)
-    return q, k, kv_b[..., nope:]
+    v]`` with the rotary parts rotated (:func:`apply_rope`) and ``k_rope``
+    given to every head: ``ops.pallas.mla_expand_qkv``, on the TPU ONE
+    kernel forward and one backward that write the flash calls' layout,
+    elsewhere the XLA composition."""
+    with jax.named_scope("mla_expand"):
+        return pallas.mla_expand_qkv(q, kv_b, k_rope, cos, sin, nope=nope,
+                                     interleave=interleave)
 
 
 class MLAttention(nn.Layer):

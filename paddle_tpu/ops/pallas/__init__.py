@@ -375,12 +375,27 @@ def ssd_scan_log():
     return list(_ssd_scans)
 
 
+# Latent attention's expansions traced so far, the same way.
+_mla_expands = collections.deque(maxlen=256)
+_mla_expand_sums = {"mla_expand_calls": 0, "mla_expand_calls_composed": 0}
+
+
+def mla_expand_log():
+    """The records of the newest traced :func:`mla_expand_qkv` calls (at
+    most 256), oldest first: ``shapes`` (q, kv_b), ``path`` (``kernel`` or
+    ``composition``) and ``reason`` (why the composition; None for the
+    kernels)."""
+    return list(_mla_expands)
+
+
 def traced_call_sums():
     """What a compiled step's account takes its own share of
-    (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`, and
+    (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
     ``ssd_calls`` / ``ssd_calls_composed``, the :func:`ssd_scan` calls
-    traced in this process and those of them the composition served."""
-    return {**_flash_layout_sums, **_ssd_scan_sums}
+    traced in this process and those of them the composition served; and
+    ``mla_expand_calls`` / ``mla_expand_calls_composed``, the same of
+    :func:`mla_expand_qkv`."""
+    return {**_flash_layout_sums, **_ssd_scan_sums, **_mla_expand_sums}
 
 
 def _ssd_refusal(x, B, C, chunk):
@@ -424,6 +439,81 @@ def ssd_scan(x, dt, A, B, C, D, chunk):
                       reason)
     from ...nn.functional import _ssd_scan_rows
     return _ssd_scan_rows(x, dt, A, B, C, D, chunk)
+
+
+def mla_rope(x, cos, sin, interleave):
+    """``x [..., T, N, D]`` rotated by ``cos``/``sin [T, D/2]``.
+    ``interleave``: the published layout keeps a pair in neighbouring
+    lanes; it is de-interleaved into halves first, and the result stays in
+    halves (q and k are permuted alike, so the scores are those of the
+    pairwise rotation)."""
+    dt = x.dtype
+    x = x.astype(jnp.float32)
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    d2 = x.shape[-1] // 2
+    x1, x2 = x[..., :d2], x[..., d2:]
+    c, s = cos[:, None, :], sin[:, None, :]
+    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                           axis=-1).astype(dt)
+
+
+def _xla_mla_expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
+    """The XLA composition of ``mla_expand_kernel``: ``q [B, T, N, nope +
+    rope]``, ``kv_b [B, T, N, nope + v]``, ``k_rope [B, T, rope]`` -> q, k
+    ``[B, T, N, nope + rope]`` and v ``[B, T, N, v]`` with the rotary parts
+    rotated (:func:`mla_rope`) and ``k_rope`` given to every head."""
+    q_rot = mla_rope(q[..., nope:], cos, sin, interleave)
+    k_rot = mla_rope(k_rope[:, :, None, :], cos, sin, interleave)
+    q = jnp.concatenate([q[..., :nope], q_rot], axis=-1)
+    k = jnp.concatenate(
+        [kv_b[..., :nope],
+         jnp.broadcast_to(k_rot, q.shape[:3] + k_rot.shape[3:])], axis=-1)
+    return q, k, kv_b[..., nope:]
+
+
+def _mla_expand_refusal(q, kv_b, k_rope, nope):
+    """Why the ``mla_expand_*`` kernels do not serve this call where
+    kernels are on, or None."""
+    from .mla_expand_kernel import supports
+
+    if kv_b.dtype != q.dtype or k_rope.dtype != q.dtype:
+        return f"kv_b {kv_b.dtype}, k_rope {k_rope.dtype} beside q {q.dtype}"
+    if not supports(q.shape[1], q.shape[2], nope, q.shape[3] - nope,
+                    kv_b.shape[3] - nope, q.dtype):
+        return "mla_expand_kernel.supports() refuses the shape"
+    if _partitioned_by_gspmd():
+        return GSPMD_REASON + "; these kernels have no sharded launch"
+    return None
+
+
+def mla_expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
+    """Latent attention's q, k, v for the flash call (``models/mla_moe.py``):
+    ``q [B, T, N, nope + rope]``, ``kv_b [B, T, N, nope + v]``, ``k_rope
+    [B, T, rope]``, ``cos`` / ``sin`` ``[T, rope / 2]``.  On the TPU the
+    ``mla_expand_fwd`` / ``mla_expand_bwd`` kernels (``mla_expand_kernel``),
+    whose results are the flash kernels' layout already; elsewhere, and
+    aloud where ``mla_expand_kernel.supports`` refuses the shapes or GSPMD
+    partitions the step, the XLA composition :func:`_xla_mla_expand_qkv`.
+    Every traced call is recorded (:func:`mla_expand_log`)."""
+    kernels_on = _use_pallas()
+    reason = _mla_expand_refusal(q, kv_b, k_rope, nope) if kernels_on else \
+        "no TPU backend (or FLAGS_use_pallas_kernels off)"
+    _mla_expands.append({
+        "shapes": (tuple(q.shape), tuple(kv_b.shape)),
+        "path": "kernel" if reason is None else "composition",
+        "reason": reason})
+    _mla_expand_sums["mla_expand_calls"] += 1
+    if reason is None:
+        from .mla_expand_kernel import mla_expand_pallas
+        return mla_expand_pallas(q, kv_b, k_rope, cos, sin, nope=nope,
+                                 interleave=interleave)
+    _mla_expand_sums["mla_expand_calls_composed"] += 1
+    if kernels_on:
+        warn_fallback("mla_expand",
+                      f"q{tuple(q.shape)} kv_b{tuple(kv_b.shape)}", reason)
+    return _xla_mla_expand_qkv(q, kv_b, k_rope, cos, sin, nope=nope,
+                               interleave=interleave)
 
 
 def _grouped_row_tile(shape):

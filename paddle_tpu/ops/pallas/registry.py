@@ -52,6 +52,7 @@ KERNEL_MODULES = (
     "attention_kernel",
     "eva_attention_kernel",
     "ssd_scan_kernel",
+    "mla_expand_kernel",
     "decode_attention_kernel",
     "ragged_attention_kernel",
     "layernorm_kernel",

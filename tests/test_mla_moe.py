@@ -76,8 +76,27 @@ def test_logits_and_counters_match_the_reference(share):
     assert counts.shape == (2, m["n_routed_experts"])
 
 
+def _force_expand_kernels(monkeypatch):
+    """``MLAttention``'s expansion through the ``mla_expand_*`` kernels, in
+    interpret mode, where the CPU's dispatcher takes the composition."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas.mla_expand_kernel import mla_expand_pallas
+
+    monkeypatch.setattr(pk, "mla_expand_qkv", lambda *a, **kw:
+                        mla_expand_pallas(*a, **kw, interpret=True))
+
+
+@pytest.fixture(params=["composition", "kernels"])
+def expand(request, monkeypatch):
+    """The expansion by the path the CPU takes (the XLA composition) or
+    with the kernels forced."""
+    if request.param == "kernels":
+        _force_expand_kernels(monkeypatch)
+    return request.param
+
+
 @pytest.mark.parametrize("share", list(SHARES))
-def test_loss_and_every_leafs_gradient_match_the_reference(share):
+def test_loss_and_every_leafs_gradient_match_the_reference(share, expand):
     m, model, tree = _seeded(share)
     ids = _ids(1)
     state = {n: t._data for n, t in model.state_dict().items()}
@@ -124,6 +143,32 @@ def test_remat_by_block_changes_no_value_and_recomputes_each_layer():
     np.testing.assert_allclose(losses[0], losses[2], rtol=1e-5)
     assert losses[0][2] < losses[0][0]
     assert recomputed[0] == 0 and recomputed[1] > 0 and recomputed[2] > 0
+
+
+def test_the_expand_kernels_change_no_value_of_the_rematerialised_step(
+        monkeypatch):
+    """Three steps of ``TrainStep(remat=[...])`` with the ``mla_expand_*``
+    kernels (interpret mode; their backward needs no saved input, so the
+    rematerialised forward and the backward each call one) against the
+    composition's: the float32 losses agree, and a kernel call is in the
+    recomputed layers."""
+    ids = paddle.to_tensor(_ids(2))
+    losses, calls = [], []
+    for kernels in (False, True):
+        if kernels:
+            _force_expand_kernels(monkeypatch)
+        _, model, _ = _seeded("share-4-of-16-from-4")
+        opt = optimizer.AdamW(learning_rate=1e-3,
+                              parameters=model.parameters())
+        step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                         remat=["flash_attention_out"])
+        calls.append(str(step.lower(ids, ids).as_text(debug_info=True))
+                     .count("rematted_computation/layers.0/attn/mla_expand/"
+                            "mla_expand_fwd"))
+        losses.append([float(step(ids, ids)._data) for _ in range(3)])
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-6)
+    assert losses[1][2] < losses[1][0]
+    assert calls[0] == 0 and calls[1] > 0
 
 
 def test_train_step_hands_back_the_counters_beside_the_loss():

@@ -825,3 +825,102 @@ class TestRaggedAttentionQuant:
                                                ctx, rows)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
+
+
+# ------------------------------------------- latent attention's expansion --
+
+# heads, nope, rope, v: the kanana cell's head geometry and mla_moe_tiny()'s
+MLA_GEOMETRY = {"cell": (32, 128, 64, 128), "tiny": (4, 32, 16, 32)}
+
+
+def _mla_expand_case(geometry, dtype, seed=0, batch=2, seq=64):
+    from paddle_tpu.models.llama import _rope_tables
+
+    heads, nope, rope, v_dim = MLA_GEOMETRY[geometry]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shapes = [(batch, seq, heads, nope + rope), (batch, seq, heads,
+                                                 nope + v_dim),
+              (batch, seq, rope)]
+    operands = [jax.random.normal(k, s, jnp.float32).astype(dtype)
+                for k, s in zip(keys, shapes)]
+    cos, sin = (jnp.asarray(t) for t in _rope_tables(rope, seq, 1e6))
+    weights = [jax.random.normal(k, s, jnp.float32).astype(dtype)
+               for k, s in zip(keys[3:], [shapes[0], shapes[0],
+                                          (batch, seq, heads, v_dim)])]
+    return operands, (cos, sin), weights, nope
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("interleave", [True, False],
+                         ids=["interleaved", "halves"])
+@pytest.mark.parametrize("geometry", list(MLA_GEOMETRY))
+def test_mla_expand_kernels_match_the_composition(geometry, interleave,
+                                                  dtype):
+    """``mla_expand_fwd`` / ``mla_expand_bwd`` (interpret mode, two row
+    blocks a row) against ``_xla_mla_expand_qkv``: q, k and v BIT FOR BIT;
+    what the backward only moves (the first ``nope`` lanes' and v's
+    gradients) bit for bit too, what it rotates back to ONE rounding of the
+    float32 result, ``k_rope``'s gradient (the float32 sum over the heads)
+    among them, where the composition rounds that sum first."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas.mla_expand_kernel import mla_expand_pallas
+
+    operands, tables, weights, nope = _mla_expand_case(geometry, dtype)
+
+    def composed(*o):
+        return pk._xla_mla_expand_qkv(*o, *tables, nope=nope,
+                                      interleave=interleave)
+
+    def kernels(*o):
+        return mla_expand_pallas(*o, *tables, nope=nope,
+                                 interleave=interleave, interpret=True,
+                                 block_rows=32)
+
+    def weighed(f):
+        return lambda *o: sum(
+            jnp.sum(x.astype(jnp.float32) * w.astype(jnp.float32))
+            for x, w in zip(f(*o), weights))
+
+    want, got = jax.jit(composed)(*operands), jax.jit(kernels)(*operands)
+    for name, w, g in zip("qkv", want, got):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32), name)
+    grads = jax.jit(jax.grad(weighed(kernels), (0, 1, 2)))(*operands)
+    same = jax.jit(jax.grad(weighed(composed), (0, 1, 2)))(*operands)
+    exact = jax.jit(jax.grad(weighed(composed), (0, 1, 2)))(
+        *(o.astype(jnp.float32) for o in operands))
+    np.testing.assert_array_equal(np.asarray(grads[0][..., :nope], "f4"),
+                                  np.asarray(same[0][..., :nope], "f4"))
+    np.testing.assert_array_equal(np.asarray(grads[1], "f4"),
+                                  np.asarray(same[1], "f4"))
+    # one rounding: half a unit in the last place, a whole one where the
+    # float32 sums' orders tip a tie
+    rtol = 2.0 ** -7 if dtype == jnp.bfloat16 else 2e-6
+    for name, g, e in zip(("dq", "dkv_b", "dk_rope"), grads, exact):
+        assert g.dtype == dtype, name
+        e = np.asarray(e, np.float32)
+        np.testing.assert_allclose(np.asarray(g, np.float32), e, rtol=rtol,
+                                   atol=1e-5 * float(np.abs(e).max()),
+                                   err_msg=name)
+
+
+def test_mla_expand_supports_gating():
+    from paddle_tpu.ops.pallas.mla_expand_kernel import (_heads_per_step,
+                                                         _pick_rows,
+                                                         supports)
+
+    bf = jnp.bfloat16
+    assert supports(8192, 32, 128, 64, 128, bf)           # the cell's
+    assert _heads_per_step(32, 128, 64, 128) == 2
+    assert _pick_rows(8192, 32, 128, 64, 128, bf) == 1024
+    assert _pick_rows(8192, 32, 128, 64, 128, jnp.float32) == 512
+    assert supports(4096, 16, 128, 128, 128, jnp.float32)  # a head a tile
+    assert not supports(8192, 32, 96, 64, 128, bf)        # k cut off a tile
+    assert not supports(8192, 32, 128, 64, 64, bf)        # v half a tile
+    assert not supports(8192, 32, 128, 63, 128, bf)       # an odd pair
+    assert not supports(8192, 3, 128, 64, 128, bf)        # heads unpaired
+    assert not supports(8200, 32, 128, 64, 128, bf)       # rows
+    assert not supports(8192, 32, 128, 64, 128, jnp.float16)
+    assert not supports(64, *MLA_GEOMETRY["tiny"], bf)    # mla_moe_tiny()

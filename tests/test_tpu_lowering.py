@@ -91,8 +91,9 @@ def test_registry_lowers_for_tpu_where_supported():
     # expanded form) fwd+vjp on the head_dim-128 engine, flash under a
     # window over one kv head vjp x 3, the block-window-plus-summaries
     # (eva) kernels vjp x 3, the two training layernorm shapes, the
-    # state-space scan's kernels vjp x 3
-    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 2 * 3 + 2 + 3
+    # state-space scan's kernels vjp x 3, latent attention's expansion
+    # (value and vjp) x 3
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 2 * 3 + 2 + 3 + 3
 
 
 def test_refusals_are_declared_only_where_needed():
@@ -533,6 +534,155 @@ def test_the_benchmark_counts_one_backward_call_by_the_kernels_names(case):
             env, "flash_window") == (0, 0)
     else:
         assert flash_attention_mla.calls_in_window(env) == (0, 0)
+
+
+# ------------------------------------------- latent attention's expansion --
+
+# batch, seq, heads, nope, rope, v, dtype: the kanana cell's call, and two
+# more that ``mla_expand_kernel.supports`` takes (a head a whole tile in
+# float32; four heads a step at a rotary width of 32)
+_MLA_EXPAND_CALLS = {
+    "kanana": (2, 8192, 32, 128, 64, 128, jnp.bfloat16),
+    "rope128-f32": (1, 1024, 16, 128, 128, 128, jnp.float32),
+    "rope32": (1, 512, 8, 128, 32, 256, jnp.bfloat16),
+}
+
+
+def _mla_expand_lowered(case, interleave=True):
+    from paddle_tpu.ops.pallas.mla_expand_kernel import (mla_expand_pallas,
+                                                         supports)
+
+    b, t, n, nope, rope, v_dim, dtype = _MLA_EXPAND_CALLS[case]
+    assert supports(t, n, nope, rope, v_dim, dtype)
+    sds = jax.ShapeDtypeStruct
+    table = sds((t, rope // 2), jnp.float32)
+
+    def f(q, kv_b, k_rope, cos, sin):
+        def loss(*o):
+            return sum(jnp.sum(x.astype(jnp.float32))
+                       for x in mla_expand_pallas(
+                           *o, cos, sin, nope=nope, interleave=interleave))
+        # the map is linear: its gradients alone need no forward
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, kv_b, k_rope)
+
+    return jax.jit(f).trace(
+        sds((b, t, n, nope + rope), dtype), sds((b, t, n, nope + v_dim),
+                                                dtype),
+        sds((b, t, rope), dtype), table, table).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("case", list(_MLA_EXPAND_CALLS))
+def test_mla_expand_is_one_kernel_forward_and_one_backward(case):
+    """Forward and gradients of latent attention's expansion, lowered for
+    the TPU at the cell's shapes and wherever ``supports()`` says yes: TWO
+    ``tpu_custom_call``s, whose names the flash rooflines do not count
+    (``chipbench/kernel_costs/flash_attention_mla.py`` counts every event
+    that holds ``flash_attention``)."""
+    import re
+    import types
+
+    from chipbench.kernel_costs import flash_attention_mla
+
+    text = _mla_expand_lowered(case)
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == ["mla_expand_bwd", "mla_expand_fwd"]
+    assert text.count("tpu_custom_call") == 2
+    events = [(f"{name}.{i} bf16[2,32,8192,192]", 0.0, 1.0)
+              for i in range(3) for name in names]
+    env = types.SimpleNamespace(traced={"devices": {0: events}})
+    assert flash_attention_mla.calls_in_window(env) == (0, 0)
+
+
+def test_mla_expand_lowers_in_halves_too():
+    """``rope_interleave`` false is the same two kernels under other lane
+    maps and tables: operands, not another program."""
+    assert _mosaic_bodies(_mla_expand_lowered("kanana", interleave=False)) \
+        == _mosaic_bodies(_mla_expand_lowered("kanana"))
+
+
+@pytest.fixture
+def mla_expand_on_tpu(monkeypatch):
+    """What the dispatcher sees on the chip: kernels on, the backend's name
+    ``tpu``; the kernels themselves in interpret mode."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas import mla_expand_kernel as mk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mk, "mla_expand_pallas", functools.partial(
+        mk.mla_expand_pallas, interpret=True))
+    return pk
+
+
+def _mla_expand_operands(heads, nope, rope, v_dim, seq=32):
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    shapes = [(1, seq, heads, nope + rope), (1, seq, heads, nope + v_dim),
+              (1, seq, rope)]
+    table = jnp.ones((seq, rope // 2), jnp.float32)
+    return [jax.random.normal(k, s, jnp.float32).astype(jnp.bfloat16)
+            for k, s in zip(ks, shapes)] + [table, 0.5 * table]
+
+
+@pytest.mark.parametrize("case", ["kernel", "off_the_tpu", "width_off_tiles",
+                                  "mesh"])
+def test_mla_expand_dispatch_is_counted_and_gives_way_aloud(
+        case, request):
+    """``ops.pallas.mla_expand_qkv``: the kernels where ``supports()`` says
+    yes on a TPU; the composition, with a warning that says why, for a
+    width off the 128-lane tiles and under a mesh GSPMD partitions; the
+    composition without a word off the TPU.  Every call is in
+    ``mla_expand_log()`` and in ``traced_call_sums()``'s two counts."""
+    import warnings
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from paddle_tpu.distributed.fleet.spmd import use_mesh
+    from paddle_tpu.ops import pallas as pk
+
+    if case != "off_the_tpu":
+        request.getfixturevalue("mla_expand_on_tpu")
+    geometry = (4, 32, 16, 32) if case == "width_off_tiles" \
+        else (4, 128, 64, 128)
+    operands = _mla_expand_operands(*geometry)
+    call = functools.partial(pk.mla_expand_qkv, *operands, nope=geometry[1],
+                             interleave=True)
+    before = pk.traced_call_sums()
+    if case in ("kernel", "off_the_tpu"):
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")
+            got = call()
+        reason = None if case == "kernel" else "no TPU backend"
+    elif case == "width_off_tiles":
+        with pytest.warns(pk.KernelFallbackWarning,
+                          match="mla_expand.*supports"):
+            got = call()
+        reason = "mla_expand_kernel.supports() refuses the shape"
+    else:
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+        with use_mesh(mesh), pytest.warns(
+                pk.KernelFallbackWarning,
+                match="mla_expand.*GSPMD cannot partition a Mosaic kernel"):
+            got = jax.eval_shape(call)
+        reason = pk.GSPMD_REASON
+    after = pk.traced_call_sums()
+    rec = pk.mla_expand_log()[-1]
+    assert rec["path"] == ("kernel" if case == "kernel" else "composition")
+    assert rec["reason"] is reason is None or reason in rec["reason"]
+    assert rec["shapes"] == (tuple(operands[0].shape),
+                             tuple(operands[1].shape))
+    assert {k: after[k] - before[k] for k in after} == {
+        "flash_calls": 0, "flash_operands_in_place": 0,
+        "flash_operands_copied": 0, "ssd_calls": 0, "ssd_calls_composed": 0,
+        "mla_expand_calls": 1,
+        "mla_expand_calls_composed": 0 if case == "kernel" else 1}
+    if case != "mesh":
+        want = pk._xla_mla_expand_qkv(*operands, nope=geometry[1],
+                                      interleave=True)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))
 
 
 @pytest.mark.slow
