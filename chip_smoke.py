@@ -141,8 +141,8 @@ def _check_losses(report, phase, losses, vocab):
 def train_phase(report, build_model, *, batch, seq, steps, on_chip):
     """``TrainStep`` + ``AdamW`` on one repeated batch; returns the
     losses (the four-chip phase compares against them)."""
-    from paddle_tpu.framework.flags import get_flags
     from paddle_tpu.jit import TrainStep
+    from paddle_tpu.ops.pallas import FLASH_MIN_SEQ
 
     phase = f"train[{batch}x{seq}]"
     model, opt, ids = _train_setup(build_model, batch, seq)
@@ -152,16 +152,14 @@ def train_phase(report, build_model, *, batch, seq, steps, on_chip):
         kernels = _mosaic_kernels(step.lower(ids, ids).as_text())
         want = {"layernorm_fwd", "layernorm_bwd"}
         flash = {"flash_attention_fwd", "flash_attention_bwd_dq_dkv"}
-        min_seq = int(get_flags("FLAGS_flash_min_seqlen")
-                      ["FLAGS_flash_min_seqlen"])
-        if seq >= min_seq:
+        if seq >= FLASH_MIN_SEQ:
             want |= flash
         report.check(phase, "lowered step holds the Mosaic custom calls",
                      want <= kernels, f"want {sorted(want)}, "
                      f"found {sorted(kernels)}")
-        if seq < min_seq:
+        if seq < FLASH_MIN_SEQ:
             report.check(phase, "flash kernel off below "
-                         f"FLAGS_flash_min_seqlen={min_seq} (by choice)",
+                         f"FLASH_MIN_SEQ={FLASH_MIN_SEQ} (by choice)",
                          not (flash & kernels))
     first, compile_s = _timed(lambda: float(step(ids, ids).numpy()))
     rest, run_s = _timed(lambda: [float(step(ids, ids).numpy())
@@ -315,7 +313,7 @@ def _serve_requests(address, prompts, max_new_tokens):
 def _dense_logprobs(model, sequences):
     """Teacher-forced log-probability of every next token under a dense
     forward of ``model`` in float32 at the highest matmul precision —
-    no paging, no kernel of ours below ``FLAGS_flash_min_seqlen``."""
+    no paging, no kernel of ours below ``ops.pallas.FLASH_MIN_SEQ``."""
     import jax
     import jax.numpy as jnp
 

@@ -11,14 +11,7 @@ _FLAG_DEFS = {
     "FLAGS_check_nan_inf": (False, lambda v: str(v).lower() in ("1", "true")),
     "FLAGS_cudnn_deterministic": (False, lambda v: str(v).lower() in ("1", "true")),
     "FLAGS_low_precision_op_list": (0, int),
-    "FLAGS_use_pallas_kernels": (True, lambda v: str(v).lower() not in ("0", "false")),
-    # Min seq length for the Pallas flash-attention path; below it the fused
-    # XLA attention wins on TPU (profiled: v5e, head_dim 64).
-    "FLAGS_flash_min_seqlen": (1024, int),
     "FLAGS_eager_vjp_cache": (True, lambda v: str(v).lower() not in ("0", "false")),
-    # Pallas block-size autotune (ops/pallas/autotune.py); off by default —
-    # the first sighting of a shape would otherwise pay N compiles.
-    "FLAGS_use_autotune": (False, lambda v: str(v).lower() in ("1", "true")),
     "FLAGS_allocator_strategy": ("auto_growth", str),
     "FLAGS_stop_check_timeout": (900, int),
 }

@@ -33,8 +33,8 @@ Rule catalog (Findings in the analysis.py style; docs/ANALYSIS.md):
   Q, dO and float32 dQ whole and reckons its allowance from its blocks;
   past the chip's physical VMEM Mosaic refuses the compile itself), and
   the finding names the binding buffer.  :func:`estimate_residency` /
-  :func:`vmem_fits` expose the same model to ``autotune.pick`` so
-  VMEM-overflowing block candidates are rejected before they are ever
+  :func:`vmem_fits` expose the same model to a caller that sizes its
+  own blocks, so a block that overflows VMEM is seen before it is ever
   compiled.
 - **K003 bounds** — interval analysis over each block's index map
   evaluated symbolically for all grid indices (grid axis ``i`` is the
@@ -460,7 +460,7 @@ def _vmem_limit(profile):
 
 def vmem_fits(blocks, scratch=(), profile="tpu-v4"):
     """True when the residency model fits the profile's VMEM budget
-    (autotune's candidate filter; profiles without a budget pass)."""
+    (profiles without a budget pass)."""
     limit = _vmem_limit(profile)
     return limit is None or estimate_residency(blocks, scratch) <= limit
 

@@ -21,9 +21,8 @@ Two implementations with identical semantics:
   meaningful across the refactor.
 
 On a TPU the masked-XLA path is never taken silently: it runs only
-when ``FLAGS_use_pallas_kernels`` is off or when the kernel's
-``supports()`` refuses the shape, and the refusal warns once with the
-shape and the reason (``ops.pallas.KernelFallbackWarning``).
+when the kernel's ``supports()`` refuses the shape, and the refusal warns
+once with the shape and the reason (``ops.pallas.KernelFallbackWarning``).
 
 The pool is HEAD-MAJOR, ``[NB, Nkv, bs, D]`` — the layout the kernel's
 page block needs (see the kernel module); the fallbacks gather pages

@@ -2,25 +2,30 @@
 
 Analog of the reference's hand-fused CUDA kernels
 (paddle/phi/kernels/fusion/, flash_attn at
-paddle/phi/kernels/gpu/flash_attn_kernel.cu).  Selection order:
-Pallas kernel (TPU, flag-gated) → XLA composition fallback (works everywhere,
-still fuses well).  ``FLAGS_use_pallas_kernels`` toggles.
+paddle/phi/kernels/gpu/flash_attn_kernel.cu).  Every kernel has an XLA
+composition of the same function that works everywhere; which of the two
+serves a call ONE rule decides from what the call shows (the backend, its
+shapes, the mesh): :func:`_refusal`, :func:`_dispatch`.  No flag and no
+environment variable enters it.
 
 On a TPU the XLA composition is never taken silently in place of a
-kernel: a dispatcher that gives up a kernel for a shape or argument it
-cannot serve calls :func:`warn_fallback`, which warns once per distinct
-(kernel, shape, reason) under :class:`KernelFallbackWarning`, so a run
-can turn it into an error (``chip_smoke.py`` does).
+kernel: a refusal calls :func:`warn_fallback`, which warns once per
+distinct (kernel, shape, reason) under :class:`KernelFallbackWarning`, so a
+run can turn it into an error (``chip_smoke.py`` does).
+
+Imports point downward only: kernel files take ``common`` and
+``registry``, this file takes the kernel files.
 """
 
-import collections
+import importlib
 import math
 import warnings
 
 import jax
 import jax.numpy as jnp
 
-from ...framework.flags import get_flags
+from . import common
+from .common import pick_block, record_flash_layout  # noqa: F401  (public)
 
 
 class KernelFallbackWarning(RuntimeWarning):
@@ -28,8 +33,8 @@ class KernelFallbackWarning(RuntimeWarning):
 
 
 def _use_pallas():
-    return (jax.default_backend() == "tpu"
-            and get_flags("FLAGS_use_pallas_kernels")["FLAGS_use_pallas_kernels"])
+    """Whether the kernels are the main path here: the platform says."""
+    return jax.default_backend() == "tpu"
 
 
 GSPMD_REASON = ("GSPMD cannot partition a Mosaic kernel; it needs a "
@@ -61,39 +66,107 @@ def warn_fallback(kernel, shape, reason):
             KernelFallbackWarning, stacklevel=3)
 
 
-# Which operands of the flash calls traced so far crossed between XLA and
-# the kernels in place, and which as copies: the newest calls' records, and
-# the running sums a compiled step takes its own share from
-# (``profiler.StepTrace.dispatch``: what was traced while it compiled).
-_flash_layouts = collections.deque(maxlen=256)
-_flash_layout_sums = {"flash_calls": 0, "flash_operands_in_place": 0,
-                      "flash_operands_copied": 0}
+NO_TPU = "no TPU backend"
+# Below this sequence length the fused XLA attention is taken on TPU; flash
+# pays off once the [T, S] score matrix dominates HBM.  The crossover was
+# profiled on the v5e when the kernel ran 128 x 128 blocks; since PR 25 it
+# runs blocks up to 512 x 512 (2.3x faster at seq 1024, 3.2x at 2048), so
+# the crossover may lie lower now: not measured (PERF.md section 7).  That
+# is a choice, not a fallback: short sequences take XLA without a word.
+FLASH_MIN_SEQ = 1024
+BY_CHOICE = "shorter than FLASH_MIN_SEQ: XLA's fused attention, by choice"
 
 
-def record_flash_layout(kernel, shapes, in_place, copied):
-    """``flash_attention_pallas`` says of one traced call which of its
-    eight operands (q, k, v, o and the backward's do, dq, dk, dv) the
-    kernels read or write where XLA holds them, and which cross as copies
-    ``[batch * heads, seq, width]`` and why (``{name: reason}``)."""
-    _flash_layouts.append({"kernel": kernel, "shapes": shapes,
-                           "in_place": tuple(in_place),
-                           "copied": dict(copied)})
-    _flash_layout_sums["flash_calls"] += 1
-    _flash_layout_sums["flash_operands_in_place"] += len(in_place)
-    _flash_layout_sums["flash_operands_copied"] += len(copied)
+def _kernels(module):
+    """A kernel file of this package, imported when a call first needs it
+    (``import paddle_tpu`` does not pay for Pallas)."""
+    return module and importlib.import_module(f"{__name__}.{module}")
+
+
+def _refusal(module, unfit=None, fits=None, sharded_refusal=None):
+    """THE rule that places a call: why it takes its XLA composition, or
+    None where it takes the kernels of ``module``.  Off the TPU: ``NO_TPU``.
+    On it: the caller's own ``unfit`` (arguments, dtypes), then
+    ``module.supports(*fits)``, then the one GSPMD rule: where GSPMD
+    partitions the computation a Mosaic kernel needs a sharded launch,
+    which only flash has (``sharded_refusal()``: why that cannot serve)."""
+    if not _use_pallas():
+        return NO_TPU
+    if unfit:
+        return unfit
+    if fits is not None and not _kernels(module).supports(*fits):
+        return f"{module}.supports() refuses the shape"
+    if _partitioned_by_gspmd():
+        return sharded_refusal() if sharded_refusal else \
+            GSPMD_REASON + "; these kernels have no sharded launch"
+    return None
+
+
+def _dispatch(kernel, module, shapes, launch, compose, counter=None,
+              more=(), **rule):
+    """One call placed by :func:`_refusal` (``rule``), recorded and run:
+    ``launch(module)``, else ``compose()``, ALOUD (:func:`warn_fallback`)
+    unless the reason is no fallback (off the TPU the composition is the
+    normal path; ``BY_CHOICE`` is a choice).  The record joins the one log
+    with ``more``; ``counter`` names the sums' count of these calls (and
+    ``<counter>_composed``)."""
+    reason = _refusal(module, **rule)
+    common.traced_calls.append({
+        "kernel": kernel, "shapes": shapes, "reason": reason,
+        "path": "kernel" if reason is None else "composition", **dict(more)})
+    if counter:
+        common.traced_sums[counter] += 1
+        common.traced_sums[counter + "_composed"] += reason is not None
+    if reason is None:
+        return launch(_kernels(module))
+    if reason not in (NO_TPU, BY_CHOICE):
+        warn_fallback(kernel, shapes, reason)
+    return compose()
+
+
+def _view(keys, kernel=None):
+    """The log's records of ``kernel`` (else: the flash launches', which
+    alone say what went in place), oldest first, as ``keys``."""
+    return [{k: r[k] for k in keys} for r in common.traced_calls
+            if (r["kernel"] == kernel if kernel else "in_place" in r)]
 
 
 def flash_layout_log():
-    """The records of the newest traced flash calls (at most 256), oldest
-    first: ``kernel``, ``shapes``, ``in_place``, ``copied``."""
-    return list(_flash_layouts)
+    """The records of the newest flash calls that reached the kernels,
+    oldest first: ``kernel``, ``shapes``, ``in_place``, ``copied``."""
+    return _view(("kernel", "shapes", "in_place", "copied"))
 
 
 def flash_layout_sums():
     """``flash_calls``, ``flash_operands_in_place`` and
     ``flash_operands_copied`` over every flash call traced in this
     process."""
-    return dict(_flash_layout_sums)
+    return {k: n for k, n in common.traced_sums.items()
+            if k.startswith("flash_")}
+
+
+def ssd_scan_log():
+    """The records of the newest traced :func:`ssd_scan` calls, oldest
+    first: ``shapes`` (x, B), ``chunk``, ``path`` (``kernel`` or
+    ``composition``) and ``reason`` (why the composition; None for the
+    kernels)."""
+    return _view(("shapes", "chunk", "path", "reason"), "ssd_scan")
+
+
+def mla_expand_log():
+    """The same of :func:`mla_expand_qkv`: ``shapes`` (q, kv_b), ``path``
+    and ``reason``."""
+    return _view(("shapes", "path", "reason"), "mla_expand")
+
+
+def traced_call_sums():
+    """What a compiled step's account takes its own share of
+    (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
+    ``ssd_calls`` / ``ssd_calls_composed``, the :func:`ssd_scan` calls
+    traced in this process and those of them the composition served; and
+    ``mla_expand_calls`` / ``mla_expand_calls_composed``, the same of
+    :func:`mla_expand_qkv`."""
+    return dict(common.traced_sums)
 
 
 def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
@@ -236,53 +309,45 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     under the same softmax is not a mask of this function: it has two
     kinds of key, its own kernels and its own dispatcher,
     :func:`eva_attention`."""
+    from ...distributed.fleet.spmd import current_mesh
+
     if dropout_p > 0.0 and dropout_key is None:
         from ...framework.random import get_rng_key
         dropout_key = get_rng_key()
-    if _use_pallas():
-        from .attention_kernel import flash_attention_pallas, supports
-        # Below this sequence length the fused XLA attention is taken on
-        # TPU; flash pays off once the [T, S] score matrix dominates HBM.
-        # The crossover was profiled on the v5e when the kernel ran 128 x
-        # 128 blocks; since PR 25 it runs blocks up to 512 x 512 (2.3x
-        # faster at seq 1024, 3.2x at 2048), so the crossover may lie
-        # lower now: not measured (PERF.md section 7).  That is a choice,
-        # not a fallback: short sequences take XLA without a word.
-        min_seq = get_flags("FLAGS_flash_min_seqlen")["FLAGS_flash_min_seqlen"]
-        if q.shape[1] >= int(min_seq):
-            reason = mesh = None
-            if attn_mask is not None or dropout_p > 0.0 \
-                    or scale is not None:
-                reason = ("the kernel takes no attn_mask, dropout or scale "
-                          "(a causal sliding window it takes as window=)")
-            elif is_causal and q.shape[1] != k.shape[1]:
-                # causal masking in the kernel is top-left aligned; for
-                # seq_q != seq_k the paddle/XLA semantics are bottom-right
-                # aligned, so only self-attention-shaped causal inputs
-                # take the kernel path
-                reason = "causal with seq_q != seq_k"
-            elif not supports(q.shape[1], k.shape[1], q.shape[3],
-                              v.shape[3], q.shape[2], k.shape[2], window,
-                              is_causal):
-                # sequence and head widths are whole in every shard, so
-                # this answers for the sharded launch too
-                reason = "attention_kernel.supports() refuses the shape"
-            elif _partitioned_by_gspmd():
-                from ...distributed.fleet.spmd import current_mesh
-                mesh = current_mesh()
-                reason = _sharded_refusal(q, mesh, k.shape[2])
-            if reason is None:
-                # a call without a window is the call it was
-                kw = {} if window is None else {"window": window}
-                if mesh is not None:
-                    return flash_attention_sharded(q, k, v, is_causal, mesh,
-                                                   **kw)
-                return flash_attention_pallas(q, k, v, is_causal, **kw)
-            warn_fallback("flash_attention",
-                          f"q{tuple(q.shape)} k{tuple(k.shape)}", reason)
-    return _xla_attention(q, k, v, attn_mask=attn_mask, is_causal=is_causal,
-                          dropout_p=dropout_p, dropout_key=dropout_key,
-                          scale=scale, window=window)
+    unfit = None
+    if q.shape[1] < FLASH_MIN_SEQ:
+        unfit = BY_CHOICE
+    elif attn_mask is not None or dropout_p > 0.0 or scale is not None:
+        unfit = ("the kernel takes no attn_mask, dropout or scale "
+                 "(a causal sliding window it takes as window=)")
+    elif is_causal and q.shape[1] != k.shape[1]:
+        # causal masking in the kernel is top-left aligned; for seq_q !=
+        # seq_k the paddle/XLA semantics are bottom-right aligned, so only
+        # self-attention-shaped causal inputs take the kernel path
+        unfit = "causal with seq_q != seq_k"
+
+    def launch(kernels):
+        # a call without a window is the call it was
+        kw = {} if window is None else {"window": window}
+        if _partitioned_by_gspmd():
+            return flash_attention_sharded(q, k, v, is_causal,
+                                           current_mesh(), **kw)
+        return kernels.flash_attention_pallas(q, k, v, is_causal, **kw)
+
+    return _dispatch(
+        "flash_attention", "attention_kernel",
+        f"q{tuple(q.shape)} k{tuple(k.shape)}", launch,
+        lambda: _xla_attention(q, k, v, attn_mask=attn_mask,
+                               is_causal=is_causal, dropout_p=dropout_p,
+                               dropout_key=dropout_key, scale=scale,
+                               window=window),
+        unfit=unfit,
+        # sequence and head widths are whole in every shard, so this
+        # answers for the sharded launch too
+        fits=(q.shape[1], k.shape[1], q.shape[3], v.shape[3], q.shape[2],
+              k.shape[2], window, is_causal),
+        sharded_refusal=lambda: _sharded_refusal(q, current_mesh(),
+                                                 k.shape[2]))
 
 
 def _xla_eva_attention(q, k, v, kt, vt, window, chunk):
@@ -315,130 +380,54 @@ def _xla_eva_attention(q, k, v, kt, vt, window, chunk):
     return out.astype(q.dtype)
 
 
-def _eva_refusal(seq, head_dim, window, chunk):
-    """Why the ``eva_attention_*`` kernels do not serve this call where
-    kernels are on, or None."""
-    from .eva_attention_kernel import supports
-
-    if not supports(seq, head_dim, window, chunk):
-        return "eva_attention_kernel.supports() refuses the shape"
-    if _partitioned_by_gspmd():
-        return GSPMD_REASON + "; these kernels have no sharded launch"
-    return None
-
-
 def eva_attention(q, k, v, kt, vt, window, chunk):
     """Attention over the exact keys of a query's own block-aligned window
     (causally) and the chunk summaries kt, vt of every earlier window, under
     one softmax.  q, k, v ``[batch, T, heads, D]``, kt, vt ``[batch, T /
-    chunk, heads, D]``; returns ``[batch, T, heads, D]``.
-
-    On the TPU the ``eva_attention_*`` kernels (``eva_attention_kernel``),
-    whose work follows the mask; elsewhere, and aloud where
-    ``eva_attention_kernel.supports`` refuses the shape or GSPMD partitions
-    the step, the dense XLA composition."""
-    if _use_pallas():
-        reason = _eva_refusal(q.shape[1], q.shape[3], window, chunk)
-        if reason is None:
-            from .eva_attention_kernel import eva_attention_pallas
-            return eva_attention_pallas(q, k, v, kt, vt, window, chunk)
-        warn_fallback("eva_attention",
-                      f"q{tuple(q.shape)} window={window} chunk={chunk}",
-                      reason)
-    return _xla_eva_attention(q, k, v, kt, vt, window, chunk)
+    chunk, heads, D]``; returns ``[batch, T, heads, D]``.  The
+    ``eva_attention_*`` kernels (``eva_attention_kernel``), whose work
+    follows the mask, or the dense XLA composition, as :func:`_dispatch`
+    places it."""
+    return _dispatch(
+        "eva_attention", "eva_attention_kernel",
+        f"q{tuple(q.shape)} window={window} chunk={chunk}",
+        lambda eva: eva.eva_attention_pallas(q, k, v, kt, vt, window, chunk),
+        lambda: _xla_eva_attention(q, k, v, kt, vt, window, chunk),
+        fits=(q.shape[1], q.shape[3], window, chunk))
 
 
 def eva_pairs_scored(seq, head_dim, window, chunk):
     """The (query, key-or-summary) pairs :func:`eva_attention` forms scores
     for over one row and head, by the path it takes where this is asked
-    (the same trace) and the block sizes that path runs: the kernels'
-    grids, or the composition's whole ``T x (T + T / chunk)`` matrix, so
-    that a fallback shows in the count."""
+    (the same trace, the same rule) and the block sizes that path runs: the
+    kernels' grids, or the composition's whole ``T x (T + T / chunk)``
+    matrix, so that a fallback shows in the count."""
     from . import eva_attention_kernel as eva
 
-    if _use_pallas() and _eva_refusal(seq, head_dim, window, chunk) is None:
+    if _refusal("eva_attention_kernel",
+                fits=(seq, head_dim, window, chunk)) is None:
         return sum(eva.pairs_scored(seq, window, chunk))
     return eva.pairs_dense(seq, chunk)
-
-
-# The state-space scans traced so far, as the flash calls above: the newest
-# calls' records, and the running sums a compiled step takes its share of.
-_ssd_scans = collections.deque(maxlen=256)
-_ssd_scan_sums = {"ssd_calls": 0, "ssd_calls_composed": 0}
-
-
-def ssd_scan_log():
-    """The records of the newest traced :func:`ssd_scan` calls (at most
-    256), oldest first: ``shapes`` (x, B), ``chunk``, ``path`` (``kernel``
-    or ``composition``) and ``reason`` (why the composition; None for the
-    kernels)."""
-    return list(_ssd_scans)
-
-
-# Latent attention's expansions traced so far, the same way.
-_mla_expands = collections.deque(maxlen=256)
-_mla_expand_sums = {"mla_expand_calls": 0, "mla_expand_calls_composed": 0}
-
-
-def mla_expand_log():
-    """The records of the newest traced :func:`mla_expand_qkv` calls (at
-    most 256), oldest first: ``shapes`` (q, kv_b), ``path`` (``kernel`` or
-    ``composition``) and ``reason`` (why the composition; None for the
-    kernels)."""
-    return list(_mla_expands)
-
-
-def traced_call_sums():
-    """What a compiled step's account takes its own share of
-    (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
-    ``ssd_calls`` / ``ssd_calls_composed``, the :func:`ssd_scan` calls
-    traced in this process and those of them the composition served; and
-    ``mla_expand_calls`` / ``mla_expand_calls_composed``, the same of
-    :func:`mla_expand_qkv`."""
-    return {**_flash_layout_sums, **_ssd_scan_sums, **_mla_expand_sums}
-
-
-def _ssd_refusal(x, B, C, chunk):
-    """Why the ``ssd_scan_*`` kernels do not serve this call where kernels
-    are on, or None."""
-    from .ssd_scan_kernel import supports
-
-    if B.dtype != x.dtype or C.dtype != x.dtype:
-        return f"B {B.dtype}, C {C.dtype} beside x {x.dtype}"
-    if not supports(x.shape[1], x.shape[2], x.shape[3], B.shape[2],
-                    B.shape[3], chunk, x.dtype):
-        return "ssd_scan_kernel.supports() refuses the shape"
-    if _partitioned_by_gspmd():
-        return GSPMD_REASON + "; these kernels have no sharded launch"
-    return None
 
 
 def ssd_scan(x, dt, A, B, C, D, chunk):
     """``nn/functional.py ssd_scan`` on rows of whole chunks: ``x [batch,
     T, heads, P]``, ``dt [batch, T, heads]``, ``B``, ``C`` ``[batch, T,
-    groups, N]``.  On the TPU the ``ssd_scan_fwd`` / ``ssd_scan_bwd``
-    kernels (``ssd_scan_kernel``); elsewhere, and aloud where
-    ``ssd_scan_kernel.supports`` refuses the shapes or GSPMD partitions the
-    step, the XLA composition ``nn/functional.py _ssd_scan_rows``.  Every
-    traced call is recorded (:func:`ssd_scan_log`)."""
-    kernels_on = _use_pallas()
-    reason = _ssd_refusal(x, B, C, chunk) if kernels_on else \
-        "no TPU backend (or FLAGS_use_pallas_kernels off)"
-    _ssd_scans.append({
-        "shapes": (tuple(x.shape), tuple(B.shape)), "chunk": chunk,
-        "path": "kernel" if reason is None else "composition",
-        "reason": reason})
-    _ssd_scan_sums["ssd_calls"] += 1
-    if reason is None:
-        from .ssd_scan_kernel import ssd_scan_pallas
-        return ssd_scan_pallas(x, dt, A, B, C, D, chunk)
-    _ssd_scan_sums["ssd_calls_composed"] += 1
-    if kernels_on:
-        warn_fallback("ssd_scan",
-                      f"x{tuple(x.shape)} B{tuple(B.shape)} chunk={chunk}",
-                      reason)
+    groups, N]``.  The ``ssd_scan_fwd`` / ``ssd_scan_bwd`` kernels
+    (``ssd_scan_kernel``) or the XLA composition ``nn/functional.py
+    _ssd_scan_rows``, as :func:`_dispatch` places it; every traced call is
+    recorded (:func:`ssd_scan_log`)."""
     from ...nn.functional import _ssd_scan_rows
-    return _ssd_scan_rows(x, dt, A, B, C, D, chunk)
+
+    return _dispatch(
+        "ssd_scan", "ssd_scan_kernel", (tuple(x.shape), tuple(B.shape)),
+        lambda ssd: ssd.ssd_scan_pallas(x, dt, A, B, C, D, chunk),
+        lambda: _ssd_scan_rows(x, dt, A, B, C, D, chunk),
+        counter="ssd_calls", more={"chunk": chunk},
+        unfit=None if B.dtype == C.dtype == x.dtype else
+        f"B {B.dtype}, C {C.dtype} beside x {x.dtype}",
+        fits=(x.shape[1], x.shape[2], x.shape[3], B.shape[2], B.shape[3],
+              chunk, x.dtype))
 
 
 def mla_rope(x, cos, sin, interleave):
@@ -472,64 +461,36 @@ def _xla_mla_expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
     return q, k, kv_b[..., nope:]
 
 
-def _mla_expand_refusal(q, kv_b, k_rope, nope):
-    """Why the ``mla_expand_*`` kernels do not serve this call where
-    kernels are on, or None."""
-    from .mla_expand_kernel import supports
-
-    if kv_b.dtype != q.dtype or k_rope.dtype != q.dtype:
-        return f"kv_b {kv_b.dtype}, k_rope {k_rope.dtype} beside q {q.dtype}"
-    if not supports(q.shape[1], q.shape[2], nope, q.shape[3] - nope,
-                    kv_b.shape[3] - nope, q.dtype):
-        return "mla_expand_kernel.supports() refuses the shape"
-    if _partitioned_by_gspmd():
-        return GSPMD_REASON + "; these kernels have no sharded launch"
-    return None
-
-
 def mla_expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
     """Latent attention's q, k, v for the flash call (``models/mla_moe.py``):
     ``q [B, T, N, nope + rope]``, ``kv_b [B, T, N, nope + v]``, ``k_rope
-    [B, T, rope]``, ``cos`` / ``sin`` ``[T, rope / 2]``.  On the TPU the
+    [B, T, rope]``, ``cos`` / ``sin`` ``[T, rope / 2]``.  The
     ``mla_expand_fwd`` / ``mla_expand_bwd`` kernels (``mla_expand_kernel``),
-    whose results are the flash kernels' layout already; elsewhere, and
-    aloud where ``mla_expand_kernel.supports`` refuses the shapes or GSPMD
-    partitions the step, the XLA composition :func:`_xla_mla_expand_qkv`.
-    Every traced call is recorded (:func:`mla_expand_log`)."""
-    kernels_on = _use_pallas()
-    reason = _mla_expand_refusal(q, kv_b, k_rope, nope) if kernels_on else \
-        "no TPU backend (or FLAGS_use_pallas_kernels off)"
-    _mla_expands.append({
-        "shapes": (tuple(q.shape), tuple(kv_b.shape)),
-        "path": "kernel" if reason is None else "composition",
-        "reason": reason})
-    _mla_expand_sums["mla_expand_calls"] += 1
-    if reason is None:
-        from .mla_expand_kernel import mla_expand_pallas
-        return mla_expand_pallas(q, kv_b, k_rope, cos, sin, nope=nope,
-                                 interleave=interleave)
-    _mla_expand_sums["mla_expand_calls_composed"] += 1
-    if kernels_on:
-        warn_fallback("mla_expand",
-                      f"q{tuple(q.shape)} kv_b{tuple(kv_b.shape)}", reason)
-    return _xla_mla_expand_qkv(q, kv_b, k_rope, cos, sin, nope=nope,
-                               interleave=interleave)
+    whose results are the flash kernels' layout already, or the XLA
+    composition :func:`_xla_mla_expand_qkv`, as :func:`_dispatch` places it;
+    every traced call is recorded (:func:`mla_expand_log`)."""
+    return _dispatch(
+        "mla_expand", "mla_expand_kernel",
+        (tuple(q.shape), tuple(kv_b.shape)),
+        lambda mla: mla.mla_expand_pallas(q, kv_b, k_rope, cos, sin,
+                                          nope=nope, interleave=interleave),
+        lambda: _xla_mla_expand_qkv(q, kv_b, k_rope, cos, sin, nope=nope,
+                                    interleave=interleave),
+        counter="mla_expand_calls",
+        unfit=None if kv_b.dtype == k_rope.dtype == q.dtype else
+        f"kv_b {kv_b.dtype}, k_rope {k_rope.dtype} beside q {q.dtype}",
+        fits=(q.shape[1], q.shape[2], nope, q.shape[3] - nope,
+              kv_b.shape[3] - nope, q.dtype))
 
 
 def _grouped_row_tile(shape):
     """The row tile of JAX's grouped-matmul Pallas kernels for ``shape
-    [M, K]`` rows on the TPU, or ``None`` where the XLA forms serve: off
-    the TPU, and (aloud) under GSPMD or where no tile divides the rows."""
-    if not _use_pallas():
-        return None
+    [M, K]`` rows where :func:`_dispatch` places the call on them, or
+    ``None`` where the XLA forms serve."""
     tm = next((t for t in (512, 256, 128) if shape[0] % t == 0), None)
-    if _partitioned_by_gspmd():
-        warn_fallback("grouped_matmul", tuple(shape), GSPMD_REASON)
-        return None
-    if tm is None:
-        warn_fallback("grouped_matmul", tuple(shape),
-                      "rows not a multiple of 128")
-    return tm
+    return _dispatch(
+        "grouped_matmul", None, tuple(shape), lambda _: tm, lambda: None,
+        unfit=None if tm else "rows not a multiple of 128")
 
 
 def grouped_matmul(xs, w, group_sizes, transpose_w=False):
@@ -577,12 +538,3 @@ def grouped_matmul_dw(xs, g, group_sizes):
             dot_dimension_numbers=(([0], [0]), ([], [])),
             lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
         preferred_element_type=xs.dtype)
-
-
-def pick_block(size, preferred, candidates=(512, 256, 128, 64, 32, 16, 8)):
-    """Largest candidate <= preferred that divides ``size`` (shared block
-    -size heuristic for the Pallas kernels)."""
-    for b in (preferred,) + tuple(candidates):
-        if b <= preferred and size % b == 0:
-            return b
-    return None

@@ -85,16 +85,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
-
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
-_NEG_INF = -1e30
-
-
-def _pick_block(seq, preferred):
-    from . import pick_block
-
-    return pick_block(seq, preferred)
+from .common import (_NEG_INF, _NN, _NT, _TN, _block_rows, _dot,
+                     _mask_below_diagonal, _rows, pick_block,
+                     record_flash_layout)
 
 
 # q/k and v head widths that differ: the pairs Mosaic was shown to take
@@ -123,9 +116,7 @@ def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
         heads_ok = heads_ok and kv_heads > 0 and q_heads % kv_heads == 0
     if window is not None:
         heads_ok = heads_ok and causal and window >= 1 and seq_q == seq_k
-    return (heads_ok
-            and _pick_block(seq_q, DEFAULT_BLOCK_Q) is not None
-            and _pick_block(seq_k, DEFAULT_BLOCK_K) is not None)
+    return heads_ok and None not in _blocks(seq_q, seq_k)
 
 
 def _compiler_params(head_qk, head_v):
@@ -159,42 +150,7 @@ def _compiler_params(head_qk, head_v):
 # 1%, at 90 TFLOP/s, above what three passes could reach), so an upcast
 # costs converts and hides from interpret mode the rounding the chip
 # applies anyway.  What bounds these kernels is the block shape
-# (``_block_candidates``).
-
-_NT = (((1,), (1,)), ((), ()))      # a @ b.T
-_NN = (((1,), (0,)), ((), ()))      # a @ b
-_TN = (((0,), (0,)), ((), ()))      # a.T @ b
-
-
-def _dot(a, b, dims):
-    return jax.lax.dot_general(a, b, dims,
-                               preferred_element_type=jnp.float32)
-
-
-def _block_rows(i, block):
-    """The slice of rows [i*block, (i+1)*block).  Mosaic has to prove the
-    alignment of a dynamic row slice of a packed dtype."""
-    return pl.ds(pl.multiple_of(i * block, block), block)
-
-
-def _rows(ref, i, block):
-    """Rows [i*block, (i+1)*block) of a [1, seq, head] ref."""
-    return ref[0, _block_rows(i, block), :]
-
-
-def _mask_below_diagonal(s, row0, col0, row_axis, window=None):
-    """Keep s where (row0 + row) >= (col0 + col), and with a ``window``
-    where row - col < window besides; ``row_axis`` is the axis of ``s``
-    that runs over queries.  A row wholly masked in a block it visits
-    before its first visible key leaves m at -1e30 and p at 1; the first
-    block with a visible key (every row sees itself) rescales that to
-    nothing (alpha = exp(-1e30 - m) = 0)."""
-    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, row_axis)
-    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - row_axis)
-    keep = rows >= cols
-    if window is not None:
-        keep = keep & (rows - cols < window)
-    return jnp.where(keep, s, _NEG_INF)
+# (``_blocks``).
 
 
 def _key_blocks(qi, block_q, block_k, seq_k, causal, window=None):
@@ -603,9 +559,11 @@ def _flash_attention_kernels(q, k, v, dims, causal, scale, interpret,
     return out
 
 
-def _block_candidates(seq_q, seq_k):
-    """(block_q, block_k) candidates, author heuristic first: the largest of
-    512, 256, 128, 64 that divides the sequence, on both axes.
+def _blocks(seq_q, seq_k):
+    """``(block_q, block_k)``: on each axis the largest of 512, 256, 128, 64
+    that divides the sequence (else of 32, 16, 8: a length :func:`supports`
+    takes has one).  The rule is the tables below: nothing is searched at
+    run time.
 
     Measured on the v5e (PR 25, bf16 causal, fwd + dq + dkv a call): a block
     step is bound by the latency of its dot -> softmax -> dot chain, not by
@@ -642,86 +600,7 @@ def _block_candidates(seq_q, seq_k):
     -> 6.0 and 4.30 -> 2.91 ms, is now within a fifth of what the MXU takes
     for the five dots as it runs them (a 192-wide side costs it 256); the
     transposed dS tile of dQ's dot does not show beside that."""
-    qs = [b for b in (512, 256, 128, 64) if seq_q % b == 0]
-    ks = [b for b in (512, 256, 128, 64) if seq_k % b == 0]
-    if not qs:
-        qs = [_pick_block(seq_q, DEFAULT_BLOCK_Q)]
-    if not ks:
-        ks = [_pick_block(seq_k, DEFAULT_BLOCK_K)]
-    head = [(qs[0], ks[0])]
-    rest = [(bq, bk) for bq in qs for bk in ks if (bq, bk) != head[0]]
-    return head + rest
-
-
-def _vmem_validate(seq_q, seq_k, head, dtype, profile="tpu-v4", head_v=None,
-                   group=1):
-    """Candidate screen for autotune.pick: reject (block_q, block_k) whose
-    per-grid-step residency (kernel_lint's K002 model: double-buffered
-    blocks, the scratch once) cannot fit the profile's VMEM for the
-    forward or the backward kernel."""
-    from ...framework.kernel_lint import vmem_fits
-
-    hv = head if head_v is None else head_v
-
-    def validate(cand):
-        bq, bk = cand
-        fwd = [((1, bq, head), dtype), ((1, seq_k, head), dtype),
-               ((1, seq_k, hv), dtype), ((1, bq, hv), dtype),
-               ((1, seq_q // bq, bq), jnp.float32)]
-        ins, outs, scratch = _bwd_blocks(group, seq_q, seq_k, head, hv, bq,
-                                         bk, dtype)
-        bwd = [(s, dt) for s, dt, _ in ins + outs]
-        return (vmem_fits(fwd, profile=profile)
-                and vmem_fits(bwd, scratch, profile=profile))
-
-    return validate
-
-
-def _tuned_blocks(q, k, v, dims, causal, scale, interpret, window=None):
-    """Autotuned (block_q, block_k) for this shape (FLAGS_use_autotune);
-    the heuristic (128-preferred divisor) wins with the flag off."""
-    from . import autotune
-
-    batch, heads, kv_heads = dims
-    seq_q, seq_k = q.shape[1], k.shape[1]
-    head, head_v = _width(q, batch, heads), _width(v, batch, kv_heads)
-    group = heads // kv_heads
-    # one head width keeps the key it had; a second width joins it
-    widths = head if head_v == head else (head, head_v)
-    key = (seq_q, seq_k, widths, str(q.dtype), causal)
-    if window is not None or group > 1:
-        # a window or grouped heads join the key; a call with neither
-        # keeps the one it had
-        key += (window, group)
-    cands = _block_candidates(seq_q, seq_k)
-
-    def measure(cand):
-        bq, bk = cand
-        import numpy as _np
-
-        rng = _np.random.RandomState(0)
-        kv_few = max(min(batch * heads, 8) // group, 1)
-        few = (1, kv_few * group, kv_few)
-        qq, kk, vv = (
-            jnp.asarray(rng.rand(*_layout_shape(1, seq, n, width, group)),
-                        q.dtype)
-            for seq, n, width in ((seq_q, few[1], head),
-                                  (seq_k, few[2], head),
-                                  (seq_k, few[2], head_v)))
-        out, lse = _flash_fwd(qq, kk, vv, few, causal, scale, bq, bk,
-                              interpret, window)
-        # measure (and VMEM-validate) the backward too: a candidate that
-        # fits the fwd can overflow the bwd's working set, and training
-        # pays both
-        grads = _flash_bwd(qq, kk, vv, out, lse, out, few, causal, scale,
-                           bq, bk, interpret, window)
-        jax.block_until_ready((out, grads))  # noqa: H001 (autotune timing sync — measurement, not a serving path)
-
-    return autotune.pick(
-        "flash_attention", key,
-        cands, measure=measure,
-        validate=_vmem_validate(seq_q, seq_k, head, q.dtype, head_v=head_v,
-                                group=group))
+    return pick_block(seq_q, 512), pick_block(seq_k, 512)
 
 
 # the forward's own results among the residuals, by the names a
@@ -732,8 +611,7 @@ SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
 def _fwd_rule(q, k, v, dims, causal, scale, interpret, window=None):
-    block_q, block_k = _tuned_blocks(q, k, v, dims, causal, scale, interpret,
-                                     window)
+    block_q, block_k = _blocks(q.shape[1], k.shape[1])
     out, lse = _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k,
                           interpret, window)
     out = checkpoint_name(out, SAVED_BY_NAME[0])
@@ -743,8 +621,7 @@ def _fwd_rule(q, k, v, dims, causal, scale, interpret, window=None):
 
 def _bwd_rule(dims, causal, scale, interpret, window, res, do):
     q, k, v, out, lse = res
-    block_q, block_k = _tuned_blocks(q, k, v, dims, causal, scale, interpret,
-                                     window)
+    block_q, block_k = _blocks(q.shape[1], k.shape[1])
     return _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q,
                       block_k, interpret, window)
 
@@ -844,8 +721,6 @@ def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
     window = None if window is None or window >= sk else int(window)
     if scale is None:
         scale = 1.0 / (h ** 0.5)
-    from . import record_flash_layout
-
     record_flash_layout(_kernel_name("fwd", window).removesuffix("_fwd"),
                         f"q{tuple(q.shape)} k{tuple(k.shape)} "
                         f"v{tuple(v.shape)}",

@@ -32,9 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import registry
+from .common import _NEG_INF, pick_block
 
 DEFAULT_BLOCK_S = 128
-_NEG_INF = -1e30
 
 # What the Pallas TPU lowering says to this kernel (jax 0.9.0, checked
 # by tests/test_tpu_lowering.py).  The ragged paged kernel had the same
@@ -46,19 +46,12 @@ TPU_REFUSAL = (
     "(1, s_max, 1, d) cache block and (1, g, 1, d) q/out blocks squeeze "
     "the second-minor (head) axis of [B, S_max, Nkv, D], and its (1,) "
     "lengths block is not a VMEM tile — Mosaic needs the last two block "
-    "dims tile-aligned or whole.  It needs a head-major dense cache.  "
-    "Set FLAGS_use_pallas_kernels=0 to run decode_attention_xla.")
-
-
-def _pick_block(s_max, preferred=DEFAULT_BLOCK_S):
-    from . import pick_block
-
-    return pick_block(s_max, preferred,
-                      candidates=(256, 128, 64, 32, 16, 8))
+    "dims tile-aligned or whole.  It needs a head-major dense cache; "
+    "until it has one, call decode_attention_xla.")
 
 
 def supports(s_max, head_dim, num_q_heads, num_kv_heads):
-    return (head_dim <= 128 and _pick_block(s_max) is not None
+    return (head_dim <= 128 and pick_block(s_max, DEFAULT_BLOCK_S) is not None
             and num_q_heads % num_kv_heads == 0)
 
 
@@ -137,7 +130,7 @@ def decode_attention_pallas(q, k_cache, v_cache, lengths, block_s=None,
     b, nq, d = q.shape
     s_max, nkv = k_cache.shape[1], k_cache.shape[2]
     g = nq // nkv
-    block_s = block_s or _pick_block(s_max)
+    block_s = block_s or pick_block(s_max, DEFAULT_BLOCK_S)
     # regroup query heads by their kv head: [B, Nkv, G, D]
     qg = q.reshape(b, nkv, g, d)
     lengths = lengths.astype(jnp.int32)

@@ -68,8 +68,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
-from .attention_kernel import (_NEG_INF, _NN, _NT, _dot,
-                               _mask_below_diagonal, _rows)
+from .common import (_NEG_INF, _NN, _NT, _dot, _mask_below_diagonal, _rows,
+                     pick_block)
 
 SAVED_BY_NAME = ("eva_attention_out", "eva_attention_lse")
 
@@ -85,8 +85,6 @@ def default_blocks(window, chunk):
     among them) 8.31, 22.67 ms; 512 | 512 | 256: 7.37, 21.13; 512 | 512 |
     512: 7.00, 21.05 with 1.43 times the summary pairs scored; 256 x 256
     exact blocks 11.27, 31.98; 1024 x 1024: 8.17, 22.55."""
-    from . import pick_block
-
     block = pick_block(window, 512)
     return block, block, pick_block(2 * (window // chunk), 256)
 

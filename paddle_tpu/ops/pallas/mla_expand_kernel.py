@@ -62,10 +62,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
-from .attention_kernel import _NN
-from .ssd_scan_kernel import _dot_for
+from .common import _LANES, _NN, _dot_for
 
-_LANES = 128
 # what one grid step's blocks may hold, in and out (Pallas keeps two of
 # each): rows of 1,024 at the published 32 x (128 + 64 | 128) in bfloat16
 _STEP_BYTES = 5 * 1024 * 1024

@@ -77,8 +77,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
+from .common import _NEG_INF
 
-_NEG_INF = -1e30
 # the Mosaic custom call's kernel_name in a lowered step, and the name
 # a device trace shows
 KERNEL_NAME = "ragged_paged_attention"

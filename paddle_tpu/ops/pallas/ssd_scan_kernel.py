@@ -60,9 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import registry
-from .attention_kernel import _NN, _NT, _TN
-
-_LANES = 128
+from .common import _LANES, _NN, _NT, _TN, _dot_for
 
 
 def supports(seq, heads, head_dim, groups, state, chunk, dtype):
@@ -86,18 +84,6 @@ def supports(seq, heads, head_dim, groups, state, chunk, dtype):
 
 
 # ------------------------------------------------------------ a chunk's own --
-
-def _dot_for(dtype):
-    """The kernels' matmul on operands of ``dtype``, float32 accumulation.
-    float32 operands ask for float32 products (``HIGHEST``): the MXU's
-    default rounds them to bfloat16 inside, and the backward's pairs of
-    sums (``_bwd_kernel``) cancel only where both sides see ONE rounding
-    of the decay-weighted matrix."""
-    precision = jax.lax.Precision.HIGHEST \
-        if jnp.dtype(dtype) == jnp.dtype(jnp.float32) else None
-    return functools.partial(jax.lax.dot_general, precision=precision,
-                             preferred_element_type=jnp.float32)
-
 
 def _running_sum(rows, reverse=False):
     """The inclusive running sum along the lanes of float32 ``[r, Q]`` (from

@@ -1,11 +1,10 @@
 """Custom-op extension path: register_custom_op / register_pallas_op /
 cpp_extension.load / host_op_from_extension, plus the op-schema single
-source and the Pallas autotune cache.
+source.
 
 Reference parity targets: paddle/fluid/framework/custom_operator.cc
 (runtime op registration), python/paddle/utils/cpp_extension/ (JIT C++
-build), paddle/phi/kernels/autotune/ (config cache),
-paddle/phi/api/yaml/ops.yaml (single-source signatures).
+build), paddle/phi/api/yaml/ops.yaml (single-source signatures).
 """
 
 import numpy as np
@@ -221,146 +220,3 @@ class TestOpSchema:
         finally:
             del OP_SCHEMA[name]
             registry.OPS.pop(name, None)
-
-
-class TestAutotune:
-    def test_pick_flag_off_returns_heuristic(self):
-        from paddle_tpu.ops.pallas import autotune
-
-        autotune.autotune_cache_clear()
-        calls = []
-        got = autotune.pick("k", (1,), ["a", "b"],
-                            measure=lambda c: calls.append(c))
-        assert got == "a" and calls == []  # flag off: no measurement
-
-    def test_pick_measures_and_caches_with_flag(self):
-        from paddle_tpu.ops.pallas import autotune
-
-        autotune.autotune_cache_clear()
-        paddle.set_flags({"FLAGS_use_autotune": True})
-        try:
-            import time
-
-            def measure(c):
-                time.sleep(0.02 if c == "slow" else 0.001)
-
-            got = autotune.pick("k2", (2,), ["slow", "fast"],
-                                measure=measure)
-            assert got == "fast"
-            # cached: a failing measure proves it is not re-run
-            got2 = autotune.pick("k2", (2,), ["slow", "fast"],
-                                 measure=lambda c: 1 / 0)
-            assert got2 == "fast"
-        finally:
-            paddle.set_flags({"FLAGS_use_autotune": False})
-
-    def test_heuristic_entry_does_not_block_later_tuning(self):
-        from paddle_tpu.ops.pallas import autotune
-
-        autotune.autotune_cache_clear()
-        # flag off: heuristic cached
-        assert autotune.pick("k4", (4,), ["a", "b"],
-                             measure=lambda c: None) == "a"
-        # flag on: the untuned entry must not satisfy the tuning request
-        paddle.set_flags({"FLAGS_use_autotune": True})
-        try:
-            import time
-
-            def measure(c):
-                time.sleep(0.02 if c == "a" else 0.001)
-
-            assert autotune.pick("k4", (4,), ["a", "b"],
-                                 measure=measure) == "b"
-        finally:
-            paddle.set_flags({"FLAGS_use_autotune": False})
-
-    def test_failing_candidate_skipped(self):
-        from paddle_tpu.ops.pallas import autotune
-
-        autotune.autotune_cache_clear()
-        paddle.set_flags({"FLAGS_use_autotune": True})
-        try:
-            def measure(c):
-                if c == "bad":
-                    raise MemoryError("vmem")
-
-            assert autotune.pick("k3", (3,), ["bad", "ok"],
-                                 measure=measure) == "ok"
-        finally:
-            paddle.set_flags({"FLAGS_use_autotune": False})
-
-    def test_validate_screens_candidates_before_measure(self):
-        from paddle_tpu.ops.pallas import autotune
-
-        autotune.autotune_cache_clear()
-        measured = []
-        paddle.set_flags({"FLAGS_use_autotune": True})
-        try:
-            got = autotune.pick("k5", (5,), ["huge", "ok", "ok2"],
-                                measure=measured.append,
-                                validate=lambda c: c != "huge")
-            # the rejected candidate never reached measure (no compile)
-            assert got in ("ok", "ok2") and "huge" not in measured
-        finally:
-            paddle.set_flags({"FLAGS_use_autotune": False})
-
-    def test_validate_rejecting_all_keeps_original_list(self):
-        from paddle_tpu.ops.pallas import autotune
-
-        autotune.autotune_cache_clear()
-        # screen is advisory: rejecting everything must not error out
-        assert autotune.pick("k6", (6,), ["a", "b"],
-                             validate=lambda c: False) == "a"
-
-    def test_save_file_is_atomic(self, tmp_path, monkeypatch):
-        """Crash mid-dump must never corrupt an existing cache file
-        (truncate-then-write lost the whole cache before)."""
-        import json
-        import os
-
-        from paddle_tpu.ops.pallas import autotune
-
-        path = tmp_path / "cache.json"
-        monkeypatch.setenv("PADDLE_TPU_AUTOTUNE_CACHE", str(path))
-        autotune.autotune_cache_clear()
-        assert autotune.pick("k7", (7,), ["a"]) == "a"
-        good = json.loads(path.read_text())
-        assert good["k7|(7,)"] == ["a", False]
-
-        # poison the dump: the existing file must survive untouched
-        monkeypatch.setattr(autotune.json, "dump",
-                            lambda *a, **k: 1 / 0)
-        autotune.autotune_cache_clear()
-        autotune.pick("k8", (8,), ["b"])
-        assert json.loads(path.read_text()) == good
-        # and no temp-file litter next to the cache
-        leftovers = [f for f in os.listdir(tmp_path)
-                     if f != "cache.json"]
-        assert leftovers == []
-        monkeypatch.undo()
-        autotune.autotune_cache_clear()
-
-    def test_flash_attention_still_correct(self):
-        # interpret-mode pallas on CPU: autotuned block path must match XLA
-        from paddle_tpu.ops.pallas.attention_kernel import (
-            flash_attention_pallas,
-        )
-        import jax.numpy as jnp
-
-        rng = np.random.RandomState(0)
-        q = jnp.asarray(rng.rand(1, 128, 2, 16).astype(np.float32))
-        k = jnp.asarray(rng.rand(1, 128, 2, 16).astype(np.float32))
-        v = jnp.asarray(rng.rand(1, 128, 2, 16).astype(np.float32))
-        out = flash_attention_pallas(q, k, v, is_causal=True,
-                                     interpret=True)
-        # dense reference
-        scale = 1.0 / np.sqrt(16)
-        qt = np.transpose(q, (0, 2, 1, 3))
-        kt = np.transpose(k, (0, 2, 1, 3))
-        vt = np.transpose(v, (0, 2, 1, 3))
-        s = (qt @ np.transpose(kt, (0, 1, 3, 2))) * scale
-        mask = np.triu(np.full((128, 128), -1e30, np.float32), 1)
-        p = np.exp(s + mask - (s + mask).max(-1, keepdims=True))
-        p = p / p.sum(-1, keepdims=True)
-        ref = np.transpose(p @ vt, (0, 2, 1, 3))
-        np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)

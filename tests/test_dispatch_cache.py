@@ -138,15 +138,6 @@ class TestDispatchCache:
         assert dispatch.dispatch_cache_info()["entries"] == before
         dispatch.dispatch_cache_clear()
 
-    def test_autotune_flag_registered(self):
-        """Advisor round-2: FLAGS_use_autotune must be a registered flag so
-        the FLAGS_* env-var default path and get_flags work."""
-        from paddle_tpu.framework.flags import _FLAG_DEFS
-
-        assert "FLAGS_use_autotune" in _FLAG_DEFS
-        val = paddle.get_flags("FLAGS_use_autotune")["FLAGS_use_autotune"]
-        assert val in (True, False)
-
     def test_steady_state_speedup(self):
         """Cached grad-path dispatch must beat fresh jax.vjp tracing.
 

@@ -193,7 +193,8 @@ def test_the_dispatcher_reports_the_path_it_takes(monkeypatch):
     assert pk.eva_pairs_scored(64, DIM, window, chunk) == sum(
         eva.pairs_scored(64, window, chunk)) == 2 * 32 * 32 + 32 * 16
     assert pk.eva_pairs_scored(64, 256, window, chunk) == 64 * (64 + 16)
-    assert "supports" in pk._eva_refusal(64, 256, window, chunk)
+    assert "supports" in pk._refusal("eva_attention_kernel",
+                                    fits=(64, 256, window, chunk))
 
 
 @pytest.mark.parametrize("seq, window, chunk, blocks, ok", [

@@ -32,8 +32,7 @@ def _rand(shape, seed, dtype=jnp.float32):
 @pytest.fixture
 def small_blocks(monkeypatch):
     """64 x 64 blocks, so that a 256-token sequence has four."""
-    monkeypatch.setattr(ak, "_block_candidates",
-                        lambda seq_q, seq_k: [(BLOCK, BLOCK)])
+    monkeypatch.setattr(ak, "_blocks", lambda seq_q, seq_k: (BLOCK, BLOCK))
 
 
 def _out_and_grads(fn, q, k, v, do):
@@ -282,24 +281,21 @@ def test_the_compiled_steps_account_counts_its_flash_operands(
     monkeypatch.setattr(
         ak, "flash_attention_pallas",
         functools.partial(ak.flash_attention_pallas, interpret=True))
-    paddle.set_flags({"FLAGS_flash_min_seqlen": 0})
-    try:
-        paddle.seed(0)
-        model = laguna_tiny(
-            num_hidden_layers=2, num_attention_heads_per_layer=(2, 2),
-            layer_types=("full_attention", "sliding_attention"),
-            mlp_layer_types=("dense", "dense"), head_dim=width,
-            num_key_value_heads=1, hidden_size=32, intermediate_size=32,
-            vocab_size=64)
-        step = TrainStep(
-            model, lambda logits, labels: model.loss(logits, labels),
-            paddle.optimizer.AdamW(learning_rate=1e-3,
-                                   parameters=model.parameters()))
-        ids = paddle.to_tensor(np.random.RandomState(0).randint(
-            0, 64, (1, 64)).astype(np.int32))
-        step(ids, ids)
-    finally:
-        paddle.set_flags({"FLAGS_flash_min_seqlen": 1024})
+    monkeypatch.setattr(pk, "FLASH_MIN_SEQ", 0)
+    paddle.seed(0)
+    model = laguna_tiny(
+        num_hidden_layers=2, num_attention_heads_per_layer=(2, 2),
+        layer_types=("full_attention", "sliding_attention"),
+        mlp_layer_types=("dense", "dense"), head_dim=width,
+        num_key_value_heads=1, hidden_size=32, intermediate_size=32,
+        vocab_size=64)
+    step = TrainStep(
+        model, lambda logits, labels: model.loss(logits, labels),
+        paddle.optimizer.AdamW(learning_rate=1e-3,
+                               parameters=model.parameters()))
+    ids = paddle.to_tensor(np.random.RandomState(0).randint(
+        0, 64, (1, 64)).astype(np.int32))
+    step(ids, ids)
     account = step.compile_account()
     assert (account["flash_calls"], account["flash_operands_in_place"],
             account["flash_operands_copied"]) == (2, in_place, copied)

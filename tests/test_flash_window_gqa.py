@@ -53,8 +53,7 @@ def _qkv(group, kv_heads, dtype=jnp.float32, batch=2, seq=SEQ):
 @pytest.fixture
 def small_blocks(monkeypatch):
     """64 x 64 blocks, so that a 256-token sequence has four."""
-    monkeypatch.setattr(ak, "_block_candidates",
-                        lambda seq_q, seq_k: [(BLOCK, BLOCK)])
+    monkeypatch.setattr(ak, "_blocks", lambda seq_q, seq_k: (BLOCK, BLOCK))
 
 
 def _out_and_grads(fn, q, k, v, do):

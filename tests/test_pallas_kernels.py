@@ -25,7 +25,7 @@ def _rand(shape, seed, dtype=jnp.float32):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("shape", [(2, 128, 2, 64), (1, 256, 4, 32),
-                                   (1, 256, 2, 192, 128)])
+                                   (1, 128, 2, 16), (1, 256, 2, 192, 128)])
 def test_flash_attention_forward(shape, causal):
     """A fifth entry is v's width where it differs from q's and k's."""
     q, k = (_rand(shape[:4], s) for s in (0, 1))
@@ -182,7 +182,7 @@ def test_flash_attention_bf16_forward_and_grads(causal, head_dim, seq, blocks):
     shape = (2, seq, head_dim)
     q, k, v, do = (_rand(shape, s, jnp.bfloat16) for s in (30, 31, 32, 33))
     if blocks is None:
-        blocks = attention_kernel._block_candidates(seq, seq)[0]
+        blocks = attention_kernel._blocks(seq, seq)
         assert blocks == (64, 64)
     got = _flash_fwd_bwd(q, k, v, do, causal, *blocks)
     want_out, vjp = jax.vjp(
