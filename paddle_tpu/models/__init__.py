@@ -36,6 +36,12 @@ from .ouro import (  # noqa: F401
     ouro_2_6b,
     ouro_tiny,
 )
+from .sdar import (  # noqa: F401
+    SdarConfig,
+    SdarForBlockDiffusion,
+    sdar_30b_a3b,
+    sdar_tiny,
+)
 from .wide_deep import WideDeep  # noqa: F401
 from .deepfm import DeepFM  # noqa: F401
 from .deepspeech import DeepSpeech2, deepspeech2_tiny  # noqa: F401

@@ -35,11 +35,14 @@ What the source's config names and does not spell out is set by the
 convention of the family its keys come from (``chipbench/configs/laguna-
 s-2.1-train-l5-ep32.json`` ``assumed``): softmax scores, the headwise
 sigmoid gate of arXiv:2505.06708 on the head's output before ``W_o``,
-rotate-half and not interleaved, no q/k norm.
+rotate-half and not interleaved, no q/k norm.  (:class:`GroupedGatedAttention`
+has a q/k norm, position ids and a block-diffusion mask as options, off
+here: ``models/sdar.py`` turns them on and the gate off.)
 
 Scopes inside ``attn`` (``docs/PROFILER.md``): ``attn_window`` or
-``attn_full`` round the whole of a layer's attention, by its kind, and
-``attn_gate`` round the gate's matmul and product.  This file trains; it
+``attn_full`` (``attn_blockdiff`` under that mask) round the whole of a
+layer's attention, by its kind, ``attn_gate`` round the gate's matmul and
+product, ``qk_norm`` round the q/k norms where a model has them.  This file trains; it
 has no decode path.
 """
 
@@ -52,6 +55,7 @@ import numpy as np
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import functional as F
+from ..ops import pallas
 from ..ops.registry import op
 from .moe_decoder import MoeDecoderConfig, MoeDecoderForCausalLM, linear
 
@@ -95,9 +99,11 @@ def yarn_inv_freq(dim, base, factor, original_max, beta_fast, beta_slow):
     return f * (1.0 - m) / factor + f * m
 
 
-def rope_tables(head_dim, seq, params):
+def rope_tables(head_dim, seq, params, positions=None):
     """``(cos, sin) [seq, rot / 2]`` float32 and ``rot``, the leading dims
-    of a head that are rotated, from one entry of ``rope_parameters``."""
+    of a head that are rotated, from one entry of ``rope_parameters``;
+    ``positions [seq]``: each row's position id (``0 .. seq - 1`` unless
+    given)."""
     rot = int(head_dim * params.get("partial_rotary_factor", 1))
     base = float(params["rope_theta"])
     kind = params.get("rope_type", "default")
@@ -113,7 +119,9 @@ def rope_tables(head_dim, seq, params):
         scale = 1.0
     else:
         raise NotImplementedError(f"rope_type {kind!r}")
-    ang = np.outer(np.arange(seq, dtype=np.float64), inv)
+    if positions is None:
+        positions = np.arange(seq)
+    ang = np.outer(np.asarray(positions, np.float64).reshape(seq), inv)
     return ((np.cos(ang) * scale).astype(np.float32),
             (np.sin(ang) * scale).astype(np.float32), rot)
 
@@ -135,6 +143,17 @@ def _rotate_half(x, cos, sin):
 @op("partial_rope")
 def _rope(q, k, cos, sin):
     return _rotate_half(q, cos, sin), _rotate_half(k, cos, sin)
+
+
+@op("headwise_rms_norm")
+def _head_norm(x, weight, eps):
+    """``x [B, T, N, D]``: RMSNorm over a head's ``D`` dims times ``weight
+    [D]``, one gain vector for all heads; statistics and product in float32,
+    ``x``'s dtype out."""
+    a = x.astype(jnp.float32)
+    a = a * jax.lax.rsqrt(jnp.mean(jnp.square(a), axis=-1, keepdims=True)
+                          + eps)
+    return (a * weight.astype(jnp.float32)).astype(x.dtype)
 
 
 @op("headwise_gate")
@@ -211,7 +230,14 @@ class LagunaConfig(MoeDecoderConfig):
         self.expert_offset = expert_offset
 
     def make_attention(self, layer_idx):
-        return GroupedGatedAttention(self, layer_idx)
+        kind = self.layer_types[layer_idx]
+        if kind not in (FULL, WINDOW):
+            raise ValueError(f"layer type {kind!r}")
+        return GroupedGatedAttention(
+            self.hidden_size, self.num_attention_heads_per_layer[layer_idx],
+            self.num_key_value_heads, self.head_dim,
+            self.rope_parameters[kind], self.initializer_range, self.out_std,
+            window=self.sliding_window if kind == WINDOW else None)
 
     def make_ffn(self, layer_idx):
         if self.mlp_layer_types[layer_idx] == "dense":
@@ -228,56 +254,92 @@ class LagunaConfig(MoeDecoderConfig):
 
 
 class GroupedGatedAttention(nn.Layer):
-    """One layer's attention: its kind (window or full), its own q head
-    count over the shared kv heads, its rotary table, and the per-head
-    sigmoid gate on the heads' outputs."""
+    """One layer's attention over grouped kv heads: its mask (causal,
+    causal under a sliding ``window``, or ``block_diffusion``), its own q
+    head count over the shared kv heads, its rotary table, and the per-head
+    sigmoid gate on the heads' outputs.  Three options, as the families
+    need them (``models/sdar.py`` sets all three; off, the layer is
+    laguna's):
 
-    def __init__(self, config, layer_idx):
+    - ``gate=False``: no ``g_proj``, the heads' outputs go on as they are;
+    - ``qk_norm_eps``: q and k are RMS-normed head by head before the
+      rotation, each under ONE gain ``[head_dim]`` shared by the layer's
+      heads (``q_norm``, ``k_norm``; scope ``qk_norm``);
+    - ``block_diffusion`` ``B``: the row is ``[noised ; clean]``, two copies
+      of ``L = T / 2`` positions whose position ids both count ``0 .. L -
+      1``, under the block-diffusion mask (``ops.pallas
+      .block_diffusion_mask``) and not the causal one; after a forward
+      ``pairs`` is ``(scored, needed)``, the (query, key) pairs one row and
+      head's scores were formed for, by the path taken, and the pairs the
+      mask holds.
+
+    The whole of the layer's attention runs under a scope that says its
+    mask: ``attn_window``, ``attn_blockdiff`` or ``attn_full``."""
+
+    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
+                 rope_params, std, out_std, window=None, gate=True,
+                 qk_norm_eps=None, block_diffusion=None):
         super().__init__()
-        c = config
-        self.kind = c.layer_types[layer_idx]
-        if self.kind not in (FULL, WINDOW):
-            raise ValueError(f"layer type {self.kind!r}")
-        self.num_heads = c.num_attention_heads_per_layer[layer_idx]
-        self.num_kv_heads, self.head_dim = c.num_key_value_heads, c.head_dim
-        if self.num_heads % self.num_kv_heads:
-            raise ValueError(f"{self.num_heads} q heads over "
-                             f"{self.num_kv_heads} kv heads")
-        self.window = c.sliding_window if self.kind == WINDOW else None
-        self._rope_params = c.rope_parameters[self.kind]
+        if num_heads % num_kv_heads:
+            raise ValueError(f"{num_heads} q heads over {num_kv_heads} kv "
+                             f"heads")
+        if window is not None and block_diffusion is not None:
+            raise ValueError("a window and a block-diffusion mask exclude "
+                             "each other")
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim, self.window = head_dim, window
+        self.block_diffusion, self.qk_norm_eps = block_diffusion, qk_norm_eps
+        self.pairs = None
+        self._rope_params = rope_params
         self._tables = {}
-        n, kv, d, h = (self.num_heads, self.num_kv_heads, self.head_dim,
-                       c.hidden_size)
-        std = c.initializer_range
+        n, kv, d, h = num_heads, num_kv_heads, head_dim, hidden_size
         self.q_proj = linear(h, n * d, std)
         self.k_proj = linear(h, kv * d, std)
         self.v_proj = linear(h, kv * d, std)
-        self.g_proj = linear(h, n, std)
-        self.o_proj = linear(n * d, h, c.out_std)
+        self.g_proj = linear(h, n, std) if gate else None
+        self.o_proj = linear(n * d, h, out_std)
+        self.q_norm = self.k_norm = None
+        if qk_norm_eps is not None:
+            self.q_norm = nn.RMSNorm(d, epsilon=qk_norm_eps)
+            self.k_norm = nn.RMSNorm(d, epsilon=qk_norm_eps)
 
-    def rope(self, seq):
-        """cos, sin ``[seq, rot / 2]`` for positions 0 .. seq - 1 (host
-        arrays: a step that is traced bakes them in as constants)."""
-        if seq not in self._tables:
-            self._tables[seq] = rope_tables(self.head_dim, seq,
-                                            self._rope_params)[:2]
-        return self._tables[seq]
+    def rope(self, seq, positions=None):
+        """cos, sin ``[seq, rot / 2]`` for the position ids ``positions
+        [seq]`` (host integers; ``0 .. seq - 1`` unless given).  Host
+        arrays: a step that is traced bakes them in as constants."""
+        key = seq if positions is None else (seq, bytes(positions))
+        if key not in self._tables:
+            self._tables[key] = rope_tables(self.head_dim, seq,
+                                            self._rope_params, positions)[:2]
+        return self._tables[key]
 
     def forward(self, x):
         b, t, _ = x.shape
         n, kv, d = self.num_heads, self.num_kv_heads, self.head_dim
-        scope = "attn_window" if self.kind == WINDOW else "attn_full"
+        block = self.block_diffusion
+        scope = "attn_window" if self.window is not None else \
+            "attn_full" if block is None else "attn_blockdiff"
         with jax.named_scope(scope):
             q = self.q_proj(x).reshape([b, t, n, d])
             k = self.k_proj(x).reshape([b, t, kv, d])
             v = self.v_proj(x).reshape([b, t, kv, d])
-            cos, sin = self.rope(t)
+            if self.q_norm is not None:
+                with jax.named_scope("qk_norm"):
+                    q = _head_norm(q, self.q_norm.weight, self.qk_norm_eps)
+                    k = _head_norm(k, self.k_norm.weight, self.qk_norm_eps)
+            # both halves of a block-diffusion row count their own positions
+            cos, sin = self.rope(t, None if block is None else np.tile(
+                np.arange(t // 2, dtype=np.int32), 2))
             q, k = _rope(q, k, Tensor(jnp.asarray(cos)),
                          Tensor(jnp.asarray(sin)))
-            out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
-                                                 window=self.window)
-            with jax.named_scope("attn_gate"):
-                out = _gate(out, self.g_proj(x))
+            out = F.scaled_dot_product_attention(
+                q, k, v, is_causal=block is None, window=self.window,
+                block_diffusion=block)
+            if block is not None:
+                self.pairs = pallas.blockdiff_pairs(t, d, n, kv, block)
+            if self.g_proj is not None:
+                with jax.named_scope("attn_gate"):
+                    out = _gate(out, self.g_proj(x))
             return self.o_proj(out.reshape([b, t, n * d]))
 
 

@@ -1129,14 +1129,17 @@ def ssd_scan(x, dt, A, B, C, D, chunk=128):
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False,
-                                 training=True, window=None):
+                                 training=True, window=None,
+                                 block_diffusion=None):
     """SDPA on [batch, seq, heads, dim] (paddle layout,
     python/paddle/nn/functional/flash_attention.py:125).  Uses the Pallas
     flash kernel on TPU when available, else XLA attention.  Attention
     dropout draws from the active key stream.  ``key`` and ``value`` may
     have fewer heads than ``query`` (a whole divisor: grouped KV heads,
     never expanded); ``window`` (with ``is_causal``): a query sees itself
-    and the ``window - 1`` keys before it."""
+    and the ``window - 1`` keys before it; ``block_diffusion`` (without
+    it): the row is ``[noised ; clean]`` under the block-diffusion mask of
+    that block (``ops.pallas.block_diffusion_mask``)."""
     from ..ops import pallas
     use_drop = dropout_p > 0.0 and training
     drop_key = get_rng_key() if use_drop else None
@@ -1146,7 +1149,7 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         return pallas.flash_attention(
             query, key, value, attn_mask=attn_mask, is_causal=is_causal,
             dropout_p=dropout_p if use_drop else 0.0, dropout_key=drop_key,
-            window=window)
+            window=window, block_diffusion=block_diffusion)
 
     return _sdpa(query, key, value, attn_mask)
 

@@ -169,8 +169,24 @@ def traced_call_sums():
     return dict(common.traced_sums)
 
 
+def block_diffusion_mask(seq, block):
+    """bool ``[seq, seq]``: whether query ``i`` sees key ``j`` of a row
+    ``[noised ; clean]`` of two copies of ``L = seq / 2`` positions, with
+    ``blk(i) = (i mod L) // block``: noised sees noised of its OWN block,
+    noised sees clean of EARLIER blocks, clean never sees noised, clean sees
+    clean of its own block and earlier ones (the vectorised training of
+    block diffusion, arXiv:2503.09573 section 3)."""
+    half = seq // 2
+    pos = jnp.arange(seq)
+    noised, blk = pos < half, (pos % half) // block
+    qn, kn, qb, kb = noised[:, None], noised[None, :], blk[:, None], \
+        blk[None, :]
+    return jnp.where(qn, jnp.where(kn, qb == kb, kb < qb), ~kn & (kb <= qb))
+
+
 def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
-                   dropout_key=None, scale=None, window=None):
+                   dropout_key=None, scale=None, window=None,
+                   block_diffusion=None):
     """Reference XLA attention on [B, T, N, H] (paddle flash-attn layout).
 
     Matmuls stay in the input dtype (bf16 on the MXU) with f32 accumulation
@@ -180,7 +196,9 @@ def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
 
     The same function as the flash kernels compute: k and v may have
     fewer heads than q (q head ``n`` reads kv head ``n // group``), and
-    ``window`` (causal only) keeps the keys ``0 <= t - j < window``.
+    ``window`` (causal only) keeps the keys ``0 <= t - j < window``;
+    ``block_diffusion`` (not causal) keeps :func:`block_diffusion_mask`'s,
+    materialised ``[seq, seq]``.
     """
     if scale is None:
         scale = 1.0 / jnp.sqrt(jnp.asarray(q.shape[-1], dtype=jnp.float32))
@@ -203,6 +221,12 @@ def _xla_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
         logits = jnp.where(causal, logits, jnp.finfo(jnp.float32).min)
     elif window is not None:
         raise ValueError("a window needs is_causal")
+    if block_diffusion is not None:
+        if is_causal or q.shape[1] != k.shape[1] or q.shape[1] % 2:
+            raise ValueError("a block-diffusion mask is its own mask, over "
+                             "a self-attention row of two halves")
+        logits = jnp.where(block_diffusion_mask(q.shape[1], block_diffusion),
+                           logits, jnp.finfo(jnp.float32).min)
     if attn_mask is not None:
         if attn_mask.dtype == jnp.bool_:
             logits = jnp.where(attn_mask, logits, jnp.finfo(jnp.float32).min)
@@ -260,7 +284,7 @@ def _sharded_refusal(q, mesh, kv_heads=None):
 
 
 def flash_attention_sharded(q, k, v, is_causal, mesh, interpret=False,
-                            window=None):
+                            window=None, block_diffusion=None):
     """The flash kernels where GSPMD partitions the step: a ``shard_map``
     that makes EVERY axis of ``mesh`` manual (what a Mosaic kernel needs),
     each shard running the kernels on its rows and heads.  Attention mixes
@@ -275,15 +299,33 @@ def flash_attention_sharded(q, k, v, is_causal, mesh, interpret=False,
     batch_axes, head_axis = _mesh_split(mesh)
     spec = P(batch_axes or None, None, head_axis, None)
     return jax.shard_map(
-        lambda q, k, v: flash_attention_pallas(q, k, v, is_causal,
-                                               interpret=interpret,
-                                               window=window),
+        lambda q, k, v: flash_attention_pallas(
+            q, k, v, is_causal, interpret=interpret, window=window,
+            block_diffusion=block_diffusion),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(q, k, v)
 
 
+def _flash_unfit(seq_q, seq_k, is_causal, masked_or_scaled=False):
+    """Why a flash call takes the composition before its shapes are asked
+    (:func:`_refusal`'s ``unfit``), or None."""
+    if seq_q < FLASH_MIN_SEQ:
+        return BY_CHOICE
+    if masked_or_scaled:
+        return ("the kernel takes no attn_mask, dropout or scale "
+                "(a causal sliding window it takes as window=, a "
+                "block-diffusion mask as block_diffusion=)")
+    if is_causal and seq_q != seq_k:
+        # causal masking in the kernel is top-left aligned; for seq_q !=
+        # seq_k the paddle/XLA semantics are bottom-right aligned, so only
+        # self-attention-shaped causal inputs take the kernel path
+        return "causal with seq_q != seq_k"
+    return None
+
+
 def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
-                    dropout_key=None, scale=None, window=None):
+                    dropout_key=None, scale=None, window=None,
+                    block_diffusion=None):
     """Flash attention on [batch, seq, num_heads, head_dim].
 
     k and v may have fewer heads than q, a whole divisor (grouped KV
@@ -302,33 +344,30 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
     the kernels run inside :func:`flash_attention_sharded`.
 
     The masks the kernels take: none, causal (top-left aligned, so
-    self-attention-shaped inputs only), and causal under a SLIDING window;
+    self-attention-shaped inputs only), causal under a SLIDING window, and
+    ``block_diffusion=B`` (not causal: the row is ``[noised ; clean]``,
+    :func:`block_diffusion_mask`; no ``[seq, seq]`` array crosses HBM);
     an ``attn_mask``, dropout or a ``scale`` takes the XLA composition,
-    aloud.  A window that is a BLOCK (query ``t`` sees the keys of ``t //
-    W`` up to itself) with pooled chunk summaries of the earlier windows
-    under the same softmax is not a mask of this function: it has two
-    kinds of key, its own kernels and its own dispatcher,
-    :func:`eva_attention`."""
+    aloud.  Segment ids (packed rows) are none of them.  A window that is
+    a BLOCK (query ``t`` sees the keys of ``t // W`` up to itself) with
+    pooled chunk summaries of the earlier windows under the same softmax is
+    not a mask of this function: it has two kinds of key, its own kernels
+    and its own dispatcher, :func:`eva_attention`."""
     from ...distributed.fleet.spmd import current_mesh
 
     if dropout_p > 0.0 and dropout_key is None:
         from ...framework.random import get_rng_key
         dropout_key = get_rng_key()
-    unfit = None
-    if q.shape[1] < FLASH_MIN_SEQ:
-        unfit = BY_CHOICE
-    elif attn_mask is not None or dropout_p > 0.0 or scale is not None:
-        unfit = ("the kernel takes no attn_mask, dropout or scale "
-                 "(a causal sliding window it takes as window=)")
-    elif is_causal and q.shape[1] != k.shape[1]:
-        # causal masking in the kernel is top-left aligned; for seq_q !=
-        # seq_k the paddle/XLA semantics are bottom-right aligned, so only
-        # self-attention-shaped causal inputs take the kernel path
-        unfit = "causal with seq_q != seq_k"
+    unfit = _flash_unfit(
+        q.shape[1], k.shape[1], is_causal,
+        attn_mask is not None or dropout_p > 0.0 or scale is not None)
 
     def launch(kernels):
-        # a call without a window is the call it was
-        kw = {} if window is None else {"window": window}
+        # a call without a window or a block-diffusion mask is the call it
+        # was
+        kw = {name: value for name, value in (
+            ("window", window), ("block_diffusion", block_diffusion))
+            if value is not None}
         if _partitioned_by_gspmd():
             return flash_attention_sharded(q, k, v, is_causal,
                                            current_mesh(), **kw)
@@ -340,14 +379,33 @@ def flash_attention(q, k, v, attn_mask=None, is_causal=False, dropout_p=0.0,
         lambda: _xla_attention(q, k, v, attn_mask=attn_mask,
                                is_causal=is_causal, dropout_p=dropout_p,
                                dropout_key=dropout_key, scale=scale,
-                               window=window),
+                               window=window,
+                               block_diffusion=block_diffusion),
         unfit=unfit,
         # sequence and head widths are whole in every shard, so this
         # answers for the sharded launch too
         fits=(q.shape[1], k.shape[1], q.shape[3], v.shape[3], q.shape[2],
-              k.shape[2], window, is_causal),
+              k.shape[2], window, is_causal, block_diffusion),
         sharded_refusal=lambda: _sharded_refusal(q, current_mesh(),
                                                  k.shape[2]))
+
+
+def blockdiff_pairs(seq, head_dim, q_heads, kv_heads, block):
+    """``(scored, needed)``: the (query, key) pairs :func:`flash_attention`
+    forms scores for over one ``[noised ; clean]`` row of ``seq`` positions
+    and one head under ``block_diffusion=block``, by the path it takes
+    where this is asked (the same trace, the same rule) -- the kernels'
+    grids, or the composition's whole ``seq x seq`` matrix, so that a
+    fallback shows in the count -- and the pairs the mask holds."""
+    from . import attention_kernel as flash
+
+    half = seq // 2
+    on_kernels = _refusal(
+        "attention_kernel", unfit=_flash_unfit(seq, seq, False),
+        fits=(seq, seq, head_dim, head_dim, q_heads, kv_heads, None, False,
+              block)) is None
+    return (flash.blockdiff_pairs_scored(half, block) if on_kernels
+            else seq * seq), flash.blockdiff_pairs_needed(half, block)
 
 
 def _xla_eva_attention(q, k, v, kt, vt, window, chunk):

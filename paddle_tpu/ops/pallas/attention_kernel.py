@@ -60,12 +60,25 @@ t - j < window``.  A q block visits only the key blocks its rows can see
 see it, ``_query_blocks``), so the work follows the window and not the
 sequence; the mask is applied in every visited block, as the causal one is.
 
+A ``block_diffusion`` mask of block ``B`` (neither causal nor a window):
+the row is ``[noised ; clean]``, two copies of ``L = seq / 2`` positions,
+and with ``blk(i) = (i mod L) // B`` a noised query sees the noised keys of
+its OWN block and the clean keys of EARLIER blocks, a clean query the clean
+keys of its own block and of earlier ones, nobody a noised key of another
+block (:func:`_mask_block_diffusion`).  A q block visits TWO ranges of key
+blocks (noised rows: the noised blocks on their own diagonal, then the
+clean blocks up to it; clean rows: one range), a k block two ranges of q
+blocks (``_blockdiff_key_blocks``, ``_blockdiff_query_blocks``); the
+staircase is applied in every visited block.
+
 Names: ``flash_attention_fwd`` and ``flash_attention_bwd_dq_dkv``
-(``flash_window<W>_attention_*`` under a window, so that a trace tells the
-two apart).  The benchmark counts a backward call as an event whose name
-holds ``_bwd_dq``, a forward call as one that holds ``_fwd``, and charges
-the kernels the time of every event whose name holds ``flash_attention`` /
-``flash_window``: a call this file adds keeps that true.
+(``flash_window<W>_attention_*`` under a window and
+``flash_blockdiff<B>_attention_*`` under a block-diffusion mask, so that a
+trace tells the three apart).  The benchmark counts a backward call as an
+event whose name holds ``_bwd_dq``, a forward call as one that holds
+``_fwd``, and charges the kernels the time of every event whose name holds
+``flash_attention`` / ``flash_window`` / ``flash_blockdiff``: a call this
+file adds keeps that true.
 
 Without a window and with equal head counts the forward kernel is the
 program it was: Mosaic is handed the same module, source locations aside
@@ -96,11 +109,14 @@ _SPLIT_HEADS = {(192, 128)}
 
 
 def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
-             kv_heads=None, window=None, causal=True):
+             kv_heads=None, window=None, causal=True, block_diffusion=None):
     """``head_dim``: q's and k's width; ``v_head_dim``: v's (else the same).
     ``q_heads`` over ``kv_heads``: any whole multiple.  ``window`` (SLIDING;
     a BLOCK window with summaries of the windows before it is asked of
     ``eva_attention_kernel.supports``): >= 1, causal, q and k of one length.
+    ``block_diffusion`` ``B``: neither causal nor a window, q and k of one
+    even length ``2 L``, ``L`` a whole number of q blocks, and ``B`` dividing
+    the blocks (so a power of two).
 
     How long a sequence Mosaic takes is the forward's to say, which holds K
     and V whole: 8192 in bf16 and 4096 in float32 at one width (its default
@@ -116,7 +132,13 @@ def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
         heads_ok = heads_ok and kv_heads > 0 and q_heads % kv_heads == 0
     if window is not None:
         heads_ok = heads_ok and causal and window >= 1 and seq_q == seq_k
-    return heads_ok and None not in _blocks(seq_q, seq_k)
+    blocks = _blocks(seq_q, seq_k)
+    if block_diffusion is not None and None not in blocks:
+        heads_ok = (heads_ok and not causal and window is None
+                    and block_diffusion >= 1 and seq_q == seq_k
+                    and seq_q % (2 * blocks[0]) == 0
+                    and not any(b % block_diffusion for b in blocks))
+    return heads_ok and None not in blocks
 
 
 def _compiler_params(head_qk, head_v):
@@ -178,7 +200,88 @@ def _query_blocks(ki, block_q, block_k, num_qb, causal, window=None):
     return first, jnp.minimum(last_row // block_q + 1, num_qb)
 
 
-def _kernel_name(kernel, window):
+def _blockdiff_key_blocks(qi, block_q, block_k, half, block):
+    """``((a0, a1), (b0, b1))``: the two ranges of key blocks q block ``qi``
+    attends to under the block-diffusion mask (``half`` = ``L``, a whole
+    number of q blocks; ``block`` = ``B`` divides ``block_q``).  Noised rows
+    ``[r0, r1)``: the noised keys ``[r0, r1)`` (their own diffusion blocks),
+    then the clean keys ``L + [0, r1 - B)`` (the blocks before the last
+    row's).  Clean rows: the clean keys up to their own block's end, ``L +
+    [0, r1 - L)``, and an empty second range.  The ranges never share a
+    block, also where a key block straddles ``L``."""
+    r0 = qi * block_q
+    r1 = r0 + block_q
+    noised = r0 < half
+    up = lambda x: (x + block_k - 1) // block_k             # noqa: E731
+    a0 = jnp.where(noised, r0 // block_k, half // block_k)
+    a1 = up(r1)
+    b0 = jnp.where(noised, jnp.maximum(half // block_k, a1), a1)
+    b1 = jnp.where(noised & (r1 > block),
+                   jnp.maximum(up(half + r1 - block), b0), b0)
+    return (a0, a1), (b0, b1)
+
+
+def _blockdiff_query_blocks(ki, block_q, block_k, num_qb, half, block):
+    """``((n0, n1), (m0, m1))``: the q blocks that see key block ``ki``,
+    the transpose of :func:`_blockdiff_key_blocks`: among the noised q
+    blocks those of the block's noised keys' own diffusion blocks and, for
+    its clean keys ``L + [p, ...)``, those from the diffusion block after
+    ``p``'s on; among the clean q blocks those from ``p``'s diffusion block
+    on."""
+    c0 = ki * block_k
+    c1 = c0 + block_k
+    has_noised, has_clean = c0 < half, c1 > half
+    half_qb = half // block_q
+    own0 = c0 // block_q
+    own1 = (jnp.minimum(c1, half) + block_q - 1) // block_q
+    p = (jnp.maximum(c0, half) - half) // block * block
+    later0 = jnp.minimum((p + block) // block_q, half_qb)
+    n0 = jnp.where(has_noised,
+                   jnp.where(has_clean, jnp.minimum(own0, later0), own0),
+                   later0)
+    n1 = jnp.where(has_clean, half_qb, own1)
+    m0 = jnp.where(has_clean, (half + p) // block_q, num_qb)
+    return (n0, n1), (m0, num_qb)
+
+
+def _over_two_ranges(ranges, body, init):
+    """``fori_loop`` of ``body(block index, carry)`` over the first range,
+    then the second."""
+    (a0, a1), (b0, b1) = ranges
+    first = a1 - a0
+
+    def step(t, carry):
+        return body(jnp.where(t < first, a0 + t, b0 + (t - first)), carry)
+
+    return jax.lax.fori_loop(0, first + (b1 - b0), step, init)
+
+
+def _mask_block_diffusion(s, row0, col0, row_axis, half, block):
+    """Keep ``s`` where query ``i = row0 + row`` sees key ``j = col0 + col``
+    under the block-diffusion mask: a CLEAN key (``j >= half``) of block
+    ``blk(j)`` by the rows with ``blk(j) < blk(i) + [i is clean]``, a NOISED
+    key by the noised rows with ``blk(i) == blk(j)``.  ``block`` is a power
+    of two; the row and the column terms are formed on a column and a row
+    vector and meet in two compares of the whole tile."""
+    shift = block.bit_length() - 1
+
+    def half_and_block(start, axis):
+        shape = (s.shape[0], 1) if axis == 0 else (1, s.shape[1])
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+        noised = pos < half
+        return noised, jnp.where(noised, pos, pos - half) >> shift
+
+    r_noised, rb = half_and_block(row0, row_axis)
+    c_noised, cb = half_and_block(col0, 1 - row_axis)
+    keep = (jnp.where(c_noised, jnp.int32(2 ** 30), cb)
+            < rb + jnp.where(r_noised, 0, 1)) \
+        | (jnp.where(c_noised, cb, -1) == jnp.where(r_noised, rb, -2))
+    return jnp.where(keep, s, _NEG_INF)
+
+
+def _kernel_name(kernel, window, block_diffusion=None):
+    if block_diffusion is not None:
+        return f"flash_blockdiff{block_diffusion}_attention_{kernel}"
     return (f"flash_attention_{kernel}" if window is None
             else f"flash_window{window}_attention_{kernel}")
 
@@ -278,11 +381,12 @@ def _head_spec(x, batch, heads, rows, at):
 # ---------------------------------------------------------------- forward --
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
-                scale, window=None):
+                scale, window=None, block_diffusion=None):
     """One (batch*head, q-block) program: online softmax over key blocks."""
     q = q_ref[0]                                       # [Bq, H]
     block_q = q.shape[0]
     qi = pl.program_id(1)
+    half = k_ref.shape[1] // 2          # read under ``block_diffusion`` only
 
     def body(j, carry):
         o_acc, m, l = carry
@@ -291,6 +395,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
         s = _dot(q, k, _NT) * scale                    # [Bq, Bk]
         if causal:
             s = _mask_below_diagonal(s, qi * block_q, j * block_k, 0, window)
+        elif block_diffusion is not None:
+            s = _mask_block_diffusion(s, qi * block_q, j * block_k, 0,
+                                      half, block_diffusion)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
@@ -301,9 +408,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
     o0 = jnp.zeros((block_q, v_ref.shape[2]), jnp.float32)
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    first, end = _key_blocks(qi, block_q, block_k, k_ref.shape[1], causal,
-                             window)
-    o_acc, m, l = jax.lax.fori_loop(first, end, body, (o0, m0, l0))
+    if block_diffusion is None:
+        first, end = _key_blocks(qi, block_q, block_k, k_ref.shape[1],
+                                 causal, window)
+        o_acc, m, l = jax.lax.fori_loop(first, end, body, (o0, m0, l0))
+    else:
+        o_acc, m, l = _over_two_ranges(
+            _blockdiff_key_blocks(qi, block_q, block_k, half,
+                                  block_diffusion), body, (o0, m0, l0))
     l = jnp.maximum(l, 1e-30)
     o_ref[0] = (o_acc / l).astype(o_ref.dtype)
     # lse leaves as the ROW [1, block_q] of the head's [seq / block_q,
@@ -315,7 +427,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, causal,
 
 
 def _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k, interpret,
-               window=None):
+               window=None, block_diffusion=None):
     """q, k, v in their kernel layouts (``_to_kernel``), ``dims = (batch,
     q heads, kv heads)``.  Returns O in the layout of its width and the
     log-sum-exp as rows ``[batch * q heads, seq_q / block_q, block_q]``."""
@@ -330,10 +442,19 @@ def _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k, interpret,
     # innermost there, and q's are ``group`` times kv's), whole
     kv_head = lambda b, i: (b // group, 0)                   # noqa: E731
     num_qb = seq_q // block_q
+    params = _compiler_params(head, head_v)
+    if block_diffusion is not None:
+        # K and V of BOTH halves are held whole, double-buffered: 16 MB at
+        # [16384, 128] bf16, which is Mosaic's whole default allowance
+        held = 2 * (_vmem_bytes((1, seq_k, head), k.dtype)
+                    + _vmem_bytes((1, seq_k, head_v), v.dtype))
+        params = {"compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=held + 16 * 1024 * 1024)}
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, block_k=block_k, causal=causal,
-                          scale=scale, window=window),
-        name=_kernel_name("fwd", window),
+                          scale=scale, window=window,
+                          block_diffusion=block_diffusion),
+        name=_kernel_name("fwd", window, block_diffusion),
         grid=(bn, num_qb),
         in_specs=[
             _head_spec(q, batch, heads, block_q, q_block),
@@ -349,7 +470,7 @@ def _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((bn, num_qb, block_q), jnp.float32),
         ],
         interpret=interpret,
-        **_compiler_params(head, head_v),
+        **params,
     )(q, k, v)
     return out, lse
 
@@ -358,7 +479,7 @@ def _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k, interpret,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_acc, *kv_acc, block_q, causal,
-                scale, window=None, group=1):
+                scale, window=None, group=1, block_diffusion=None):
     """One (kv head, q head of its group, k block) program over the q
     blocks that see the k block: S, the mask, P and dP are formed ONCE a
     block pair and all three gradients take their part from them.
@@ -407,6 +528,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if causal:
             st = _mask_below_diagonal(st, i * block_q, ki * block_k, 1,
                                       window)
+        elif block_diffusion is not None:
+            st = _mask_block_diffusion(st, i * block_q, ki * block_k, 1,
+                                       q_ref.shape[1] // 2, block_diffusion)
         pt = jnp.exp(st - lse)
         dv_new = dv_acc + _dot(pt.astype(do.dtype), do, _NN)
         dst = (pt * (_dot(v, do, _NT) - delta)).astype(q.dtype)
@@ -417,8 +541,15 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = dk0 if v.shape == k.shape else jnp.zeros(v.shape, jnp.float32)
     # q blocks before this k block's diagonal contribute nothing
-    first, end = _query_blocks(ki, block_q, block_k, num_qb, causal, window)
-    dk, dv = jax.lax.fori_loop(first, end, body, (dk0, dv0))
+    if block_diffusion is None:
+        first, end = _query_blocks(ki, block_q, block_k, num_qb, causal,
+                                   window)
+        dk, dv = jax.lax.fori_loop(first, end, body, (dk0, dv0))
+    else:
+        dk, dv = _over_two_ranges(
+            _blockdiff_query_blocks(ki, block_q, block_k, num_qb,
+                                    q_ref.shape[1] // 2, block_diffusion),
+            body, (dk0, dv0))
 
     # dS = P * (dP - delta) * scale: the scale goes on once, at the end
     @pl.when(ki == pl.num_programs(2) - 1)
@@ -494,7 +625,7 @@ def _bwd_blocks(group, seq_q, seq_k, head, head_v, block_q, block_k, dtype):
 
 
 def _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q, block_k,
-               interpret, window=None):
+               interpret, window=None, block_diffusion=None):
     """Operands in their kernel layouts (``out`` and ``do`` in the layout
     of v's width), ``lse`` as the forward left it, ``dims = (batch, q
     heads, kv heads)``; dQ, dK, dV come back in q's, k's and v's layouts."""
@@ -530,8 +661,9 @@ def _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q, block_k,
     of_heads = (heads, kv_heads, kv_heads, heads, heads, heads)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, block_q=block_q, causal=causal,
-                          scale=scale, window=window, group=group),
-        name=_kernel_name("bwd_dq_dkv", window),
+                          scale=scale, window=window, group=group,
+                          block_diffusion=block_diffusion),
+        name=_kernel_name("bwd_dq_dkv", window, block_diffusion),
         grid=(batch * kv_heads, group, seq_k // block_k),
         in_specs=[_head_spec(x, batch, n, s[1], at)
                   for x, n, (s, _, at) in zip(operands, of_heads, ins)],
@@ -549,13 +681,14 @@ def _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q, block_k,
 
 # ------------------------------------------------------------- public API --
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_attention_kernels(q, k, v, dims, causal, scale, interpret,
-                             window=None):
+                             window=None, block_diffusion=None):
     """q, k, v and the result in their kernel layouts (``_to_kernel``),
     which are the residuals too: an operand that had to be copied is
     copied once, as it always was."""
-    out, _ = _fwd_rule(q, k, v, dims, causal, scale, interpret, window)
+    out, _ = _fwd_rule(q, k, v, dims, causal, scale, interpret, window,
+                       block_diffusion)
     return out
 
 
@@ -603,6 +736,34 @@ def _blocks(seq_q, seq_k):
     return pick_block(seq_q, 512), pick_block(seq_k, 512)
 
 
+def blockdiff_pairs_needed(half, block):
+    """The (query, key) pairs one row and head holds under the
+    block-diffusion mask: a noised query its own block and the blocks
+    before it, ``B + blk B``, a clean query its own and those before it,
+    the same count: ``L (L + B)`` in all."""
+    return half * (half + block)
+
+
+@functools.lru_cache(maxsize=None)
+def _blockdiff_tiles_visited(half, block, block_q, block_k):
+    """The (q block, key block) pairs the forward's grid visits: asked once
+    a layer while a step is traced, so reckoned once a shape."""
+    with jax.ensure_compile_time_eval():
+        return sum(
+            int((a1 - a0) + (b1 - b0)) for (a0, a1), (b0, b1) in (
+                _blockdiff_key_blocks(qi, block_q, block_k, half, block)
+                for qi in range(2 * half // block_q)))
+
+
+def blockdiff_pairs_scored(half, block):
+    """The pairs the forward's grid forms scores for over one row and head
+    (the backward's grid visits the same block pairs, transposed): the key
+    blocks every q block visits, whole."""
+    block_q, block_k = _blocks(2 * half, 2 * half)
+    return _blockdiff_tiles_visited(half, block, block_q, block_k) \
+        * block_q * block_k
+
+
 # the forward's own results among the residuals, by the names a
 # rematerialisation policy can keep (``jax.checkpoint_policies
 # .save_only_these_names``): with both saved, the recomputed forward of a
@@ -610,20 +771,22 @@ def _blocks(seq_q, seq_k):
 SAVED_BY_NAME = ("flash_attention_out", "flash_attention_lse")
 
 
-def _fwd_rule(q, k, v, dims, causal, scale, interpret, window=None):
+def _fwd_rule(q, k, v, dims, causal, scale, interpret, window=None,
+              block_diffusion=None):
     block_q, block_k = _blocks(q.shape[1], k.shape[1])
     out, lse = _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k,
-                          interpret, window)
+                          interpret, window, block_diffusion)
     out = checkpoint_name(out, SAVED_BY_NAME[0])
     lse = checkpoint_name(lse, SAVED_BY_NAME[1])
     return out, (q, k, v, out, lse)
 
 
-def _bwd_rule(dims, causal, scale, interpret, window, res, do):
+def _bwd_rule(dims, causal, scale, interpret, window, block_diffusion, res,
+              do):
     q, k, v, out, lse = res
     block_q, block_k = _blocks(q.shape[1], k.shape[1])
     return _flash_bwd(q, k, v, out, lse, do, dims, causal, scale, block_q,
-                      block_k, interpret, window)
+                      block_k, interpret, window, block_diffusion)
 
 
 _flash_attention_kernels.defvjp(_fwd_rule, _bwd_rule)
@@ -666,6 +829,17 @@ def _engine_cases(engine):
 
     yield registry.KernelCase(f"vjp_window[s{seq},w{window},{n}over1]",
                               vjp_window, (x, one_kv, one_kv), None)
+    # a block-diffusion mask of 4 over the same grouped heads, the row the
+    # context's two halves: both loops' two ranges and the staircase
+    if supports(seq, seq, h, h, n, 1, None, False, 4):
+        def vjp_blockdiff(q, k, v):
+            def loss(*a):
+                return jnp.sum(flash_attention_pallas(
+                    *a, block_diffusion=4).astype(jnp.float32))
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+        yield registry.KernelCase(f"vjp_blockdiff[s{seq},b4,{n}over1]",
+                                  vjp_blockdiff, (x, one_kv, one_kv), None)
     # latent attention's expanded form: q and k carry a rotary part half
     # as wide again as the head (128 + 64 over 128)
     wide = h + h // 2
@@ -685,7 +859,8 @@ def _engine_cases(engine):
     supports=supports,
     grad=True)
 def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
-                           interpret=False, window=None):
+                           interpret=False, window=None,
+                           block_diffusion=None):
     """q: [batch, seq, num_heads, head_dim]; k: [batch, seq, kv_heads,
     head_dim]; v: [batch, seq, kv_heads, v_head_dim] (paddle flash-attn
     layout; the two widths as :func:`supports` lists them).  ``kv_heads``
@@ -693,7 +868,9 @@ def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
     (num_heads / kv_heads)``, and K and V are never expanded.  ``window``
     (causal only): a query sees itself and the ``window - 1`` keys before
     it; one that covers the sequence is plain causal attention and runs as
-    such.
+    such.  ``block_diffusion`` ``B`` (neither causal nor a window): the row
+    is ``[noised ; clean]`` and the mask the block-diffusion one of the
+    module's docstring; the shapes as :func:`supports` says.
 
     What the kernels read and write in HBM (the module's docstring,
     "Layouts"): where the kv heads are grouped, an operand whose width is a
@@ -718,14 +895,22 @@ def flash_attention_pallas(q, k, v, is_causal=False, scale=None,
             f"k{tuple(k.shape)} causal={is_causal} window={window}: kv "
             f"heads must divide q heads, a window needs causal "
             f"self-attention")
+    if block_diffusion is not None and not supports(
+            sq, sk, h, v.shape[3], n, nkv, window, is_causal,
+            block_diffusion):
+        raise ValueError(
+            f"flash attention does not serve q{tuple(q.shape)} "
+            f"k{tuple(k.shape)} causal={is_causal} window={window} under a "
+            f"block-diffusion mask of {block_diffusion}: see supports()")
     window = None if window is None or window >= sk else int(window)
     if scale is None:
         scale = 1.0 / (h ** 0.5)
-    record_flash_layout(_kernel_name("fwd", window).removesuffix("_fwd"),
+    record_flash_layout(_kernel_name("fwd", window, block_diffusion)
+                        .removesuffix("_fwd"),
                         f"q{tuple(q.shape)} k{tuple(k.shape)} "
                         f"v{tuple(v.shape)}",
                         *operand_layouts(h, v.shape[3], n // nkv))
     out = _flash_attention_kernels(
         *(_to_kernel(x, n // nkv) for x in (q, k, v)), (b, n, nkv),
-        bool(is_causal), float(scale), interpret, window)
+        bool(is_causal), float(scale), interpret, window, block_diffusion)
     return _from_kernel(out, b, n)

@@ -405,11 +405,11 @@ def test_both_expert_models_are_the_one_shell():
     assert {type(l.attn) for l in a.model.layers} \
         == {laguna.GroupedGatedAttention}
     assert {type(l.attn) for l in b.model.layers} == {mla_moe.MLAttention}
-    kinds = [(l.attn.kind, l.attn.num_heads, l.attn.window,
-              l.moe is not None) for l in a.model.layers]
-    assert kinds == [(FULL, 4, None, False), (WINDOW, 6, 8, True),
-                     (WINDOW, 6, 8, True), (WINDOW, 6, 8, True),
-                     (FULL, 4, None, True)]
+    # a layer's kind is its window: a sliding layer has one, a full none
+    kinds = [(l.attn.num_heads, l.attn.window, l.moe is not None)
+             for l in a.model.layers]
+    assert kinds == [(4, None, False), (6, 8, True), (6, 8, True),
+                     (6, 8, True), (4, None, True)]
 
 
 def test_train_step_hands_back_the_counters_and_the_loss_falls():

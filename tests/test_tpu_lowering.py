@@ -89,11 +89,12 @@ def test_registry_lowers_for_tpu_where_supported():
     # ragged + ragged_quant over 6 buckets x 3 engines, flash and
     # layernorm fwd+vjp x 3, flash at 192 | 128 (latent attention's
     # expanded form) fwd+vjp on the head_dim-128 engine, flash under a
-    # window over one kv head vjp x 3, the block-window-plus-summaries
+    # window over one kv head and under a block-diffusion mask of 4 over
+    # one kv head vjp x 3 each, the block-window-plus-summaries
     # (eva) kernels vjp x 3, the two training layernorm shapes, the
     # state-space scan's kernels vjp x 3, latent attention's expansion
     # (value and vjp) x 3
-    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 2 * 3 + 2 + 3 + 3
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 3 + 2 * 3 + 2 + 3 + 3
 
 
 def test_refusals_are_declared_only_where_needed():
