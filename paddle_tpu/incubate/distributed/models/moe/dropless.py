@@ -11,15 +11,20 @@ work follows the rows that are really there:
    or :func:`route_softmax_topk` (softmax over all the experts, the largest
    few, normed over them); any other router that yields ``(idx [S, k],
    weights [S, k])`` serves.
-2. :func:`sort_by_expert` -- the ``S * k`` assignments in expert order, the
-   ones whose expert does not live here (``expert_offset``,
-   ``num_local_experts``: this chip's share of an expert-parallel layer)
-   behind all the others, and the tokens each local expert received.
-3. :func:`dispatch` / :func:`grouped_matmul` / :func:`combine` -- a row
-   gather into that order, a grouped matmul over the ragged groups
+2. A SORT of the ``A = S * k`` assignments by expert (:func:`expert_keys`,
+   :func:`_sorted_by`): the ones whose expert does not live here
+   (``expert_offset``, ``num_local_experts``: this chip's share of an
+   expert-parallel layer) behind all the others, and the tokens each local
+   expert received (:func:`group_sizes`).  The routing weights ride that
+   sort as its payload.  :func:`sort_by_expert` is the same sort with the
+   inverse permutation beside it (a second sort), for the capacity-free
+   ``MoELayer``'s :func:`dispatch` / :func:`combine`.
+3. :func:`routed_experts` -- the layers' routed block: a row gather into
+   that order, a grouped matmul over the ragged groups
    (``ops.pallas.grouped_matmul``: on the TPU a Pallas kernel whose tiles
    follow ``group_sizes``, with its transposes for the backward; elsewhere
-   ``jax.lax.ragged_dot``), and a gather back with the routing weights.
+   ``jax.lax.ragged_dot``), and the rows summed back to their tokens by run
+   (:func:`_sum_by_runs`), times the routing weights.
 
 The buffers hold the rows of a BUCKET, not the worst case's
 (:func:`routed_experts`, the layer's routed block).  The worst case
@@ -40,12 +45,18 @@ case, there is one bucket and no switch.
 Within a bucket rows behind the last group belong to no group and no tile
 of the grouped matmul visits them.  What such rows hold is never defined
 and never used: wherever sorted rows go back to their tokens, they are
-masked inside that reduction.  The gathers are permutations whose inverse
-is known, so their transposes are gathers too (``custom_vjp``): no
-scatter-add in either direction.  Back at the tokens a small bucket's rows
-are summed BY RUN (:func:`_sum_by_runs`: the rows in token order, each run
-of a token added up, one row gathered a token: ``S + R`` rows move); the
-worst case's one row a SLOT (:func:`_sum_by_slots`: ``k * S`` rows).
+masked inside that reduction.  Back at the tokens EVERY bucket's rows are
+summed BY RUN (:func:`_sum_by_runs`, since PR 45: the rows in token order,
+each run of a token added up by the ``moe_run_sum`` kernel, one row
+gathered a token: ``S + 3 R`` rows move), combine and the dispatch's
+transpose alike.  Between the router and the tokens again nothing is sized
+by the ``A`` assignments but integer sorts and elementwise integer ops: no
+row buffer, no gather and no scatter of ``A`` entries (the parent gathered
+one row a SLOT, ``k * S`` rows each way, three quarters of them a clamped
+row nobody read, and brought the weights' gradient back through a gather of
+``A`` scalars; :func:`_sum_by_slots` is that form, kept for
+``MoELayer``'s :func:`dispatch`).  The permutations' transposes are gathers
+too (``custom_vjp``): no scatter-add in either direction.
 
 What an expert IS stays a parameter of that one path: its BODY
 (:data:`BODIES`: ``swiglu``, ``down(silu(gate) * up)`` with gate | up in one
@@ -102,6 +113,31 @@ def sorted_rows(tokens, top_k, num_local):
     return tokens * min(top_k, num_local)
 
 
+def expert_keys(idx, expert_offset, num_local):
+    """``idx [S, k]`` -> int32 ``[A]``, ``A = S * k``: each assignment's
+    expert as the chip numbers the ``num_local`` it holds, ``num_local``
+    itself for every expert held elsewhere.  Assignments are numbered
+    SLOT-MAJOR, ``a = slot * S + token``."""
+    local = idx.T.reshape(-1) - expert_offset
+    return jnp.where((local >= 0) & (local < num_local), local, num_local)
+
+
+def group_sizes(key, num_local):
+    """int32 ``[num_local]``: the assignments of :func:`expert_keys` each
+    expert held here received."""
+    return jnp.sum(key[:, None] == jnp.arange(num_local)[None, :], axis=0,
+                   dtype=jnp.int32)
+
+
+def _sorted_by(key, *payloads):
+    """``(key sorted, its permutation, each of payloads permuted alike)``:
+    ONE stable sort whose operands ride along, where ``payload[argsort(key)]``
+    would be a gather an element (7 ns each on the v5e, whatever it
+    fetches)."""
+    iota = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, iota) + payloads, num_keys=1, is_stable=True)
+
+
 def sort_by_expert(idx, expert_offset, num_local):
     """The ``A = S * k`` assignments in the order the grouped matmul wants.
 
@@ -117,19 +153,20 @@ def sort_by_expert(idx, expert_offset, num_local):
     held here received.  ``order`` is cut to :func:`sorted_rows` rows (no
     local assignment lies behind them); ``inverse`` keeps all ``A``
     entries, and those of assignments served elsewhere may point past the
-    cut: :func:`_unsort` clamps them, and nobody reads what they fetch."""
+    cut: :func:`_unsort` clamps them, and nobody reads what they fetch.
+
+    Both permutations come out of a SORT: ``inverse`` is the payload of
+    sorting ``order`` (its keys are distinct), not ``zeros.at[order].set(
+    iota)``, which the v5e runs as a scatter of ``A`` scalars at six times
+    the sort's cost (0.455 ms for 0.078 at 98,304, PR 42's trace).  The
+    layers' routed block (:func:`routed_experts`) sorts for itself and needs
+    no inverse at all; this is the capacity-free ``MoELayer``'s."""
     tokens, top_k = idx.shape
-    local = idx.T.reshape(-1) - expert_offset
-    key = jnp.where((local >= 0) & (local < num_local), local, num_local)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=jnp.int32))
-    counts = jnp.sum(key[:, None] == jnp.arange(num_local)[None, :], axis=0,
-                     dtype=jnp.int32)
+    key = expert_keys(idx, expert_offset, num_local)
+    order = _sorted_by(key)[1]
+    inverse = _sorted_by(order)[1]
     rows = sorted_rows(tokens, top_k, num_local)
-    if rows < order.shape[0]:
-        order = order[:rows]
-    return order, inverse, counts
+    return order[:rows], inverse, group_sizes(key, num_local)
 
 
 def _unsort(ys, inverse):
@@ -313,85 +350,115 @@ def bucket_of(counts, buckets):
                    dtype=jnp.int32)
 
 
-def _run_passes(top_k, num_local):
-    """Doubling passes that cover a token's longest run of rows here."""
-    return (min(top_k, num_local) - 1).bit_length()
+def _rows_behind(token, tokens, max_run):
+    """int32 ``[R]``: the rows of its own run that lie BEHIND each row of
+    ``token [R]`` (rows in token order; ``tokens`` marks a row of no token,
+    which is in no run).  A run is at most ``max_run`` long."""
+    behind = sum(
+        jnp.pad(token[i:] == token[:-i], (0, i)).astype(jnp.int32)
+        for i in range(1, max_run)) if max_run > 1 else jnp.zeros_like(token)
+    return jnp.where(token < tokens, behind, 0)
 
 
-def _by_runs(rows, tokens, top_k, num_local):
-    """Whether a bucket of ``rows`` goes back to its tokens by runs or by
-    slots.  Timed alone on the v5e (PR 31, ms a call): by slots is one
-    gather that WRITES ``k * S`` rows and a sum that reads them, whatever
-    the bucket: 1.57 at 81,920 x 3,072 (laguna's layer) and 1.32 at 98,304
-    x 2,048 (kanana's), where the worst case's took 4.8 and 4.0 (a small
-    bucket's clamped reads hit one row).  By runs moves ``S + R`` rows and
-    makes three float32 passes over ``[R, H]``, each a shifted copy: 0.53
-    at 3,584 x 3,072, 1.11 at 5,120, 3.59 at 10,240; 4.0 at 15,360 x
-    2,048.  The crossover lies near a twelfth of the slots' rows in both
-    layers: laguna's bucket of 5,120 goes by runs, kanana's of 24,576 by
-    slots."""
-    return 4 * max(_run_passes(top_k, num_local), 1) * rows < top_k * tokens
-
-
-def _sum_by_runs(rows, row_weights, order, inverse, counts, tokens):
-    """What :func:`_sum_by_slots` gives, formed over the ``R`` rows of a
-    bucket instead of the ``k * S`` slots: the rows in token order (a sort
-    of ``R`` keys), each run of one token added up (a token's experts are
-    distinct, so a run is at most ``min(k, num_local)`` long: doubling
-    shifted, masked float32 adds cover it), and ONE row gathered a token,
-    zero where a token has none here.  ``S + R`` rows move.  ``row_weights
-    [R]`` float32 or ``None``."""
-    n, top_k = rows.shape[0], inverse.shape[0] // tokens
-    valid = jnp.arange(n) < jnp.sum(counts)
-    token = jnp.where(valid, order % tokens, tokens)
-    by_token = jnp.argsort(token).astype(jnp.int32)
-    token = token[by_token]
-    acc = rows[by_token].astype(jnp.float32)
-    if row_weights is not None:
-        acc = acc * row_weights[by_token][:, None]
-    acc = jnp.where((token < tokens)[:, None], acc, 0.0)
-    # after the pass at ``step``, acc[i] holds rows i .. i + 2 * step - 1
-    # of i's run
-    for step in (1 << p for p in range(_run_passes(top_k, counts.shape[0]))):
-        same = jnp.pad(token[step:] == token[:-step], (0, step))
-        acc = acc + jnp.where(same[:, None],
+def _run_sums(rows, rem, weights=None, *, max_run):
+    """``rows [R, H]`` in token order -> ``[R, H]``: at the first row of
+    every run the float32 sum of the run's rows, each times its float32
+    weight where given, rounded once; ``rem`` is :func:`_rows_behind`'s.
+    Doubling shifted, masked adds: after the pass at ``step`` row ``j``
+    holds the rows ``j .. j + 2 step - 1`` of its run.  The XLA composition
+    of ``ops/pallas/moe_run_sum_kernel.py``, bit for bit: each pass is a
+    shifted float32 copy of ``[R, H]`` through HBM."""
+    acc = rows.astype(jnp.float32)
+    if weights is not None:
+        acc = acc * weights[:, None]
+    for step in (1 << p for p in range((max_run - 1).bit_length())):
+        acc = acc + jnp.where((rem >= step)[:, None],
                               jnp.pad(acc[step:], ((0, step), (0, 0))), 0.0)
-    acc = acc.astype(rows.dtype)
-    here = jnp.sum(_served(inverse, counts, tokens), axis=0, dtype=jnp.int32)
+    return acc.astype(rows.dtype)
+
+
+def _sum_by_runs(rows, token, row_weights, here, max_run):
+    """Expert-sorted ``rows [R, H]`` of a bucket -> ``[S, H]``: each token's
+    float32 sum over its rows here, times the row's float32 weight where
+    given, rounded once.  ``token [R]`` int32 is each row's token, ``S`` for
+    the rows behind the last group (they hold anything, NaN included, and
+    reach no sum); ``here [S]`` int32 the rows each token has here; a
+    token's experts are distinct, so it has at most ``max_run``.
+
+    The rows go into token order (ONE stable sort of ``R`` keys with the
+    weights as its payload, one gather of ``R`` rows), each run of one token
+    is added up (``ops.pallas.moe_run_sum``: the ``moe_run_sum`` kernel
+    where the shapes allow, else :func:`_run_sums`), and ONE row is gathered
+    a token, zero where a token has none here.  Nothing is sized by the ``k *
+    S`` assignments."""
+    from .....ops import pallas
+
+    n, tokens = rows.shape[0], here.shape[0]
+    token, by_token, *weights = _sorted_by(
+        token, *(() if row_weights is None else (row_weights,)))
+    acc = pallas.moe_run_sum(
+        rows[by_token], _rows_behind(token, tokens, max_run), *weights,
+        max_run=max_run)
     first = jnp.minimum(jnp.cumsum(here) - here, n - 1)
     return jnp.where((here > 0)[:, None], acc[first], 0)
 
 
-def _to_tokens(rows, weights, order, inverse, counts, tokens):
+def _to_tokens(rows, row_weights, token, here, counts, max_run):
     """Sorted ``rows [R, H]`` of a bucket -> ``[S, H]``: each token's sum
-    over its rows, times its ``weights [S, k]`` where given."""
-    top_k = inverse.shape[0] // tokens
-    if not _by_runs(rows.shape[0], tokens, top_k, counts.shape[0]):
-        return _sum_by_slots(rows, weights, inverse, counts, tokens)
-    row_weights = None if weights is None else \
-        weights.astype(jnp.float32).T.reshape(-1)[order]
-    return _sum_by_runs(rows, row_weights, order, inverse, counts, tokens)
+    over its rows, times ``row_weights [R]`` where given.  THE way back to
+    the tokens, combine and the dispatch's transpose alike, whatever the
+    bucket: :func:`_sum_by_runs`, its passes over ``[R, H]`` one Pallas
+    kernel where the shapes allow.  ``token [R]`` is each sorted row's
+    token.
+
+    Until PR 45 ``_by_runs`` chose between this form (its passes XLA's) and
+    the sum BY SLOTS (:func:`_sum_by_slots`: one row gathered a slot, ``k *
+    S`` rows written whatever the bucket) at a twelfth of the slots' rows.
+    With the kernel in place the crossover is gone.  Timed on the v5e IN THE
+    TRACE, each way alone on seeded uniform routings at the four cells'
+    small buckets (``chiprun_out/pr45/micro_*.json``, PR 45; ms a call, by
+    runs with the kernel | by runs, XLA's passes | by slots): sdar ``[32768,
+    2048]`` of 16,384 tokens at top-8 2.13 | 7.79 | 5.57; kanana ``[24576,
+    2048]`` at top-6 1.73 | 5.50 | 1.29; laguna ``[5120, 3072]`` of 8,192 at
+    top-10 0.30 | 1.00 | 1.54; the hybrid cell ``[7168, 1024]`` of 4,096 at
+    top-22 0.093 | 0.124 | 0.615.  The pass alone: 0.44 | 6.16, 0.33 | 4.53,
+    0.10 | 0.75, 0.053 | 0.086.  What is left of a call by runs is XLA's two
+    row gathers, and what THEY cost is the compiler's memory assignment, not
+    the rows': 6.4 ns a row where it holds the operand in VMEM, 27-36 ns
+    where it reads HBM (an operand of 128 MiB, sdar's bucket, never fits;
+    by slots paid 36 ns for each of sdar's 131,072 rows, 4.7 ms a call, and
+    6.3 ns for kanana's 98,304: ``PERF.md`` section 5, PR 45).  Alone kanana's
+    bucket went faster by slots (its gathers read HBM there); in the cell's
+    step, where they sit in VMEM, it does not: dispatch and combine 26.95 ->
+    16.97 ms a step, sdar's 90.30 -> 35.97, laguna's 15.58 -> 4.09, the
+    hybrid cell's 13.55 -> 3.61 (section 6).  The worst-case bucket, which
+    no run has taken, costs about what it did (a gather of ``A`` random rows
+    either way)."""
+    valid = jnp.arange(rows.shape[0]) < jnp.sum(counts)
+    return _sum_by_runs(rows, jnp.where(valid, token, here.shape[0]),
+                        row_weights, here, max_run)
 
 
-def _routed_fwd_rows(body, rows, x, weights, w_in, w_out, order, inverse,
-                     counts):
+def _routed_fwd_rows(body, max_run, rows, x, w_in, w_out, order, w_sorted,
+                     here, counts):
     """The routed block, its experts of ``body``, in a bucket of ``rows``
     rows."""
-    tokens, order = x.shape[0], order[:rows]
+    token = order[:rows] % x.shape[0]
     with jax.named_scope("dispatch"):
-        xs = x[order % tokens]
+        xs = x[token]
     with jax.named_scope("experts"):
         ys = experts_mlp(xs, w_in, w_out, counts, body)
     with jax.named_scope("combine"):
-        return _to_tokens(ys, weights, order, inverse, counts, tokens)
+        return _to_tokens(ys, w_sorted[:rows], token, here, counts, max_run)
 
 
-def _routed_bwd_rows(body, rows, x, weights, w_in, w_out, order, inverse,
-                     counts, g):
-    """Its transpose in the same bucket, from the block's INPUTS: ``xs`` and
-    the experts' intermediate values are rebuilt at ``rows`` rows."""
-    tokens, order = x.shape[0], order[:rows]
-    token = order % tokens
+def _routed_bwd_rows(body, max_run, rows, x, weights, w_in, w_out, order,
+                     w_sorted, here, counts, g):
+    """Its transpose in the same bucket, from the block's INPUTS and the
+    forward's sort: ``xs`` and the experts' intermediate values are rebuilt
+    at ``rows`` rows."""
+    order, w_rows = order[:rows], w_sorted[:rows]
+    token = order % x.shape[0]
     with jax.named_scope("dispatch"):
         xs = x[token]
     with jax.named_scope("experts"):
@@ -400,16 +467,18 @@ def _routed_bwd_rows(body, rows, x, weights, w_in, w_out, order, inverse,
         # ONE gather of the tokens' gradient: times the row's weight for
         # d ys, dotted with ys for the weight's own
         g32 = g[token].astype(jnp.float32)
-        w32 = weights.astype(jnp.float32).T.reshape(-1)[order]
-        d_ys = (g32 * w32[:, None]).astype(ys.dtype)
+        d_ys = (g32 * w_rows[:, None]).astype(ys.dtype)
         d_w = jnp.sum(ys.astype(jnp.float32) * g32, axis=-1)
-        served = inverse < jnp.sum(counts)
-        d_w = jnp.where(served, d_w[jnp.minimum(inverse, rows - 1)], 0.0)
-        d_w = d_w.reshape(-1, tokens).T.astype(weights.dtype)
+        # back at its slots by ONE scatter of the bucket's rows, each to
+        # its own assignment (rows behind the last group bring zero)
+        d_w = jnp.where(jnp.arange(rows) < jnp.sum(counts), d_w, 0.0)
+        d_w = jnp.zeros(weights.size, jnp.float32).at[order].set(
+            d_w, unique_indices=True, mode="promise_in_bounds")
+        d_w = d_w.reshape(weights.shape[::-1]).T.astype(weights.dtype)
     with jax.named_scope("experts"):
         d_xs, d_in, d_out = experts_vjp(d_ys)
     with jax.named_scope("dispatch"):
-        d_x = _to_tokens(d_xs, None, order, inverse, counts, tokens)
+        d_x = _to_tokens(d_xs, None, token, here, counts, max_run)
     return d_x, d_w, d_in, d_out
 
 
@@ -422,34 +491,53 @@ def _in_bucket(rows_fn, buckets, counts, *operands):
         [functools.partial(rows_fn, rows) for rows in buckets], *operands)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def routed_experts(x, weights, w_in, w_out, order, inverse, counts, buckets,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def routed_experts(x, weights, w_in, w_out, idx, expert_offset, buckets,
                    body="swiglu"):
-    """``x [S, K]`` -> ``[S, K]``: dispatch, the experts held here
+    """``x [S, K]`` -> ``[S, K]``: the sort of the router's ``idx [S, k]``
+    (``weights [S, k]`` float32) by the experts held here (``w_in``'s
+    ``num_local``, from ``expert_offset``), dispatch, those experts
     (:func:`experts_mlp` with ``body``) and combine, every buffer in
     between at the rows of the smallest of ``buckets``
-    (:func:`row_buckets`) that holds ``sum(counts)``.
+    (:func:`row_buckets`) that holds the rows served here.
 
-    ONE ``custom_vjp`` round the three, because the choice is a
+    Between the router and the tokens again nothing is sized by the ``A =
+    k * S`` assignments but integer sorts and elementwise integer ops: the
+    weights ride the sort as its payload, no inverse permutation is formed
+    (:func:`_to_tokens` needs none), and the weights' gradient goes back to
+    its slots by one scatter of the bucket's rows.
+
+    ONE ``custom_vjp`` round the lot, because the choice is a
     ``lax.switch``: differentiated THROUGH, each branch would write zeros
-    for every other branch's residuals, the worst case's among them.  The
-    residuals here are the block's inputs, whose shapes no bucket changes;
-    the backward opens its own switch."""
-    return _routed_fwd(x, weights, w_in, w_out, order, inverse, counts,
-                       buckets, body)[0]
+    for every other branch's residuals, the worst case's among them, and the
+    sort's payload would be transposed as a scatter-add.  The residuals here
+    are the block's inputs and the sort's results, whose shapes no bucket
+    changes; the backward opens its own switch."""
+    return _routed_fwd(x, weights, w_in, w_out, idx, expert_offset, buckets,
+                       body)[0]
 
 
-def _routed_fwd(x, weights, w_in, w_out, order, inverse, counts, buckets,
-                body):
-    operands = (x, weights, w_in, w_out, order, inverse, counts)
-    return _in_bucket(functools.partial(_routed_fwd_rows, body),
-                      buckets, counts, *operands), operands
+def _routed_fwd(x, weights, w_in, w_out, idx, expert_offset, buckets, body):
+    (tokens, top_k), num_local = idx.shape, w_in.shape[0]
+    max_run = min(top_k, num_local)
+    with jax.named_scope("dispatch"):
+        key = expert_keys(idx, expert_offset, num_local)
+        _, order, w_sorted = _sorted_by(
+            key, weights.astype(jnp.float32).T.reshape(-1))
+        routing = (order[:buckets[-1]], w_sorted[:buckets[-1]],
+                   jnp.sum((key < num_local).reshape(top_k, tokens), axis=0,
+                           dtype=jnp.int32),
+                   group_sizes(key, num_local))
+    out = _in_bucket(functools.partial(_routed_fwd_rows, body, max_run),
+                     buckets, routing[-1], x, w_in, w_out, *routing)
+    return out, (x, weights, w_in, w_out, *routing)
 
 
-def _routed_bwd(buckets, body, operands, g):
-    grads = _in_bucket(functools.partial(_routed_bwd_rows, body),
+def _routed_bwd(expert_offset, buckets, body, operands, g):
+    max_run = min(operands[1].shape[1], operands[2].shape[0])
+    grads = _in_bucket(functools.partial(_routed_bwd_rows, body, max_run),
                        buckets, operands[-1], *operands, g)
-    return (*grads, None, None, None)
+    return (*grads, None)
 
 
 routed_experts.defvjp(_routed_fwd, _routed_bwd)

@@ -210,11 +210,11 @@ def _routed_experts(rows2d, idx, weights, w_in, w_out, *, expert_offset,
     this step."""
     num_local = w_in.shape[0]
     with jax.named_scope("dispatch"):
-        order, inverse, counts = _dl.sort_by_expert(idx, expert_offset,
-                                                    num_local)
+        counts = _dl.group_sizes(
+            _dl.expert_keys(idx, expert_offset, num_local), num_local)
         rows = jnp.asarray(buckets, jnp.int32)[_dl.bucket_of(counts, buckets)]
-    out = _dl.routed_experts(rows2d, weights, w_in, w_out, order, inverse,
-                             counts, buckets, body)
+    out = _dl.routed_experts(rows2d, weights, w_in, w_out, idx, expert_offset,
+                             buckets, body)
     return out, counts, rows
 
 

@@ -159,13 +159,23 @@ def mla_expand_log():
     return _view(("shapes", "path", "reason"), "mla_expand")
 
 
+def moe_run_sum_log():
+    """The same of :func:`moe_run_sum`: ``shapes`` (the bucket's rows),
+    ``max_run``, ``weighted``, ``path`` and ``reason``."""
+    return _view(("shapes", "max_run", "weighted", "path", "reason"),
+                 "moe_run_sum")
+
+
 def traced_call_sums():
     """What a compiled step's account takes its own share of
     (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
     ``ssd_calls`` / ``ssd_calls_composed``, the :func:`ssd_scan` calls
-    traced in this process and those of them the composition served; and
+    traced in this process and those of them the composition served;
     ``mla_expand_calls`` / ``mla_expand_calls_composed``, the same of
-    :func:`mla_expand_qkv`."""
+    :func:`mla_expand_qkv`; and ``moe_run_sum_calls`` /
+    ``moe_run_sum_calls_composed``, the same of :func:`moe_run_sum` (an
+    expert layer's routed block traces one a branch of each of its two
+    switches: combine, and the dispatch's transpose)."""
     return dict(common.traced_sums)
 
 
@@ -539,6 +549,28 @@ def mla_expand_qkv(q, kv_b, k_rope, cos, sin, *, nope, interleave):
         f"kv_b {kv_b.dtype}, k_rope {k_rope.dtype} beside q {q.dtype}",
         fits=(q.shape[1], q.shape[2], nope, q.shape[3] - nope,
               kv_b.shape[3] - nope, q.dtype))
+
+
+def moe_run_sum(rows, rem, weights=None, *, max_run):
+    """The pass that adds up each token's rows on the expert layer's way
+    back to its tokens (``incubate/distributed/models/moe/dropless.py
+    _sum_by_runs``): ``rows [R, H]`` in token order, ``rem [R]`` int32 (the
+    rows of its own run behind each row, a run at most ``max_run`` long),
+    ``weights [R]`` float32 or ``None`` -> ``[R, H]``, at the first row of
+    every run its rows' float32 sum, weighted, rounded once.  The
+    ``moe_run_sum`` kernel (``moe_run_sum_kernel``) or the XLA composition
+    ``dropless._run_sums``, as :func:`_dispatch` places it; every traced
+    call is recorded (:func:`moe_run_sum_log`)."""
+    from ...incubate.distributed.models.moe.dropless import _run_sums
+
+    return _dispatch(
+        "moe_run_sum", "moe_run_sum_kernel", tuple(rows.shape),
+        lambda runs: runs.moe_run_sum_pallas(rows, rem, weights,
+                                             max_run=max_run),
+        lambda: _run_sums(rows, rem, weights, max_run=max_run),
+        counter="moe_run_sum_calls",
+        more={"max_run": max_run, "weighted": weights is not None},
+        fits=(rows.shape[0], rows.shape[1], max_run, rows.dtype))
 
 
 def _grouped_row_tile(shape):

@@ -53,6 +53,7 @@ KERNEL_MODULES = (
     "eva_attention_kernel",
     "ssd_scan_kernel",
     "mla_expand_kernel",
+    "moe_run_sum_kernel",
     "decode_attention_kernel",
     "ragged_attention_kernel",
     "layernorm_kernel",

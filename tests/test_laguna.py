@@ -89,8 +89,26 @@ def test_logits_and_counters_match_the_reference(share):
                                               for _, c in want))
 
 
+@pytest.fixture(params=["composition", "kernel"])
+def run_sums(request, monkeypatch):
+    """The expert layer's way back to its tokens by the path the CPU takes
+    (``dropless._run_sums``) or with the ``moe_run_sum`` kernel forced, in
+    interpret mode, a row block of 16 over the tiny model's whole width."""
+    if request.param == "kernel":
+        from paddle_tpu.ops import pallas as pk
+        from paddle_tpu.ops.pallas.moe_run_sum_kernel import (
+            moe_run_sum_pallas)
+
+        monkeypatch.setattr(
+            pk, "moe_run_sum",
+            lambda rows, rem, weights=None, *, max_run: moe_run_sum_pallas(
+                rows, rem, weights, max_run=max_run, interpret=True,
+                block=(16, rows.shape[1])))
+    return request.param
+
+
 @pytest.mark.parametrize("share", list(SHARES))
-def test_loss_and_every_leafs_gradient_match_the_reference(share):
+def test_loss_and_every_leafs_gradient_match_the_reference(share, run_sums):
     m, model, tree = _seeded(share)
     ids = _ids(1)
     names = list(model.state_dict())

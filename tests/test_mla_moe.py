@@ -171,6 +171,44 @@ def test_the_expand_kernels_change_no_value_of_the_rematerialised_step(
     assert calls[0] == 0 and calls[1] > 0
 
 
+@pytest.mark.parametrize("share", ["share-4-of-16-from-4", "uncut"])
+def test_the_run_sum_kernel_changes_no_value_of_the_rematerialised_step(
+        share, monkeypatch):
+    """Three steps of ``TrainStep(remat=[...])`` with the ``moe_run_sum``
+    kernel in the dispatcher's place (interpret mode, a row block of 16 over
+    the tiny model's whole width) against ``dropless._run_sums``: the float32
+    losses agree, and the kernel is called in every traced branch of the
+    routed block (combine with the weights, the dispatch's transpose
+    without), in the buckets of a cut layer and in the one bucket of an
+    uncut one."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas.moe_run_sum_kernel import moe_run_sum_pallas
+
+    ids = paddle.to_tensor(_ids(2))
+    losses, calls = [], []
+
+    def kernel(rows, rem, weights=None, *, max_run):
+        calls.append((rows.shape, weights is not None))
+        return moe_run_sum_pallas(rows, rem, weights, max_run=max_run,
+                                  interpret=True, block=(16, rows.shape[1]))
+
+    for kernels in (False, True):
+        if kernels:
+            monkeypatch.setattr(pk, "moe_run_sum", kernel)
+        _, model, _ = _seeded(share)
+        opt = optimizer.AdamW(learning_rate=1e-3,
+                              parameters=model.parameters())
+        step = TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                         remat=["flash_attention_out"])
+        losses.append([float(step(ids, ids)._data) for _ in range(3)])
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-6)
+    assert losses[1][2] < losses[1][0]
+    # combine is traced in the forward and in the rematerialised forward,
+    # the dispatch's transpose once
+    weighted = sum(w for _, w in calls)
+    assert weighted == 2 * (len(calls) - weighted) > 0
+
+
 def test_train_step_hands_back_the_counters_beside_the_loss():
     _, model, _ = _seeded("share-4-of-16-from-4")
     opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
@@ -334,12 +372,12 @@ def _block_case(hot, seed=0):
 def _block_routed(idx, x, w, gate_up, down, buckets=None):
     """The layer's routed block -> (out, counts, rows buffered)."""
     c = BLOCK
-    order, inverse, counts = dropless.sort_by_expert(idx, c["offset"],
-                                                     c["held"])
+    counts = dropless.group_sizes(
+        dropless.expert_keys(idx, c["offset"], c["held"]), c["held"])
     if buckets is None:
         buckets = dropless.row_buckets(c["s"], c["k"], c["held"], c["e"])
-    out = dropless.routed_experts(x, w, gate_up, down, order,
-                                         inverse, counts, buckets)
+    out = dropless.routed_experts(x, w, gate_up, down, idx, c["offset"],
+                                  buckets)
     return out, counts, \
         jnp.asarray(buckets)[dropless.bucket_of(counts, buckets)]
 
@@ -403,11 +441,8 @@ def test_routed_block_in_every_bucket(hot, bucket):
 def test_a_larger_bucket_than_needed_gives_the_same(rows):
     """The rows behind the last group are masked wherever rows go back to
     their tokens: ANY bucket that holds the rows gives the value and the
-    gradients of the smallest, whichever way it sums (by runs up to 4,096
-    rows here, by slots in the worst case)."""
+    gradients of the smallest (every bucket sums by runs since PR 45)."""
     idx, x, w, gate_up, down, probe = _block_case(0.0)
-    by_runs = dropless._by_runs(rows, BLOCK["s"], BLOCK["k"], BLOCK["held"])
-    assert by_runs == (rows <= 4096)
 
     def f(*a, buckets):
         return jnp.sum(_block_routed(idx, *a, buckets=buckets)[0] * probe)
@@ -443,8 +478,11 @@ def test_sum_by_runs_is_the_sum_by_slots(top_k, held, experts, weighted):
     weights = jnp.asarray(rng.rand(tokens, top_k) + 0.1, jnp.float32) \
         if weighted else None
     row_weights = weights.T.reshape(-1)[order] if weighted else None
-    got = dropless._sum_by_runs(rows, row_weights, order, inverse, counts,
-                                tokens)
+    token = jnp.where(jnp.arange(order.shape[0]) < total, order % tokens,
+                      tokens)
+    got = dropless._sum_by_runs(
+        rows, token, row_weights,
+        jnp.bincount(token, length=tokens + 1)[:tokens], min(top_k, held))
     want = dropless._sum_by_slots(rows, weights, inverse, counts, tokens)
     assert np.isfinite(np.asarray(want)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,

@@ -1,5 +1,5 @@
 """``ops/pallas``: the ONE rule that places a call (``_refusal`` /
-``_dispatch``) held over its five dispatchers, the flash kernels' block
+``_dispatch``) held over its six dispatchers, the flash kernels' block
 rule at the benchmark's shapes, and the direction of the package's imports.
 
 The kernels' values are other files' business (``test_pallas_kernels.py``,
@@ -18,7 +18,8 @@ import pytest
 from paddle_tpu.ops import pallas as pk
 from paddle_tpu.ops.pallas import (attention_kernel, common,
                                    eva_attention_kernel, mla_expand_kernel,
-                                   registry, ssd_scan_kernel)
+                                   moe_run_sum_kernel, registry,
+                                   ssd_scan_kernel)
 
 
 def _x(*shape, dtype=jnp.bfloat16):
@@ -36,6 +37,10 @@ def _ssd(chunk, t=256, nh=8, p=64, g=1, n=128):
     f32 = jnp.float32
     return ((_x(1, t, nh, p), _x(1, t, nh, dtype=f32), _x(nh, dtype=f32),
              _x(1, t, g, n), _x(1, t, g, n), _x(nh, dtype=f32), chunk), {})
+
+
+def _run_sum(width):
+    return ((_x(64, width), _x(64, dtype=jnp.int32), None), {"max_run": 8})
 
 
 def _grouped(rows):
@@ -70,6 +75,11 @@ DISPATCHERS = {
         (pk, "_xla_mla_expand_qkv"),
         _mla(4, 128, 64, 128), _mla(4, 32, 16, 32),
         "mla_expand_kernel.supports() refuses", "mla_expand_calls"),
+    "moe_run_sum": (
+        pk.moe_run_sum, (moe_run_sum_kernel, "moe_run_sum_pallas"),
+        ("paddle_tpu.incubate.distributed.models.moe.dropless", "_run_sums"),
+        _run_sum(128), _run_sum(96),
+        "moe_run_sum_kernel.supports() refuses", "moe_run_sum_calls"),
     "grouped_matmul": (
         pk.grouped_matmul,
         ("jax.experimental.pallas.ops.tpu.megablox.ops", "gmm"),

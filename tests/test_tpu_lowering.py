@@ -93,8 +93,10 @@ def test_registry_lowers_for_tpu_where_supported():
     # one kv head vjp x 3 each, the block-window-plus-summaries
     # (eva) kernels vjp x 3, the two training layernorm shapes, the
     # state-space scan's kernels vjp x 3, latent attention's expansion
-    # (value and vjp) x 3
-    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 3 + 2 * 3 + 2 + 3 + 3
+    # (value and vjp) x 3, the expert layer's run sums (weighted and not)
+    # x 3
+    assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 3 + 2 * 3 + 2 + 3 + 3 \
+        + 2 * 3
 
 
 def test_refusals_are_declared_only_where_needed():
@@ -314,27 +316,87 @@ def test_a_small_buckets_branch_holds_no_worst_case_residual(monkeypatch):
     assert not any(f"<{rows}x" in r for rows in buckets for r in results), \
         results
     assert f"tensor<{tokens}x{h}xbf16>" in results[0]
-    # 2 grouped matmuls forward and 2 + 4 backward, in each branch
-    assert text.count("tpu_custom_call") == 8 * len(buckets)
+    # 2 grouped matmuls forward and 2 + 4 backward, and the rows' way back
+    # to the tokens (``moe_run_sum``) once each way, in each branch
+    assert text.count("tpu_custom_call") == 10 * len(buckets)
     assert "ragged_dot" not in text
 
-    def through(x, weights, gate_up, down, order, inverse, counts):
-        operands = (x, weights, gate_up, down, order, inverse, counts)
+    # differentiated through, the block is its XLA compositions (the run
+    # sums' kernel has no rule of its own: the block's custom_vjp is its)
+    monkeypatch.setattr(pk, "_use_pallas", lambda: False)
+
+    def through(x, gate_up, down, order, w_sorted, here, counts):
+        operands = (x, gate_up, down, order, w_sorted, here, counts)
         return jnp.sum(jax.lax.switch(
             dropless.bucket_of(counts, buckets),
-            [functools.partial(dropless._routed_fwd_rows, "swiglu", rows)
+            [functools.partial(dropless._routed_fwd_rows, "swiglu", top_k,
+                               rows)
              for rows in buckets], *operands).astype(jnp.float32))
 
     sds = jax.ShapeDtypeStruct
     operands = (sds((tokens, h), jnp.bfloat16),
-                sds((tokens, top_k), jnp.float32),
                 p["experts.gate_up"], p["experts.down"],
                 sds((buckets[-1],), jnp.int32),
-                sds((tokens * top_k,), jnp.int32), sds((held,), jnp.int32))
-    naive = _case_results(jax.jit(jax.grad(through, argnums=(0, 2, 3)))
+                sds((buckets[-1],), jnp.float32),
+                sds((tokens,), jnp.int32), sds((held,), jnp.int32))
+    naive = _case_results(jax.jit(jax.grad(through, argnums=(0, 1, 2)))
                           .trace(*operands).lower(
                               lowering_platforms=("tpu",)).as_text())
     assert any(f"<{buckets[-1]}x" in r for r in naive), naive
+
+
+def test_sdars_routed_block_moves_nothing_sized_by_its_assignments(
+        monkeypatch):
+    """The routed block at the sdar cell's shapes (16,384 positions, top-8,
+    16 of 128 experts held, rows of 2,048), value and gradients, lowered
+    for the TPU in its small bucket of 32,768 rows: between the router and
+    the tokens again no gather and no scatter takes ``A = k S`` = 131,072
+    indices and no ``[131072, 2048]`` buffer exists (PR 44's tree: one such
+    gather each way and a scalar gather and a scatter of 131,072); what is
+    sized by ``A`` is the sort, its payload and elementwise integer ops."""
+    import re
+
+    from paddle_tpu.incubate.distributed.models.moe import dropless
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    tokens, top_k, held, experts, h, inner = 16384, 8, 16, 128, 2048, 768
+    buckets = dropless.row_buckets(tokens, top_k, held, experts)
+    assert buckets == (32768, 131072)
+    sds = jax.ShapeDtypeStruct
+    operands = (sds((tokens, h), jnp.bfloat16),
+                sds((tokens, top_k), jnp.float32),
+                sds((held, h, 2 * inner), jnp.bfloat16),
+                sds((held, inner, h), jnp.bfloat16),
+                sds((tokens, top_k), jnp.int32))
+
+    def loss(x, weights, w_in, w_out, idx):
+        # the small bucket's branch of both switches, alone
+        return jnp.sum(dropless.routed_experts(
+            x, weights, w_in, w_out, idx, 0, buckets[:1])
+            .astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).trace(
+        *operands).lower(lowering_platforms=("tpu",)).as_text()
+    assert f"{tokens * top_k}x{h}" not in text
+    gathers = re.findall(
+        r'"stablehlo\.gather"\(.*?\) <\{.*?\}> : \(tensor<(\S+?)>, '
+        r'tensor<(\d+)x1xi32>\)', text)
+    # the rows: x[token] forward and backward, g[token], the rows into
+    # token order and one row a token, combine and the dispatch's transpose
+    rows = sorted(int(n) for of, n in gathers if of.endswith(f"x{h}xbf16"))
+    assert rows == [tokens] * 2 + [buckets[0]] * 5, gathers
+    assert max(int(n) for _, n in gathers) == buckets[0]
+    scatters = re.findall(
+        r"\}\) : \(tensor<(\S+?)>, tensor<(\d+)x1xi32>, tensor<\S+?>\) -> ",
+        text)
+    assert (f"{tokens * top_k}xf32", str(buckets[0])) in scatters    # d_w
+    assert max(int(n) for _, n in scatters) == buckets[0], scatters
+    # the sort by expert with its two payloads, the sort by token each way
+    assert text.count("stablehlo.sort") == 3
+    # 2 + 6 grouped matmuls and the two run sums
+    assert text.count("tpu_custom_call") == 10
+    assert text.count('kernel_name = "moe_run_sum"') == 2
 
 
 # The flash kernels as Mosaic gets them (the module inside each
@@ -676,6 +738,7 @@ def test_mla_expand_dispatch_is_counted_and_gives_way_aloud(
     assert {k: after[k] - before[k] for k in after} == {
         "flash_calls": 0, "flash_operands_in_place": 0,
         "flash_operands_copied": 0, "ssd_calls": 0, "ssd_calls_composed": 0,
+        "moe_run_sum_calls": 0, "moe_run_sum_calls_composed": 0,
         "mla_expand_calls": 1,
         "mla_expand_calls_composed": 0 if case == "kernel" else 1}
     if case != "mesh":
