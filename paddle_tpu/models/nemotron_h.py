@@ -33,6 +33,18 @@ groups of B and C at state ``N`` = ``ssm_state_size``, head ``h`` on group
   the tiny test config's chunk of 16, a GSPMD mesh) the XLA composition
   ``F._ssd_scan_row`` runs, differentiated as it stands.
 
+  The convolution is ``F.causal_conv1d(..., activation="silu", start=)``,
+  once each for x, B and C where they lie in ``W_in``'s result, so that no
+  ``[T, d + 2 G N]`` slice is made before it and none of x, B, C after it.  On
+  the TPU, at shapes ``ops/pallas/causal_conv_kernel.py supports`` takes (the
+  published ones: 8192 | 1024 | 1024 channels at lanes 8192 | 16384 | 17408,
+  4 taps), that is the ``causal_conv_fwd`` / ``causal_conv_bwd`` kernels: a
+  block of rows crosses once each way in bfloat16, the taps' sum and the
+  ``silu`` are float32 in VMEM, and the backward keeps x alone and sums the
+  taps' and the bias's gradients inside the kernel.  Elsewhere (off the TPU,
+  a width off the 128-lane tiles, a GSPMD mesh) the XLA composition
+  ``F._causal_conv1d_silu`` runs on the slice.
+
 ``*``, attention: ``num_attention_heads`` q heads over
 ``num_key_value_heads`` kv heads of ``head_dim``, causal, scale ``head_dim
 ** -0.5``; NO rotary, no q/k norm, no gate (the public ``nemotron_h``
@@ -230,16 +242,21 @@ class Mamba2Mixer(nn.Layer):
         zxbcdt = self.in_proj(a)
         z = zxbcdt[..., :d]
         with jax.named_scope("mamba_conv"):
-            xbc = F.silu(F.causal_conv1d(zxbcdt[..., d:d + self.conv_dim],
-                                         self.conv_weight, self.conv_bias))
+            # x | B | C, each convolved where it lies in the projection's
+            # result and written as the scan reads it
+            x, b_t, c_t = (
+                F.causal_conv1d(
+                    zxbcdt, self.conv_weight[lo:hi],
+                    None if self.conv_bias is None else self.conv_bias[lo:hi],
+                    activation="silu", start=d + lo)
+                for lo, hi in ((0, d), (d, d + gn), (d + gn, d + 2 * gn)))
         with jax.named_scope("mamba_ssd"):
             dt, a_head = _step_sizes(zxbcdt[..., d + self.conv_dim:],
                                      self.dt_bias, self.A_log)
             y = F.ssd_scan(
-                xbc[..., :d].reshape([b, t, self.heads, self.head_dim]), dt,
-                a_head,
-                xbc[..., d:d + gn].reshape([b, t, self.groups, self.state]),
-                xbc[..., d + gn:].reshape([b, t, self.groups, self.state]),
+                x.reshape([b, t, self.heads, self.head_dim]), dt, a_head,
+                b_t.reshape([b, t, self.groups, self.state]),
+                c_t.reshape([b, t, self.groups, self.state]),
                 self.D, chunk=self.chunk)
         with jax.named_scope("gated_norm"):
             y = _gated_norm(y.reshape([b, t, d]), z, self.norm_weight,

@@ -1009,15 +1009,12 @@ def ctc_loss(log_probs, labels, input_lengths=None, label_lengths=None,
 
 # ---------------- state-space (Mamba-2) ----------------
 
-@op()
-def causal_conv1d(x, weight, bias=None):
-    """Causal depthwise convolution over time: ``x [B, T, C]``, ``weight [C,
-    K]`` (tap ``k`` reads position ``t - (K - 1) + k``: the last tap is the
-    position itself), ``bias [C]``; positions before the row's first read
-    zero.  A sum of ``K`` shifted products in float32, rounded once to
-    ``x``'s dtype: at ``K`` = 4 one fused pass over the row, no
-    convolution op."""
-    taps = weight.shape[1]
+def _causal_conv1d_taps(x, weight, bias=None, start=0):
+    """:func:`causal_conv1d` as the XLA composition: over the channels
+    ``start .. start + C`` of ``x`` a sum of ``K`` shifted products in
+    float32, rounded once to ``x``'s dtype."""
+    channels, taps = weight.shape
+    x = x[..., start:start + channels]
     x32 = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
     w32 = weight.astype(jnp.float32)
     t = x.shape[1]
@@ -1025,6 +1022,41 @@ def causal_conv1d(x, weight, bias=None):
     if bias is not None:
         out = out + bias.astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def _causal_conv1d_silu(x, weight, bias=None, start=0):
+    """``silu`` of :func:`_causal_conv1d_taps`: the composition the
+    ``causal_conv_*`` kernels stand in for."""
+    return jax.nn.silu(_causal_conv1d_taps(x, weight, bias, start))
+
+
+@op()
+def causal_conv1d(x, weight, bias=None, activation=None, start=0):
+    """Causal depthwise convolution over time: ``x [B, T, W]``, ``weight [C,
+    K]`` (tap ``k`` reads position ``t - (K - 1) + k``: the last tap is the
+    position itself), ``bias [C]``, over the channels ``start .. start + C``
+    of ``x`` (all of them where ``W`` is ``C``); positions before the row's
+    first read zero.  A sum of ``K`` shifted products in float32, rounded
+    once to ``x``'s dtype: at ``K`` = 4 one fused pass over the row, no
+    convolution op.
+
+    ``activation="silu"`` applies :func:`silu` to that (the Mamba mixer's
+    use) and lets ``ops.pallas.causal_conv1d`` place the two as ONE call:
+    on the TPU, where ``causal_conv_kernel.supports`` takes the shapes
+    (``C`` and ``start`` whole 128-lane tiles, rows whole tiles of 16), the
+    ``causal_conv_fwd`` / ``causal_conv_bwd`` kernels, which read the
+    channels where they lie in ``x`` and whose backward keeps ``x`` alone;
+    elsewhere (off the TPU; aloud on it, ``KernelFallbackWarning``, for
+    other shapes or under a GSPMD mesh) this composition, differentiated as
+    it stands.  ``None`` is the composition alone, never a kernel."""
+    if activation is None:
+        return _causal_conv1d_taps(x, weight, bias, start)
+    if activation != "silu":
+        raise ValueError(f"causal_conv1d: activation {activation!r} is "
+                         f"neither None nor 'silu'")
+    from ..ops import pallas
+
+    return pallas.causal_conv1d(x, weight, bias, start)
 
 
 def _ssd_scan_row(x, dt, a_head, b, c, d_head, chunk):

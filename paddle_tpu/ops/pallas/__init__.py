@@ -166,16 +166,24 @@ def moe_run_sum_log():
                  "moe_run_sum")
 
 
+def causal_conv_log():
+    """The same of :func:`causal_conv1d`: ``shapes`` (x, weight), ``start``,
+    ``path`` and ``reason``."""
+    return _view(("shapes", "start", "path", "reason"), "causal_conv")
+
+
 def traced_call_sums():
     """What a compiled step's account takes its own share of
     (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
     ``ssd_calls`` / ``ssd_calls_composed``, the :func:`ssd_scan` calls
     traced in this process and those of them the composition served;
     ``mla_expand_calls`` / ``mla_expand_calls_composed``, the same of
-    :func:`mla_expand_qkv`; and ``moe_run_sum_calls`` /
+    :func:`mla_expand_qkv`; ``moe_run_sum_calls`` /
     ``moe_run_sum_calls_composed``, the same of :func:`moe_run_sum` (an
     expert layer's routed block traces one a branch of each of its two
-    switches: combine, and the dispatch's transpose)."""
+    switches: combine, and the dispatch's transpose); and
+    ``causal_conv_calls`` / ``causal_conv_calls_composed``, the same of
+    :func:`causal_conv1d` (three a Mamba mixer: x, B and C)."""
     return dict(common.traced_sums)
 
 
@@ -496,6 +504,26 @@ def ssd_scan(x, dt, A, B, C, D, chunk):
         f"B {B.dtype}, C {C.dtype} beside x {x.dtype}",
         fits=(x.shape[1], x.shape[2], x.shape[3], B.shape[2], B.shape[3],
               chunk, x.dtype))
+
+
+def causal_conv1d(x, weight, bias=None, start=0):
+    """``nn/functional.py causal_conv1d(..., activation="silu")``: ``x
+    [batch, T, W]``, ``weight [C, K]``, ``bias [C]`` or ``None`` ->
+    ``silu(conv(x[..., start:start + C]) + bias)`` ``[batch, T, C]``, the
+    taps' sum in float32.  The ``causal_conv_fwd`` / ``causal_conv_bwd``
+    kernels (``causal_conv_kernel``), which read the channels where they lie
+    in ``x``, or the XLA composition ``nn/functional.py
+    _causal_conv1d_silu``, as :func:`_dispatch` places it; every traced call
+    is recorded (:func:`causal_conv_log`)."""
+    from ...nn.functional import _causal_conv1d_silu
+
+    return _dispatch(
+        "causal_conv", "causal_conv_kernel",
+        (tuple(x.shape), tuple(weight.shape)),
+        lambda conv: conv.causal_conv_pallas(x, weight, bias, start=start),
+        lambda: _causal_conv1d_silu(x, weight, bias, start),
+        counter="causal_conv_calls", more={"start": start},
+        fits=(x.shape[1], weight.shape[0], weight.shape[1], x.dtype, start))
 
 
 def mla_rope(x, cos, sin, interleave):

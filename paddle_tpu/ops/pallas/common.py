@@ -94,7 +94,8 @@ traced_sums = dict.fromkeys(
     ("flash_calls", "flash_operands_in_place", "flash_operands_copied",
      "ssd_calls", "ssd_calls_composed",
      "mla_expand_calls", "mla_expand_calls_composed",
-     "moe_run_sum_calls", "moe_run_sum_calls_composed"), 0)
+     "moe_run_sum_calls", "moe_run_sum_calls_composed",
+     "causal_conv_calls", "causal_conv_calls_composed"), 0)
 
 
 def record_flash_layout(kernel, shapes, in_place, copied):

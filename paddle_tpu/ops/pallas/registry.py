@@ -52,6 +52,7 @@ KERNEL_MODULES = (
     "attention_kernel",
     "eva_attention_kernel",
     "ssd_scan_kernel",
+    "causal_conv_kernel",
     "mla_expand_kernel",
     "moe_run_sum_kernel",
     "decode_attention_kernel",

@@ -57,16 +57,15 @@ SMALL = {
     "deployment": {"router_experts": 12, "expert_offset": 4}}
 
 
-@pytest.fixture(scope="module")
-def seeded():
+def _both_sides(small, seq):
     """The model in float32 holding the reference's seeded weights, a
     batch, and both sides' loss and gradients under the highest matmul
     precision."""
-    m = runner.model_group(SMALL)
+    m = runner.model_group(small)
     model = NemotronHForCausalLM(runner.model_config(m))
     tree = ref.init_params(7, m, jnp.float32)
     runner.load_seeded(model, tree, ref, m)
-    ids = np.random.default_rng(0).integers(0, 64, (2, 24)).astype(np.int32)
+    ids = np.random.default_rng(0).integers(0, 64, (2, seq)).astype(np.int32)
     params = {k: v._data for k, v in model.state_dict().items()}
 
     def program_loss(p):
@@ -84,6 +83,11 @@ def seeded():
     return m, model, got, want
 
 
+@pytest.fixture(scope="module")
+def seeded():
+    return _both_sides(SMALL, 24)
+
+
 def test_loss_is_the_reference_s(seeded):
     """float32 on both sides: the two differ by the order of their sums
     (the chunked scan against the recurrence, the sorted dispatch against
@@ -92,10 +96,7 @@ def test_loss_is_the_reference_s(seeded):
     assert float(loss) == pytest.approx(float(want), rel=2e-6)
 
 
-def test_every_parameter_s_gradient_is_the_reference_s(seeded):
-    """Each leaf's gradient to 5e-5 of that leaf's largest entry (float32
-    sums in another order; the worst read here is ``dt_bias``'s at 5e-6)."""
-    m, model, (_, grads), (_, want) = seeded
+def _assert_gradients_are_the_reference_s(m, model, grads, want):
     want = ref.keyed(ref.split_layers(want, m), m)
     group_of = functools.partial(ref.group_of, m)
     compared = 0
@@ -110,6 +111,41 @@ def test_every_parameter_s_gradient_is_the_reference_s(seeded):
                                    err_msg=name)
         compared += 1
     assert compared == len(model.parameters())
+
+
+def test_every_parameter_s_gradient_is_the_reference_s(seeded):
+    """Each leaf's gradient to 5e-5 of that leaf's largest entry (float32
+    sums in another order; the worst read here is ``dt_bias``'s at 5e-6)."""
+    m, model, (_, grads), (_, want) = seeded
+    _assert_gradients_are_the_reference_s(m, model, grads, want)
+
+
+def test_the_convolution_s_kernels_give_the_reference_s_loss_and_gradients(
+        monkeypatch):
+    """The mixers' convolutions through the ``causal_conv_*`` kernels
+    (interpret mode) at widths they take: x 16 * 8 = 128 channels, B and C
+    2 * 64 = 128 each, read where they lie in the projection's ``[z | x | B
+    | C | dt]`` over rows of 32: the reference's loss and every parameter's
+    gradient, at the tolerances the composition is held to above."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas import causal_conv_kernel as ck
+
+    served = []
+
+    def through_the_kernels(x, weight, bias=None, start=0):
+        served.append((tuple(x.shape), weight.shape[0], start))
+        return ck.causal_conv_pallas(x, weight, bias, start=start,
+                                     interpret=True,
+                                     block=(16, 128, 16, 128))
+
+    monkeypatch.setattr(pk, "causal_conv1d", through_the_kernels)
+    small = dict(SMALL, mamba_num_heads=16, ssm_state_size=64)
+    m, model, (loss, grads), (want_loss, want) = _both_sides(small, 32)
+    wide = (2, 32, 128 + 384 + 16)
+    assert served == [(wide, 128, 128), (wide, 128, 256),
+                      (wide, 128, 384)] * PATTERN.count("M")
+    assert float(loss) == pytest.approx(float(want_loss), rel=2e-6)
+    _assert_gradients_are_the_reference_s(m, model, grads, want)
 
 
 def test_the_reference_s_gradient_by_blocks_is_its_gradient_whole(seeded):

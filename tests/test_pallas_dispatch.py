@@ -1,5 +1,5 @@
 """``ops/pallas``: the ONE rule that places a call (``_refusal`` /
-``_dispatch``) held over its six dispatchers, the flash kernels' block
+``_dispatch``) held over its seven dispatchers, the flash kernels' block
 rule at the benchmark's shapes, and the direction of the package's imports.
 
 The kernels' values are other files' business (``test_pallas_kernels.py``,
@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.ops import pallas as pk
-from paddle_tpu.ops.pallas import (attention_kernel, common,
-                                   eva_attention_kernel, mla_expand_kernel,
+from paddle_tpu.ops.pallas import (attention_kernel, causal_conv_kernel,
+                                   common, eva_attention_kernel,
+                                   mla_expand_kernel,
                                    moe_run_sum_kernel, registry,
                                    ssd_scan_kernel)
 
@@ -41,6 +42,10 @@ def _ssd(chunk, t=256, nh=8, p=64, g=1, n=128):
 
 def _run_sum(width):
     return ((_x(64, width), _x(64, dtype=jnp.int32), None), {"max_run": 8})
+
+
+def _conv(channels):
+    return ((_x(1, 64, channels), _x(channels, 4), _x(channels)), {})
 
 
 def _grouped(rows):
@@ -80,6 +85,11 @@ DISPATCHERS = {
         ("paddle_tpu.incubate.distributed.models.moe.dropless", "_run_sums"),
         _run_sum(128), _run_sum(96),
         "moe_run_sum_kernel.supports() refuses", "moe_run_sum_calls"),
+    "causal_conv": (
+        pk.causal_conv1d, (causal_conv_kernel, "causal_conv_pallas"),
+        ("paddle_tpu.nn.functional", "_causal_conv1d_silu"),
+        _conv(128), _conv(96),
+        "causal_conv_kernel.supports() refuses", "causal_conv_calls"),
     "grouped_matmul": (
         pk.grouped_matmul,
         ("jax.experimental.pallas.ops.tpu.megablox.ops", "gmm"),

@@ -94,9 +94,9 @@ def test_registry_lowers_for_tpu_where_supported():
     # (eva) kernels vjp x 3, the two training layernorm shapes, the
     # state-space scan's kernels vjp x 3, latent attention's expansion
     # (value and vjp) x 3, the expert layer's run sums (weighted and not)
-    # x 3
+    # x 3, the causal convolution's kernels (value and vjp) x 3
     assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 3 + 2 * 3 + 2 + 3 + 3 \
-        + 2 * 3
+        + 2 * 3 + 2 * 3
 
 
 def test_refusals_are_declared_only_where_needed():
@@ -739,6 +739,7 @@ def test_mla_expand_dispatch_is_counted_and_gives_way_aloud(
         "flash_calls": 0, "flash_operands_in_place": 0,
         "flash_operands_copied": 0, "ssd_calls": 0, "ssd_calls_composed": 0,
         "moe_run_sum_calls": 0, "moe_run_sum_calls_composed": 0,
+        "causal_conv_calls": 0, "causal_conv_calls_composed": 0,
         "mla_expand_calls": 1,
         "mla_expand_calls_composed": 0 if case == "kernel" else 1}
     if case != "mesh":
