@@ -92,15 +92,23 @@ def route_sigmoid_topk(logits, bias, top_k, scale, norm_topk=True):
     return idx.astype(jnp.int32), w * scale
 
 
-def route_softmax_topk(logits, top_k, scale, norm_topk=True):
+def route_softmax_topk(logits, top_k, scale, norm_topk=True, bias=None):
     """``logits [S, E]`` float32 -> ``(idx [S, k] int32, weights [S, k])``.
 
     ``s = softmax(logits)`` over ALL the experts; the ``k`` largest are
     chosen; weights are ``s`` at the chosen, normed over them, and scaled
     (the softmax-routed family: ``norm_topk_prob``, a routed scaling
-    factor; no selection bias)."""
+    factor).  With a selection ``bias`` the ``k`` largest of ``s + bias``
+    are chosen and the weights stay ``s`` at the chosen (the bias steers the
+    choice only).  At ``top_k`` 1 a normed weight is the constant 1 and the
+    router has no gradient: a top-1 router passes ``norm_topk=False`` and
+    its weight is the probability itself."""
     s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(s, top_k)
+    if bias is None:
+        w, idx = jax.lax.top_k(s, top_k)
+    else:
+        _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+        w = jnp.take_along_axis(s, idx, axis=1)
     if norm_topk:
         w = w / jnp.sum(w, axis=1, keepdims=True)
     return idx.astype(jnp.int32), w * scale

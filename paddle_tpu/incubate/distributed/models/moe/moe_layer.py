@@ -167,7 +167,12 @@ class TopKRouter(Layer):
       moves it outside the gradient);
     - ``"softmax"``: the softmax-routed family (``norm_topk_prob`` beside a
       routed scaling factor): softmax over all the experts, the largest;
-      no bias, no buffer."""
+      no bias, no buffer.
+
+    One matrix is the whole of this router.  :class:`StateMlpRouter` is the
+    other kind: an MLP over a state that one layer's router hands to the
+    next, softmax scores WITH a selection bias, top-1 by the probability
+    itself."""
 
     def __init__(self, d_model, num_experts, top_k, scale=1.0,
                  norm_topk_prob=True, init_std=0.02, score_func="sigmoid"):
@@ -216,6 +221,83 @@ def _routed_experts(rows2d, idx, weights, w_in, w_out, *, expert_offset,
     out = _dl.routed_experts(rows2d, weights, w_in, w_out, idx, expert_offset,
                              buckets, body)
     return out, counts, rows
+
+
+class StateMlpRouter(Layer):
+    """The router of the ``zaya`` family (``models/zaya.py``): an MLP over a
+    ``state_size``-wide router state that carries a term from the layer
+    BEFORE.  With ``x [S, H]`` the expert layer's input and ``r_prev [S,
+    state_size]`` the state the previous layer's router made (zeros into
+    the first):
+
+        r = x W_down + gamma * r_prev                      (handed on as r)
+        z = W_3 gelu(W_2 gelu(W_1 rms(r) + c_1) + c_2) + c_3
+        p = softmax(z) over ALL num_experts;  the top_k largest of p + bias;
+        weights p at the chosen, normed over them only with norm_topk_prob
+
+    ``W_down`` is a plain projection in the model's dtype (its sum and its
+    result float32); everything from ``r`` on is float32, parameters too
+    (``amp_keep_float32``: ``gamma``, the norm's gain, the MLP; exact
+    ``gelu``).  ``e_score_correction_bias`` is a zero buffer outside the
+    gradient, as :class:`TopKRouter`'s.  At ``top_k`` 1 pass
+    ``norm_topk_prob=False``: the weight is then the probability itself and
+    the router has a gradient (normed, it is the constant 1).  Gradients
+    reach the earlier layers' routers through ``r_prev``.
+
+    ``forward(x2d, state)`` -> ``(idx, weights, r)``.  Scopes inside the
+    layer's ``router``: ``router_down``, ``router_mlp``."""
+
+    def __init__(self, d_model, num_experts, top_k, state_size, scale=1.0,
+                 norm_topk_prob=False, init_std=0.02, epsilon=1e-5):
+        super().__init__()
+        self.top_k, self.scale = top_k, scale
+        self.norm_topk_prob, self.epsilon = norm_topk_prob, epsilon
+        self.state_size = state_size
+        self.down = _linear(d_model, state_size, init_std)
+        self.state_gain = self.create_parameter(
+            (state_size,), default_initializer=Constant(0.5))
+        self.norm_weight = self.create_parameter(
+            (state_size,), default_initializer=Constant(1.0))
+        widths = (state_size, state_size, state_size, num_experts)
+        for i, (d_in, d_out) in enumerate(zip(widths, widths[1:]), start=1):
+            setattr(self, f"fc{i}_weight", self.create_parameter(
+                (d_in, d_out), default_initializer=Normal(0.0, init_std)))
+            setattr(self, f"fc{i}_bias", self.create_parameter(
+                (d_out,), default_initializer=Constant(0.0)))
+        for name, p_ in self.named_parameters():
+            if not name.startswith("down."):
+                p_.amp_keep_float32 = True
+        self.register_buffer(
+            "e_score_correction_bias",
+            Tensor(jnp.zeros((num_experts,), jnp.float32)))
+
+    def forward(self, x2d, state):
+        return _route_state_mlp(
+            x2d, state, self.down.weight, self.state_gain, self.norm_weight,
+            self.fc1_weight, self.fc1_bias, self.fc2_weight, self.fc2_bias,
+            self.fc3_weight, self.fc3_bias, self.e_score_correction_bias,
+            top_k=self.top_k, scale=self.scale,
+            norm_topk=self.norm_topk_prob, eps=self.epsilon)
+
+
+@op("moe_route_state_mlp")
+def _route_state_mlp(x2d, state, down, gain, norm_weight, w1, b1, w2, b2, w3,
+                     b3, bias, *, top_k, scale, norm_topk, eps):
+    """:class:`StateMlpRouter`'s ``(idx, weights, r)``."""
+    f32, hi = jnp.float32, lax.Precision.HIGHEST
+    with jax.named_scope("router_down"):
+        r = jnp.matmul(x2d, down, preferred_element_type=f32) \
+            + gain.astype(f32) * state.astype(f32)
+    with jax.named_scope("router_mlp"):
+        a = r * lax.rsqrt(jnp.mean(jnp.square(r), axis=-1, keepdims=True)
+                          + eps) * norm_weight.astype(f32)
+        for w, b in ((w1, b1), (w2, b2)):
+            a = jax.nn.gelu(jnp.matmul(a, w.astype(f32), precision=hi)
+                            + b.astype(f32), approximate=False)
+        z = jnp.matmul(a, w3.astype(f32), precision=hi) + b3.astype(f32)
+        idx, weights = _dl.route_softmax_topk(z, top_k, scale, norm_topk,
+                                              bias)
+    return idx, weights, r
 
 
 def _linear(d_in, d_out, std):
@@ -315,6 +397,13 @@ class DroplessMoELayer(Layer):
     rows over the rows expected here (``dropless.row_buckets``); a trainer
     whose router is NOT balanced sets it on the layer before the first step.
 
+    ``router_state`` (a dict of :class:`StateMlpRouter`'s ``state_size`` and
+    ``epsilon``) replaces the one-matrix router
+    by the MLP over a carried state: ``forward(x, router_state)`` then takes
+    the state the layer before made, ``[..., state_size]`` float32, and
+    leaves its own in ``router_state_out`` (the block hands it on as an
+    output).
+
     Scopes: ``router``, ``latent_down``, ``dispatch``, ``experts``,
     ``combine``, ``latent_up``, ``shared_experts`` (``docs/PROFILER.md``).
     """
@@ -326,7 +415,7 @@ class DroplessMoELayer(Layer):
                  norm_topk_prob=True, num_local_experts=None,
                  expert_offset=0, init_std=0.02, down_std=None,
                  score_func="sigmoid", body="swiglu", d_latent=None,
-                 d_shared=None):
+                 d_shared=None, router_state=None):
         super().__init__()
         num_local = num_experts if num_local_experts is None \
             else num_local_experts
@@ -336,9 +425,16 @@ class DroplessMoELayer(Layer):
                 f"are not among the router's {num_experts}")
         self.d_model, self.num_experts = d_model, num_experts
         self.num_local_experts, self.expert_offset = num_local, expert_offset
-        self.router = TopKRouter(d_model, num_experts, top_k,
-                                 routed_scaling_factor, norm_topk_prob,
-                                 init_std, score_func)
+        if router_state is None:
+            self.router = TopKRouter(d_model, num_experts, top_k,
+                                     routed_scaling_factor, norm_topk_prob,
+                                     init_std, score_func)
+        else:
+            self.router = StateMlpRouter(
+                d_model, num_experts, top_k, scale=routed_scaling_factor,
+                norm_topk_prob=norm_topk_prob, init_std=init_std,
+                **router_state)
+        self.router_state_out = None
         # in a latent the residual projection is ``latent_up``, not the
         # experts' own second matrix
         self.latent_down = self.latent_up = None
@@ -356,10 +452,16 @@ class DroplessMoELayer(Layer):
             d_model, d_shared, init_std, down_std) if d_shared else None
         self.tokens_per_expert = self.rows_buffered = None
 
-    def forward(self, x):
+    def forward(self, x, router_state=None):
         shape = x.shape
         x2d = x.reshape([-1, self.d_model])
-        idx, weights = self.router(x2d)
+        if router_state is None:
+            idx, weights = self.router(x2d)
+        else:
+            idx, weights, made = self.router(
+                x2d, router_state.reshape([-1, self.router.state_size]))
+            self.router_state_out = made.reshape(
+                list(router_state.shape))
         rows = x2d if self.latent_down is None else self.latent_down(x2d)
         out, self.tokens_per_expert, self.rows_buffered = _routed_experts(
             rows, idx, weights, self.experts.w_in, self.experts.down,

@@ -42,6 +42,13 @@ from .sdar import (  # noqa: F401
     sdar_30b_a3b,
     sdar_tiny,
 )
+from .zaya import (  # noqa: F401
+    CompressedConvAttention,
+    ZayaConfig,
+    ZayaForCausalLM,
+    zaya1_8b,
+    zaya_tiny,
+)
 from .wide_deep import WideDeep  # noqa: F401
 from .deepfm import DeepFM  # noqa: F401
 from .deepspeech import DeepSpeech2, deepspeech2_tiny  # noqa: F401

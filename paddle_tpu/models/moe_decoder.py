@@ -1,8 +1,10 @@
 """The decoder shell the expert models share (``models/mla_moe.py``,
-``models/laguna.py``, ``models/evabyte.py``, ``models/nemotron_h.py``):
-pre-norm residual blocks, the stack, a final RMSNorm, an untied
-``lm_head`` over whatever slice of the vocabulary is held, the
-shifted-label loss and the step's counters.  A block has two branches,
+``models/laguna.py``, ``models/evabyte.py``, ``models/nemotron_h.py``,
+``models/ouro.py``, ``models/sdar.py``, ``models/zaya.py``):
+pre-norm residual blocks, the stack, a final RMSNorm, an ``lm_head`` over
+whatever slice of the vocabulary is held (untied, or the embedding itself
+under ``tie_word_embeddings``), the shifted-label loss and the step's
+counters.  A block has two branches,
 
     x <- x + attn(rms(x));  x <- x + ffn(rms(x));  logits = W_head rms(x)
 
@@ -50,16 +52,33 @@ Two more, off by default too (the looped family, ``models/ouro.py``):
   and its entropy (:meth:`MoeDecoderForCausalLM.looped`); ``loss`` is
   their expectation less ``exit_entropy_beta`` times the entropy.
 
+Three more, off by default too (the ``zaya`` family, ``models/zaya.py``):
+
+- ``router_state_size`` ``S > 0``: a block's expert layer routes through
+  an MLP over a ``[B, T, S]`` float32 router state that carries a term from
+  the block BEFORE (``StateMlpRouter``): a block then takes ``(x, state)``
+  and returns the state its router made as a fourth output, zeros go into
+  the first block, and ``router_state_rms`` float32 ``[layers]`` (the RMS of
+  the state ENTERING each block) joins the step's counters;
+- ``residual_scale``: a residual add becomes ``(s_r * x + b_r) + (s_o *
+  f(norm(x)) + b_o)``, four learned vectors ``[H]`` a branch
+  (:class:`ResidualScale`, held as ``res_1`` / ``res_2``), the sum in
+  float32 and rounded once;
+- ``tie_word_embeddings``: no ``lm_head`` matrix; the logits are ``rms(x)
+  E^T`` over the embedding, whose gradient is the sum of its two uses.
+
 A decoder layer hands its expert counters on as OUTPUTS, so that
 ``jit.TrainStep(remat=...)`` can rematerialise each layer in the backward
 pass.  Scopes: ``embeddings`` / ``layers.i`` / ``ln_1`` / ``attn`` /
-``ln_2`` / ``mlp`` | ``moe`` / ``ln_f`` / ``lm_head``; a block of one
+``ln_2`` / ``mlp`` | ``moe`` / ``ln_f`` / ``lm_head`` (a tied head's
+matmul too); ``residual_scale`` round a scaled add; a block of one
 branch ``layers.i`` / ``ln_1`` / ``mamba`` | ``attn`` | ``moe``; the
 post-branch norms ``ln_1b`` / ``ln_2b``; in a looped model ``lm_head``,
 ``loss`` and ``exit_gate`` inside a pass and ``exit_gate`` round the exit
 distribution (``docs/PROFILER.md``).
 """
 
+import functools
 import math
 
 import jax
@@ -103,7 +122,7 @@ class MoeDecoderConfig:
     ``num_hidden_layers``, ``rms_norm_eps``, ``initializer_range``,
     ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, the
     factories (two for a block of two branches, ``make_mixer`` for a block
-    of one), and the five options of the module's docstring."""
+    of one), and the eight options of the module's docstring."""
 
     fp32_skip_add = False
     norm_add_unit_offset = False
@@ -112,6 +131,9 @@ class MoeDecoderConfig:
     total_ut_steps = 1          # passes of the stack over the same weights
     exit_entropy_beta = 0.0     # read where ``total_ut_steps`` > 1
     branches_per_layer = 2      # residual adds a block makes (``out_std``)
+    router_state_size = 0       # width of the state a router hands on
+    residual_scale = False
+    tie_word_embeddings = False
 
     def make_norm(self):
         cls = UnitOffsetRMSNorm if self.norm_add_unit_offset else nn.RMSNorm
@@ -149,7 +171,7 @@ class MoeDecoderConfig:
         """``router_experts`` is the router's width; the experts held
         here are ``num_local_experts`` from ``expert_offset`` on.
         ``body_and_latent``: ``DroplessMoELayer``'s ``body``, ``d_latent``,
-        ``d_shared``."""
+        ``d_shared``, ``router_state``."""
         return DroplessMoELayer(
             self.hidden_size, width, router_experts, top_k, shared_experts,
             scale, self.norm_topk_prob, self.num_local_experts,
@@ -157,11 +179,43 @@ class MoeDecoderConfig:
             score_func, **body_and_latent)
 
 
+class ResidualScale(nn.Layer):
+    """``(skip_scale * x + skip_bias) + (out_scale * made + out_bias)``, four
+    vectors ``[H]`` (scales 1, biases 0 at the start): the residual add of
+    a family that learns how much of the stream and of the branch goes on
+    (``residual_scale``).  The vectors stay float32 under ``amp.decorate``
+    (``amp_keep_float32``); float32 inside, ``x``'s dtype out; scope
+    ``residual_scale``."""
+
+    def __init__(self, hidden_size):
+        super().__init__()
+        for name, start in (("skip_scale", 1.0), ("skip_bias", 0.0),
+                            ("out_scale", 1.0), ("out_bias", 0.0)):
+            setattr(self, name, self.create_parameter(
+                (hidden_size,), default_initializer=Constant(start)))
+            # a bfloat16 scale at 1 moves in steps of 0.0078: never
+            getattr(self, name).amp_keep_float32 = True
+
+    def forward(self, x, made):
+        with jax.named_scope("residual_scale"):
+            return _scaled_add(x, made, self.skip_scale, self.skip_bias,
+                               self.out_scale, self.out_bias)
+
+
+@op("residual_scaled_add")
+def _scaled_add(x, made, skip_scale, skip_bias, out_scale, out_bias):
+    f32 = jnp.float32
+    return ((skip_scale.astype(f32) * x.astype(f32) + skip_bias.astype(f32))
+            + (out_scale.astype(f32) * made.astype(f32)
+               + out_bias.astype(f32))).astype(x.dtype)
+
+
 class MoeDecoderLayer(nn.Layer):
     """One block, of two branches or of one (the module's docstring).
     Returns ``(x, tokens_per_expert, rows_buffered)``; the counters of a
     layer without experts are empty arrays, so every layer has the same
-    outputs."""
+    outputs.  Under ``router_state_size`` it takes ``(x, router_state)``
+    and the state its router made is a fourth output."""
 
     def __init__(self, config, layer_idx):
         super().__init__()
@@ -176,6 +230,10 @@ class MoeDecoderLayer(nn.Layer):
             setattr(self, self._mixer, module)
             return
         self.attn = c.make_attention(layer_idx)
+        self.res_1 = self.res_2 = None
+        if c.residual_scale:
+            self.res_1 = ResidualScale(c.hidden_size)
+            self.res_2 = ResidualScale(c.hidden_size)
         self.ln_2 = c.make_norm()
         self.ln_2b = c.make_norm() if c.post_branch_norm else None
         ffn = c.make_ffn(layer_idx)
@@ -183,10 +241,11 @@ class MoeDecoderLayer(nn.Layer):
         self.mlp = None if is_moe else ffn
         self.moe = ffn if is_moe else None
 
-    def _branch(self, x, norm, f, post):
+    def _branch(self, x, norm, f, post, add=None):
         """``x + f(norm(x))``, or ``x + post(f(norm(x)))`` with a norm
         after the branch; under ``fp32_skip_add`` the sum in float32 and
-        ``f`` on the parameters' dtype."""
+        ``f`` on the parameters' dtype; ``add(x, made)`` in the sum's place
+        under ``residual_scale``."""
         a = norm(x)
         if self._fp32_skip_add:
             a = a.astype(norm.weight.dtype)
@@ -195,9 +254,9 @@ class MoeDecoderLayer(nn.Layer):
             made = post(made)
         if self._fp32_skip_add:
             made = made.astype("float32")
-        return x + made
+        return x + made if add is None else add(x, made)
 
-    def forward(self, x):
+    def forward(self, x, router_state=None):
         if self._mixer is not None:
             x = self._branch(x, self.ln_1, getattr(self, self._mixer),
                              self.ln_1b)
@@ -205,13 +264,17 @@ class MoeDecoderLayer(nn.Layer):
                 none = Tensor(jnp.zeros((0,), jnp.int32))
                 return x, none, none
             return x, self.moe.tokens_per_expert, self.moe.rows_buffered
-        x = self._branch(x, self.ln_1, self.attn, self.ln_1b)
+        x = self._branch(x, self.ln_1, self.attn, self.ln_1b, self.res_1)
         if self.moe is None:
             none = Tensor(jnp.zeros((0,), jnp.int32))
-            return self._branch(x, self.ln_2, self.mlp, self.ln_2b), \
-                none, none
-        x = self._branch(x, self.ln_2, self.moe, self.ln_2b)
-        return x, self.moe.tokens_per_expert, self.moe.rows_buffered
+            return self._branch(x, self.ln_2, self.mlp, self.ln_2b,
+                                self.res_2), none, none
+        moe = self.moe if router_state is None else functools.partial(
+            self.moe, router_state=router_state)
+        x = self._branch(x, self.ln_2, moe, self.ln_2b, self.res_2)
+        made = (x, self.moe.tokens_per_expert, self.moe.rows_buffered)
+        return made if router_state is None \
+            else made + (self.moe.router_state_out,)
 
 
 class MoeDecoderModel(nn.Layer):
@@ -227,6 +290,7 @@ class MoeDecoderModel(nn.Layer):
             for i in range(config.num_hidden_layers)])
         self.ln_f = config.make_norm()
         self.tokens_per_expert = self.rows_buffered = None
+        self.router_state_rms = None
 
     def forward(self, input_ids):
         return self.stack(self.embed(input_ids))
@@ -239,14 +303,24 @@ class MoeDecoderModel(nn.Layer):
 
     def stack(self, x):
         """The layers and ``ln_f`` once, on the residual stream ``x``."""
-        counters = []
+        counters, state, entering = [], None, []
+        if self.config.router_state_size:
+            state = Tensor(jnp.zeros(
+                (*x.shape[:-1], self.config.router_state_size), jnp.float32))
         for layer in self.layers:
-            x, *made = layer(x)
+            if state is None:
+                x, *made = layer(x)
+            else:
+                with jax.named_scope("router"):
+                    entering.append(jnp.sqrt(jnp.mean(jnp.square(
+                        state._data))))
+                x, *made, state = layer(x, state)
             if layer.moe is not None:
                 counters.append([c._data if isinstance(c, Tensor) else c
                                  for c in made])
         self.tokens_per_expert, self.rows_buffered = \
             [jnp.stack(c) for c in zip(*counters)] or (None, None)
+        self.router_state_rms = jnp.stack(entering) if entering else None
         x = self.ln_f(x)
         if self.config.fp32_skip_add:
             x = x.astype(self.ln_f.weight.dtype)
@@ -286,9 +360,16 @@ class MoeDecoderForCausalLM(nn.Layer):
         super().__init__()
         self.config = config
         self.model = MoeDecoderModel(config)
-        self.lm_head = linear(config.hidden_size,
-                              config.num_pred_heads * config.vocab_size,
-                              config.initializer_range)
+        if config.tie_word_embeddings:
+            if config.num_pred_heads > 1 or config.total_ut_steps > 1:
+                raise NotImplementedError(
+                    "a tied head with several heads or a looped stack is "
+                    "not built")
+            self.lm_head = None
+        else:
+            self.lm_head = linear(config.hidden_size,
+                                  config.num_pred_heads * config.vocab_size,
+                                  config.initializer_range)
         self.pass_loss = self.exit_mass = None
         if config.total_ut_steps > 1:
             if config.fp32_skip_add or config.num_pred_heads > 1 or any(
@@ -304,6 +385,11 @@ class MoeDecoderForCausalLM(nn.Layer):
         if self.config.total_ut_steps > 1:
             return self.looped(input_ids,
                                input_ids if labels is None else labels)
+        if self.lm_head is None:
+            # tied: the embedding is the head, its gradient the sum of both
+            hidden = self.model(input_ids)
+            with jax.named_scope("lm_head"):
+                return F.linear(hidden, self.model.embeddings.weight.T)
         logits = self.lm_head(self.model(input_ids))
         heads = self.config.num_pred_heads
         if heads == 1:
@@ -410,11 +496,18 @@ class MoeDecoderForCausalLM(nn.Layer):
         A looped model (``total_ut_steps`` ``R > 1``): ``ouro_pass_loss``
         float32 ``[R]``, each pass's mean cross entropy, and
         ``ouro_exit_mass`` float32 ``[R]``, the mean over the positions
-        with a target of the exit distribution (sums to 1)."""
+        with a target of the exit distribution (sums to 1).
+        Under ``router_state_size``: ``router_state_rms`` float32
+        ``[layers]``, the RMS of the router state ENTERING each block (0
+        into the first): whether the carried term grows with depth."""
         if self.pass_loss is not None:
             return {"ouro_pass_loss": self.pass_loss,
                     "ouro_exit_mass": self.exit_mass}
         counts = self.model.tokens_per_expert
-        return {} if counts is None else {
-            "moe_tokens_per_expert": counts,
-            "moe_rows_buffered": self.model.rows_buffered}
+        if counts is None:
+            return {}
+        counters = {"moe_tokens_per_expert": counts,
+                    "moe_rows_buffered": self.model.rows_buffered}
+        if self.model.router_state_rms is not None:
+            counters["router_state_rms"] = self.model.router_state_rms
+        return counters

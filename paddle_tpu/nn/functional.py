@@ -1037,8 +1037,10 @@ def causal_conv1d(x, weight, bias=None, activation=None, start=0):
     position itself), ``bias [C]``, over the channels ``start .. start + C``
     of ``x`` (all of them where ``W`` is ``C``); positions before the row's
     first read zero.  A sum of ``K`` shifted products in float32, rounded
-    once to ``x``'s dtype: at ``K`` = 4 one fused pass over the row, no
-    convolution op.
+    once to ``x``'s dtype: one fused pass over the row, no convolution op, at
+    the Mamba mixer's ``K`` = 4 as at the ``K`` = 2 of a compressed
+    attention's first convolution (``models/zaya.py``: no bias, no
+    activation).
 
     ``activation="silu"`` applies :func:`silu` to that (the Mamba mixer's
     use) and lets ``ops.pallas.causal_conv1d`` place the two as ONE call:
@@ -1057,6 +1059,44 @@ def causal_conv1d(x, weight, bias=None, activation=None, start=0):
     from ..ops import pallas
 
     return pallas.causal_conv1d(x, weight, bias, start)
+
+
+def _later(x, steps):
+    """``x [B, T, ...]`` ``steps`` later in time, zeros before the row's
+    first."""
+    if not steps:
+        return x
+    pad = ((0, 0), (steps, 0)) + ((0, 0),) * (x.ndim - 2)
+    return jnp.pad(x, pad)[:, :x.shape[1]]
+
+
+@op()
+def time_shift(x, steps=1):
+    """``x [B, T, ...]`` one step (``steps``) later in time: position ``t``
+    reads position ``t - steps``, positions before the row's first read
+    zero."""
+    return _later(x, steps)
+
+
+@op()
+def causal_conv1d_heads(x, weight):
+    """Causal convolution over time that is FULL over a head's channels and
+    none across heads: ``x [B, T, N, D]``, ``weight [N, K, D, E]`` (tap ``k``
+    reads position ``t - (K - 1) + k``, as :func:`causal_conv1d`) ->
+    ``[B, T, N, E]``, ``out[t, n] = sum_k x[t - (K - 1) + k, n] @ weight[n,
+    k]``; positions before the row's first read zero.  ``K`` batched matmuls
+    over the heads on ``x`` as it lies, each result shifted to its tap's
+    place in time (the shift commutes with a matmul a position), summed in
+    float32 and rounded once to ``x``'s dtype.  The operands are cast up
+    and multiplied at the default precision (one bfloat16 pass on the TPU,
+    which drops the casts; the CPU's batched dot has no bfloat16 x bfloat16
+    -> float32)."""
+    taps = weight.shape[1]
+    x32, w32 = x.astype(jnp.float32), weight.astype(jnp.float32)
+    out = sum(_later(jnp.einsum("btnd,nde->btne", x32, w32[:, k],
+                                precision=lax.Precision.DEFAULT),
+                     taps - 1 - k) for k in range(taps))
+    return out.astype(x.dtype)
 
 
 def _ssd_scan_row(x, dt, a_head, b, c, d_head, chunk):

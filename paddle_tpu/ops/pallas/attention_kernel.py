@@ -119,8 +119,10 @@ def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
     the blocks (so a power of two).
 
     How long a sequence Mosaic takes is the forward's to say, which holds K
-    and V whole: 8192 in bf16 and 4096 in float32 at one width (its default
-    16 MB), 32,768 at 192 | 128 (64 MB asked).  The backward, which holds a
+    and V whole: 8192 in bf16 and 4096 in float32 at one width on its default
+    16 MB, 16,384 in bf16 with what it then asks (K and V's 16 MB and 16 MB
+    more, ``_flash_fwd``), 32,768 at 192 | 128 (64 MB asked).  The
+    backward, which holds a
     q head's Q, dO and float32 dQ whole, fits further: 32,768 in bf16
     (16,384 grouped, where whole-sequence dK and dV join them) and 16,384
     in float32 (ahead of time for the v5e, 512 x 512 blocks, PR 33)."""
@@ -139,6 +141,11 @@ def supports(seq_q, seq_k, head_dim, v_head_dim=None, q_heads=None,
                     and seq_q % (2 * blocks[0]) == 0
                     and not any(b % block_diffusion for b in blocks))
     return heads_ok and None not in blocks
+
+
+# K and V bytes held in VMEM from which the forward asks for its allowance
+# (what is held and 16 MB more) and no longer lives on Mosaic's default 16 MB
+_ASK_VMEM_FROM = 12 * 1024 * 1024
 
 
 def _compiler_params(head_qk, head_v):
@@ -443,11 +450,14 @@ def _flash_fwd(q, k, v, dims, causal, scale, block_q, block_k, interpret,
     kv_head = lambda b, i: (b // group, 0)                   # noqa: E731
     num_qb = seq_q // block_q
     params = _compiler_params(head, head_v)
-    if block_diffusion is not None:
-        # K and V of BOTH halves are held whole, double-buffered: 16 MB at
-        # [16384, 128] bf16, which is Mosaic's whole default allowance
-        held = 2 * (_vmem_bytes((1, seq_k, head), k.dtype)
-                    + _vmem_bytes((1, seq_k, head_v), v.dtype))
+    # K and V are held whole, double-buffered: 16 MB at [16384, 128] bf16
+    # (both halves of a block-diffusion row, or one causal row that long),
+    # which is Mosaic's whole default allowance.  A call that holds less
+    # than _ASK_VMEM_FROM asks nothing, as before (8 MB at 8,192)
+    held = 2 * (_vmem_bytes((1, seq_k, head), k.dtype)
+                + _vmem_bytes((1, seq_k, head_v), v.dtype))
+    if block_diffusion is not None or (not params
+                                       and held >= _ASK_VMEM_FROM):
         params = {"compiler_params": pltpu.CompilerParams(
             vmem_limit_bytes=held + 16 * 1024 * 1024)}
     out, lse = pl.pallas_call(

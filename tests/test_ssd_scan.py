@@ -112,7 +112,7 @@ def test_bfloat16_operands_keep_float32_sums():
         < 3e-2 * scale
 
 
-@pytest.mark.parametrize("taps", [1, 4])
+@pytest.mark.parametrize("taps", [1, 2, 4])
 def test_causal_conv_is_a_sum_of_shifted_products(taps):
     r = np.random.default_rng(taps)
     x = r.standard_normal((2, 9, 6)).astype(np.float32)

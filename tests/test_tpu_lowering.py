@@ -16,6 +16,7 @@ from meaning "has never met Mosaic":
 """
 
 import functools
+import re
 import types
 
 import jax
@@ -497,6 +498,27 @@ def test_window_and_grouped_calls_are_other_programs():
              for h in bodies.values()}
     assert not known & (set(window.values()) | set(full.values()))
     assert "tensor<1x8192x1024xbf16>" in _flash_grads_lowered(q48, kv, kv)
+
+
+def _scoped_vmem(text, kernel):
+    """The bytes of VMEM a custom call of ``text`` asks for, or nothing."""
+    line = next(l for l in text.splitlines()
+                if f'kernel_name = "{kernel}"' in l)
+    asked = re.search(r'scoped_memory_configs.*?\\22size\\22: (\d+)', line)
+    return int(asked.group(1)) if asked else None
+
+
+@pytest.mark.parametrize("seq,asked", [(8192, None), (16384, 32 << 20)])
+def test_a_causal_forward_asks_for_vmem_from_a_row_of_16384(seq, asked):
+    """The forward holds a kv head's K and V whole, double-buffered: 8 MB at
+    8,192 (nothing asked: laguna's call as it was), 16 MB at 16,384, which
+    is Mosaic's whole default allowance, so the call asks for what it holds
+    and 16 MB more (the v5e compiler refused the zaya cell's step
+    without: "Scoped allocation with size 16.62M and limit 16.00M")."""
+    q = jax.ShapeDtypeStruct((1, seq, 8, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, seq, 2, 128), jnp.bfloat16)
+    assert _scoped_vmem(_flash_grads_lowered(q, kv, kv),
+                        "flash_attention_fwd") == asked
 
 
 def test_eva_kernels_lower_as_before_the_flash_backward_was_one_kernel():
