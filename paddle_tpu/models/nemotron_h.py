@@ -45,6 +45,17 @@ groups of B and C at state ``N`` = ``ssm_state_size``, head ``h`` on group
   a width off the 128-lane tiles, a GSPMD mesh) the XLA composition
   ``F._causal_conv1d_silu`` runs on the slice.
 
+  The gated norm is ``_gated_norm(y, W_in's result, g_norm, start=0)``: the
+  gate z is read where it lies, the result's first ``d`` lanes.  On the TPU,
+  at shapes ``ops/pallas/gated_norm_kernel.py supports`` takes (the published
+  ones: 8 groups of 1,024 lanes), that is the ``gated_norm_fwd`` /
+  ``gated_norm_bwd`` kernels: a program holds one group's lanes of a block of
+  rows, so the view ``[T, G, d / G]`` is never formed, everything between
+  the bfloat16 operands and the bfloat16 result is float32 in VMEM, and the
+  backward keeps y and z alone.  Elsewhere (off the TPU, the tiny test
+  config's groups of 32 lanes, a GSPMD mesh) the XLA composition
+  ``_gated_norm_composed`` runs on the slice.
+
 ``*``, attention: ``num_attention_heads`` q heads over
 ``num_key_value_heads`` kv heads of ``head_dim``, causal, scale ``head_dim
 ** -0.5``; NO rotary, no q/k norm, no gate (the public ``nemotron_h``
@@ -167,17 +178,33 @@ class NemotronHConfig(MoeDecoderConfig):
             d_shared=self.moe_shared_expert_intermediate_size)
 
 
-@op("mamba_gated_rms_norm")
-def _gated_norm(y, z, weight, groups, epsilon):
-    """``rms_groups(y * silu(z)) * weight``: the gate BEFORE the norm, the
-    statistics over each of ``groups`` equal parts of the last axis;
-    float32 inside, ``y``'s dtype out."""
+def _gated_norm_composed(y, z, weight, groups, epsilon, start=0):
+    """:func:`_gated_norm` as the XLA composition, on the slice of ``z``."""
+    z = z[..., start:start + y.shape[-1]]
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     parts = g.reshape(g.shape[:-1] + (groups, -1))
     parts = parts * jax.lax.rsqrt(
         jnp.mean(jnp.square(parts), axis=-1, keepdims=True) + epsilon)
     return (parts.reshape(g.shape) * weight.astype(jnp.float32)).astype(
         y.dtype)
+
+
+@op("mamba_gated_rms_norm")
+def _gated_norm(y, z, weight, groups, epsilon, start=0):
+    """``rms_groups(y * silu(z)) * weight``: the gate BEFORE the norm, the
+    statistics over each of ``groups`` equal parts of the last axis;
+    float32 inside, ``y``'s dtype out.  The gate is lanes ``start .. start +
+    C`` of ``z`` (all of them where z is as wide as y).
+    ``ops.pallas.gated_rms_norm`` places the call: on the TPU, where
+    ``gated_norm_kernel.supports`` takes the shapes (a group's width and
+    ``start`` whole 128-lane tiles and whole groups, rows whole tiles of 16),
+    the ``gated_norm_fwd`` / ``gated_norm_bwd`` kernels, which read the gate
+    where it lies and whose backward keeps y and z alone; elsewhere (off the
+    TPU; aloud on it, ``KernelFallbackWarning``, for other shapes or under a
+    GSPMD mesh) :func:`_gated_norm_composed`, differentiated as it stands."""
+    from ..ops import pallas
+
+    return pallas.gated_rms_norm(y, z, weight, groups, epsilon, start)
 
 
 @op("mamba_step_sizes")
@@ -240,7 +267,6 @@ class Mamba2Mixer(nn.Layer):
         b, t, _ = a.shape
         d, gn = self.inner, self.groups * self.state
         zxbcdt = self.in_proj(a)
-        z = zxbcdt[..., :d]
         with jax.named_scope("mamba_conv"):
             # x | B | C, each convolved where it lies in the projection's
             # result and written as the scan reads it
@@ -259,8 +285,10 @@ class Mamba2Mixer(nn.Layer):
                 c_t.reshape([b, t, self.groups, self.state]),
                 self.D, chunk=self.chunk)
         with jax.named_scope("gated_norm"):
-            y = _gated_norm(y.reshape([b, t, d]), z, self.norm_weight,
-                            groups=self.groups, epsilon=self.epsilon)
+            # the gate z where it lies: the projection's first d lanes
+            y = _gated_norm(y.reshape([b, t, d]), zxbcdt, self.norm_weight,
+                            groups=self.groups, epsilon=self.epsilon,
+                            start=0)
         return self.out_proj(y)
 
 

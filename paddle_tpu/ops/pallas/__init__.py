@@ -172,6 +172,13 @@ def causal_conv_log():
     return _view(("shapes", "start", "path", "reason"), "causal_conv")
 
 
+def gated_norm_log():
+    """The same of :func:`gated_rms_norm`: ``shapes`` (y, z), ``groups``,
+    ``start``, ``path`` and ``reason``."""
+    return _view(("shapes", "groups", "start", "path", "reason"),
+                 "gated_norm")
+
+
 def traced_call_sums():
     """What a compiled step's account takes its own share of
     (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
@@ -181,9 +188,11 @@ def traced_call_sums():
     :func:`mla_expand_qkv`; ``moe_run_sum_calls`` /
     ``moe_run_sum_calls_composed``, the same of :func:`moe_run_sum` (an
     expert layer's routed block traces one a branch of each of its two
-    switches: combine, and the dispatch's transpose); and
+    switches: combine, and the dispatch's transpose);
     ``causal_conv_calls`` / ``causal_conv_calls_composed``, the same of
-    :func:`causal_conv1d` (three a Mamba mixer: x, B and C)."""
+    :func:`causal_conv1d` (three a Mamba mixer: x, B and C); and
+    ``gated_norm_calls`` / ``gated_norm_calls_composed``, the same of
+    :func:`gated_rms_norm` (one a Mamba mixer)."""
     return dict(common.traced_sums)
 
 
@@ -524,6 +533,29 @@ def causal_conv1d(x, weight, bias=None, start=0):
         lambda: _causal_conv1d_silu(x, weight, bias, start),
         counter="causal_conv_calls", more={"start": start},
         fits=(x.shape[1], weight.shape[0], weight.shape[1], x.dtype, start))
+
+
+def gated_rms_norm(y, z, weight, groups, epsilon, start=0):
+    """The Mamba mixer's gated group norm (``models/nemotron_h.py
+    _gated_norm``): ``y [..., C]``, ``z [..., W]`` of the same rows,
+    ``weight [C]`` -> ``rms_groups(y * silu(z[..., start:start + C])) *
+    weight`` ``[..., C]``, the statistics over each of ``groups`` equal
+    parts of ``C``, float32 inside.  The ``gated_norm_fwd`` /
+    ``gated_norm_bwd`` kernels (``gated_norm_kernel``), which read the gate
+    where it lies in ``z``, or the XLA composition ``models/nemotron_h.py
+    _gated_norm_composed``, as :func:`_dispatch` places it; every traced
+    call is recorded (:func:`gated_norm_log`)."""
+    from ...models.nemotron_h import _gated_norm_composed
+
+    return _dispatch(
+        "gated_norm", "gated_norm_kernel", (tuple(y.shape), tuple(z.shape)),
+        lambda norm: norm.gated_norm_pallas(
+            y, z, weight, groups=groups, epsilon=epsilon, start=start),
+        lambda: _gated_norm_composed(y, z, weight, groups, epsilon, start),
+        counter="gated_norm_calls", more={"groups": groups, "start": start},
+        unfit=None if z.dtype == y.dtype else
+        f"z {z.dtype} beside y {y.dtype}",
+        fits=(math.prod(y.shape[:-1]), y.shape[-1], groups, y.dtype, start))
 
 
 def mla_rope(x, cos, sin, interleave):

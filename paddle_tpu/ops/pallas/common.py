@@ -95,7 +95,8 @@ traced_sums = dict.fromkeys(
      "ssd_calls", "ssd_calls_composed",
      "mla_expand_calls", "mla_expand_calls_composed",
      "moe_run_sum_calls", "moe_run_sum_calls_composed",
-     "causal_conv_calls", "causal_conv_calls_composed"), 0)
+     "causal_conv_calls", "causal_conv_calls_composed",
+     "gated_norm_calls", "gated_norm_calls_composed"), 0)
 
 
 def record_flash_layout(kernel, shapes, in_place, copied):

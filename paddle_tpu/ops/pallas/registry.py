@@ -58,6 +58,7 @@ KERNEL_MODULES = (
     "decode_attention_kernel",
     "ragged_attention_kernel",
     "layernorm_kernel",
+    "gated_norm_kernel",
 )
 
 _REGISTRY = {}

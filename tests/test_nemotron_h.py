@@ -148,6 +148,56 @@ def test_the_convolution_s_kernels_give_the_reference_s_loss_and_gradients(
     _assert_gradients_are_the_reference_s(m, model, grads, want)
 
 
+def test_the_gated_norm_s_kernels_give_the_composition_s_mixer(monkeypatch):
+    """One mixer's forward and backward with its gated norm through the
+    ``gated_norm_*`` kernels (interpret mode) at a width they take: 32 heads
+    of 8 = 256 lanes over 2 groups of one tile each, the gate read where it
+    lies, the first 256 lanes of the projection's ``[z | x | B | C | dt]``,
+    over two batch rows of 16: the composition's output and the gradient of
+    the input and of every parameter, to float32's orders of summation."""
+    from paddle_tpu.ops import pallas as pk
+    from paddle_tpu.ops.pallas import gated_norm_kernel as gk
+
+    paddle.seed(3)
+    mixer = Mamba2Mixer(NemotronHConfig(
+        hidden_size=32, num_hidden_layers=1, hybrid_override_pattern="M",
+        mamba_num_heads=32, mamba_head_dim=8, n_groups=2, ssm_state_size=8,
+        chunk_size=8, initializer_range=0.2))
+    r = np.random.default_rng(3)
+    params = {k: v._data for k, v in mixer.state_dict().items()}
+    params["norm_weight"] = jnp.asarray(
+        1.0 + 0.2 * r.standard_normal(256), jnp.float32)
+    a, co = (jnp.asarray(r.standard_normal((2, 16, 32)), jnp.float32)
+             for _ in range(2))
+
+    def loss(p, a):
+        out = functional_call(mixer, p, a)
+        return jnp.sum(out * co), out
+
+    both = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+    with jax.default_matmul_precision("highest"):
+        (_, want), want_grads = both(params, a)
+        served = []
+
+        def through_the_kernels(y, z, weight, groups, epsilon, start=0):
+            served.append((tuple(y.shape), tuple(z.shape), groups, start))
+            return gk.gated_norm_pallas(
+                y, z, weight, groups=groups, epsilon=epsilon, start=start,
+                interpret=True, block=(16, 16))
+
+        monkeypatch.setattr(pk, "gated_rms_norm", through_the_kernels)
+        (_, got), got_grads = both(params, a)
+    assert served == [((2, 16, 256), (2, 16, 256 + 288 + 32), 2, 0)]
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    flat = dict(got_grads[0], input=got_grads[1])
+    for name, w in dict(want_grads[0], input=want_grads[1]).items():
+        scale = float(jnp.max(jnp.abs(w)))
+        assert scale > 0, name
+        np.testing.assert_allclose(flat[name], w, rtol=0, atol=2e-5 * scale,
+                                   err_msg=name)
+
+
 def test_the_reference_s_gradient_by_blocks_is_its_gradient_whole(seeded):
     """``row_loss_and_grad`` (the chain rule by hand over ``jax.vjp`` of
     each block, what the chip's comparison follows so that float32 at the

@@ -1,5 +1,5 @@
 """``ops/pallas``: the ONE rule that places a call (``_refusal`` /
-``_dispatch``) held over its seven dispatchers, the flash kernels' block
+``_dispatch``) held over its eight dispatchers, the flash kernels' block
 rule at the benchmark's shapes, and the direction of the package's imports.
 
 The kernels' values are other files' business (``test_pallas_kernels.py``,
@@ -18,7 +18,7 @@ import pytest
 from paddle_tpu.ops import pallas as pk
 from paddle_tpu.ops.pallas import (attention_kernel, causal_conv_kernel,
                                    common, eva_attention_kernel,
-                                   mla_expand_kernel,
+                                   gated_norm_kernel, mla_expand_kernel,
                                    moe_run_sum_kernel, registry,
                                    ssd_scan_kernel)
 
@@ -46,6 +46,11 @@ def _run_sum(width):
 
 def _conv(channels):
     return ((_x(1, 64, channels), _x(channels, 4), _x(channels)), {})
+
+
+def _norm(channels):
+    return ((_x(1, 64, channels), _x(1, 64, 2 * channels), _x(channels), 2,
+             1e-5), {"start": channels})
 
 
 def _grouped(rows):
@@ -90,6 +95,11 @@ DISPATCHERS = {
         ("paddle_tpu.nn.functional", "_causal_conv1d_silu"),
         _conv(128), _conv(96),
         "causal_conv_kernel.supports() refuses", "causal_conv_calls"),
+    "gated_norm": (
+        pk.gated_rms_norm, (gated_norm_kernel, "gated_norm_pallas"),
+        ("paddle_tpu.models.nemotron_h", "_gated_norm_composed"),
+        _norm(256), _norm(192),
+        "gated_norm_kernel.supports() refuses", "gated_norm_calls"),
     "grouped_matmul": (
         pk.grouped_matmul,
         ("jax.experimental.pallas.ops.tpu.megablox.ops", "gmm"),

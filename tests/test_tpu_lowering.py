@@ -95,9 +95,10 @@ def test_registry_lowers_for_tpu_where_supported():
     # (eva) kernels vjp x 3, the two training layernorm shapes, the
     # state-space scan's kernels vjp x 3, latent attention's expansion
     # (value and vjp) x 3, the expert layer's run sums (weighted and not)
-    # x 3, the causal convolution's kernels (value and vjp) x 3
+    # x 3, the causal convolution's kernels (value and vjp) x 3, the gated
+    # group norm's (value and vjp) x 3
     assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 3 + 2 * 3 + 2 + 3 + 3 \
-        + 2 * 3 + 2 * 3
+        + 2 * 3 + 2 * 3 + 2 * 3
 
 
 def test_refusals_are_declared_only_where_needed():
@@ -762,6 +763,7 @@ def test_mla_expand_dispatch_is_counted_and_gives_way_aloud(
         "flash_operands_copied": 0, "ssd_calls": 0, "ssd_calls_composed": 0,
         "moe_run_sum_calls": 0, "moe_run_sum_calls_composed": 0,
         "causal_conv_calls": 0, "causal_conv_calls_composed": 0,
+        "gated_norm_calls": 0, "gated_norm_calls_composed": 0,
         "mla_expand_calls": 1,
         "mla_expand_calls_composed": 0 if case == "kernel" else 1}
     if case != "mesh":
@@ -770,6 +772,56 @@ def test_mla_expand_dispatch_is_counted_and_gives_way_aloud(
         for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g, np.float32),
                                           np.asarray(w, np.float32))
+
+
+# ------------------------------------------ the Mamba mixer's gated norm --
+
+# The ``gated_norm_*`` kernels as Mosaic gets them at the hybrid cell's call
+# (y ``bf16[1, 4096, 8192]`` over 8 groups, the gate the first 8,192 lanes of
+# the projection's ``[1, 4096, 18560]``), source locations stripped
+# (``_mosaic_bodies``); read at PR 48's tree.
+_GATED_NORM_BODIES = {"gated_norm_fwd": "62813a11718d5111",
+                      "gated_norm_bwd": "0530d9f17d30e112"}
+
+
+def _gated_norm_lowered(wide, start):
+    from paddle_tpu.ops.pallas.gated_norm_kernel import (gated_norm_pallas,
+                                                         supports)
+
+    dtype = jnp.bfloat16
+    assert supports(4096, 8192, 8, dtype, start)
+    norm = functools.partial(gated_norm_pallas, groups=8, epsilon=1e-5,
+                             start=start)
+    sds = jax.ShapeDtypeStruct
+
+    def f(y, z, w):
+        def loss(*o):
+            return jnp.sum(norm(*o).astype(jnp.float32))
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(y, z, w)
+
+    return jax.jit(f).trace(
+        sds((1, 4096, 8192), dtype), sds((1, 4096, wide), dtype),
+        sds((8192,), dtype)).lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("wide,start,same", [
+    (18560, 0, True), (8192, 0, True), (18560, 10240, False)],
+    ids=["in_place", "sliced", "behind_other_lanes"])
+def test_the_gated_norm_is_one_kernel_forward_and_one_backward(wide, start,
+                                                               same):
+    """Value and gradients of the mixer's gated norm, lowered for the TPU at
+    the cell's shape: TWO ``tpu_custom_call``s and no op shaped by the view
+    ``[..., groups, width]``.  The gate read in place at the head of the
+    projection's result and a gate sliced out before the call are ONE
+    program (the operand's width is none of the kernel's business); a gate
+    that lies behind other lanes is the same program under another block
+    offset."""
+    text = _gated_norm_lowered(wide, start)
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == ["gated_norm_bwd", "gated_norm_fwd"]
+    assert text.count("tpu_custom_call") == 2
+    assert "x8x1024x" not in text
+    assert (_mosaic_bodies(text) == _GATED_NORM_BODIES) == same
 
 
 @pytest.mark.slow
