@@ -28,7 +28,7 @@ from ..core.tensor import Tensor
 from ..framework import mode
 from ..framework.random import get_rng_key, key_stream
 from ..nn.layer_base import Layer, block_remat
-from ..profiler import RecordEvent, StepTrace
+from ..profiler import StepTrace
 
 _is_tensor = lambda x: isinstance(x, Tensor)
 
@@ -429,22 +429,26 @@ class TrainStep:
         loss_fn(output, *labels)."""
         self._step += 1
         step, trace = self._step, self._trace
-        with RecordEvent(trace.STEP, step=step):
-            with RecordEvent(trace.OPERANDS, step=step):
+        with trace.call(step):
+            with trace.phase(trace.OPERANDS):
                 args = self._operands(step, get_rng_key(), inputs, labels)
             out = trace.dispatch(self._compiled, args, step)
             loss, self._params, self._opt_state, self.counters = out[:4]
             if self.scaler is not None:
                 self.scaler._compiled_state = out[4]
-            with RecordEvent(trace.SYNC, step=step):
+            with trace.phase(trace.SYNC):
                 self.sync_to_model()
         return Tensor(loss)
 
     def stats(self):
-        """``{"steps", "compiles"}``: calls so far, and how many of them
-        compiled (1 after the first; more means a shape or a dtype changed
-        under way -- the trace marks which step, ``train_step::compiled``)."""
-        return {"steps": self._step, "compiles": self._trace.compiles}
+        """``{"steps", "compiles", "long_steps"}``: calls so far, how many
+        of them compiled (1 after the first; more means a shape or a dtype
+        changed under way -- the trace marks which step,
+        ``train_step::compiled``) and how many took over ``StepTrace.LONG``
+        times the median of the steps before them (``profiler.step_log()`` says
+        which, and who held each)."""
+        return {"steps": self._step, "compiles": self._trace.compiles,
+                "long_steps": self._trace.long_steps}
 
     def compile_account(self):
         """The compile log's record of the newest call that compiled

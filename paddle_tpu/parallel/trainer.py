@@ -27,7 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.tensor import Tensor
 from ..distributed.fleet.spmd import data_axes, use_mesh
 from ..framework.random import get_rng_key, key_stream
-from ..profiler import RecordEvent, StepTrace
+from ..profiler import StepTrace
 from .pipeline import spmd_pipeline
 
 
@@ -369,8 +369,8 @@ class SpmdTrainStep:
     def step(self, input_ids, labels):
         self._step_count += 1
         step, trace = self._step_count, self._trace
-        with RecordEvent(trace.STEP, step=step):
-            with RecordEvent(trace.OPERANDS, step=step):
+        with trace.call(step):
+            with trace.phase(trace.OPERANDS):
                 args = self._operands(step, get_rng_key(), input_ids, labels)
             # use_mesh, not a bare ``with mesh``: the kernel dispatchers read
             # fleet.spmd.current_mesh() to know GSPMD partitions this step
@@ -386,8 +386,10 @@ class SpmdTrainStep:
     __call__ = step
 
     def stats(self):
-        """``{"steps", "compiles"}``, as ``jit.TrainStep.stats``."""
-        return {"steps": self._step_count, "compiles": self._trace.compiles}
+        """``{"steps", "compiles", "long_steps"}``, as
+        ``jit.TrainStep.stats``."""
+        return {"steps": self._step_count, "compiles": self._trace.compiles,
+                "long_steps": self._trace.long_steps}
 
     def compile_account(self):
         """The newest compile's record, as ``jit.TrainStep

@@ -13,8 +13,11 @@ summary-table shape.
 import collections
 import contextlib
 import functools
+import gc
 import json
 import os
+import resource
+import statistics
 import threading
 import time
 import warnings
@@ -270,11 +273,128 @@ def compile_log():
     return list(_ExecutablesBuilt.log)
 
 
+def step_log():
+    """The process's flight record of its train steps, oldest first: one
+    small record for every call of a ``jit.TrainStep`` or a
+    ``parallel.SpmdTrainStep`` (the newest ``StepTrace.KEEP``), kept with
+    or without a profiler session, and after the step object has gone.
+    docs/PROFILER.md, "The step's flight record"."""
+    with StepTrace._lock:
+        return list(StepTrace.log)
+
+
+class _Collections:
+    """Python's collector, counted: ``count`` collections that took
+    ``seconds`` in all since the process began.  ONE function in
+    ``gc.callbacks``, registered when this module is imported; it runs only
+    when a collection does."""
+
+    count = 0
+    seconds = 0.0
+    _began = None
+
+    @classmethod
+    def _on(cls, phase, info):
+        if phase == "start":
+            cls._began = time.perf_counter()
+        elif cls._began is not None:
+            cls.count += 1
+            cls.seconds += time.perf_counter() - cls._began
+            cls._began = None
+
+
+gc.callbacks.append(_Collections._on)
+
+
+class _SchedStat:
+    """The calling thread's ``/proc/thread-self/schedstat``, kept open: the
+    name is resolved when the file is opened, so the descriptor stays this
+    thread's and is read by it alone (a read costs 0.6 us, opening the
+    file each time 8).  ``fd`` is ``None`` where there is no such file.
+    Closed when the thread's locals go."""
+
+    def __init__(self):
+        try:
+            self.fd = os.open(StepTrace.SCHEDSTAT, os.O_RDONLY)
+        except OSError:
+            self.fd = None
+
+    def close(self):
+        fd, self.fd = self.fd, None
+        if fd is not None:
+            with contextlib.suppress(OSError):
+                os.close(fd)
+
+    __del__ = close
+
+
+_sched = threading.local()      # .stat: this thread's _SchedStat
+
+
+def _run_delay_ns():
+    """The nanoseconds the calling thread has stood RUNNABLE without a CPU
+    (the second number of its ``schedstat``), or ``None``."""
+    try:
+        stat = _sched.stat
+    except AttributeError:
+        stat = _sched.stat = _SchedStat()
+    if stat.fd is None:
+        return None
+    try:
+        return int(os.pread(stat.fd, 64, 0).split()[1])
+    except (OSError, ValueError, IndexError):
+        # a descriptor from before a fork, a short read: the record goes
+        # on without run-delay, and never raises into the step
+        stat.close()
+        return None
+
+
+def thread_snapshot():
+    """``[(tid, comm, on-CPU ns, run-delay ns, timeslices)]`` of every task
+    of the process, cumulative (``/proc/self/task/<tid>/schedstat``): the
+    difference of two snapshots says which threads ran, which stood
+    runnable without a CPU and which never woke.  ``None`` where the
+    calling thread has no ``schedstat`` to read; a thread that ends while
+    the tasks are listed is left out."""
+    if _run_delay_ns() is None:
+        return None
+    out = []
+    for tid in os.listdir(StepTrace.TASKS):
+        try:
+            with open(f"{StepTrace.TASKS}/{tid}/comm") as f:
+                comm = f.read().strip()
+            with open(f"{StepTrace.TASKS}/{tid}/schedstat") as f:
+                on_cpu, delay, slices = map(int, f.read().split())
+        except OSError:
+            continue
+        out.append((int(tid), comm, on_cpu, delay, slices))
+    return out
+
+
+class _Phase:
+    """A span of the step that also writes its seconds into the call's
+    record."""
+
+    __slots__ = ("record", "field", "span", "t0")
+
+    def __init__(self, record, name):
+        self.record, self.field = record, StepTrace.PHASES[name]
+        self.span = RecordEvent(name, step=record["step"])
+
+    def __enter__(self):
+        self.t0 = time.perf_counter()
+        self.span.begin()
+
+    def __exit__(self, *exc):
+        self.span.end()
+        self.record[self.field] = time.perf_counter() - self.t0
+
+
 class StepTrace:
-    """The host spans, the compile count and the compile's account of a
-    compiled train step: ``jit.TrainStep`` and ``parallel.SpmdTrainStep``
-    mark every call the same way, so one reader of the trace serves both
-    (docs/PROFILER.md).
+    """The host spans, the compile count, the compile's account and the
+    flight record of a compiled train step: ``jit.TrainStep`` and
+    ``parallel.SpmdTrainStep`` mark every call the same way, so one reader
+    of the trace serves both (docs/PROFILER.md).
 
     ``train_step`` covers the whole call; inside it ``::operands`` (RNG
     key, step and learning-rate scalars, the batch's placement),
@@ -288,7 +408,17 @@ class StepTrace:
     ``flash_operands_copied`` (``ops.pallas.record_flash_layout``), its
     ``ssd_calls`` / ``ssd_calls_composed`` (``ops.pallas.ssd_scan``) and the
     compiled text's ``collectives_async`` / ``collectives_sync``.  Every
-    span carries ``step``.  ``::init`` covers the trainer's construction."""
+    span carries ``step``.  ``::init`` covers the trainer's construction.
+
+    The flight record (docs/PROFILER.md, "The step's flight record") is
+    taken where those spans open and close, with or without a profiler
+    session: ``with trace.call(step):`` round the call, ``with
+    trace.phase(name):`` round a phase.  Every call leaves one small
+    record in ``log`` (the process's, read by ``profiler.step_log()``), the
+    newest ``KEEP``.  At the entry of call n+1 record n is marked ``long``
+    where ``enter[n+1] - enter[n]`` passed ``LONG`` times the median of the
+    last ``MEDIAN_OF`` such intervals (``MEDIAN_MIN`` known at least, none
+    of a call that compiled); ``long_steps`` counts them."""
 
     STEP = "train_step"
     OPERANDS = "train_step::operands"
@@ -296,10 +426,27 @@ class StepTrace:
     SYNC = "train_step::sync_to_model"
     COMPILED = "train_step::compiled"
     INIT = "train_step::init"
+    PHASES = {OPERANDS: "operands_s", DISPATCH: "dispatch_s",
+              SYNC: "sync_s"}
+
+    SCHEDSTAT = "/proc/thread-self/schedstat"
+    TASKS = "/proc/self/task"
+    KEEP = 4096
+    LONG = 1.25
+    MEDIAN_OF = 16
+    MEDIAN_MIN = 8
+    SNAPSHOT_OVER = 0.02    # seconds over the median that are worth the
+    #                         threads' snapshot (it costs 1-2 ms)
+    log = collections.deque(maxlen=KEEP)
+    _lock = threading.Lock()
 
     def __init__(self):
         self.compiles = 0
         self.account = None
+        self.long_steps = 0
+        self._intervals = collections.deque(maxlen=self.MEDIAN_OF)
+        self._record = {}       # the newest call's
+        self._last = None       # its probes, at its entry and its return
 
     @staticmethod
     def init(constructor):
@@ -317,6 +464,82 @@ class StepTrace:
                                    "at": now, "seconds": now - t0})
         return timed
 
+    @contextlib.contextmanager
+    def call(self, step):
+        """``with trace.call(step):`` round one call of the step: its
+        record and, inside the record's probes, the span ``train_step``.
+        The clock is read first at the entry and last at the return, so
+        what the record costs lies inside ``call_s`` and never in
+        ``between_s``; the span opens after the entry's probes and closes
+        before the return's, so it covers what it covered without them."""
+        now = time.perf_counter()
+        ident, cpu, delay = (threading.get_ident(), time.thread_time_ns(),
+                             _run_delay_ns())
+        process = time.process_time_ns()
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
+        collections_, collecting = _Collections.count, _Collections.seconds
+        self._record = rec = {
+            "name": self.STEP, "step": step, "enter": now, "call_s": None,
+            "operands_s": 0.0, "dispatch_s": 0.0, "sync_s": 0.0,
+            "between_s": None, "between_cpu_s": None, "call_cpu_s": None,
+            "between_run_delay_s": None, "call_run_delay_s": None,
+            "process_cpu_s": None, "nivcsw": None, "majflt": None,
+            "gc": None, "compiled": False, "long": False, "threads": None}
+        last = self._last
+        # CPU seconds and context switches are a thread's own: a call by
+        # another thread than the last starts anew, as an object's first
+        if last is not None and last["ident"] == ident:
+            rec["between_s"] = now - last["left"]
+            rec["between_cpu_s"] = 1e-9 * (cpu - last["left_cpu"])
+            if None not in (delay, last["left_delay"]):
+                rec["between_run_delay_s"] = 1e-9 * (
+                    delay - last["left_delay"])
+            rec["process_cpu_s"] = 1e-9 * (process - last["process"])
+            rec["nivcsw"] = usage.ru_nivcsw - last["usage"].ru_nivcsw
+            rec["majflt"] = usage.ru_majflt - last["usage"].ru_majflt
+            rec["gc"] = (collections_ - last["collections"],
+                         collecting - last["collecting"])
+            self._judge(last["record"], now - last["record"]["enter"], rec)
+        self._last = last = {
+            "ident": ident, "record": rec, "process": process,
+            "usage": usage, "collections": collections_,
+            "collecting": collecting}
+        try:
+            with RecordEvent(self.STEP, step=step):
+                yield
+        finally:
+            with self._lock:
+                self.log.append(rec)
+            left_cpu, left_delay = time.thread_time_ns(), _run_delay_ns()
+            rec["call_cpu_s"] = 1e-9 * (left_cpu - cpu)
+            if None not in (delay, left_delay):
+                rec["call_run_delay_s"] = 1e-9 * (left_delay - delay)
+            last["left_cpu"], last["left_delay"] = left_cpu, left_delay
+            last["left"] = left = time.perf_counter()
+            rec["call_s"] = left - now
+
+    def phase(self, name):
+        """``with trace.phase(trace.OPERANDS):`` inside ``call``: the span
+        of that name, its seconds into the call's record."""
+        return _Phase(self._record, name)
+
+    def _judge(self, before, interval, rec):
+        """At the entry of a call: was the interval since the entry of the
+        call ``before`` a long one?  A call that compiled is never long
+        and never in the median; the first call after one takes the
+        baseline snapshot of the threads."""
+        if before["compiled"]:
+            rec["threads"] = thread_snapshot()
+            return
+        if len(self._intervals) >= self.MEDIAN_MIN:
+            median = statistics.median(self._intervals)
+            if interval > self.LONG * median:
+                before["long"] = True
+                self.long_steps += 1
+                if interval - median > self.SNAPSHOT_OVER:
+                    before["threads"] = thread_snapshot()
+        self._intervals.append(interval)
+
     def dispatch(self, compiled, args, step):
         """``compiled(*args)`` under its span; counts and marks the call
         if it compiled: the step's cache gained an entry
@@ -333,10 +556,11 @@ class StepTrace:
 
         known, built = compiled._cache_size(), _ExecutablesBuilt.count
         traced = traced_call_sums()
-        with RecordEvent(self.DISPATCH, step=step):
+        with self.phase(self.DISPATCH):
             out = compiled(*args)
         if compiled._cache_size() > known and _ExecutablesBuilt.count > built:
             self.compiles += 1
+            self._record["compiled"] = True
             self.account = rec = _ExecutablesBuilt.account(compiled, args,
                                                            step)
             # the flash calls traced while the step was built are the

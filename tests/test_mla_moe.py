@@ -226,7 +226,8 @@ def test_train_step_hands_back_the_counters_beside_the_loss():
     # 64 tokens: the worst case's 192 rows are the only bucket
     rows = step.counters["moe_rows_buffered"]
     assert rows.dtype == jnp.int32 and rows.tolist() == [192, 192]
-    assert step.stats() == {"steps": 1, "compiles": 1}
+    assert step.stats() == {"steps": 1, "compiles": 1,
+                            "long_steps": 0}
 
 
 def test_a_model_without_counters_returns_none():

@@ -250,13 +250,16 @@ def test_a_step_records_its_spans_and_marks_the_one_that_compiled(which):
     with Profiler(timer_only=True) as p:
         assert _spans_of(p, lambda: step(ids, ids)) == {
             **own, StepTrace.COMPILED: 1}
-        assert step.stats() == {"steps": 1, "compiles": 1}
+        assert step.stats() == {"steps": 1, "compiles": 1,
+                                "long_steps": 0}
         assert _spans_of(p, lambda: step(ids, ids)) == own
         assert _spans_of(p, lambda: step(ids, ids)) == own
-        assert step.stats() == {"steps": 3, "compiles": 1}
+        assert step.stats() == {"steps": 3, "compiles": 1,
+                                "long_steps": 0}
         assert _spans_of(p, lambda: step(short, short)) == {
             **own, StepTrace.COMPILED: 1}
-    assert step.stats() == {"steps": 4, "compiles": 2}
+    assert step.stats() == {"steps": 4, "compiles": 2,
+                            "long_steps": 0}
 
 
 def test_record_event_raises_what_the_annotation_raises(monkeypatch):
@@ -328,7 +331,8 @@ def test_the_first_call_takes_the_account_and_builds_nothing_for_it(
     assert acc["trace_s"] + acc["lower_s"] + acc["backend_s"] <= acc["call_s"]
     assert acc["since"] < acc["at"]
     assert 0 < acc["account_s"] < 0.25      # answered from jax's caches
-    assert step.stats() == {"steps": 1, "compiles": 1}
+    assert step.stats() == {"steps": 1, "compiles": 1,
+                            "long_steps": 0}
 
 
 @pytest.mark.parametrize("which", ["train", "spmd"])
@@ -374,7 +378,8 @@ def test_the_account_of_a_step_with_compile_options_builds_nothing(
     assert profiler._ExecutablesBuilt.count == built + 1
     acc = step.compile_account()
     assert acc["collectives_sync"] > 0 and 0 < acc["account_s"] < 0.25
-    assert step.stats() == {"steps": 1, "compiles": 1}
+    assert step.stats() == {"steps": 1, "compiles": 1,
+                            "long_steps": 0}
 
 
 def test_the_account_is_one_chips_share_under_a_mesh():
