@@ -39,8 +39,18 @@ CCA(h), :class:`CompressedConvAttention`:
 K and V go to ``F.scaled_dot_product_attention`` at their own head count
 (the flash kernels on the TPU, both operands groups in place).  Everything
 between the projections and that call (the value shift, the convolutions,
-the q-k mean, the norms, the rotation) runs under the scope ``cca_mix``; it
-is an XLA composition.
+the q-k mean, the norms, the rotation) is ONE op, :func:`_mix`, under the
+scope ``cca_mix``, and ``ops.pallas.cca_mix`` places it.  On the TPU, at
+shapes ``ops/pallas/cca_mix_kernel.py supports`` takes (the published ones:
+heads of 128 lanes, q heads in whole groups over an even number of kv heads,
+rows whole tiles of 16), that is the ``cca_mix_fwd`` / ``cca_mix_bwd``
+kernels: a program holds a block of rows of ``[T, heads D]`` as the
+projections leave it, a head is its own 128 lanes, everything between the
+bfloat16 operands and the bfloat16 results is float32 in VMEM with conv1's
+matmuls on the MXU, the results are where the flash kernels read them in
+place, and the backward keeps q~ and k~ alone.  Elsewhere (off the TPU, the
+tiny test config's heads of 16, a GSPMD mesh) the XLA composition
+:func:`_mix_composed` runs: the ops named above, one after the other.
 
 MoE(h, r_prev): ``DroplessMoELayer`` routed by ``StateMlpRouter``
 (``incubate/distributed/models/moe/moe_layer.py``): ``r = h W_d + gamma *
@@ -98,6 +108,47 @@ def _qk_mean_norm(q_conv, k_conv, q_lat, k_lat, tau, eps):
 
     return (unit(q).astype(q_lat.dtype),
             (unit(k) * tau.astype(f32)[:, None]).astype(k_lat.dtype))
+
+
+def _mix_composed(q_lat, k_lat, v, q_conv0, q_conv1, k_conv0, k_conv1, tau,
+                  cos, sin, eps):
+    """:func:`_mix` as the XLA composition, on arrays: steps 2 to 5 and the
+    value shift, the ops of the module's docstring one after the other, each
+    looked up where it is called (a test that stands in for one of them, as
+    the benchmark's planted faults do, is met)."""
+    q_lat, k_lat, v, q_conv0, q_conv1, k_conv0, k_conv1, tau, cos, sin = [
+        Tensor(a) for a in (q_lat, k_lat, v, q_conv0, q_conv1, k_conv0,
+                            k_conv1, tau, cos, sin)]
+    b, t, n, d = q_lat.shape
+    kv = k_lat.shape[2]
+
+    def convolved(x, conv0, conv1):
+        y = F.causal_conv1d(x.reshape([b, t, -1]), conv0)
+        return F.causal_conv1d_heads(y.reshape(x.shape), conv1)
+
+    q = convolved(q_lat, q_conv0, q_conv1)
+    k = convolved(k_lat, k_conv0, k_conv1)
+    q, k = _qk_mean_norm(q, k, q_lat, k_lat, tau, eps)
+    q, k = _rope(q, k, cos, sin)
+    now, before = v[:, :, :kv // 2], v[:, :, kv // 2:]
+    v = concat([now, F.time_shift(before)], axis=2)
+    return q._data, k._data, v._data
+
+
+@op("cca_mix")
+def _mix(q_lat, k_lat, v, q_conv0, q_conv1, k_conv0, k_conv1, tau, cos, sin,
+         eps):
+    """Steps 2 to 5 of CCA and the value shift on ``[B, T, heads, D]``: q^,
+    k^, v' for the flash call.  ``ops.pallas.cca_mix`` places the call: on
+    the TPU, where ``cca_mix_kernel.supports`` takes the shapes, the
+    ``cca_mix_fwd`` / ``cca_mix_bwd`` kernels, whose backward keeps q~ and
+    k~ alone; elsewhere (off the TPU; aloud on it, ``KernelFallbackWarning``,
+    for other shapes or under a GSPMD mesh) :func:`_mix_composed`,
+    differentiated as it stands."""
+    from ..ops import pallas
+
+    return pallas.cca_mix(q_lat, k_lat, v, q_conv0, q_conv1, k_conv0,
+                          k_conv1, tau, cos, sin, eps)
 
 
 class ZayaConfig(MoeDecoderConfig):
@@ -197,21 +248,11 @@ class CompressedConvAttention(nn.Layer):
 
     def mix(self, q_lat, k_lat, v):
         """Steps 2 to 5 and the value shift on ``[B, T, heads, D]``."""
-        b, t, n, d = q_lat.shape
-        kv = self.num_kv_heads
-
-        def convolved(x, conv0, conv1):
-            y = F.causal_conv1d(x.reshape([b, t, -1]), conv0)
-            return F.causal_conv1d_heads(y.reshape(x.shape), conv1)
-
-        q = convolved(q_lat, self.q_conv0, self.q_conv1)
-        k = convolved(k_lat, self.k_conv0, self.k_conv1)
-        q, k = _qk_mean_norm(q, k, q_lat, k_lat, self.temperature, self.eps)
-        cos, sin = self.rope(t)
-        q, k = _rope(q, k, Tensor(jnp.asarray(cos)), Tensor(jnp.asarray(sin)))
-        now, before = v[:, :, :kv // 2], v[:, :, kv // 2:]
-        v = concat([now, F.time_shift(before)], axis=2)
-        return q, k, v
+        cos, sin = self.rope(q_lat.shape[1])
+        return _mix(q_lat, k_lat, v, self.q_conv0, self.q_conv1,
+                    self.k_conv0, self.k_conv1, self.temperature,
+                    Tensor(jnp.asarray(cos)), Tensor(jnp.asarray(sin)),
+                    self.eps)
 
     def forward(self, x):
         b, t, _ = x.shape

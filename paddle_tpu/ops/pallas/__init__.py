@@ -179,6 +179,12 @@ def gated_norm_log():
                  "gated_norm")
 
 
+def cca_mix_log():
+    """The same of :func:`cca_mix`: ``shapes`` (q, k), ``path`` and
+    ``reason``."""
+    return _view(("shapes", "path", "reason"), "cca_mix")
+
+
 def traced_call_sums():
     """What a compiled step's account takes its own share of
     (``profiler.StepTrace.dispatch``): :func:`flash_layout_sums`;
@@ -190,9 +196,11 @@ def traced_call_sums():
     expert layer's routed block traces one a branch of each of its two
     switches: combine, and the dispatch's transpose);
     ``causal_conv_calls`` / ``causal_conv_calls_composed``, the same of
-    :func:`causal_conv1d` (three a Mamba mixer: x, B and C); and
+    :func:`causal_conv1d` (three a Mamba mixer: x, B and C);
     ``gated_norm_calls`` / ``gated_norm_calls_composed``, the same of
-    :func:`gated_rms_norm` (one a Mamba mixer)."""
+    :func:`gated_rms_norm` (one a Mamba mixer); and ``cca_mix_calls`` /
+    ``cca_mix_calls_composed``, the same of :func:`cca_mix` (one a compressed
+    convolutional attention)."""
     return dict(common.traced_sums)
 
 
@@ -556,6 +564,35 @@ def gated_rms_norm(y, z, weight, groups, epsilon, start=0):
         unfit=None if z.dtype == y.dtype else
         f"z {z.dtype} beside y {y.dtype}",
         fits=(math.prod(y.shape[:-1]), y.shape[-1], groups, y.dtype, start))
+
+
+def cca_mix(q, k, v, q_conv0, q_conv1, k_conv0, k_conv1, tau, cos, sin,
+            epsilon):
+    """Compressed convolutional attention between its projections and its
+    flash call (``models/zaya.py CompressedConvAttention.mix``): ``q [B, T,
+    n, D]``, ``k``, ``v`` ``[B, T, kv, D]``, the depthwise taps ``[heads D,
+    K0]`` and the per-head taps ``[heads, K1, D, D]`` of q and of k, ``tau
+    [kv]``, ``cos`` / ``sin`` ``[T, rot / 2]`` -> q^, k^, v' of the
+    operands' shapes.  The ``cca_mix_fwd`` / ``cca_mix_bwd`` kernels
+    (``cca_mix_kernel``), which read and write ``[B, T, heads D]`` as the
+    projections leave it and the flash kernels take it, or the XLA
+    composition ``models/zaya.py _mix_composed``, as :func:`_dispatch`
+    places it; every traced call is recorded (:func:`cca_mix_log`)."""
+    from ...models.zaya import _mix_composed
+
+    operands = (q, k, v, q_conv0, q_conv1, k_conv0, k_conv1, tau, cos, sin)
+    others = {str(x.dtype) for x in (k, v, q_conv1, k_conv1)} \
+        - {str(q.dtype)}
+    return _dispatch(
+        "cca_mix", "cca_mix_kernel", (tuple(q.shape), tuple(k.shape)),
+        lambda mix: mix.cca_mix_pallas(*operands, epsilon=epsilon),
+        lambda: _mix_composed(*operands, epsilon),
+        counter="cca_mix_calls",
+        unfit=f"{', '.join(sorted(others))} beside q {q.dtype}"
+        if others else None,
+        fits=(q.shape[1], q.shape[2], k.shape[2], q.shape[3],
+              (q_conv0.shape[1], q_conv1.shape[1]), 2 * cos.shape[1],
+              q.dtype))
 
 
 def mla_rope(x, cos, sin, interleave):

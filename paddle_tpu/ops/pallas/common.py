@@ -96,7 +96,8 @@ traced_sums = dict.fromkeys(
      "mla_expand_calls", "mla_expand_calls_composed",
      "moe_run_sum_calls", "moe_run_sum_calls_composed",
      "causal_conv_calls", "causal_conv_calls_composed",
-     "gated_norm_calls", "gated_norm_calls_composed"), 0)
+     "gated_norm_calls", "gated_norm_calls_composed",
+     "cca_mix_calls", "cca_mix_calls_composed"), 0)
 
 
 def record_flash_layout(kernel, shapes, in_place, copied):

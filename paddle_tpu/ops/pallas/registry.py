@@ -59,6 +59,7 @@ KERNEL_MODULES = (
     "ragged_attention_kernel",
     "layernorm_kernel",
     "gated_norm_kernel",
+    "cca_mix_kernel",
 )
 
 _REGISTRY = {}

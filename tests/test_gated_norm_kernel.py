@@ -268,7 +268,8 @@ def test_on_the_tpu_the_kernels_run_and_are_recorded(on_tpu):
         "mla_expand_calls": 0, "mla_expand_calls_composed": 0,
         "moe_run_sum_calls": 0, "moe_run_sum_calls_composed": 0,
         "causal_conv_calls": 0, "causal_conv_calls_composed": 0,
-        "gated_norm_calls": 1, "gated_norm_calls_composed": 0}
+        "gated_norm_calls": 1, "gated_norm_calls_composed": 0,
+        "cca_mix_calls": 0, "cca_mix_calls_composed": 0}
 
 
 @pytest.mark.parametrize("case", ["group_width_96", "ragged_rows",
@@ -334,6 +335,9 @@ def test_the_compiled_steps_account_counts_its_gated_norms():
     account = step.compile_account()
     assert (account["gated_norm_calls"],
             account["gated_norm_calls_composed"]) == (2, 2)
+    # another family's op: none in this step
+    assert (account["cca_mix_calls"],
+            account["cca_mix_calls_composed"]) == (0, 0)
     # y 64 under z 64 | x 64 | B 32 | C 32 | dt 8
     assert [(r["shapes"], r["groups"], r["start"], r["path"])
             for r in pk.gated_norm_log()[-2:]] == [

@@ -1,5 +1,5 @@
 """``ops/pallas``: the ONE rule that places a call (``_refusal`` /
-``_dispatch``) held over its eight dispatchers, the flash kernels' block
+``_dispatch``) held over its nine dispatchers, the flash kernels' block
 rule at the benchmark's shapes, and the direction of the package's imports.
 
 The kernels' values are other files' business (``test_pallas_kernels.py``,
@@ -17,7 +17,8 @@ import pytest
 
 from paddle_tpu.ops import pallas as pk
 from paddle_tpu.ops.pallas import (attention_kernel, causal_conv_kernel,
-                                   common, eva_attention_kernel,
+                                   cca_mix_kernel, common,
+                                   eva_attention_kernel,
                                    gated_norm_kernel, mla_expand_kernel,
                                    moe_run_sum_kernel, registry,
                                    ssd_scan_kernel)
@@ -51,6 +52,16 @@ def _conv(channels):
 def _norm(channels):
     return ((_x(1, 64, channels), _x(1, 64, 2 * channels), _x(channels), 2,
              1e-5), {"start": channels})
+
+
+def _cca(head_dim, seq=64, n=4, kv=2):
+    f32 = jnp.float32
+    table = _x(seq, head_dim // 4, dtype=f32)
+    return ((_x(1, seq, n, head_dim), _x(1, seq, kv, head_dim),
+             _x(1, seq, kv, head_dim), _x(n * head_dim, 2),
+             _x(n, 2, head_dim, head_dim), _x(kv * head_dim, 2),
+             _x(kv, 2, head_dim, head_dim), _x(kv, dtype=f32), table, table,
+             1e-5), {})
 
 
 def _grouped(rows):
@@ -100,6 +111,11 @@ DISPATCHERS = {
         ("paddle_tpu.models.nemotron_h", "_gated_norm_composed"),
         _norm(256), _norm(192),
         "gated_norm_kernel.supports() refuses", "gated_norm_calls"),
+    "cca_mix": (
+        pk.cca_mix, (cca_mix_kernel, "cca_mix_pallas"),
+        ("paddle_tpu.models.zaya", "_mix_composed"),
+        _cca(128), _cca(64),
+        "cca_mix_kernel.supports() refuses", "cca_mix_calls"),
     "grouped_matmul": (
         pk.grouped_matmul,
         ("jax.experimental.pallas.ops.tpu.megablox.ops", "gmm"),

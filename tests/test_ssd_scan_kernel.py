@@ -223,7 +223,8 @@ def test_on_the_tpu_the_kernels_run_and_are_recorded(on_tpu):
         "mla_expand_calls": 0, "mla_expand_calls_composed": 0,
         "moe_run_sum_calls": 0, "moe_run_sum_calls_composed": 0,
         "causal_conv_calls": 0, "causal_conv_calls_composed": 0,
-        "gated_norm_calls": 0, "gated_norm_calls_composed": 0}
+        "gated_norm_calls": 0, "gated_norm_calls_composed": 0,
+        "cca_mix_calls": 0, "cca_mix_calls_composed": 0}
 
 
 @pytest.mark.parametrize("case", ["chunk_16", "state_16", "head_8"])

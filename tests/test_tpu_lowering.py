@@ -96,9 +96,10 @@ def test_registry_lowers_for_tpu_where_supported():
     # state-space scan's kernels vjp x 3, latent attention's expansion
     # (value and vjp) x 3, the expert layer's run sums (weighted and not)
     # x 3, the causal convolution's kernels (value and vjp) x 3, the gated
-    # group norm's (value and vjp) x 3
+    # group norm's (value and vjp) x 3, compressed convolutional attention's
+    # mix (value and vjp) x 3
     assert lowered == 2 * 6 * 3 + 2 * 3 + 2 + 3 + 3 + 3 + 2 * 3 + 2 + 3 + 3 \
-        + 2 * 3 + 2 * 3 + 2 * 3
+        + 2 * 3 + 2 * 3 + 2 * 3 + 2 * 3
 
 
 def test_refusals_are_declared_only_where_needed():
@@ -764,6 +765,7 @@ def test_mla_expand_dispatch_is_counted_and_gives_way_aloud(
         "moe_run_sum_calls": 0, "moe_run_sum_calls_composed": 0,
         "causal_conv_calls": 0, "causal_conv_calls_composed": 0,
         "gated_norm_calls": 0, "gated_norm_calls_composed": 0,
+        "cca_mix_calls": 0, "cca_mix_calls_composed": 0,
         "mla_expand_calls": 1,
         "mla_expand_calls_composed": 0 if case == "kernel" else 1}
     if case != "mesh":
@@ -822,6 +824,57 @@ def test_the_gated_norm_is_one_kernel_forward_and_one_backward(wide, start,
     assert text.count("tpu_custom_call") == 2
     assert "x8x1024x" not in text
     assert (_mosaic_bodies(text) == _GATED_NORM_BODIES) == same
+
+
+# ------------------------- compressed convolutional attention's mix --
+
+# The ``cca_mix_*`` kernels as Mosaic gets them at the zaya cell's call (q~
+# ``bf16[1, 16384, 8, 128]`` over k~, v ``[1, 16384, 2, 128]``, two taps each,
+# 64 dims rotated), source locations stripped (``_mosaic_bodies``); read at
+# PR 50's tree.
+_CCA_MIX_BODIES = {"cca_mix_fwd": "14059de0043b4fbc",
+                   "cca_mix_bwd": "cc3d0fc11112d692"}
+
+
+def _cca_mix_args(dtype=jnp.bfloat16, seq=16384, n=8, kv=2, d=128, rot=64):
+    sds = jax.ShapeDtypeStruct
+    f32 = jnp.float32
+    return (sds((1, seq, n, d), dtype), sds((1, seq, kv, d), dtype),
+            sds((1, seq, kv, d), dtype), sds((n * d, 2), dtype),
+            sds((n, 2, d, d), dtype), sds((kv * d, 2), dtype),
+            sds((kv, 2, d, d), dtype), sds((kv,), f32),
+            sds((seq, rot // 2), f32), sds((seq, rot // 2), f32))
+
+
+def _cca_mix_value_and_grads(*o):
+    from paddle_tpu.ops.pallas.cca_mix_kernel import cca_mix_pallas
+
+    def loss(*o):
+        return sum(jnp.sum(x.astype(jnp.float32))
+                   for x in cca_mix_pallas(*o, epsilon=1e-5))
+    return jax.value_and_grad(loss, argnums=tuple(range(8)))(*o)
+
+
+def test_the_cca_mix_is_one_kernel_forward_and_one_backward():
+    """Values and all eight gradients of the mix, lowered for the TPU at
+    the cell's shape: TWO ``tpu_custom_call``s, which take q~, k~ and v as
+    ``[1, 16384, heads * 128]`` (the projections' results, reshaped) and
+    give q^, k^, v' the same way (the flash kernels' in-place layout): no
+    operand of either is a ``[.., heads, 128]`` view."""
+    from paddle_tpu.ops.pallas.cca_mix_kernel import supports
+
+    assert supports(16384, 8, 2, 128, (2, 2), 64, jnp.bfloat16)
+    text = jax.jit(_cca_mix_value_and_grads).trace(*_cca_mix_args()).lower(
+        lowering_platforms=("tpu",)).as_text()
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert sorted(names) == ["cca_mix_bwd", "cca_mix_fwd"]
+    assert text.count("tpu_custom_call") == 2
+    calls = [l for l in text.splitlines() if "tpu_custom_call" in l]
+    for line in calls:
+        assert "tensor<1x16384x1024xbf16>" in line \
+            and "tensor<1x16384x256xbf16>" in line
+        assert "16384x8x128x" not in line and "16384x2x128x" not in line
+    assert _mosaic_bodies(text) == _CCA_MIX_BODIES
 
 
 @pytest.mark.slow
