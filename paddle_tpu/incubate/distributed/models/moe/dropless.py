@@ -60,7 +60,8 @@ too (``custom_vjp``): no scatter-add in either direction.
 
 What an expert IS stays a parameter of that one path: its BODY
 (:data:`BODIES`: ``swiglu``, ``down(silu(gate) * up)`` with gate | up in one
-matrix; ``relu2``, ``down(relu(up) ** 2)``, not gated) and the width ``K``
+matrix; ``reglu``, ``down(relu(gate) * up)`` in the same layout; ``relu2``,
+``down(relu(up) ** 2)``, not gated) and the width ``K``
 of the rows it works on, which need not be the router's input width (experts
 in a latent: the layer projects the tokens down before the dispatch and up
 after the combine, and every buffer here is ``K`` wide).
@@ -287,6 +288,12 @@ def _swiglu(gu):
     return jax.nn.silu(gu[:, :inter]) * gu[:, inter:]
 
 
+def _reglu(gu):
+    """``relu(gate) * up`` of ``gu [R, 2I]`` (gate | up)."""
+    inter = gu.shape[1] // 2
+    return jax.nn.relu(gu[:, :inter]) * gu[:, inter:]
+
+
 def _relu2(h):
     """``relu(h) ** 2`` of ``h [R, I]``: no gate."""
     return jnp.square(jax.nn.relu(h))
@@ -294,15 +301,18 @@ def _relu2(h):
 
 # an expert's BODY: what stands between its two matrices, and so how wide
 # the first is for an inner width ``I`` (``w_in [G, K, in_width * I]``)
-BODIES = {"swiglu": (_swiglu, 2), "relu2": (_relu2, 1)}
+BODIES = {"swiglu": (_swiglu, 2), "reglu": (_reglu, 2), "relu2": (_relu2, 1)}
 
 
 def experts_mlp(xs, w_in, w_out, counts, body="swiglu"):
     """Every local expert's MLP on its own rows: ``w_in [G, K, 2I]`` (gate
-    | up) under ``swiglu``, ``[G, K, I]`` under ``relu2``; ``w_out [G, I,
-    K]``.  ``K`` is the rows' width, whatever the router read."""
-    return grouped_matmul(BODIES[body][0](grouped_matmul(xs, w_in, counts)),
-                          w_out, counts)
+    | up) under ``swiglu`` and ``reglu``, ``[G, K, I]`` under ``relu2``;
+    ``w_out [G, I, K]``.  ``K`` is the rows' width, whatever the router
+    read.  The body runs under the scope ``expert_body``."""
+    pre = grouped_matmul(xs, w_in, counts)
+    with jax.named_scope("expert_body"):
+        h = BODIES[body][0](pre)
+    return grouped_matmul(h, w_out, counts)
 
 
 def _experts_mlp_vjp(xs, w_in, w_out, counts, body):
@@ -312,10 +322,13 @@ def _experts_mlp_vjp(xs, w_in, w_out, counts, body):
     from .....ops.pallas import grouped_matmul_dw
 
     pre = grouped_matmul(xs, w_in, counts)
-    h, body_vjp = jax.vjp(BODIES[body][0], pre)
+    with jax.named_scope("expert_body"):
+        h, body_vjp = jax.vjp(BODIES[body][0], pre)
 
     def vjp(d_ys):
-        d_pre, = body_vjp(grouped_matmul(d_ys, w_out, counts, True))
+        d_h = grouped_matmul(d_ys, w_out, counts, True)
+        with jax.named_scope("expert_body"):
+            d_pre, = body_vjp(d_h)
         return (grouped_matmul(d_pre, w_in, counts, True),
                 grouped_matmul_dw(xs, d_pre, counts),
                 grouped_matmul_dw(h, d_ys, counts))

@@ -310,8 +310,9 @@ def _linear(d_in, d_out, std):
 
 class GroupedExperts(Layer):
     """The experts held here, stacked: the first matrix ``[G, K, 2I]``
-    (gate | up, held as ``gate_up``) under the ``swiglu`` body, ``[G, K,
-    I]`` (held as ``up``) under ``relu2``; ``down [G, I, K]``.  ``K`` is the
+    (gate | up, held as ``gate_up``) under the gated bodies ``swiglu`` and
+    ``reglu``, ``[G, K, I]`` (held as ``up``) under ``relu2``; ``down [G, I,
+    K]``.  ``K`` is the
     width of the rows the experts work on.  It holds them and no more: the
     layer's routed block multiplies them (``dropless.routed_experts``)."""
 
@@ -319,9 +320,10 @@ class GroupedExperts(Layer):
                  down_std=None, body="swiglu"):
         super().__init__()
         self.body = body
-        self._in_name = "gate_up" if body == "swiglu" else "up"
+        in_width = _dl.BODIES[body][1]
+        self._in_name = "gate_up" if in_width == 2 else "up"
         setattr(self, self._in_name, self.create_parameter(
-            (num_local, d_rows, _dl.BODIES[body][1] * d_expert),
+            (num_local, d_rows, in_width * d_expert),
             default_initializer=Normal(0.0, init_std)))
         self.down = self.create_parameter(
             (num_local, d_expert, d_rows),
@@ -386,8 +388,9 @@ class DroplessMoELayer(Layer):
     that is held here, that holds those tokens.  Both stay on the device;
     the worst case's rows mean the fallback ran.
 
-    ``body`` is the experts' (``dropless.BODIES``: ``swiglu`` gated,
-    ``relu2`` not), routed and shared alike.  With ``d_latent`` the routed
+    ``body`` is the experts' (``dropless.BODIES``: ``swiglu`` and ``reglu``
+    gated, ``relu2`` not), routed and shared alike (no shared expert is
+    built for ``reglu``).  With ``d_latent`` the routed
     experts work in a LATENT: ``latent_down [d_model, d_latent]`` before
     the dispatch and ``latent_up [d_latent, d_model]`` after the combine,
     so the rows that are sorted, gathered and summed are ``d_latent`` wide;
@@ -403,6 +406,14 @@ class DroplessMoELayer(Layer):
     the state the layer before made, ``[..., state_size]`` float32, and
     leaves its own in ``router_state_out`` (the block hands it on as an
     output).
+
+    ``forward(x, router_input=r)``: the ROUTER reads ``r`` (of ``x``'s
+    shape) and everything else, the latent and the shared expert too,
+    reads ``x`` as before: a family whose router sits before attention
+    hands the block's input here (``models/moe_decoder.py``
+    ``router_reads_block_input``).  After a forward ``expert_idx`` is the
+    router's choice ``[S, k]`` and :meth:`tokens_unserved` counts from it
+    the tokens that got nothing from the routed experts here.
 
     Scopes: ``router``, ``latent_down``, ``dispatch``, ``experts``,
     ``combine``, ``latent_up``, ``shared_experts`` (``docs/PROFILER.md``).
@@ -450,18 +461,21 @@ class DroplessMoELayer(Layer):
             d_shared = num_shared_experts * d_expert
         self.shared_experts = _MLPS[body](
             d_model, d_shared, init_std, down_std) if d_shared else None
-        self.tokens_per_expert = self.rows_buffered = None
+        self.tokens_per_expert = self.rows_buffered = self.expert_idx = None
 
-    def forward(self, x, router_state=None):
+    def forward(self, x, router_state=None, router_input=None):
         shape = x.shape
         x2d = x.reshape([-1, self.d_model])
+        r2d = x2d if router_input is None \
+            else router_input.reshape([-1, self.d_model])
         if router_state is None:
-            idx, weights = self.router(x2d)
+            idx, weights = self.router(r2d)
         else:
             idx, weights, made = self.router(
-                x2d, router_state.reshape([-1, self.router.state_size]))
+                r2d, router_state.reshape([-1, self.router.state_size]))
             self.router_state_out = made.reshape(
                 list(router_state.shape))
+        self.expert_idx = idx
         rows = x2d if self.latent_down is None else self.latent_down(x2d)
         out, self.tokens_per_expert, self.rows_buffered = _routed_experts(
             rows, idx, weights, self.experts.w_in, self.experts.down,
@@ -474,6 +488,18 @@ class DroplessMoELayer(Layer):
         if self.shared_experts is not None:
             out = out + self.shared_experts(x2d)
         return out.reshape(shape)
+
+    def tokens_unserved(self):
+        """int32 scalar, on the device: the tokens of the last forward NONE
+        of whose chosen experts is held here.  Without a shared expert such
+        a token leaves the layer with exactly nothing.  Formed only where
+        somebody asks (scope ``dispatch``): a step that does not ask traces
+        what it traced before."""
+        idx = getattr(self.expert_idx, "_data", self.expert_idx)
+        with jax.named_scope("dispatch"):
+            local = idx - self.expert_offset
+            elsewhere = (local < 0) | (local >= self.num_local_experts)
+            return jnp.sum(jnp.all(elsewhere, axis=1), dtype=jnp.int32)
 
 
 # ----------------------------- global_scatter / global_gather parity ------

@@ -42,6 +42,12 @@ from .sdar import (  # noqa: F401
     sdar_30b_a3b,
     sdar_tiny,
 )
+from .smallthinker import (  # noqa: F401
+    SmallThinkerConfig,
+    SmallThinkerForCausalLM,
+    smallthinker_21b_a3b,
+    smallthinker_tiny,
+)
 from .zaya import (  # noqa: F401
     CompressedConvAttention,
     ZayaConfig,

@@ -103,7 +103,10 @@ def rope_tables(head_dim, seq, params, positions=None):
     """``(cos, sin) [seq, rot / 2]`` float32 and ``rot``, the leading dims
     of a head that are rotated, from one entry of ``rope_parameters``;
     ``positions [seq]``: each row's position id (``0 .. seq - 1`` unless
-    given)."""
+    given).  ``params`` ``None`` is a layer WITHOUT a position encoding:
+    no tables and nothing rotated, ``(None, None, 0)``."""
+    if params is None:
+        return None, None, 0
     rot = int(head_dim * params.get("partial_rotary_factor", 1))
     base = float(params["rope_theta"])
     kind = params.get("rope_type", "default")
@@ -330,8 +333,9 @@ class GroupedGatedAttention(nn.Layer):
             # both halves of a block-diffusion row count their own positions
             cos, sin = self.rope(t, None if block is None else np.tile(
                 np.arange(t // 2, dtype=np.int32), 2))
-            q, k = _rope(q, k, Tensor(jnp.asarray(cos)),
-                         Tensor(jnp.asarray(sin)))
+            if cos is not None:
+                q, k = _rope(q, k, Tensor(jnp.asarray(cos)),
+                             Tensor(jnp.asarray(sin)))
             out = F.scaled_dot_product_attention(
                 q, k, v, is_causal=block is None, window=self.window,
                 block_diffusion=block)

@@ -1,6 +1,7 @@
 """The decoder shell the expert models share (``models/mla_moe.py``,
 ``models/laguna.py``, ``models/evabyte.py``, ``models/nemotron_h.py``,
-``models/ouro.py``, ``models/sdar.py``, ``models/zaya.py``):
+``models/ouro.py``, ``models/sdar.py``, ``models/zaya.py``,
+``models/smallthinker.py``):
 pre-norm residual blocks, the stack, a final RMSNorm, an ``lm_head`` over
 whatever slice of the vocabulary is held (untied, or the embedding itself
 under ``tie_word_embeddings``), the shifted-label loss and the step's
@@ -67,6 +68,18 @@ Three more, off by default too (the ``zaya`` family, ``models/zaya.py``):
 - ``tie_word_embeddings``: no ``lm_head`` matrix; the logits are ``rms(x)
   E^T`` over the embedding, whose gradient is the sum of its two uses.
 
+One more, off by default too (the ``smallthinker`` family,
+``models/smallthinker.py``):
+
+- ``router_reads_block_input``: a block's expert layer routes on the
+  block's own INPUT, un-normed, as it was before attention, while the
+  experts read the normed post-attention stream as ever
+  (``DroplessMoELayer.forward(x, router_input=...)``; inside
+  ``fleet.recompute`` too, the block's input being the recomputation's);
+  the block then counts the tokens none of whose chosen experts is held here
+  as one output more, and ``moe_tokens_unserved`` (float32, their MEAN over
+  the expert layers) joins the step's counters.
+
 A decoder layer hands its expert counters on as OUTPUTS, so that
 ``jit.TrainStep(remat=...)`` can rematerialise each layer in the backward
 pass.  Scopes: ``embeddings`` / ``layers.i`` / ``ln_1`` / ``attn`` /
@@ -122,7 +135,7 @@ class MoeDecoderConfig:
     ``num_hidden_layers``, ``rms_norm_eps``, ``initializer_range``,
     ``norm_topk_prob``, ``num_local_experts``, ``expert_offset``, the
     factories (two for a block of two branches, ``make_mixer`` for a block
-    of one), and the eight options of the module's docstring."""
+    of one), and the nine options of the module's docstring."""
 
     fp32_skip_add = False
     norm_add_unit_offset = False
@@ -134,6 +147,7 @@ class MoeDecoderConfig:
     router_state_size = 0       # width of the state a router hands on
     residual_scale = False
     tie_word_embeddings = False
+    router_reads_block_input = False    # the router sits before attention
 
     def make_norm(self):
         cls = UnitOffsetRMSNorm if self.norm_add_unit_offset else nn.RMSNorm
@@ -215,7 +229,9 @@ class MoeDecoderLayer(nn.Layer):
     Returns ``(x, tokens_per_expert, rows_buffered)``; the counters of a
     layer without experts are empty arrays, so every layer has the same
     outputs.  Under ``router_state_size`` it takes ``(x, router_state)``
-    and the state its router made is a fourth output."""
+    and the state its router made is a fourth output.  Under
+    ``router_reads_block_input`` the tokens left unserved follow the two
+    counters."""
 
     def __init__(self, config, layer_idx):
         super().__init__()
@@ -223,6 +239,7 @@ class MoeDecoderLayer(nn.Layer):
         self.ln_1 = c.make_norm()
         self.ln_1b = c.make_norm() if c.post_branch_norm else None
         self._fp32_skip_add = c.fp32_skip_add
+        self._router_reads_input = c.router_reads_block_input
         self._mixer, module = c.make_mixer(layer_idx) or (None, None)
         if module is not None:
             self.ln_2 = self.ln_2b = None
@@ -264,6 +281,7 @@ class MoeDecoderLayer(nn.Layer):
                 none = Tensor(jnp.zeros((0,), jnp.int32))
                 return x, none, none
             return x, self.moe.tokens_per_expert, self.moe.rows_buffered
+        entered = x
         x = self._branch(x, self.ln_1, self.attn, self.ln_1b, self.res_1)
         if self.moe is None:
             none = Tensor(jnp.zeros((0,), jnp.int32))
@@ -271,8 +289,13 @@ class MoeDecoderLayer(nn.Layer):
                                 self.res_2), none, none
         moe = self.moe if router_state is None else functools.partial(
             self.moe, router_state=router_state)
+        if self._router_reads_input:
+            moe = functools.partial(moe, router_input=entered)
         x = self._branch(x, self.ln_2, moe, self.ln_2b, self.res_2)
         made = (x, self.moe.tokens_per_expert, self.moe.rows_buffered)
+        if self._router_reads_input:
+            with jax.named_scope("moe"):
+                made += (Tensor(self.moe.tokens_unserved()),)
         return made if router_state is None \
             else made + (self.moe.router_state_out,)
 
@@ -290,7 +313,7 @@ class MoeDecoderModel(nn.Layer):
             for i in range(config.num_hidden_layers)])
         self.ln_f = config.make_norm()
         self.tokens_per_expert = self.rows_buffered = None
-        self.router_state_rms = None
+        self.tokens_unserved = self.router_state_rms = None
 
     def forward(self, input_ids):
         return self.stack(self.embed(input_ids))
@@ -318,8 +341,9 @@ class MoeDecoderModel(nn.Layer):
             if layer.moe is not None:
                 counters.append([c._data if isinstance(c, Tensor) else c
                                  for c in made])
-        self.tokens_per_expert, self.rows_buffered = \
+        self.tokens_per_expert, self.rows_buffered, *more = \
             [jnp.stack(c) for c in zip(*counters)] or (None, None)
+        self.tokens_unserved = more[0] if more else None
         self.router_state_rms = jnp.stack(entering) if entering else None
         x = self.ln_f(x)
         if self.config.fp32_skip_add:
@@ -499,7 +523,10 @@ class MoeDecoderForCausalLM(nn.Layer):
         with a target of the exit distribution (sums to 1).
         Under ``router_state_size``: ``router_state_rms`` float32
         ``[layers]``, the RMS of the router state ENTERING each block (0
-        into the first): whether the carried term grows with depth."""
+        into the first): whether the carried term grows with depth.
+        Under ``router_reads_block_input``: ``moe_tokens_unserved`` float32,
+        the tokens none of whose chosen experts is held here, the MEAN over
+        the expert layers (over the step's tokens it is a share)."""
         if self.pass_loss is not None:
             return {"ouro_pass_loss": self.pass_loss,
                     "ouro_exit_mass": self.exit_mass}
@@ -510,4 +537,7 @@ class MoeDecoderForCausalLM(nn.Layer):
                     "moe_rows_buffered": self.model.rows_buffered}
         if self.model.router_state_rms is not None:
             counters["router_state_rms"] = self.model.router_state_rms
+        if self.model.tokens_unserved is not None:
+            counters["moe_tokens_unserved"] = jnp.mean(
+                self.model.tokens_unserved.astype(jnp.float32))
         return counters

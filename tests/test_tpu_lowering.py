@@ -559,6 +559,11 @@ _CELL_FLASH_CALLS = {
                     "flash_attention"),
     "laguna-window": ((1, 8192, 72, 128), 8, 128, 512, "gqa",
                       "flash_window"),
+    # a group of 7, the first that is no power of two, in a row of 16,384
+    "smallthinker-full": ((1, 16384, 28, 128), 4, 128, None, "gqa",
+                          "flash_attention"),
+    "smallthinker-window": ((1, 16384, 28, 128), 4, 128, 4096, "gqa",
+                            "flash_window"),
 }
 
 
@@ -581,8 +586,9 @@ def test_a_flash_backward_is_one_custom_call(case):
 
     text = _cell_flash_lowered(case)
     names = re.findall(r'kernel_name = "([^"]+)"', text)
-    stem = ("flash_attention" if _CELL_FLASH_CALLS[case][3] is None
-            else "flash_window512_attention")
+    window = _CELL_FLASH_CALLS[case][3]
+    stem = ("flash_attention" if window is None
+            else f"flash_window{window}_attention")
     assert sorted(names) == [f"{stem}_bwd_dq_dkv", f"{stem}_fwd"]
     assert text.count("tpu_custom_call") == 2
 
@@ -621,6 +627,63 @@ def test_the_benchmark_counts_one_backward_call_by_the_kernels_names(case):
             env, "flash_window") == (0, 0)
     else:
         assert flash_attention_mla.calls_in_window(env) == (0, 0)
+
+
+@pytest.mark.parametrize("layer_idx,stem", [
+    (0, "flash_attention"), (1, "flash_window4096_attention")])
+def test_a_published_smallthinker_layer_lowers_with_every_operand_in_place(
+        monkeypatch, layer_idx, stem):
+    """One block of SmallThinker-21BA3B at its published widths and the
+    cell's share (28 q heads over 4 kv heads of 128, 16 of 64 ReGLU experts,
+    six a token, a row of 16,384), value and gradients, lowered for the TPU:
+    layer 0 is full attention with no rotation, layer 1 a window of 4,096
+    with one.  Both flash calls are the kernels with all eight operands in
+    place, the routed block's way back to its tokens is the ``moe_run_sum``
+    kernel in every branch, and the router's matmul reads the block's INPUT
+    (a ``[16384, 2560] x [2560, 64]`` float32 dot whose operand is the
+    function's argument, cast)."""
+    import re
+
+    import paddle_tpu as paddle
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.models import moe_decoder
+    from paddle_tpu.models.smallthinker import SmallThinkerConfig
+    from paddle_tpu.ops import pallas as pk
+
+    monkeypatch.setattr(pk, "_use_pallas", lambda: True)
+    config = SmallThinkerConfig(
+        vocab_size=512, hidden_size=2560, num_hidden_layers=4,
+        num_attention_heads=28, num_key_value_heads=4, head_dim=128,
+        moe_ffn_hidden_size=768, moe_num_primary_experts=64,
+        moe_num_active_primary_experts=6, sliding_window_size=4096,
+        num_local_experts=16)
+    paddle.seed(0)
+    block = paddle.amp.decorate(moe_decoder.MoeDecoderLayer(config, layer_idx),
+                                level="O2", dtype="bfloat16")
+    p = {k: jax.ShapeDtypeStruct(tuple(v.shape), v.dtype)
+         for k, v in block.state_dict().items()}
+    x = jax.ShapeDtypeStruct((1, 16384, 2560), jnp.bfloat16)
+
+    def loss(p, x):
+        out, counts, rows, unserved = functional_call(block, p, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2), (counts, unserved)
+
+    before = pk.traced_call_sums()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)) \
+        .trace(p, x).lower(lowering_platforms=("tpu",)).as_text()
+    sums = {k: v - before.get(k, 0) for k, v in pk.traced_call_sums().items()}
+    assert sums["flash_calls"] == 1 and sums["flash_operands_in_place"] == 8
+    assert not sums.get("flash_operands_copied")
+    assert sums["moe_run_sum_calls"] == 4           # two buckets, each way
+    assert not sums.get("moe_run_sum_calls_composed")
+    names = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert sorted(set(n for n in names if "flash" in n)) \
+        == [f"{stem}_bwd_dq_dkv", f"{stem}_fwd"]
+    assert names.count("moe_run_sum") == 4
+    assert "ragged_dot" not in text
+    # layer 0 rotates nothing: no cosine table is baked into its program
+    tables = re.findall(r"stablehlo\.constant .*tensor<16384x64xf32>", text)
+    assert len(tables) == (2 if layer_idx == 1 else 0)
 
 
 # ------------------------------------------- latent attention's expansion --
