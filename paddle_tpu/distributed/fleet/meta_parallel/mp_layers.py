@@ -10,6 +10,12 @@ column-parallel keeps activations sharded on the feature dim, row-parallel
 emits the all-reduce after the partial matmul.  Inside an explicit shard_map
 region the layers fall back to hand-written lax collectives, matching the
 reference semantics op-for-op.
+
+The layers TAG their results (``fleet.recompute.tagged``,
+:data:`SAVED_BY_NAME`): a matmul's product, and behind a row-parallel one the
+``mp`` all-reduce GSPMD put there, are dear to make again, so a rematerialised
+block keeps them by name (``remat=True`` of either trainer, or a list that
+holds the names).  A step that keeps none of them holds no tag.
 """
 
 import jax
@@ -21,6 +27,7 @@ from ....nn import functional as F
 from ....nn.initializer import XavierUniform, Normal
 from ....nn.layer_base import Layer
 from ....ops.registry import op
+from ..recompute import PROJECTIONS as SAVED_BY_NAME, tagged
 
 
 def _in_shard_map(axis):
@@ -58,7 +65,7 @@ class ColumnParallelLinear(Layer):
         out = F.linear(x, self.weight, self.bias)
         if not self.gather_output:
             out = _shard_hint(out, ("mp",), dim=-1)
-        return out
+        return tagged(out, SAVED_BY_NAME[0])
 
 
 class RowParallelLinear(Layer):
@@ -84,7 +91,7 @@ class RowParallelLinear(Layer):
             self.bias = None
 
     def forward(self, x):
-        return F.linear(x, self.weight, self.bias)
+        return tagged(F.linear(x, self.weight, self.bias), SAVED_BY_NAME[1])
 
 
 class VocabParallelEmbedding(Layer):

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from ..distributed.fleet.recompute import remat_kept
 from ..framework import mode
 from ..framework.random import get_rng_key, key_stream
 from ..nn.layer_base import Layer, block_remat
@@ -291,15 +292,20 @@ class TrainStep:
         step = TrainStep(model, loss_fn, opt)
         loss = step(batch_x, batch_y)      # Tensors in, loss Tensor out
 
-    ``remat``: activation rematerialisation BY BLOCK.  Every layer that a
-    ``LayerList`` of the model holds (its repeated blocks) runs under
-    ``fleet.recompute`` inside the step: its forward is run again in the
-    backward pass, so one block's activations are live at a time.  ``True``
-    saves nothing of a block; a policy name of ``fleet.recompute`` or a
-    list of ``checkpoint_name`` tags (``["flash_attention_out",
-    "flash_attention_lse"]``: the flash kernel is not run twice) keeps what
-    it names.  A block must hand on what it makes through its outputs, not
-    through attributes.
+    ``remat``: activation rematerialisation BY BLOCK, as
+    ``parallel.SpmdTrainStep`` has it (``distributed/fleet/recompute.py``'s
+    header is the one description).  Every layer that a ``LayerList`` of
+    the model holds (its repeated blocks) runs under ``fleet.recompute``
+    inside the step: its forward is run again in the backward pass, so one
+    block's activations are live at a time.  ``True`` runs it again EXCEPT
+    what the block tagged as dear to make again (``recompute.KEPT_BY_BLOCK``:
+    its parallel projections' and its attention kernels' results);
+    ``"full"`` keeps nothing of a block; a policy name of
+    ``fleet.recompute`` or a list of ``checkpoint_name`` tags
+    (``["flash_attention_out", "flash_attention_lse"]``: the flash kernel is
+    not run twice) keeps what it names.  ``compile_account()["remat_kept"]``
+    says which.  A block must hand on what it makes through its outputs,
+    not through attributes.
 
     Counters: a model that defines ``step_counters()`` (a dict of small
     arrays its last forward made, e.g. the tokens each expert received)
@@ -319,7 +325,7 @@ class TrainStep:
         self._opt_state = optimizer.init_state_pytree(self._params)
         self._step = 0
         self._compiled = None
-        self._trace = StepTrace()
+        self._trace = StepTrace(remat_kept=remat_kept(remat))
         self._donate = donate
         self.counters = {}      # the last step's, see the class docstring
         # loss scaling composed INTO the compiled step (reference
@@ -339,9 +345,8 @@ class TrainStep:
         optimizer = self.optimizer
         grad_clip = optimizer._grad_clip
         counters_of = getattr(model, "step_counters", None)
-        # ``True`` saves nothing of a block; a policy name or a list of
-        # ``checkpoint_name`` tags is handed on (``fleet.recompute``)
-        remat = "full" if self.remat is True else (self.remat or None)
+        # handed on to ``fleet.recompute`` round each block as it stands
+        remat = self.remat or None
 
         def make_loss_f(frozen, key, inputs, labels):
             """``loss_f(params) -> (loss, counters)``."""

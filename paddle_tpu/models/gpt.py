@@ -24,6 +24,7 @@ from ..distributed.fleet.meta_parallel.mp_layers import (
     RowParallelLinear,
     VocabParallelEmbedding,
 )
+from ..distributed.fleet.recompute import PROJECTIONS, tagged
 from ..nn import functional as F
 from ..nn.initializer import Normal
 from ..nn.layer_base import ParamAttr
@@ -39,8 +40,11 @@ def _qkv_by_heads(x, weight, bias):
     axis over ``mp`` q, k and v leave the matmul sharded by heads, as the
     flash ``shard_map`` takes them, and no collective moves the
     activation, its gradient or the weight; each output column is still
-    one full-``h`` dot on one chip."""
-    qkv = jnp.einsum("bth,hcnd->btcnd", x, weight) + bias
+    one full-``h`` dot on one chip.  The product is tagged as the
+    column-parallel layers tag theirs (``fleet.recompute.PROJECTIONS``),
+    for a rematerialised block to keep."""
+    qkv = tagged(jnp.einsum("bth,hcnd->btcnd", x, weight) + bias,
+                 PROJECTIONS[2])
     return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
 

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.tensor import Tensor
+from ..distributed.fleet.recompute import checkpointed, remat_kept
 from ..distributed.fleet.spmd import data_axes, use_mesh
 from ..framework.random import get_rng_key, key_stream
 from ..profiler import StepTrace
@@ -158,6 +159,17 @@ class SpmdTrainStep:
     ``self.opt_state`` carry that shape.  ``state_dict()`` and
     ``sync_to_model()`` give the model's stored layout back.
 
+    ``remat``: activation rematerialisation BY BLOCK, as ``jit.TrainStep``
+    has it (``distributed/fleet/recompute.py``'s header is the one
+    description): ``block_fn`` runs under ``jax.checkpoint`` in the layer
+    scan, so its forward is run again in the backward pass.  ``True`` runs
+    it again EXCEPT what the block tagged as dear to make again
+    (``recompute.KEPT_BY_BLOCK``: its parallel projections' results, the
+    row-parallel ones' ``mp`` all-reduce with them, and its attention
+    kernels'); ``"full"`` keeps nothing of a block; a policy name of
+    ``fleet.recompute`` or a list of ``checkpoint_name`` tags keeps what it
+    names.  ``compile_account()["remat_kept"]`` says which.
+
     The step is compiled with ``compile_options(mesh)``: on a mesh of more
     than one TPU device ``ASYNC_ALL_REDUCE``, which turns the all-reduces
     GSPMD put in into async collective fusions that run beside the
@@ -278,7 +290,7 @@ class SpmdTrainStep:
         self.batch_sharding = NamedSharding(mesh, P(self._batch_axes))
         self._step_count = 0
         self._compiled = None
-        self._trace = StepTrace()
+        self._trace = StepTrace(remat_kept=remat_kept(remat))
 
     # ---- the step program ----
     def _build(self):
@@ -292,7 +304,7 @@ class SpmdTrainStep:
             else P(self._batch_axes, None, None)
         blk = block_fn
         if self.remat:
-            blk = jax.checkpoint(block_fn)
+            blk = checkpointed(block_fn, self.remat)
 
         def forward(params, input_ids, labels, key):
             key, pipe_key = jax.random.split(key)

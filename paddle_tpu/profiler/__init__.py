@@ -406,8 +406,11 @@ class StepTrace:
     call's record of the compile log, the newest, with the step's
     ``flash_calls``, ``flash_operands_in_place`` and
     ``flash_operands_copied`` (``ops.pallas.record_flash_layout``), its
-    ``ssd_calls`` / ``ssd_calls_composed`` (``ops.pallas.ssd_scan``) and the
-    compiled text's ``collectives_async`` / ``collectives_sync``.  Every
+    ``ssd_calls`` / ``ssd_calls_composed`` (``ops.pallas.ssd_scan``), the
+    compiled text's ``collectives_async`` / ``collectives_sync`` and
+    ``remat_kept``: what the trainer's ``remat`` keeps of a rematerialised
+    block (``fleet.recompute.remat_kept``: the tags, a policy's name, or
+    ``None`` where nothing is rematerialised).  Every
     span carries ``step``.  ``::init`` covers the trainer's construction.
 
     The flight record (docs/PROFILER.md, "The step's flight record") is
@@ -440,9 +443,10 @@ class StepTrace:
     log = collections.deque(maxlen=KEEP)
     _lock = threading.Lock()
 
-    def __init__(self):
+    def __init__(self, remat_kept=None):
         self.compiles = 0
         self.account = None
+        self.remat_kept = remat_kept    # the trainer's, for the account
         self.long_steps = 0
         self._intervals = collections.deque(maxlen=self.MEDIAN_OF)
         self._record = {}       # the newest call's
@@ -569,6 +573,7 @@ class StepTrace:
             # state-space scans, and those the composition served
             rec.update({k: n - traced[k]
                         for k, n in traced_call_sums().items()})
+            rec["remat_kept"] = self.remat_kept
             with RecordEvent(self.COMPILED, step=step, cache=rec["cache"],
                              backend_s=rec["backend_s"],
                              temp_bytes=rec.get("temp_bytes")):

@@ -743,3 +743,134 @@ class TestCompiledCollectives:
             trainer.lower(ids, labels).compile().as_text())
         assert found and not any(asynchronous
                                  for _, _, asynchronous in found)
+
+
+# ---- what a rematerialised block keeps (PR 52) ----------------------------
+REMATS = {"plain": False, "tagged": True, "full": "full",
+          "names": ["row_parallel_out", "column_parallel_out",
+                    "column_parallel_by_heads_out"]}
+
+
+@pytest.fixture(scope="module")
+def one_step_by_remat():
+    """For each ``remat``: one step of the dp2 x mp2 trainer on the same
+    model and batch: the loss, the state after it (the updated parameters
+    and AdamW's first moment, 0.1 x the gradient after one step) and the
+    compiled step's text."""
+    import re
+
+    from paddle_tpu.parallel import compiled_collectives
+
+    ids, labels = make_batch(batch=4)
+    out = {}
+    for key, remat in REMATS.items():
+        trainer, _ = _tiny_trainer(2, 2, remat=remat)
+        loss = np.asarray(trainer.step(ids, labels)._data)
+        state = jax.tree_util.tree_map(np.asarray, trainer.state_dict())
+        text = trainer.lower(ids, labels).compile().as_text()
+        out[key] = {"loss": loss, "state": state,
+                    "dots": len(re.findall(r" dot\(", text)),
+                    "all_reduces": len(compiled_collectives(text)),
+                    "kept": trainer.compile_account()["remat_kept"]}
+    return out
+
+
+class TestRematKeepsByName:
+    @pytest.mark.parametrize("key", list(REMATS))
+    def test_remat_changes_no_bit_of_loss_or_gradient(self,
+                                                      one_step_by_remat, key):
+        """A kept value is the bits the re-run would make: the loss, every
+        updated leaf and every leaf's first moment (the gradient's image)
+        are those of the plain step, bit for bit."""
+        got, want = one_step_by_remat[key], one_step_by_remat["plain"]
+        assert got["loss"].tobytes() == want["loss"].tobytes()
+        leaves, tree = jax.tree_util.tree_flatten(got["state"])
+        want_leaves, want_tree = jax.tree_util.tree_flatten(want["state"])
+        assert tree == want_tree and len(leaves) > 40
+        for a, b in zip(leaves, want_leaves):
+            assert a.tobytes() == b.tobytes()
+
+    def test_kept_projections_leave_the_backward_body(self,
+                                                     one_step_by_remat):
+        """``"full"`` runs a block's every matmul again and the row-parallel
+        ``proj``'s ``mp`` all-reduce with them.  A list that names all three
+        projections' tags keeps ``qkv``, ``proj`` and ``fc_in`` out of the
+        backward body (``fc_out``'s result feeds the residual add alone:
+        the backward never asked for it) and the re-run all-reduce with
+        them; ``True`` keeps those of them that ``KEPT_BY_BLOCK`` lists.
+        The attention scores' two products stay in every rematerialised
+        form (the XLA composition off the chip tags nothing)."""
+        from paddle_tpu.distributed.fleet.recompute import (KEPT_BY_BLOCK,
+                                                            PROJECTIONS)
+
+        full, tagged, names, plain = (
+            one_step_by_remat[k] for k in ("full", "tagged", "names", "plain"))
+        assert names["dots"] == full["dots"] - 3
+        assert names["all_reduces"] == full["all_reduces"] - 1
+        kept = [tag for tag in PROJECTIONS if tag in KEPT_BY_BLOCK]
+        assert "row_parallel_out" in kept and len(kept) >= 2
+        assert tagged["dots"] == full["dots"] - len(kept)
+        assert tagged["all_reduces"] == full["all_reduces"] - 1
+        assert plain["dots"] == names["dots"] - 2       # the scores' two
+        assert plain["all_reduces"] == names["all_reduces"]
+
+    @pytest.mark.parametrize("key", list(REMATS))
+    def test_the_account_names_what_the_step_keeps(self, one_step_by_remat,
+                                                   key):
+        from paddle_tpu.distributed.fleet.meta_parallel import mp_layers
+        from paddle_tpu.distributed.fleet.recompute import KEPT_BY_BLOCK
+        from paddle_tpu.ops.pallas.attention_kernel import SAVED_BY_NAME
+
+        want = {"plain": None, "tagged": list(KEPT_BY_BLOCK), "full": "full",
+                "names": REMATS["names"]}[key]
+        assert one_step_by_remat[key]["kept"] == want
+        assert set(SAVED_BY_NAME) <= set(KEPT_BY_BLOCK)
+        assert set(KEPT_BY_BLOCK) & set(mp_layers.SAVED_BY_NAME)
+
+    @pytest.mark.parametrize("which", ["spmd", "train"])
+    def test_an_unknown_policy_raises_in_both_trainers(self, which):
+        """One function resolves ``remat`` for both trainers
+        (``fleet.recompute._resolve_policy``), at construction."""
+        from paddle_tpu.jit import TrainStep
+
+        paddle.seed(0)
+        model = gpt_tiny(num_layers=2)
+        opt = optimizer.AdamW(learning_rate=1e-3,
+                              parameters=model.parameters())
+        with pytest.raises(ValueError, match="unknown recompute policy"):
+            if which == "spmd":
+                _tiny_trainer(2, 2, model=model, remat="keep_everything")
+            else:
+                TrainStep(model, lambda lg, lb: model.loss(lg, lb), opt,
+                          remat="keep_everything")
+
+
+def test_the_sweep_tool_runs_the_benchmark_under_the_tags_it_is_given(
+        monkeypatch):
+    """``tools/remat_kept_run.py --keep a,b <benchmark arguments>`` sets
+    what ``remat=True`` keeps (``recompute.KEPT_BY_BLOCK``) and hands the
+    other arguments to ``chipbench.run.main`` in the same process."""
+    import importlib.util
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "remat_kept_run", os.path.join(root, "tools", "remat_kept_run.py"))
+    tool = importlib.util.module_from_spec(spec)
+    monkeypatch.setattr(sys, "path", list(sys.path))
+    spec.loader.exec_module(tool)
+    recompute = sys.modules["paddle_tpu.distributed.fleet.recompute"]
+    monkeypatch.setattr(recompute, "KEPT_BY_BLOCK", recompute.KEPT_BY_BLOCK)
+    seen = []
+    monkeypatch.setattr(
+        tool.bench, "main",
+        lambda argv: seen.append((argv, recompute.KEPT_BY_BLOCK,
+                                  recompute.remat_kept(True))) or 0)
+    assert tool.main(["--keep", "row_parallel_out,flash_attention_out",
+                      "--workload", "w", "--seed", "3"]) == 0
+    assert seen == [(["--workload", "w", "--seed", "3"],
+                     ("row_parallel_out", "flash_attention_out"),
+                     ["row_parallel_out", "flash_attention_out"])]
+    assert tool.main(["--keep", "", "--trace", "1"]) == 0
+    assert seen[1][1] == () and seen[1][2] == []

@@ -270,14 +270,34 @@ def _dp_mp_mesh(**degrees):
     return build_mesh(devices=jax.devices()[:n], **degrees)
 
 
-@pytest.mark.parametrize("form", ["plain", "checkpoint_in_scan"])
+def _kernel_calls(jaxpr, name):
+    """How many ``pallas_call`` equations named ``name`` a jaxpr holds,
+    sub-jaxprs included (a scan's body once)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" and \
+                eqn.params["name"] == name:
+            found += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _kernel_calls(sub, name)
+    return found
+
+
+@pytest.mark.parametrize("form", ["plain", "checkpoint_in_scan",
+                                  "checkpoint_by_name_in_scan"])
 def test_flash_attention_sharded_matches_xla(form):
     """The kernels inside the dispatcher's shard_map on the four-axis mesh
     ``build_mesh(dp=2, mp=2)`` gives (2 x 1 x 1 x 2: two rows, four heads)
     against the XLA composition, in value and in the gradients the
     custom_vjp gives per shard; ``checkpoint_in_scan`` is the form
-    ``SpmdTrainStep(remat=True)`` puts it in (a rematerialised block
-    inside the scan over layers), where the forward kernel runs twice."""
+    ``SpmdTrainStep(remat="full")`` puts it in (a rematerialised block
+    inside the scan over layers that keeps nothing), where the forward
+    kernel runs twice; ``checkpoint_by_name_in_scan`` is the form
+    ``SpmdTrainStep(remat=True)`` builds since PR 52 (the checkpoint with
+    the by-name policy of ``fleet.recompute``: the forward's tagged
+    output and row statistics cross the ``shard_map`` and the scan as
+    residuals, and the forward kernel runs once)."""
+    from paddle_tpu.distributed.fleet.recompute import _resolve_policy
     from paddle_tpu.distributed.fleet.spmd import use_mesh
     from paddle_tpu.ops.pallas import flash_attention_sharded
 
@@ -287,20 +307,29 @@ def test_flash_attention_sharded_matches_xla(form):
     q, k, v, w = (_rand((2, 128, 4, 32), s) for s in (40, 41, 42, 43))
 
     def loss(attn):
-        def layer(h, _):
-            return h + attn(q + h, k, v), None
-
         def f(q, k, v):
             if form == "plain":
                 return jnp.sum(attn(q, k, v) * w)
-            h, _ = jax.lax.scan(jax.checkpoint(layer), jnp.zeros_like(q),
-                                None, length=2)
+
+            def layer(h, _):        # over f's own q, k, v: they get gradients
+                return h + attn(q + h, k, v), None
+
+            policy = _resolve_policy(
+                True if form == "checkpoint_by_name_in_scan" else "full")
+            h, _ = jax.lax.scan(jax.checkpoint(layer, policy=policy),
+                                jnp.zeros_like(q), None, length=2)
             return jnp.sum(h * w)
         return jax.value_and_grad(f, argnums=(0, 1, 2))
 
     with use_mesh(mesh):
-        got, grads = jax.jit(loss(lambda q, k, v: flash_attention_sharded(
-            q, k, v, True, mesh, interpret=True)))(q, k, v)
+        step = jax.jit(loss(lambda q, k, v: flash_attention_sharded(
+            q, k, v, True, mesh, interpret=True)))
+        got, grads = step(q, k, v)
+        if form != "plain":
+            # the by-name form's backward body holds no forward kernel
+            forwards = _kernel_calls(step.trace(q, k, v).jaxpr.jaxpr,
+                                     "flash_attention_fwd")
+            assert forwards == (1 if "by_name" in form else 2), forwards
     want, ref = loss(lambda q, k, v: _xla_attention(
         q, k, v, is_causal=True))(q, k, v)
     np.testing.assert_allclose(got, want, rtol=2e-4)

@@ -193,14 +193,20 @@ def _gpt3_width(**kw):
                      attention_probs_dropout_prob=0.0, **kw)
 
 
-def test_dp_mp_step_with_the_viewed_qkv_lowers_for_tpu(monkeypatch):
+@pytest.mark.parametrize("remat,forwards", [(True, 1), ("full", 2)],
+                         ids=["tagged", "full"])
+def test_dp_mp_step_with_the_viewed_qkv_lowers_for_tpu(monkeypatch, remat,
+                                                       forwards):
     """The four-chip cell's whole step (one layer of it: the layers are a
     scan) at ``[4, 2048]`` tokens under dp=2 x mp=2, rematerialised, on
     the kernel path: the trainer holds the fused q|k|v viewed ``[L, h, 3,
     heads, head_dim]``, heads over ``mp``, the TPU lowering takes the step
     with the flash kernels inside, and NO tensor of the program is 12,288
     wide any more: neither activation, gradient nor weight is ever in the
-    form whose halves are no set of heads."""
+    form whose halves are no set of heads.  Under ``remat=True`` (the
+    cell's) the flash forward's tagged results are kept and the layer body
+    holds the forward kernel ONCE; under ``"full"`` it runs again in the
+    backward pass, twice a layer."""
     import numpy as np
 
     import paddle_tpu as paddle
@@ -217,7 +223,7 @@ def test_dp_mp_step_with_the_viewed_qkv_lowers_for_tpu(monkeypatch):
     opt = optimizer.AdamW(learning_rate=1.2e-4,
                           parameters=model.parameters())
     mesh = build_mesh(dp=2, mp=2, devices=jax.devices()[:4])
-    trainer = SpmdTrainStep(model, opt, mesh, remat=True)
+    trainer = SpmdTrainStep(model, opt, mesh, remat=remat)
     held = trainer.params["blocks"]["attn.qkv.weight"]
     assert held.shape == (1, 4096, 3, 32, 128)
     assert {s.data.shape for s in held.addressable_shards} \
@@ -228,8 +234,8 @@ def test_dp_mp_step_with_the_viewed_qkv_lowers_for_tpu(monkeypatch):
     with use_mesh(mesh):
         text = trainer._compiled.trace(*args).lower(
             lowering_platforms=("tpu",)).as_text()
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq_dkv"):
-        assert f'kernel_name = "{kernel}"' in text, kernel
+    assert text.count('kernel_name = "flash_attention_fwd"') == forwards
+    assert text.count('kernel_name = "flash_attention_bwd_dq_dkv"') == 1
     assert "1x4096x3x32x128xbf16" in text
     assert "12288" not in text
     # the boundary still speaks the stored layout
